@@ -27,7 +27,7 @@ capability stack of the s-udhaya/distributed-deep-learning-workshop reference
 - ``ddw_tpu.serving``   — packaged-model format + distributed batch scorer
                           (MLflow pyfunc / spark_udf roles) (in progress this round).
 
-The behavioral contract is documented in /root/repo/SURVEY.md; reference file:line
+The behavioral contract is documented in SURVEY.md; reference file:line
 citations appear in each module's docstring.
 """
 

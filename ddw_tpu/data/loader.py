@@ -14,9 +14,9 @@ Two semantics are load-bearing (SURVEY.md §2b.8, §7 hard-part 2):
 This loader reads ddw_tpu table shards directly (no intermediate cache: the store's
 codec *is* the cache format), decodes/resizes JPEGs per batch in the native C++
 pipeline (:mod:`ddw_tpu.native.decode` — libjpeg + std::thread pool, one GIL
-release per batch; PIL thread-pool fallback — the tf.data/petastorm worker-pool
-role), and prefetches batches to device HBM on a background thread (double
-buffering), so the TPU never waits on host IO.
+release per batch — the tf.data/petastorm worker-pool role), and prefetches
+batches to device HBM on a background thread (double buffering), so the TPU
+never waits on host IO.
 
 Preprocessing is THE shared implementation for training and serving —
 :func:`preprocess_image` is the single decode path ``ddw_tpu.serving`` packages with
@@ -118,12 +118,11 @@ def _dequant_jitted():
 
 
 def active_decoder() -> str:
-    """Which decode impl :func:`preprocess_image` dispatches to here: ``native``
-    (libjpeg pipeline) or ``pil``. Serving packages record this at save time and
-    warn when the serving environment resolves differently (decoder skew)."""
-    from ddw_tpu.native.decode import native_available
-
-    return "native" if native_available() else "pil"
+    """Which decode impl :func:`preprocess_image` uses: ``native`` (the libjpeg
+    pipeline; it builds or raises). Serving packages record this at save time
+    and warn when they were trained under another decoder (packages written by
+    earlier builds may say ``pil``)."""
+    return "native"
 
 
 def preprocess_image(content: bytes, height: int, width: int) -> np.ndarray:
@@ -133,13 +132,10 @@ def preprocess_image(content: bytes, height: int, width: int) -> np.ndarray:
     (the ``tf.image.decode_jpeg`` + ``resize`` + ``preprocess_input`` chain,
     reference ``02_model_training_single_node.py:119-126``). Single
     implementation shared by the training loader and the packaged model's
-    predict path. Dispatches to the native libjpeg pipeline
-    (:mod:`ddw_tpu.native.decode` — point-sampled bilinear, the
-    ``tf.image.resize`` semantics of the reference) when built, else PIL
-    (area-filtered bilinear); both sides of train/serve go through this same
-    dispatch, so train and serve agree whenever both environments resolve the
-    same impl; :func:`active_decoder` + the serving package manifest surface
-    the case where they don't.
+    predict path: the native libjpeg pipeline (:mod:`ddw_tpu.native.decode` —
+    point-sampled bilinear, the ``tf.image.resize`` semantics of the
+    reference), with PIL (area-filtered bilinear) for an image libjpeg
+    refuses. Both sides of train/serve go through this same dispatch.
     """
     from ddw_tpu.native.decode import decode_one_native
 
@@ -359,7 +355,7 @@ class ShardedLoader:
         return it
 
     def _iter_batches(self) -> Iterator[tuple[np.ndarray, np.ndarray]]:
-        from ddw_tpu.native.decode import decode_batch_native, native_available
+        from ddw_tpu.native.decode import decode_batch_native
 
         if self._token_len:
             # Token fast path: yield next-token pairs [B, S] — the LM step's
@@ -415,47 +411,24 @@ class ShardedLoader:
 
         imgs = np.empty((self.batch_size, self.height, self.width, 3), np.float32)
 
-        if native_available():
-            # Native batch path: one C++ thread-pool call per batch (one GIL
-            # release, real OS-thread decode parallelism); per-image failures
-            # fall back to PIL.
-            contents: list[bytes] = []
-            for content, label_idx in self._iter_raw_resumed():
-                lbls[len(contents)] = label_idx
-                contents.append(content)
-                if len(contents) == self.batch_size:
-                    _, ok = decode_batch_native(
-                        contents, self.height, self.width,
-                        threads=self.workers, out=imgs)
-                    for j in np.nonzero(~ok)[0]:
-                        imgs[j] = _preprocess_image_pil(
-                            contents[j], self.height, self.width)
-                    yield imgs.copy(), lbls.copy()
-                    contents = []
-            return  # drop remainder: static shapes for XLA
-
-        # PIL path: decode on a Python thread pool (PIL releases the GIL in its
-        # C decode, so threads still overlap).
-        pool = ThreadPoolExecutor(max_workers=self.workers)
-        try:
-            def decode(entry):
-                content, label_idx = entry
-                return (
-                    preprocess_image(content, self.height, self.width),
-                    np.int32(label_idx),
-                )
-
-            i = 0
-            for img, lbl in bounded_map(pool, decode, self._iter_raw_resumed(),
-                                        self.workers * 4):
-                imgs[i], lbls[i] = img, lbl
-                i += 1
-                if i == self.batch_size:
-                    yield imgs.copy(), lbls.copy()
-                    i = 0
-            # drop remainder: static shapes for XLA
-        finally:
-            pool.shutdown(wait=False, cancel_futures=True)
+        # One C++ thread-pool call per batch (one GIL release, real OS-thread
+        # decode parallelism); an image libjpeg refuses is re-decoded by PIL.
+        # A library that cannot be built raises here (native/build.py) — the
+        # loader has no slower path to drop to.
+        contents: list[bytes] = []
+        for content, label_idx in self._iter_raw_resumed():
+            lbls[len(contents)] = label_idx
+            contents.append(content)
+            if len(contents) == self.batch_size:
+                _, ok = decode_batch_native(
+                    contents, self.height, self.width,
+                    threads=self.workers, out=imgs)
+                for j in np.nonzero(~ok)[0]:
+                    imgs[j] = _preprocess_image_pil(
+                        contents[j], self.height, self.width)
+                yield imgs.copy(), lbls.copy()
+                contents = []
+        # drop remainder: static shapes for XLA
 
     def __iter__(self):
         """Yield batches; when ``prefetch_to`` is set, a background thread runs the

@@ -76,25 +76,21 @@ def _write_shard(path: str, records: list[Record]) -> dict:
 
 
 def _native_reader():
-    """Resolve the native codec module, or None (unavailable / disabled via
-    ``DDW_NATIVE_CODEC=0``). Only resolution failures select the Python
-    fallback; parse errors from an available native codec propagate."""
+    """The native codec module, or None when ``DDW_NATIVE_CODEC=0`` selects
+    the pure-Python framing. A codec that cannot be built raises, and parse
+    errors from it propagate — neither drops to the Python path."""
     if os.environ.get("DDW_NATIVE_CODEC", "1") == "0":
         return None
-    try:
-        from ddw_tpu.native import codec as native_codec
+    from ddw_tpu.native import codec as native_codec
 
-        return native_codec if native_codec.native_available() else None
-    except Exception:
-        return None
+    return native_codec
 
 
 def read_shard(path: str) -> Iterator[Record]:
     """Stream records from one shard file.
 
-    Prefers the C++ codec (``ddw_tpu/native``, one index pass over the buffer)
-    when it builds/loads; falls back to the pure-Python framing. Disable with
-    ``DDW_NATIVE_CODEC=0``."""
+    Uses the C++ codec (``ddw_tpu/native``, one index pass over the buffer);
+    ``DDW_NATIVE_CODEC=0`` selects the pure-Python framing instead."""
     native = _native_reader()
     if native is not None:
         # Errors from an available native parser propagate: swallowing them
@@ -108,7 +104,8 @@ def read_shard(path: str) -> Iterator[Record]:
 
 def read_shard_contents(path: str) -> Iterator[tuple[bytes, int]]:
     """Loader hot path: yield (content, label_idx) only — no path/label string
-    decoding, no Record objects. Native C++ index pass when available."""
+    decoding, no Record objects. Native C++ index pass unless
+    ``DDW_NATIVE_CODEC=0``."""
     native = _native_reader()
     if native is not None:
         yield from native.read_shard_contents_native(path)

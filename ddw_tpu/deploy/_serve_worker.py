@@ -77,14 +77,15 @@ def main(argv=None) -> int:
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel degree: this replica spans a "
                         "tp-wide model-axis mesh slice (folded into "
-                        "EngineCfg.tp; on the CPU host platform the flag "
+                        "EngineCfg.tp; with JAX_PLATFORMS=cpu the flag "
                         "also forces tp fake devices before jax loads)")
     args = p.parse_args(argv)
 
-    if args.tp > 1 and os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
+    if args.tp > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
         # must land before ANY jax import: the host platform mints its
         # device count at backend init, so a TP slice of fake CPU devices
-        # (tests, laptops) exists only if the flag precedes the import
+        # (tests, laptops — chosen by their environment, never defaulted
+        # here) exists only if the flag precedes the import
         flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
                  if "xla_force_host_platform_device_count" not in f]
         flags.append(f"--xla_force_host_platform_device_count={args.tp}")
@@ -94,6 +95,16 @@ def main(argv=None) -> int:
     from ddw_tpu.gateway.http import Gateway
     from ddw_tpu.serve.engine import EngineCfg, ServingEngine
     from ddw_tpu.serving.lm_package import load_lm_package
+    from ddw_tpu.utils.compile_cache import enable_compile_cache
+
+    import jax
+
+    enable_compile_cache()
+    dev = jax.devices()
+    # the parent reads this line from the child log: which device serves
+    print(f"[serve_worker] replica={args.replica_id} "
+          f"platform={dev[0].platform} device_kind={dev[0].device_kind!r} "
+          f"devices={len(dev)}", flush=True)
 
     pkg = load_lm_package(args.model_dir)
     draft = load_lm_package(args.draft_dir) if args.draft_dir else None

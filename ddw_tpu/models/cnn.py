@@ -1,7 +1,7 @@
 """SmallCNN — a fast from-scratch CNN for tests and CPU-capable runs.
 
 Fills the "small CNN, flowers JPEG subset, CPU, 1 epoch" baseline config
-(/root/repo/BASELINE.json configs[0]) and keeps the unit-test suite fast. Same
+(BASELINE.json configs[0]) and keeps the unit-test suite fast. Same
 head contract as MobileNetV2 (GAP -> Dropout -> Dense logits) so the trainer and
 serving paths are model-agnostic. Stateless normalization (GroupNorm) — no
 batch_stats collection — so seeded 1-device vs N-device equivalence tests are exact.

@@ -26,8 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ddw_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 from ddw_tpu.ops.flash_attention import flash_mha
 from ddw_tpu.parallel.ring_attention import ring_attention

@@ -56,26 +56,24 @@ class ConvBN(nn.Module):
     bn_momentum: float = 0.9
     dtype: Any = jnp.bfloat16
     s2d: bool = False  # stem trick: identical math, MXU-friendly channel depth
-    dw_impl: str = "xla"  # depthwise layers: "xla" grouped conv, "pallas"
-                          # (ddw_tpu.ops.depthwise_conv — auto-dispatch: Pallas
-                          # for stride-1 on TPU, XLA elsewhere), or
-                          # "pallas_interpret" (test-only CPU interpreter)
+    dw_impl: str = "xla"  # depthwise layers: "xla" grouped conv, or
+                          # "pallas" — the ddw_tpu.ops.depthwise_conv kernel
+                          # on every stride-1 layer (the kernel has no strided
+                          # form; the four stride-2 layers keep XLA)
 
     @nn.compact
     def __call__(self, x, train: bool):
-        if self.dw_impl not in ("xla", "pallas", "pallas_interpret"):
+        if self.dw_impl not in ("xla", "pallas"):
             raise ValueError(f"unknown dw_impl {self.dw_impl!r}")
         depthwise = self.groups > 1 and self.groups == x.shape[-1]
-        if (depthwise and self.dw_impl != "xla" and self.kernel == (3, 3)):
+        if (depthwise and self.dw_impl == "pallas" and self.kernel == (3, 3)):
             from ddw_tpu.ops.depthwise_conv import DepthwiseConv3x3
 
-            interp = self.dw_impl == "pallas_interpret" and self.strides == 1
             # Same param path/shape as the nn.Conv branch (see module doc).
-            x = DepthwiseConv3x3(self.features, strides=self.strides,
-                                 dtype=self.dtype,
-                                 impl="pallas" if interp else "auto",
-                                 interpret=interp,
-                                 name="Conv_0")(x)
+            x = DepthwiseConv3x3(
+                self.features, strides=self.strides, dtype=self.dtype,
+                impl="pallas" if self.strides == 1 else "xla",
+                name="Conv_0")(x)
         else:
             from ddw_tpu.ops.s2d_conv import conv_or_s2d
 

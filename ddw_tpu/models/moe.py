@@ -40,8 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ddw_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 
 def collect_sown(mods: dict, name: str) -> list:

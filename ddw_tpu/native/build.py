@@ -1,9 +1,10 @@
 """Shared lazy g++ build/load for the native components (codec, decode pipeline).
 
-The toolchain (g++) is part of the environment contract; pybind11 is not, so all
-native modules use a plain C ABI loaded via ctypes. Build failures latch and
-callers fall back to pure-Python paths — native is a performance tier, never a
-correctness dependency.
+The toolchain (g++, libjpeg) is part of the environment contract; pybind11 is
+not, so all native modules use a plain C ABI loaded via ctypes. A library that
+cannot be built or loaded is an error carrying the compiler's own message —
+callers never drop to a slower Python path on their own: a run that quietly
+decoded through PIL would still look healthy, only slower.
 """
 
 from __future__ import annotations
@@ -14,12 +15,17 @@ import subprocess
 import threading
 
 
+class NativeBuildError(RuntimeError):
+    """g++ refused the source, or the built library would not load."""
+
+
 class LazyLibrary:
     """Builds ``src`` -> ``lib`` with g++ on first use (if stale), then loads it.
 
     ``configure(cdll)`` sets restype/argtypes once after load. Thread-safe;
     concurrent processes build to a per-pid temp path and ``os.replace`` so no
-    process ever dlopens a half-written .so.
+    process ever dlopens a half-written .so. A failure latches: every later
+    :meth:`load` re-raises the first error without re-running the compiler.
     """
 
     def __init__(self, src: str, lib: str, extra_flags: tuple[str, ...] = (),
@@ -30,46 +36,51 @@ class LazyLibrary:
         self.configure = configure
         self._lock = threading.Lock()
         self._lib: ctypes.CDLL | None = None
-        self._failed = False
+        self._error: NativeBuildError | None = None
 
-    def _build(self) -> bool:
+    def _build(self) -> None:
         tmp = f"{self.lib_path}.{os.getpid()}.tmp"
+        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", self.src,
+               "-o", tmp, *self.extra_flags]
         try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", self.src,
-                 "-o", tmp, *self.extra_flags],
-                check=True, capture_output=True, timeout=120)
+            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp, self.lib_path)
-            return True
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-            return False
+            stderr = getattr(e, "stderr", None) or b""
+            raise NativeBuildError(
+                f"native build failed: {' '.join(cmd)}\n{e}\n"
+                f"{stderr.decode(errors='replace')}") from e
 
-    def load(self) -> ctypes.CDLL | None:
+    def load(self) -> ctypes.CDLL:
         with self._lock:
-            if self._lib is not None or self._failed:
+            if self._error is not None:
+                raise self._error
+            if self._lib is not None:
                 return self._lib
             try:
-                stale = (not os.path.exists(self.lib_path)
-                         or os.path.getmtime(self.lib_path) < os.path.getmtime(self.src))
-            except OSError:
-                # source missing (deployment shipping only the built .so): use
-                # the existing library if present, else latch the failure.
-                stale = not os.path.exists(self.lib_path)
-            if stale and not self._build():
-                self._failed = True
-                return None
-            try:
-                lib = ctypes.CDLL(self.lib_path)
+                try:
+                    stale = (not os.path.exists(self.lib_path)
+                             or os.path.getmtime(self.lib_path)
+                             < os.path.getmtime(self.src))
+                except OSError:
+                    # source missing (deployment shipping only the built
+                    # .so): use the existing library if present
+                    stale = not os.path.exists(self.lib_path)
+                if stale:
+                    self._build()
+                try:
+                    lib = ctypes.CDLL(self.lib_path)
+                except OSError as e:
+                    raise NativeBuildError(
+                        f"cannot load {self.lib_path}: {e}") from e
                 if self.configure is not None:
                     self.configure(lib)
                 self._lib = lib
-            except Exception:
-                self._failed = True
+            except NativeBuildError as e:
+                self._error = e
+                raise
         return self._lib
-
-    def available(self) -> bool:
-        return self.load() is not None

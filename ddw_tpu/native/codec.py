@@ -1,9 +1,10 @@
 """ctypes bindings for the C++ shard codec (see codec.cpp for the role).
 
 The shared library builds lazily with g++ on first use (toolchain is part of the
-environment contract; pybind11 is not, hence the plain C ABI + ctypes). If the
-build or load fails, callers fall back to the pure-Python codec — the native path
-is a performance tier, never a correctness dependency.
+environment contract; pybind11 is not, hence the plain C ABI + ctypes). A build
+or load failure raises :class:`~ddw_tpu.native.build.NativeBuildError` with the
+compiler's message; the pure-Python codec in ``data/store.py`` is selected only
+by ``DDW_NATIVE_CODEC=0``, never found by the program on its own.
 
 Measured reality (kept honest per SURVEY.md §7 hard-part 3, "measure before
 writing C++"): at realistic record sizes (3KB+) both codecs are memory-bound on
@@ -51,18 +52,15 @@ _library = LazyLibrary(
 )
 
 
-def _load() -> ctypes.CDLL | None:
-    return _library.load()
-
-
 def native_available() -> bool:
-    return _library.available()
+    """True once the library is built and loaded; a build or load failure
+    raises :class:`~ddw_tpu.native.build.NativeBuildError` instead."""
+    _library.load()
+    return True
 
 
 def _index(path: str):
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native codec unavailable")
+    lib = _library.load()
     with open(path, "rb") as f:
         buf = f.read()
     n = lib.ddws_count_records(buf, len(buf))
@@ -95,8 +93,8 @@ def read_shard_contents_native(path: str) -> list[tuple[bytes, int]]:
 
 def read_shard_native(path: str) -> list[Record]:
     """Read a whole shard via the C++ index pass. Raises RuntimeError on codec
-    errors; raises if the native library is unavailable (callers check
-    :func:`native_available` or use ``ddw_tpu.data.store.read_shard``)."""
+    errors and :class:`~ddw_tpu.native.build.NativeBuildError` if the library
+    cannot be built."""
     buf, arr = _index(path)
     rows = arr.tolist()  # one bulk conversion to python ints
     out = []

@@ -2,9 +2,11 @@
 
 The loader/serving hot loop: JPEG -> RGB -> bilinear resize -> [-1, 1] f32,
 single images or whole batches on a C++ thread pool (one GIL release per
-batch). Falls back to PIL when libjpeg/g++ are unavailable or an individual
-image fails to decode — same dispatch on the training and serving sides, so
-there is no train/serve preprocessing skew (SURVEY.md §7 step 7).
+batch). An individual image that fails to decode is flagged and re-decoded by
+the caller through PIL — same dispatch on the training and serving sides, so
+there is no train/serve preprocessing skew (SURVEY.md §7 step 7). A library
+that cannot be built raises :class:`~ddw_tpu.native.build.NativeBuildError`;
+nothing drops to PIL wholesale.
 """
 
 from __future__ import annotations
@@ -40,14 +42,16 @@ _library = LazyLibrary(
 
 
 def native_available() -> bool:
-    return _library.available()
+    """True once the library is built and loaded; a build or load failure
+    raises :class:`~ddw_tpu.native.build.NativeBuildError` instead."""
+    _library.load()
+    return True
 
 
 def decode_one_native(content: bytes, height: int, width: int) -> np.ndarray | None:
-    """Decode one JPEG to float32 [H, W, 3] in [-1, 1]; None on failure."""
+    """Decode one JPEG to float32 [H, W, 3] in [-1, 1]; None if this image
+    does not decode."""
     lib = _library.load()
-    if lib is None:
-        return None
     out = np.empty((height, width, 3), np.float32)
     rc = lib.ddws_decode_one(
         content, len(content), height, width,
@@ -58,16 +62,14 @@ def decode_one_native(content: bytes, height: int, width: int) -> np.ndarray | N
 def decode_batch_native(
     contents: list[bytes], height: int, width: int, threads: int = 4,
     out: np.ndarray | None = None,
-) -> tuple[np.ndarray, np.ndarray] | None:
+) -> tuple[np.ndarray, np.ndarray]:
     """Decode a batch of JPEGs on the C++ thread pool.
 
     Returns ``(images [N, H, W, 3] f32, ok [N] bool)`` — failed slots are left
-    uninitialized and flagged False (callers re-decode those via PIL) — or None
-    if the native library is unavailable. ``out`` reuses a caller buffer.
+    uninitialized and flagged False (callers re-decode those via PIL).
+    ``out`` reuses a caller buffer.
     """
     lib = _library.load()
-    if lib is None:
-        return None
     n = len(contents)
     if out is None:
         out = np.empty((n, height, width, 3), np.float32)

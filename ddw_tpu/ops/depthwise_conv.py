@@ -9,18 +9,21 @@ the hand-written alternative that reads each input tile into VMEM ONCE and
 computes all nine taps from registers/VMEM:
 
 - grid over the batch; one [H, W, C] image block per step (every depthwise
-  layer in MobileNetV2-224 has H <= 112, so the block is <= 2.4 MiB bf16 —
-  VMEM holds input + output + taps comfortably);
+  layer in MobileNetV2-224 has H <= 112; with C padded to 128 lanes and the
+  f32 working copies Mosaic asks for up to 22 MiB of scoped VMEM at 112x112x32
+  and 56x56x144, above its 16 MiB default — hence ``_VMEM_LIMIT_BYTES``);
 - taps are static slices of the zero-padded block, accumulated in f32 on the
   VPU (8x128 lanes; C is the lane dim);
 - backward is two more Pallas kernels: dx = the same conv with spatially
   flipped taps; dw accumulates the 9 per-channel correlations across the
   batch grid (constant output index_map -> the [3,3,C] block stays resident).
 
-``impl="auto"`` uses Pallas on TPU for stride 1 and falls back to the XLA
-grouped conv elsewhere (stride-2 depthwise appears 4x in MobileNetV2 vs ~13
-stride-1 layers). Numerics are pinned against the XLA path in
-``tests/test_depthwise.py`` (interpreter mode on CPU), including gradients.
+The kernel is stride 1 only (stride-2 depthwise appears 4x in MobileNetV2 vs
+~13 stride-1 layers; those stay on the XLA grouped conv). ``impl="pallas"``
+means the kernel: compiled by Mosaic on a device, interpreted on the CPU
+backend (:mod:`ddw_tpu.ops.backend`), never swapped for XLA. Numerics are
+pinned against the XLA path in ``tests/test_depthwise.py``, including
+gradients.
 """
 
 from __future__ import annotations
@@ -34,9 +37,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddw_tpu.ops.backend import interpret_by_default
+
+# Scoped-VMEM ceiling for the whole-image blocks (see module doc); a v5e core
+# has 128 MiB.
+_VMEM_LIMIT_BYTES = 64 * 2**20
+
 
 def _xla_depthwise(x: jnp.ndarray, w: jnp.ndarray, stride: int) -> jnp.ndarray:
-    """Reference/fallback: XLA grouped conv. ``w`` is [3, 3, C]."""
+    """The XLA grouped conv (and the numerics reference). ``w`` is [3, 3, C]."""
     c = x.shape[-1]
     return lax.conv_general_dilated(
         x, w[:, :, None, :], window_strides=(stride, stride), padding="SAME",
@@ -83,7 +92,8 @@ def _pallas_fwd(x, w, interpret):
         out_specs=pl.BlockSpec((1, h, wd, c), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, wd, c), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, w)
 
@@ -101,7 +111,8 @@ def _pallas_dw(x, g, interpret):
         out_shape=jax.ShapeDtypeStruct((3, 3, c), jnp.float32),
         # the dw block accumulates across grid steps -> sequential grid
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, g)
 
@@ -127,35 +138,23 @@ _depthwise_pallas.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def depthwise_conv3x3(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
-                      impl: str = "auto", interpret: bool = False) -> jnp.ndarray:
+                      impl: str = "xla") -> jnp.ndarray:
     """SAME depthwise 3x3 conv, NHWC; ``w`` is [3, 3, C].
 
-    ``impl``: "auto" (Pallas for stride-1 on TPU, else XLA), "pallas",
-    "xla". ``interpret=True`` runs the Pallas path in interpreter mode
-    (CPU tests).
+    ``impl``: "xla" (grouped conv) or "pallas" (the kernel, stride 1 only —
+    whoever asks for it gets it or an error, never the other one).
     """
     if w.shape[:2] != (3, 3) or w.ndim != 3:
         raise ValueError(f"w must be [3, 3, C], got {w.shape}")
     if x.shape[-1] != w.shape[-1]:
         raise ValueError(f"channel mismatch: x {x.shape} vs w {w.shape}")
-    if impl not in ("auto", "pallas", "xla"):
+    if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown impl {impl!r}")
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "pallas" if (stride == 1 and (on_tpu or interpret)) else "xla"
     if impl == "pallas":
         if stride != 1:
             raise ValueError("the Pallas depthwise kernel supports stride 1; "
                              "use impl='xla' for strided layers")
-        if not interpret and jax.default_backend() != "tpu":
-            # No Mosaic compiler off-TPU. Refuse rather than silently running
-            # the interpreter (orders of magnitude slower): callers wanting
-            # hardware-independent dispatch use impl="auto"; tests wanting the
-            # kernel semantics on CPU pass interpret=True explicitly.
-            raise ValueError("impl='pallas' needs a TPU backend; use "
-                             "impl='auto' (XLA fallback) or interpret=True "
-                             "(tests)")
-        return _depthwise_pallas(x, w, interpret)
+        return _depthwise_pallas(x, w, interpret_by_default())
     return _xla_depthwise(x, w, stride)
 
 
@@ -164,14 +163,13 @@ class DepthwiseConv3x3(nn.Module):
     use_bias=False)``: same param name ("kernel") and shape ``[3, 3, 1, C]``,
     same init and dtype promotion — give it the name the nn.Conv would have
     gotten and the checkpoint format is unchanged. Routes the compute through
-    :func:`depthwise_conv3x3` (Pallas on stride-1 TPU layers, XLA elsewhere).
+    :func:`depthwise_conv3x3` with this module's ``impl``.
     """
 
     features: int
     strides: int = 1
     dtype: object = jnp.bfloat16
-    impl: str = "auto"
-    interpret: bool = False  # test-only: Pallas interpreter off-TPU
+    impl: str = "xla"
 
     @nn.compact
     def __call__(self, x):
@@ -182,4 +180,4 @@ class DepthwiseConv3x3(nn.Module):
                             (3, 3, 1, self.features), jnp.float32)
         x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
         return depthwise_conv3x3(x, kernel[:, :, 0, :], stride=self.strides,
-                                 impl=self.impl, interpret=self.interpret)
+                                 impl=self.impl)

@@ -17,7 +17,8 @@ Design (Dao et al. flash attention, TPU-first):
   per-row logsumexp; dQ streams K/V blocks, dK/dV streams Q/dO blocks, each
   rematerializing p = exp(s - L) blockwise in VMEM — O(S) HBM for the whole
   train step, the S x S matrices never exist in HBM;
-- ``interpret=True`` automatically off-TPU so the same code runs in CPU tests.
+- ``interpret=None`` means the interpreter on the CPU backend (tests) and the
+  Mosaic compiler on every other backend (:mod:`ddw_tpu.ops.backend`).
 """
 
 from __future__ import annotations
@@ -30,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.custom_partitioning import custom_partitioning
 from jax.experimental.pallas import tpu as pltpu
+
+from ddw_tpu.ops.backend import interpret_by_default
 
 _NEG_INF = -1e30
 
@@ -95,20 +98,13 @@ def _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
         n_in=3, out_ndims=(4, 3))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def _resolve_defaults(sm_scale, interpret, head_dim):
     """Single place the primal, fwd-rule, and bwd-rule resolve their defaults —
     a divergence here would silently scale/backend the two paths differently."""
     if sm_scale is None:
         sm_scale = 1.0 / float(head_dim) ** 0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_by_default()
     return sm_scale, interpret
 
 

@@ -24,8 +24,9 @@ Mapping to TPU:
 
 Call :func:`ring_all_reduce_pallas` inside ``shard_map`` binding the named
 axis (multi-axis meshes are fine — RDMA hops use MESH addressing along that
-axis). Off-TPU it runs under the Pallas TPU interpreter (cross-device DMA
-simulation), so the same kernel is exercised by the CPU test suite.
+axis). On the CPU backend it runs under the Pallas TPU interpreter
+(cross-device DMA simulation), so the same kernel is exercised by the CPU test
+suite; on any other backend Mosaic compiles it (:mod:`ddw_tpu.ops.backend`).
 """
 
 from __future__ import annotations
@@ -35,10 +36,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ddw_tpu.utils.compat import axis_size
+from ddw_tpu.ops.backend import interpret_by_default
 
 _LANE = 128  # TPU lane tile; chunks are padded to this multiple
 _VMEM_BUDGET_BYTES = 8 * 2**20  # per-kernel budget for in + out + comm scratch
@@ -116,15 +118,15 @@ def ring_all_reduce_pallas(x: jax.Array, axis_name: str,
 
     Must run inside ``shard_map`` binding ``axis_name``; every participant must
     pass the same-shaped ``x``. ``interpret`` may be a bool or a
-    ``pltpu.InterpretParams`` (e.g. ``detect_races=True``); ``None``
-    auto-selects the Pallas TPU interpreter off-TPU so tests cover the kernel
-    on a CPU mesh.
+    ``pltpu.InterpretParams`` (e.g. ``detect_races=True``); ``None`` selects
+    the Pallas TPU interpreter on the CPU backend only, so tests cover the
+    kernel on a CPU mesh and every device backend compiles it.
     """
     n = axis_size(axis_name)
     if n == 1:
         return x
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_by_default()
     if interpret is True:
         interpret = pltpu.InterpretParams()
 

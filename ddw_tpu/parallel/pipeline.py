@@ -68,13 +68,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddw_tpu.models.lm import DecoderBlock, TransformerLM
 from ddw_tpu.train.lm_step import lm_loss
 from ddw_tpu.train.step import TrainState
-from ddw_tpu.utils.compat import shard_map
 
 PIPE_AXIS = "pipe"
 

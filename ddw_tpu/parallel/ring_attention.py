@@ -40,8 +40,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ddw_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 from ddw_tpu.ops.flash_attention import flash_mha_lse
 

@@ -79,7 +79,9 @@ def main() -> int:
     maybe_fault("coord_bind")
     from ddw_tpu.runtime.elastic import context as elastic_context
     from ddw_tpu.runtime.mesh import initialize_distributed, is_coordinator
+    from ddw_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     rdzv = elastic_context()
     if rdzv is not None:
         # Elastic gang: membership/barrier/reduce live in the explicit

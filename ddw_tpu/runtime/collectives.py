@@ -31,8 +31,7 @@ from typing import Any, TypeVar
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ddw_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 T = TypeVar("T")
 

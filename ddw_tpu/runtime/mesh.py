@@ -27,6 +27,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 DATA_AXIS = "data"
@@ -86,15 +87,11 @@ def initialize_distributed(
         return  # single-process
     num_processes = num_processes or int(os.environ.get("DDW_NUM_PROCESSES", "1"))
     process_id = process_id if process_id is not None else int(os.environ.get("DDW_PROCESS_ID", "0"))
-    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
         # The CPU stand-in gang (launcher tests, dev boxes) needs a real
         # cross-process collectives transport; without gloo, XLA:CPU refuses
-        # multiprocess computations. Best-effort: jax versions where gloo is
-        # the built-in default dropped the option.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+        # multiprocess computations.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -175,6 +172,17 @@ class HybridMeshSpec:
                      in zip(self.axes, dcn_sizes, ici_sizes))
 
 
+def _device_grid(dims: Sequence[int], devices: Sequence[jax.Device]):
+    """``devices`` arranged into ``dims``. On an accelerator ``mesh_utils``
+    picks the ICI-aware order and its refusal propagates — a plain reshape
+    there would hide why the topology did not fit and may lay collectives
+    across the slow links. Host CPU devices have no topology; reshape."""
+    devices = list(devices)
+    if devices[0].platform == "cpu":
+        return np.asarray(devices).reshape(dims)
+    return mesh_utils.create_device_mesh(dims, devices=devices)
+
+
 def device_slice_index(d: jax.Device) -> int:
     """Which slice (pod unit connected by ICI) a device belongs to.
 
@@ -226,20 +234,11 @@ def make_hybrid_mesh(
     dcn_dims = tuple(d for _, d, _ in shape)
     ici_dims = tuple(i for _, _, i in shape)
 
-    def inner(slice_devices):
-        try:
-            from jax.experimental import mesh_utils
-
-            return mesh_utils.create_device_mesh(
-                ici_dims, devices=list(slice_devices))
-        except Exception:
-            return np.asarray(list(slice_devices)).reshape(ici_dims)
-
     ordered = [groups[k] for k in sorted(groups)]
     # [*dcn_dims, *ici_dims] -> interleave (d_j, i_j) pairs -> fuse each pair:
     # along every realized axis, same-slice devices are consecutive and the
     # slice boundary is the outermost stride.
-    arr = np.stack([inner(g) for g in ordered]).reshape(
+    arr = np.stack([_device_grid(ici_dims, g) for g in ordered]).reshape(
         (*dcn_dims, *ici_dims))
     k = len(shape)
     arr = np.transpose(arr, [a for j in range(k) for a in (j, k + j)])
@@ -288,10 +287,4 @@ def make_mesh(
     shape = spec.resolve(len(devices))
     names = tuple(a for a, _ in shape)
     dims = tuple(s for _, s in shape)
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(dims, devices=list(devices))
-    except Exception:
-        dev_array = np.asarray(list(devices)).reshape(dims)
-    return Mesh(dev_array, names)
+    return Mesh(_device_grid(dims, devices), names)

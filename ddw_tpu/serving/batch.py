@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ddw_tpu.data.loader import bounded_map, preprocess_image
+from ddw_tpu.data.loader import preprocess_image
 from ddw_tpu.data.store import Record, Table, TableStore, read_shard
 from ddw_tpu.runtime.mesh import DATA_AXIS, make_mesh, MeshSpec
 from ddw_tpu.serving.package import PackagedModel
@@ -119,7 +119,7 @@ class BatchScorer:
         re-score with a newer model or table from silently merging a previous
         run's parts for slower processes.
         """
-        from ddw_tpu.native.decode import decode_batch_native, native_available
+        from ddw_tpu.native.decode import decode_batch_native
 
         h, w = self.model.height, self.model.width
         results: list[tuple[str, str]] = []
@@ -166,7 +166,7 @@ class BatchScorer:
             if i:
                 dequantize_raw_u8(imgs[:i])
                 score(imgs, i, paths)
-        elif native_available():
+        else:
             # Double-buffered pipeline: one background thread decodes batch
             # N+1 (C++ pool, GIL released) while the device scores batch N —
             # per-batch wall time ~max(decode, score) instead of their sum,
@@ -209,24 +209,6 @@ class BatchScorer:
                 if in_flight is not None:
                     fut, buf, prev_paths = in_flight
                     score(buf, fut.result(), prev_paths)
-        else:
-            from concurrent.futures import ThreadPoolExecutor
-
-            def decode(rec: Record):
-                return rec.path, preprocess_image(rec.content, h, w)
-
-            buf_paths: list[str] = []
-            buf_imgs: list[np.ndarray] = []
-            with ThreadPoolExecutor(max_workers=self.workers) as pool:
-                for path, img in bounded_map(pool, decode, records(),
-                                             self.workers * 4):
-                    buf_paths.append(path)
-                    buf_imgs.append(img)
-                    if len(buf_imgs) == self.batch:
-                        score(np.stack(buf_imgs), len(buf_imgs), buf_paths)
-                        buf_paths, buf_imgs = [], []
-                if buf_imgs:
-                    score(np.stack(buf_imgs), len(buf_imgs), buf_paths)
 
         if out_store is not None:
             _write_scored_table(

@@ -21,11 +21,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddw_tpu.train.step import TrainState, cross_entropy_loss
-from ddw_tpu.utils.compat import shard_map
 
 # next-token CE is the same sparse CE (it broadcasts over [B, S, V] vs [B, S])
 lm_loss = cross_entropy_loss

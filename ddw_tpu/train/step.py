@@ -35,11 +35,10 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddw_tpu.utils.config import ModelCfg, TrainCfg
-from ddw_tpu.utils.compat import shard_map
 
 
 @flax.struct.dataclass

@@ -24,6 +24,7 @@ from ddw_tpu.runtime.mesh import DATA_AXIS
 from ddw_tpu.serving import load_lm_package, save_lm_package
 from ddw_tpu.tracking.tracker import Tracker
 from ddw_tpu.train.lm_trainer import LMTrainer
+from ddw_tpu.utils.compile_cache import enable_compile_cache
 from ddw_tpu.utils.config import LMCfg, TrainCfg, apply_overrides
 
 
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--workdir", default="/tmp/ddw_tpu_workshop")
     ap.add_argument("overrides", nargs="*")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfgs = {"lm": LMCfg(), "train": TrainCfg(warmup_epochs=0)}
     if args.quick:

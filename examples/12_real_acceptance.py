@@ -207,6 +207,9 @@ def main():
                          "(a crash mid-run must not re-pay downloads, "
                          "training, or HPO)")
     args = ap.parse_args()
+    from ddw_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     os.makedirs(args.work, exist_ok=True)
     st = Stages(args.work, args.golden, args.record, resume=args.resume)

@@ -37,6 +37,9 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("overrides", nargs="*", help="lm.key=value")
     args = ap.parse_args()
+    from ddw_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     overrides = args.overrides
 
     import jax

@@ -41,6 +41,9 @@ def main():
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("overrides", nargs="*", help="lm.key=value")
     args = ap.parse_args()
+    from ddw_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import numpy as np

@@ -21,6 +21,7 @@ from ddw_tpu.data.prep import generate_synthetic_flowers
 from ddw_tpu.data.store import TableStore
 from ddw_tpu.tracking.registry import ModelRegistry
 from ddw_tpu.tracking.tracker import Tracker
+from ddw_tpu.utils.compile_cache import enable_compile_cache
 from ddw_tpu.utils.config import DataCfg, ModelCfg, TrainCfg, TuneCfg, apply_overrides
 
 
@@ -38,6 +39,7 @@ def parse_args(description: str, extra=None):
 
 def setup(args) -> dict:
     """Build the config tree + workspace handles from CLI args."""
+    enable_compile_cache()
     cfgs = {"data": DataCfg(), "model": ModelCfg(), "train": TrainCfg(), "tune": TuneCfg()}
     if args.quick:
         cfgs["data"].img_height = cfgs["data"].img_width = 32

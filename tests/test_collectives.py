@@ -4,9 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ddw_tpu.utils.compat import shard_map
 
 from ddw_tpu.runtime import collectives
 from ddw_tpu.runtime.mesh import make_mesh, MeshSpec

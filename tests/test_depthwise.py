@@ -1,6 +1,7 @@
 """Pallas depthwise 3x3 kernel vs the XLA grouped conv: forward and both
-gradients, interpreter mode on the CPU mesh (the same pinning discipline as
-the flash-attention kernels in test_ops_parallel.py)."""
+gradients. ``impl="pallas"`` runs the kernel in the interpreter here because
+the test backend is the CPU (ddw_tpu.ops.backend; the same pinning discipline
+as the flash-attention kernels in test_ops_parallel.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,7 @@ def test_forward_matches_xla(shape):
     x = jnp.asarray(rng.randn(*shape).astype(np.float32))
     w = jnp.asarray(rng.randn(3, 3, shape[-1]).astype(np.float32))
     ref = _xla_depthwise(x, w, 1)
-    got = depthwise_conv3x3(x, w, impl="pallas", interpret=True)
+    got = depthwise_conv3x3(x, w, impl="pallas")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -27,7 +28,7 @@ def test_gradients_match_xla():
     w = jnp.asarray(rng.randn(3, 3, 8).astype(np.float32))
 
     def loss_pallas(x, w):
-        y = depthwise_conv3x3(x, w, impl="pallas", interpret=True)
+        y = depthwise_conv3x3(x, w, impl="pallas")
         return jnp.sum(jnp.sin(y))
 
     def loss_xla(x, w):
@@ -41,11 +42,11 @@ def test_gradients_match_xla():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_stride2_and_fallbacks():
+def test_stride2_and_refusals():
     rng = np.random.RandomState(2)
     x = jnp.asarray(rng.randn(1, 8, 8, 8).astype(np.float32))
     w = jnp.asarray(rng.randn(3, 3, 8).astype(np.float32))
-    out = depthwise_conv3x3(x, w, stride=2)  # auto -> xla off-TPU
+    out = depthwise_conv3x3(x, w, stride=2)  # the default impl is xla
     assert out.shape == (1, 4, 4, 8)
     with pytest.raises(ValueError, match="stride 1"):
         depthwise_conv3x3(x, w, stride=2, impl="pallas")
@@ -55,13 +56,8 @@ def test_stride2_and_fallbacks():
         depthwise_conv3x3(x, jnp.zeros((3, 3, 4)), impl="xla")
     with pytest.raises(ValueError, match="unknown impl"):
         depthwise_conv3x3(x, w, impl="cudnn")
-    # explicit pallas off-TPU without interpret must refuse, not crawl
-    with pytest.raises(ValueError, match="needs a TPU backend"):
-        depthwise_conv3x3(x, w, impl="pallas")
-    # auto off-TPU silently routes to XLA
-    np.testing.assert_allclose(
-        np.asarray(depthwise_conv3x3(x, w, impl="auto")),
-        np.asarray(_xla_depthwise(x, w, 1)), rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="unknown impl"):
+        depthwise_conv3x3(x, w, impl="auto")  # no dispatch that picks for you
 
 
 @pytest.mark.slow  # tier-1 budget (PR 18): the mobilenet-level composition
@@ -70,7 +66,8 @@ def test_stride2_and_fallbacks():
                    # 14s of tier-1 for no extra signal.
 def test_mobilenet_dw_impl_preserves_function_and_checkpoint():
     """dw_impl='pallas' keeps the exact param tree and the model function
-    (stride-2 depthwise layers fall back to XLA inside the same flag)."""
+    (stride-2 depthwise layers use XLA inside the same flag: the kernel has
+    no strided form)."""
     from ddw_tpu.models.registry import build_model
     from ddw_tpu.utils.config import ModelCfg
 
@@ -79,7 +76,7 @@ def test_mobilenet_dw_impl_preserves_function_and_checkpoint():
     base = dict(name="mobilenet_v2", num_classes=5, dropout=0.0,
                 freeze_base=False, dtype="float32")
     m0 = build_model(ModelCfg(**base))
-    m1 = build_model(ModelCfg(**base, dw_impl="pallas_interpret"))
+    m1 = build_model(ModelCfg(**base, dw_impl="pallas"))
     v = m0.init({"params": jax.random.PRNGKey(0)}, x, train=False)
     v1 = m1.init({"params": jax.random.PRNGKey(0)}, x, train=False)
     assert jax.tree_util.tree_structure(v) == jax.tree_util.tree_structure(v1)
@@ -95,7 +92,7 @@ def test_bf16_inputs_accumulate_f32():
     w32 = rng.randn(3, 3, 8).astype(np.float32)
     got = depthwise_conv3x3(jnp.asarray(x32, jnp.bfloat16),
                             jnp.asarray(w32, jnp.bfloat16),
-                            impl="pallas", interpret=True)
+                            impl="pallas")
     assert got.dtype == jnp.bfloat16
     ref = _xla_depthwise(jnp.asarray(x32), jnp.asarray(w32), 1)
     # bf16 inputs, f32 accumulation: agreement to bf16 resolution

@@ -5,9 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from ddw_tpu.utils.compat import shard_map
 
 from ddw_tpu.models.lm import TransformerLM
 from ddw_tpu.parallel.sharding import LM_TP_RULES, make_sharded_train_step

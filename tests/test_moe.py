@@ -11,9 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from ddw_tpu.utils.compat import shard_map
 
 from ddw_tpu.models.lm import TransformerLM
 from ddw_tpu.models.moe import MoEMlp, top1_routing

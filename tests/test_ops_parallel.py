@@ -5,9 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ddw_tpu.utils.compat import shard_map
 
 from ddw_tpu.ops.flash_attention import flash_attention, mha_reference
 from ddw_tpu.parallel.ring_attention import ring_attention

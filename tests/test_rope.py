@@ -127,14 +127,13 @@ def test_rope_decode_matches_full_forward():
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow   # 8-device ring-attention equivalence (~14 s) — newly
-#                     green via utils.compat.shard_map; tier-2 keeps it
+@pytest.mark.slow   # 8-device ring-attention equivalence (~14 s);
+#                     tier-2 keeps it
 def test_rope_sp_ring_matches_single_device():
     """Ring attention with per-shard pre-rotated K equals the full forward
     (K needs no position plumbing through the ring)."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    from ddw_tpu.utils.compat import shard_map
 
     rng = np.random.RandomState(5)
     toks = jnp.asarray(rng.randint(0, 32, (2, 32)))

@@ -7,7 +7,9 @@ unexplained 4.8%"). `tools/conv_profile.py` measures that on the chip; this
 tool computes the other half of the argument anywhere: per-layer FLOPs and
 minimal HBM bytes from the layer shapes alone, each layer's best-case time
 ``max(flops/peak, bytes/bw)``, and therefore the whole step's **time floor
-and MFU ceiling** on the v5e (197 TF/s bf16, 819 GB/s HBM).
+and MFU ceiling** on the v5e (197 TF/s bf16, 819 GB/s HBM — assumed whatever
+device is attached; ROADMAP S1 moves the peaks into one table keyed by
+``device_kind``).
 
 The model is deliberately optimistic for the hardware (a true ceiling):
 
