@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from io import BytesIO
 from typing import Iterator
@@ -36,6 +37,7 @@ from typing import Iterator
 import numpy as np
 
 from ddw_tpu.data.store import Table, read_shard_contents
+from ddw_tpu.obs.trace import span_lane
 
 
 def bounded_map(pool: ThreadPoolExecutor, fn, iterable, window: int):
@@ -175,6 +177,12 @@ class ShardedLoader:
         exactly the per-batch path's — only the Python dispatch granularity
         changes. Requires ``prefetch_to``; ``None``/all-ones means plain
         per-step batches.
+      tracer: optional :class:`ddw_tpu.obs.trace.Tracer` (the trainers pass
+        their own). The prefetch thread then records, a batch, on
+        ``tid="loader"``: ``loader_batch`` (read, decode and assemble on the
+        host), ``loader_h2d`` (the transfer call and the ``raw_u8``
+        dequantise dispatch) and, only when the queue was full,
+        ``loader_blocked`` — the time the loader was ahead.
     """
 
     def __init__(
@@ -193,6 +201,7 @@ class ShardedLoader:
         prefetch_to=None,
         skip_records: int = 0,
         super_batch=None,
+        tracer=None,
     ):
         if not 0 <= cur_shard < shard_count:
             raise ValueError(f"cur_shard {cur_shard} out of range for shard_count {shard_count}")
@@ -228,6 +237,7 @@ class ShardedLoader:
         self.prefetch = prefetch
         self.prefetch_to = prefetch_to
         self.skip_records = skip_records
+        self.tracer = tracer
 
         # Cached-feature table (train.transfer.materialize_features): content is
         # the frozen backbone's pooled feature vector (f32 bytes); batches are
@@ -460,13 +470,21 @@ class ShardedLoader:
                 imgs = dequant(imgs)
             return imgs, lbls
 
+        sp = span_lane(self.tracer, "data", "loader")
+
         def put_or_stop(item) -> bool:
             # Never block forever on a full queue: an abandoned consumer (e.g. the
             # trainer dropping a val iterator after val_steps) sets `stop`; re-check
             # it between bounded put attempts so the thread can exit.
+            try:
+                q.put_nowait(item)
+                return True
+            except queue.Full:
+                t_full = time.monotonic()
             while not stop.is_set():
                 try:
                     q.put(item, timeout=0.1)
+                    sp.span("loader_blocked", t_full, time.monotonic())
                     return True
                 except queue.Full:
                     continue
@@ -494,21 +512,36 @@ class ShardedLoader:
                 lambda g: jax.tree.map(lambda *xs: jax.numpy.stack(xs), *g),
                 out_shardings=(sup_sh, sup_sh))
 
+        def transferred():
+            """Batches on the device until the stream ends or the consumer
+            is gone; one stamp a boundary, so a batch's host work ends where
+            its transfer starts."""
+            batches = self._iter_batches()
+            while True:
+                t0 = time.monotonic()
+                try:
+                    imgs, lbls = next(batches)
+                except StopIteration:
+                    return
+                t1 = time.monotonic()
+                sp.span("loader_batch", t0, t1)
+                if stop.is_set():
+                    return
+                item = transfer(imgs, lbls)
+                sp.span("loader_h2d", t1, time.monotonic())
+                yield item
+
         def producer():
             try:
                 if plan is None:
-                    for imgs, lbls in self._iter_batches():
-                        if stop.is_set():
-                            return
-                        if not put_or_stop(transfer(imgs, lbls)):
+                    for item in transferred():
+                        if not put_or_stop(item):
                             return
                 else:
                     group: list = []
                     ci = 0
-                    for imgs, lbls in self._iter_batches():
-                        if stop.is_set():
-                            return
-                        group.append(transfer(imgs, lbls))
+                    for item in transferred():
+                        group.append(item)
                         if len(group) == plan[ci % len(plan)]:
                             if not put_or_stop(stack_fn(tuple(group))):
                                 return

@@ -42,8 +42,8 @@ import os
 import threading
 import time
 
-__all__ = ["Tracer", "gen_id", "chrome_trace", "to_ndjson", "load_events",
-           "span_index"]
+__all__ = ["Tracer", "SpanLane", "span_lane", "gen_id", "chrome_trace",
+           "to_ndjson", "load_events", "span_index"]
 
 
 def gen_id() -> str:
@@ -196,6 +196,55 @@ class Tracer:
             return True
         except OSError:
             return False
+
+
+class SpanLane:
+    """One thread's spans on a :class:`Tracer`, for loops that stamp their
+    own boundaries with ``time.monotonic()`` (the trainers' epoch loop on
+    ``tid="train"``, the loader's producer on ``tid="loader"``). The loop
+    takes each stamp once and hands pairs of them to :meth:`span`; a parent's
+    id comes from :meth:`open` before its children finish, so the finished
+    spans form a tree.
+
+    Build one with :func:`span_lane`: without a tracer it returns the one
+    :data:`NULL_LANE`, whose ``open`` and ``span`` do nothing, so an untraced
+    loop pays its clock reads and an empty call a span — no event, no id, no
+    string. Arguments that cost something to build go behind the lane's
+    ``on`` flag at the call site: ``args=lane.on and {"step": step}``."""
+
+    __slots__ = ("_tracer", "_cat", "_tid")
+    on = True
+
+    def __init__(self, tracer: Tracer, cat: str, tid: str):
+        self._tracer, self._cat, self._tid = tracer, cat, tid
+
+    def open(self) -> str:
+        """The id of a span that is still running."""
+        return self._tracer._next_span_id()
+
+    def span(self, name: str, t0: float, t1: float, parent=None, span=None,
+             args=None) -> None:
+        self._tracer.record_span(name, self._cat, t0, t1, parent=parent,
+                                 tid=self._tid, args=args or None, span=span)
+
+
+class _NullLane:
+    __slots__ = ()
+    on = False
+
+    def open(self) -> None:
+        return None
+
+    def span(self, name, t0, t1, parent=None, span=None, args=None) -> None:
+        return None
+
+
+NULL_LANE = _NullLane()
+
+
+def span_lane(tracer: Tracer | None, cat: str, tid: str):
+    """``SpanLane(tracer, cat, tid)``, or :data:`NULL_LANE` for no tracer."""
+    return NULL_LANE if tracer is None else SpanLane(tracer, cat, tid)
 
 
 # -- exporters ----------------------------------------------------------------

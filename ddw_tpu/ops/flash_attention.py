@@ -625,6 +625,13 @@ def flash_mha_lse(q, k, v, causal: bool = False, sm_scale: float | None = None,
     Same dispatch and padding contract as :func:`flash_mha`; the lse rows for
     padded queries are sliced off with the outputs. Ring attention calls this
     per hop so arbitrary local shard lengths work."""
+    with jax.named_scope("attention"):      # every tier, for a profile's split
+        return _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k,
+                             interpret, impl)
+
+
+def _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                  impl):
     chosen = _attn_impl(q, k, impl)
     if chosen in ("xla", "xla_ckpt"):
         scale, _ = _resolve_defaults(sm_scale, interpret, q.shape[-1])

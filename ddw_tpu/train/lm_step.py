@@ -143,11 +143,16 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
                 logits = model.apply({"params": params}, in_mb, train=True,
                                      rngs={"dropout": rng_mb})
                 aux = jnp.zeros((), jnp.float32)
-            ce = lm_loss(logits, tg_mb)
+            with jax.named_scope("loss"):
+                ce = lm_loss(logits, tg_mb)
             acc = jnp.mean((jnp.argmax(logits, -1) == tg_mb).astype(jnp.float32))
             return ce + aux_loss_weight * aux, (ce, acc, aux)
 
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+        def grad_fn(*args):
+            # the scopes of train/step.py, for the same split of a profile
+            with jax.named_scope("fwd_bwd"):
+                return jax.value_and_grad(loss_fn, has_aux=True)(*args)
+
         if grad_accum_steps > 1:
             # Microbatch accumulation over the local batch dim (lax.scan) —
             # same semantics as ddw_tpu.train.step.accumulate_grads; the
@@ -181,9 +186,11 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
         else:
             (_, (loss, acc, aux)), grads = grad_fn(
                 state.params, inputs, targets, dropout_rng)
-        grads = lax.pmean(grads, axes)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_sync"):
+            grads = lax.pmean(grads, axes)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": lax.pmean(loss, axes),
                    "accuracy": lax.pmean(acc, axes)}
         if moe:

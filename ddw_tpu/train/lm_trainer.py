@@ -31,6 +31,7 @@ import numpy as np
 
 from ddw_tpu.checkpoint.ckpt import CheckpointManager
 from ddw_tpu.models.lm import build_lm
+from ddw_tpu.obs.trace import span_lane
 from ddw_tpu.runtime.elastic import maybe_elastic_restart, process_topology
 from ddw_tpu.runtime.faults import Preempted, maybe_fault, preemption_requested
 from ddw_tpu.runtime.mesh import (DATA_AXIS, PIPE_AXIS, SEQ_AXIS, MeshSpec,
@@ -63,7 +64,9 @@ class LMTrainer:
     def __init__(self, lm_cfg: LMCfg, train_cfg: TrainCfg,
                  mesh=None, seq_devices: int = 1, run=None, tracer=None):
         self.lm_cfg, self.train_cfg, self.run = lm_cfg, train_cfg, run
-        self.tracer = tracer   # optional obs.Tracer: chain-boundary spans
+        # optional obs.Tracer: the span tree of one fit, and the loaders'
+        # producer spans (docs/observability.md lists them)
+        self.tracer = tracer
         self.pp = train_cfg.pipeline_stages > 0
         self.sharded = train_cfg.zero or train_cfg.fsdp
         if train_cfg.ema_decay and getattr(lm_cfg, "lora_rank", 0):
@@ -182,6 +185,7 @@ class LMTrainer:
     def fit(self, tokens: np.ndarray, val_fraction: float = 0.1,
             resume: bool = False) -> LMTrainResult:
         """Train from an in-memory token corpus ``[num_seqs, seq_len+1]``."""
+        t_fit = time.monotonic()
         cfg = self.train_cfg
         dp = self.mesh.shape[DATA_AXIS]
         sp = self.mesh.shape.get(SEQ_AXIS, 1)
@@ -238,7 +242,7 @@ class LMTrainer:
             return train_batches, val_batches
 
         return self._run(seq_len, steps_per_epoch, val_steps, global_batch,
-                         make_providers, resume)
+                         make_providers, resume, t_fit)
 
     def fit_tables(self, train_table, val_table,
                    resume: bool = False) -> LMTrainResult:
@@ -248,6 +252,7 @@ class LMTrainer:
         repeat, exact ``skip_records`` resume of the consumed stream."""
         from ddw_tpu.data.loader import ShardedLoader
 
+        t_fit = time.monotonic()
         cfg = self.train_cfg
         dp = self.mesh.shape[DATA_AXIS]
         sp = self.mesh.shape.get(SEQ_AXIS, 1)
@@ -304,8 +309,8 @@ class LMTrainer:
                 raise ValueError("steps_per_dispatch > 1 under fit_tables "
                                  "needs a step with a batch sharding — the "
                                  "loader stacks super-batches on device")
-            shard_kw = dict(cur_shard=cur_proc,
-                            shard_count=n_proc, prefetch_to=prefetch_to)
+            shard_kw = dict(cur_shard=cur_proc, shard_count=n_proc,
+                            prefetch_to=prefetch_to, tracer=self.tracer)
             train_iter = iter(ShardedLoader(
                 train_table, batch_size=host_batch, num_epochs=None,
                 shuffle=True, seed=cfg.seed + 1,
@@ -336,14 +341,19 @@ class LMTrainer:
             return train_batches, val_batches
 
         return self._run(seq_len, steps_per_epoch, val_steps, global_batch,
-                         make_providers, resume)
+                         make_providers, resume, t_fit)
 
     def _run(self, seq_len, steps_per_epoch, val_steps, global_batch,
-             make_providers, resume) -> LMTrainResult:
+             make_providers, resume, t_fit) -> LMTrainResult:
         cfg = self.train_cfg
         mesh = self.mesh
         dp = mesh.shape[DATA_AXIS]
+        # every boundary below is stamped once; the stamps feed the span tree
+        # (a no-op lane without a tracer) and the telemetry hub alike
+        sp = span_lane(self.tracer, "train", "train")
+        setup_id = sp.open()
 
+        t0 = time.monotonic()
         tx = make_optimizer(cfg)
         if cfg.ema_decay:
             from ddw_tpu.train.step import with_param_ema
@@ -356,6 +366,8 @@ class LMTrainer:
         plan = chain_plan(steps_per_epoch, cfg.steps_per_dispatch)
         chained = cfg.steps_per_dispatch > 1 and any(k > 1 for k in plan)
         rng = jax.random.PRNGKey(cfg.seed)
+        t1 = time.monotonic()
+        sp.span("optimizer_init", t0, t1, setup_id)
         if self.pp:
             from ddw_tpu.parallel.pipeline import (init_pp_state,
                                                    make_pp_lm_train_step)
@@ -364,6 +376,7 @@ class LMTrainer:
                        if cfg.pipeline_schedule == "interleaved" else 1)
             state = init_pp_state(self.model, tx, mesh, rng,
                                   virtual_stages=vstages)
+            t2 = time.monotonic()
             step = make_pp_lm_train_step(
                 self.model, tx, mesh, data_axis=DATA_AXIS,
                 num_microbatches=cfg.pipeline_microbatches,
@@ -378,6 +391,7 @@ class LMTrainer:
 
             state = init_lm_state(self.model, tx, rng,
                                   seq_len=min(8, seq_len))
+            t2 = time.monotonic()
             make_sharded = (make_fsdp_train_step if cfg.fsdp
                             else make_zero_train_step)
             # DATA_AXIS, not cfg.data_axis: LMTrainer builds (and validates)
@@ -398,6 +412,7 @@ class LMTrainer:
         else:
             state = init_lm_state(self.model, tx, rng,
                                   seq_len=min(8, seq_len))
+            t2 = time.monotonic()
             step = make_lm_train_step(self.model, tx, mesh,
                                       seq_axis=self.seq_axis,
                                       grad_accum_steps=cfg.grad_accum_steps)
@@ -407,6 +422,9 @@ class LMTrainer:
                     grad_accum_steps=cfg.grad_accum_steps)
             eval_step = make_lm_eval_step(self.model, mesh,
                                           seq_axis=self.seq_axis)
+        t3 = time.monotonic()
+        sp.span("model_init", t1, t2, setup_id)
+        sp.span("build_step", t2, t3, setup_id)
 
         if not cfg.checkpoint_dir:
             ckpt = None
@@ -430,6 +448,7 @@ class LMTrainer:
             if at_step is not None:
                 start_epoch = int(at_step) // steps_per_epoch
                 restored_meta = ckpt.read_metadata(at_step)
+            sp.span("restore", t3, time.monotonic(), setup_id)
 
         if ckpt and resume and start_epoch > 0 and start_epoch >= cfg.epochs:
             # The restored checkpoint already covers every requested epoch —
@@ -493,8 +512,10 @@ class LMTrainer:
                                  "steps_per_epoch": steps_per_epoch,
                                  "global_batch": global_batch})
 
+        t0 = time.monotonic()
         train_batches, val_batches = make_providers(
             start_epoch, chain if chained else step, plan, chained)
+        sp.span("build_loaders", t0, time.monotonic(), setup_id)
 
         history: list[dict[str, float]] = []
         step_rng = jax.random.PRNGKey(cfg.seed + 1)
@@ -512,14 +533,22 @@ class LMTrainer:
         host_step = int(jax.device_get(state.step))
         try:
             for epoch in range(start_epoch, cfg.epochs):
+                t_epoch = time.monotonic()
+                epoch_id = sp.open()
                 tlosses, taccs = [], []
                 batch_it = train_batches(epoch)
                 step_i = 0
                 for k_chain in plan:
-                    t_chain = (time.monotonic()
-                               if self.tracer is not None or hub is not None
-                               else 0.0)
+                    t_chain = time.monotonic()
+                    chain_id = sp.open()
+                    if setup_id is not None:
+                        # set-up ends where the first chain starts
+                        sp.span("fit_setup", t_fit, t_chain, span=setup_id)
+                        setup_id = None
                     inputs, targets = next(batch_it)
+                    t_data = time.monotonic()
+                    sp.span("data_wait", t_chain, t_data, chain_id,
+                            args=sp.on and {"step": host_step})
                     # Fault-injection hook (runtime.faults): free no-op
                     # unless DDW_FAULT targets this rank/step/generation.
                     # Under chained dispatch the hook (and the preemption
@@ -538,14 +567,19 @@ class LMTrainer:
                         # to EXIT_PREEMPTED (restart outside the crash
                         # budget). The finally block joins the async writer.
                         if ckpt:
+                            t0 = time.monotonic()
                             ckpt.save(state, host_step,
                                       metadata={"epoch": epoch,
                                                 "preempted": True,
                                                 "callbacks": sched.state_dicts()})
+                            sp.span("ckpt_save", t0, time.monotonic(),
+                                    epoch_id,
+                                    args=sp.on and {"step": host_step})
                         raise Preempted(host_step)
                     lr = sched.lr_for_batch(epoch, step_i, steps_per_epoch)
                     if lr is not None:
                         state = set_lr(state, lr)
+                    t_disp = time.monotonic()
                     if self.pp:  # the pipeline step is deterministic: no rng
                         state, m = step(state, inputs, targets)
                     elif chained:
@@ -558,17 +592,18 @@ class LMTrainer:
                         state, m = step(state, inputs, targets,
                                         jax.random.fold_in(step_rng,
                                                            host_step))
-                    if self.tracer is not None:
-                        # chain-boundary span: the host-side dispatch window
-                        # (device per-op time is tools/step_trace.py's job)
-                        self.tracer.record_span(
-                            "train_chain", "train", t_chain,
-                            time.monotonic(), tid="train",
-                            args={"epoch": epoch, "step": host_step,
-                                  "k": k_chain, "chained": bool(chained)})
+                    t_end = time.monotonic()
+                    # enqueue plus back-pressure from the device queue
+                    sp.span("dispatch", t_disp, t_end, chain_id,
+                            args=sp.on and {"step": host_step, "k": k_chain})
+                    # the chain boundary as the host sees it; its self time
+                    # (less data_wait and dispatch) is the loop's own work
+                    sp.span("train_chain", t_chain, t_end, epoch_id, chain_id,
+                            args=sp.on and {"epoch": epoch, "step": host_step,
+                                            "k": k_chain,
+                                            "chained": bool(chained)})
                     if hub is not None:
-                        hub.observe("train.chain_ms",
-                                    (time.monotonic() - t_chain) * 1e3)
+                        hub.observe("train.chain_ms", (t_end - t_chain) * 1e3)
                     host_step += k_chain
                     step_i += k_chain
                     tlosses.append(m["loss"])
@@ -586,14 +621,28 @@ class LMTrainer:
                     # evaluate the Polyak shadow (what serving should ship)
                     eval_state = eval_state.replace(
                         params=ema_params(state), opt_state=())
-                for vin, vtg in val_batches():
+                # the first wait holds the building of the epoch's
+                # validation loader (fit_tables makes one anew every epoch)
+                t0 = t_val = time.monotonic()
+                val_id = sp.open()
+                for i, (vin, vtg) in enumerate(val_batches()):
+                    t1 = time.monotonic()
+                    sp.span("val_data_wait", t0, t1, val_id,
+                            args=sp.on and {"i": i, "first": i == 0})
                     vm = eval_step(eval_state, vin, vtg)
                     vlosses.append(vm["loss"])
                     vaccs.append(vm["accuracy"])
+                    t0 = time.monotonic()
+                    sp.span("val_dispatch", t1, t0, val_id,
+                            args=sp.on and {"i": i})
+                sp.span("validation", t_val, t0, epoch_id, val_id,
+                        args=sp.on and {"steps": len(vlosses)})
                 # ONE device reduction + fetch per metric for the whole epoch
                 # (fetch_metrics_mean) instead of a device_get per scalar —
                 # exact per-step mean whether entries are scalars or [k]
-                # chain arrays.
+                # chain arrays. The first fetch is the epoch's barrier: it
+                # returns when the device has run every step before it.
+                t0 = time.monotonic()
                 row = {
                     "epoch": epoch,
                     "loss": fetch_metrics_mean(tlosses),
@@ -605,14 +654,19 @@ class LMTrainer:
                 if self.pp:  # schedule idle fraction, logged beside loss
                     row["pp_bubble_fraction"] = float(
                         jax.device_get(m["pp_bubble_fraction"]))
+                t1 = time.monotonic()
+                sp.span("epoch_fetch", t0, t1, epoch_id)
                 history.append(row)
                 epochs_run = epoch + 1
                 if self.run is not None:
                     self.run.log_metrics(row, step=epoch)
+                t0 = time.monotonic()
+                sp.span("epoch_report", t1, t0, epoch_id)
 
                 # Callbacks consume this epoch's metrics FIRST, then the
                 # checkpoint saves the post-callback counters/LR — resume =
                 # continuation (ScheduleSuite holds the ordering rules).
+                end_id = sp.open()
                 state, stop = sched.epoch_end(state, row["val_loss"], epoch)
                 if ckpt and (epoch + 1) % cfg.checkpoint_every_epochs == 0:
                     t_ck = time.monotonic()
@@ -620,11 +674,18 @@ class LMTrainer:
                               metadata={"epoch": epoch,
                                         "callbacks": sched.state_dicts(),
                                         "metrics": row})
+                    t1 = time.monotonic()
+                    sp.span("ckpt_save", t_ck, t1, end_id,
+                            args=sp.on and {"step": host_step})
                     if hub is not None:
-                        hub.observe("train.ckpt_write_ms",
-                                    (time.monotonic() - t_ck) * 1e3)
+                        hub.observe("train.ckpt_write_ms", (t1 - t_ck) * 1e3)
                 if best is not None:
                     best.maybe_save(state, host_step, row, {"epoch": epoch})
+                t1 = time.monotonic()
+                sp.span("epoch_end", t0, t1, epoch_id, end_id)
+                sp.span("epoch", t_epoch, t1, span=epoch_id,
+                        args=sp.on and {"epoch": epoch,
+                                        "steps": steps_per_epoch})
                 if stop:
                     break
         finally:

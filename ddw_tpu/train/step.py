@@ -269,19 +269,26 @@ def forward_and_grads(model, state: TrainState, images, labels, dropout_rng):
             mutable=mutable,
         )
         logits, new_vars = out if mutable else (out, {})
-        loss = cross_entropy_loss(logits, labels)
+        with jax.named_scope("loss"):
+            loss = cross_entropy_loss(logits, labels)
         acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
         return loss, (acc, new_vars.get("batch_stats", state.batch_stats))
 
-    (loss, (acc, new_bs)), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+    # named scopes (fwd_bwd, loss, grad_sync, optimizer; attention at the ops'
+    # dispatch) are metadata on the operations of the compiled step: a profile
+    # can be split by them (benchmark/tools/scope_shares.py)
+    with jax.named_scope("fwd_bwd"):
+        (loss, (acc, new_bs)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params)
     return loss, acc, new_bs, grads
 
 
 def apply_gradients(state: TrainState, tx: optax.GradientTransformation,
                     grads, new_batch_stats) -> TrainState:
     """Shared step core: optimizer update + state advance."""
-    updates, new_opt = tx.update(grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
+    with jax.named_scope("optimizer"):
+        updates, new_opt = tx.update(grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
     return TrainState(new_params, new_batch_stats, new_opt, state.step + 1)
 
 
@@ -351,13 +358,15 @@ def _dp_step_body(model, tx: optax.GradientTransformation, axis_name: str,
             model, state, images, labels, dropout_rng)
     # THE collective: gradient averaging across the data axis
     # (hvd.DistributedOptimizer role, reference :302).
-    grads = lax.pmean(grads, axis_name)
-    if state.batch_stats:
-        new_bs = lax.pmean(new_bs, axis_name)  # world-consistent BN statistics
-    metrics = {
-        "loss": lax.pmean(loss, axis_name),
-        "accuracy": lax.pmean(acc, axis_name),
-    }
+    with jax.named_scope("grad_sync"):
+        grads = lax.pmean(grads, axis_name)
+        if state.batch_stats:
+            # world-consistent BN statistics
+            new_bs = lax.pmean(new_bs, axis_name)
+        metrics = {
+            "loss": lax.pmean(loss, axis_name),
+            "accuracy": lax.pmean(acc, axis_name),
+        }
     return apply_gradients(state, tx, grads, new_bs), metrics
 
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import json
 import os
 import time
 from typing import Any
@@ -35,6 +36,7 @@ from ddw_tpu.checkpoint.ckpt import CheckpointManager
 from ddw_tpu.data.loader import ShardedLoader
 from ddw_tpu.data.store import Table
 from ddw_tpu.models.registry import build_model
+from ddw_tpu.obs.trace import Tracer, chrome_trace, span_lane
 from ddw_tpu.runtime.elastic import maybe_elastic_restart, process_topology
 from ddw_tpu.runtime.faults import Preempted, maybe_fault, preemption_requested
 from ddw_tpu.runtime.mesh import make_data_mesh, make_mesh, MeshSpec, DATA_AXIS
@@ -147,9 +149,9 @@ class Trainer:
         self.model = model if model is not None else build_model(model_cfg)
         self._initial = initial
         self._on_epoch = on_epoch
-        # optional obs.Tracer: chain-boundary spans on the shared timeline
-        # (the per-op device story stays with tools/step_trace.py; this is
-        # the host-side control-flow record)
+        # optional obs.Tracer: the span tree of one fit and the loaders'
+        # producer spans, the host-side record (docs/observability.md lists
+        # them; ``TrainCfg.trace_dir`` makes one when none is given)
         self.tracer = tracer
 
     # -- sizing ---------------------------------------------------------------
@@ -160,7 +162,7 @@ class Trainer:
         return int(self.mesh.shape[self.train_cfg.data_axis])
 
     def _loaders(self, train_table: Table, val_table: Table,
-                 consumed_batches: int = 0, super_plan=None):
+                 consumed_batches: int = 0, super_plan=None, tracer=None):
         # Elastic-aware topology: under an elastic gang the data-parallel
         # ranks live in the rendezvous (jax.distributed is per-process), and
         # after a shrink recovery the re-derived loaders re-partition the
@@ -188,6 +190,7 @@ class Trainer:
             # Fused-dispatch mode: [k, B, ...] super-batches stacked on
             # device per the epoch's chain plan (chain_plan(spe, K)).
             super_batch=super_plan,
+            tracer=tracer,
         )
         val_loader_factory = lambda: ShardedLoader(  # noqa: E731 — fresh pass per epoch
             val_table,
@@ -201,13 +204,24 @@ class Trainer:
             workers=self.data_cfg.loader_workers,
             prefetch=self.data_cfg.prefetch,
             prefetch_to=sharding,
+            tracer=tracer,
         )
         return train_loader, val_loader_factory
 
     # -- main loop ------------------------------------------------------------
     def fit(self, train_table: Table, val_table: Table, resume: bool = False) -> TrainResult:
+        t_fit = time.monotonic()
         cfg = self.train_cfg
         world = self.world_size
+        profiling = bool(cfg.trace_dir) and process_topology()[0] == 0
+        tracer = self.tracer
+        if profiling and tracer is None:
+            # an operator's trace is the device profile AND the span tree
+            tracer = Tracer(capacity=65536, process="train")
+        # every boundary below is stamped once; the stamps feed the span tree
+        # (a no-op lane without a tracer) and the telemetry hub alike
+        sp = span_lane(tracer, "train", "train")
+        setup_id = sp.open()
 
         if self._initial is not None:
             state, tx = self._initial
@@ -226,6 +240,9 @@ class Trainer:
                 (self.data_cfg.img_height, self.data_cfg.img_width, self.data_cfg.channels),
                 rng,
             )
+            # init_state makes the optimizer and its state too
+            sp.span("model_init", t_fit, time.monotonic(), setup_id)
+        t0 = time.monotonic()
         sharded_state = cfg.zero or cfg.fsdp
         if sharded_state:
             if cfg.zero and cfg.fsdp:
@@ -264,6 +281,8 @@ class Trainer:
                                   grad_accum_steps=cfg.grad_accum_steps)
                        if cfg.steps_per_dispatch > 1 else None)
         eval_step = make_eval_step(self.model, self.mesh, cfg.data_axis)
+        t1 = time.monotonic()
+        sp.span("build_step", t0, t1, setup_id)
 
         if not cfg.checkpoint_dir:
             ckpt = None
@@ -287,6 +306,7 @@ class Trainer:
             if at_step is not None:
                 start_epoch = int(at_step) // steps_per_epoch
                 restored_meta = ckpt.read_metadata(at_step)
+            sp.span("restore", t1, time.monotonic(), setup_id)
         if sharded_state:
             # leaves onto their data-axis shards (no-op on a restored
             # already-sharded state)
@@ -335,11 +355,13 @@ class Trainer:
         chained = train_chain is not None and any(k > 1 for k in plan)
 
         with monitor if monitor is not None else contextlib.nullcontext():
+            t0 = time.monotonic()
             train_loader, val_loader_factory = self._loaders(
                 train_table, val_table,
                 consumed_batches=start_epoch * steps_per_epoch,
-                super_plan=plan if chained else None)
+                super_plan=plan if chained else None, tracer=tracer)
             train_iter = iter(train_loader)
+            sp.span("build_loaders", t0, time.monotonic(), setup_id)
             step_rng = jax.random.PRNGKey(cfg.seed + 1)
 
             history: list[dict[str, float]] = []
@@ -353,10 +375,22 @@ class Trainer:
                    if self.run is not None else None)
             resumed = ckpt is not None and resume and start_epoch > 0
             state = sched.initial_state(state, start_epoch, resumed)
+            # TrainCfg.trace_dir: the device profile of the first SETTLED
+            # epoch (the one before it compiles), device lines only — the
+            # host tracer at its default more than doubles an epoch — with
+            # the tracer's ring written beside it
+            profile_epoch = (min(start_epoch + 1, cfg.epochs - 1)
+                             if profiling else -1)
             try:
                 for epoch in range(start_epoch, cfg.epochs):
-                    if cfg.trace_dir and epoch == start_epoch and process_topology()[0] == 0:
-                        jax.profiler.start_trace(cfg.trace_dir)
+                    t_epoch = time.monotonic()
+                    epoch_id = sp.open()
+                    if epoch == profile_epoch:
+                        options = jax.profiler.ProfileOptions()
+                        options.python_tracer_level = 0
+                        options.host_tracer_level = 0
+                        jax.profiler.start_trace(cfg.trace_dir,
+                                                 profiler_options=options)
                         tracing = True
                         if self.run is not None:
                             # The report links this param as the per-run
@@ -367,9 +401,13 @@ class Trainer:
                     losses, accs = [], []
                     step_i = 0
                     for k_chain in plan:
-                        t_chain = (time.monotonic()
-                                   if self.tracer is not None
-                                   or hub is not None else 0.0)
+                        t_chain = time.monotonic()
+                        chain_id = sp.open()
+                        if setup_id is not None:
+                            # set-up ends where the first chain starts
+                            sp.span("fit_setup", t_fit, t_chain,
+                                    span=setup_id)
+                            setup_id = None
                         # Fault-injection hook (runtime.faults): free no-op
                         # unless DDW_FAULT targets this rank/step/generation.
                         # Under chained dispatch it (like the preemption check
@@ -397,10 +435,14 @@ class Trainer:
                             # async writer, making the save durable.
                             step_now = int(jax.device_get(state.step))
                             if ckpt:
+                                t_ck = time.monotonic()
                                 ckpt.save(state, step_now,
                                           metadata={"epoch": epoch,
                                                     "preempted": True,
                                                     "callbacks": sched.state_dicts()})
+                                sp.span("ckpt_save", t_ck, time.monotonic(),
+                                        epoch_id,
+                                        args=sp.on and {"step": step_now})
                             raise Preempted(step_now)
                         # Per-batch LR: cosine everywhere, or the Goyal warmup
                         # ramp (Horovod warmup-callback granularity, reference
@@ -411,7 +453,11 @@ class Trainer:
                                                   steps_per_epoch)
                         if lr_b is not None:
                             state = set_lr(state, lr_b)
+                        t_wait = time.monotonic()
                         images, labels = next(train_iter)
+                        t_disp = time.monotonic()
+                        sp.span("data_wait", t_wait, t_disp, chain_id,
+                                args=sp.on and {"step": step_i})
                         if chained:
                             # [k, B, ...] super-batch through the fused scan
                             # program; metrics come back as [k] per-step
@@ -421,32 +467,40 @@ class Trainer:
                         else:
                             state, metrics = train_step(state, images, labels,
                                                         step_rng)
+                        t_end = time.monotonic()
+                        # enqueue plus back-pressure from the device queue
+                        sp.span("dispatch", t_disp, t_end, chain_id,
+                                args=sp.on and {"step": step_i, "k": k_chain})
                         losses.append(metrics["loss"])
                         accs.append(metrics["accuracy"])
-                        if self.tracer is not None:
-                            # one span per chain BOUNDARY (the host-side
-                            # dispatch window — device time for the chain
-                            # lives in the jax.profiler trace, not here)
-                            self.tracer.record_span(
-                                "train_chain", "train", t_chain,
-                                time.monotonic(), tid="train",
-                                args={"epoch": epoch, "step": step_i,
-                                      "k": k_chain,
-                                      "chained": bool(chained)})
+                        # the chain boundary as the host sees it (device time
+                        # for the chain lives in the jax.profiler trace, not
+                        # here); its self time, less data_wait and dispatch,
+                        # is the loop's own work
+                        sp.span("train_chain", t_chain, t_end, epoch_id,
+                                chain_id,
+                                args=sp.on and {"epoch": epoch,
+                                                "step": step_i, "k": k_chain,
+                                                "chained": bool(chained)})
                         if hub is not None:
                             hub.observe("train.chain_ms",
-                                        (time.monotonic() - t_chain) * 1e3)
+                                        (t_end - t_chain) * 1e3)
                         step_i += k_chain
                     # ONE device reduction + fetch for the whole epoch
                     # (fetch_metrics_mean) instead of a device_get per scalar.
+                    # The device drains here: validation starts on an idle
+                    # chip.
+                    t_f = time.monotonic()
                     train_loss = fetch_metrics_mean(losses)
                     train_acc = fetch_metrics_mean(accs)
                     epoch_s = time.time() - t0
-                    if tracing:
-                        jax.profiler.stop_trace()
-                        tracing = False
+                    t_val = time.monotonic()
+                    sp.span("train_fetch", t_f, t_val, epoch_id)
 
                     vlosses, vaccs = [], []
+                    val_id = sp.open()
+                    # the first wait holds the building of this epoch's
+                    # validation loader and the start of its producer
                     viter = iter(val_loader_factory())
                     # ZeRO/FSDP: eval reads only params/batch_stats — pass the
                     # state without the sharded moments or the eval jit would
@@ -458,15 +512,31 @@ class Trainer:
                         # evaluate the Polyak shadow (what serving should ship)
                         eval_state = eval_state.replace(
                             params=ema_params(state), opt_state=())
-                    for _ in range(val_steps):
+                    t0v = t_val
+                    for i in range(val_steps):
                         images, labels = next(viter)
+                        t1v = time.monotonic()
+                        sp.span("val_data_wait", t0v, t1v, val_id,
+                                args=sp.on and {"i": i, "first": i == 0})
                         m = eval_step(eval_state, images, labels)
                         vlosses.append(m["loss"])
                         vaccs.append(m["accuracy"])
+                        t0v = time.monotonic()
+                        sp.span("val_dispatch", t1v, t0v, val_id,
+                                args=sp.on and {"i": i})
+                    sp.span("validation", t_val, t0v, epoch_id, val_id,
+                            args=sp.on and {"steps": val_steps})
                     val_loss = fetch_metrics_mean(vlosses)
                     val_acc = fetch_metrics_mean(vaccs)
 
                     lr = get_lr(state)
+                    t_rep = time.monotonic()
+                    sp.span("epoch_fetch", t0v, t_rep, epoch_id)
+                    if tracing:
+                        # after the barrier, so the profile holds the epoch's
+                        # validation and every device operation of it
+                        jax.profiler.stop_trace()
+                        tracing = False
                     row = {
                         "epoch": epoch, "loss": train_loss, "accuracy": train_acc,
                         "val_loss": val_loss, "val_accuracy": val_acc, "lr": lr,
@@ -478,7 +548,10 @@ class Trainer:
                     if self.run is not None:
                         self.run.log_metrics(
                             {k: v for k, v in row.items() if k != "epoch"}, step=epoch)
+                    t_cb = time.monotonic()
+                    sp.span("epoch_report", t_rep, t_cb, epoch_id)
 
+                    end_id = sp.open()
                     if cfg.debug_cross_host_checks:
                         # SPMD consistency sanitizer (SURVEY §5): params must be identical
                         # across hosts; checksum computed locally, compared via tracker logs.
@@ -495,16 +568,32 @@ class Trainer:
                     # state the next epoch starts from — resume = continuation.
                     if ckpt and ((epoch + 1) % cfg.checkpoint_every_epochs == 0):
                         t_ck = time.monotonic()
-                        ckpt.save(state, int(jax.device_get(state.step)),
+                        step_now = int(jax.device_get(state.step))
+                        ckpt.save(state, step_now,
                                   metadata={"epoch": epoch, "val_loss": val_loss,
                                             "val_accuracy": val_acc,
                                             "callbacks": sched.state_dicts()})
+                        t1 = time.monotonic()
+                        sp.span("ckpt_save", t_ck, t1, end_id,
+                                args=sp.on and {"step": step_now})
                         if hub is not None:
                             hub.observe("train.ckpt_write_ms",
-                                        (time.monotonic() - t_ck) * 1e3)
+                                        (t1 - t_ck) * 1e3)
                     if best is not None:
                         best.maybe_save(state, int(jax.device_get(state.step)),
                                         row, {"epoch": epoch})
+                    t1 = time.monotonic()
+                    sp.span("epoch_end", t_cb, t1, epoch_id, end_id)
+                    sp.span("epoch", t_epoch, t1, span=epoch_id,
+                            args=sp.on and {"epoch": epoch,
+                                            "steps": steps_per_epoch})
+                    if epoch == profile_epoch:
+                        # the spans of everything so far, the profiled epoch
+                        # whole, in the form Perfetto loads beside the profile
+                        with open(os.path.join(cfg.trace_dir,
+                                               "train_spans.trace.json"),
+                                  "w") as f:
+                            json.dump(chrome_trace(tracer.drain()), f)
                     if stop:
                         break
 

@@ -205,7 +205,8 @@ class TrainCfg:
                                         # (model selection; the resume stream's
                                         # newest-K retention would prune it)
     log_every_steps: int = 10
-    trace_dir: str = ""                 # --trace flag role (jax.profiler), SURVEY §5
+    trace_dir: str = ""                 # --trace flag role, SURVEY §5: profile of
+                                        # the first settled epoch + span tree
     debug_cross_host_checks: bool = False  # SPMD consistency sanitizer, SURVEY §5
     monitor_interval_s: float = 0.0     # >0: sys.* utilization sampler into the
                                         # tracker (Ganglia role, SURVEY §5)

@@ -137,18 +137,27 @@ def test_on_epoch_hook(small_cfgs, silver, tmp_path):
 @pytest.mark.slow  # ~17s; artifact-presence check (no numeric pin) —
 # the profiler-trace drill moves wholesale to the slow tier
 def test_profiler_trace_writes_files(small_cfgs, silver, tmp_path):
-    """TrainCfg.trace_dir (Horovod-Timeline role): the first epoch runs under
-    jax.profiler and a trace lands on disk, openable in TensorBoard/Perfetto."""
+    """TrainCfg.trace_dir (Horovod-Timeline role): the first settled epoch
+    runs under jax.profiler, a trace lands on disk, openable in
+    TensorBoard/Perfetto, and the trainer's own span tree beside it."""
     import os
+
+    from ddw_tpu.obs.trace import load_events
 
     train_tbl, val_tbl, _ = silver
     trace_dir = str(tmp_path / "trace")
-    tr = _mk_trainer(small_cfgs, silver, tmp_path, epochs=1,
+    tr = _mk_trainer(small_cfgs, silver, tmp_path, epochs=2,
                      trace_dir=trace_dir)
     tr.fit(train_tbl, val_tbl)
+    assert tr.tracer is None            # the fit's own, not left on the trainer
     found = [os.path.join(r, f) for r, _, fs in os.walk(trace_dir) for f in fs]
     assert any(f.endswith((".trace.json.gz", ".xplane.pb"))
                for f in found), found
+    spans = load_events(os.path.join(trace_dir, "train_spans.trace.json"))
+    epochs = [e for e in spans if e["name"] == "epoch"]
+    assert [e["args"]["epoch"] for e in epochs] == [0, 1]   # 1 is the profiled
+    assert {"fit_setup", "train_chain", "dispatch", "validation",
+            "loader_batch"} <= {e["name"] for e in spans}
 
 
 def test_early_stopping(small_cfgs, silver, tmp_path):
