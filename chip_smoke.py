@@ -9,9 +9,9 @@ on whatever ``jax.devices()`` gives:
 1. ``Trainer.fit`` — MobileNetV2 at the reference's transfer shape over a
    seeded synthetic flowers table written by ``data/prep.py`` and read back
    through ``ShardedLoader`` (native JPEG decode, host->device prefetch);
-2. ``LMTrainer.fit_tables`` — the widest LM the repo runs, at a batch x
-   sequence whose score matrix is past the XLA attention tiers, so the step
-   runs the Pallas flash forward and backward kernels;
+2. ``LMTrainer.fit_tables`` — the widest LM the repo runs, at a sequence
+   (4096) past the attention dispatch's crossover, so the step runs the
+   three Pallas flash kernels (forward, dQ, dK/dV) in every layer;
 3. ``save_lm_package`` -> ``load_lm_package`` -> ``ServingEngine`` with default
    ``EngineCfg``: warm-up, then concurrent ``submit_generate`` requests of
    mixed prompt length, checked against ``pkg.generate`` (see ``_check_tokens``).
@@ -39,6 +39,7 @@ import argparse
 import json
 import math
 import os
+import re
 import sys
 import tempfile
 import time
@@ -226,9 +227,9 @@ def lm_leg(shape: dict, work: str, tracker):
                              n_dev, math.log(vocab))
 
     # Which attention tier the step lowered to: the dispatch's own answer at
-    # the per-device shape, and the Mosaic kernel calls in the lowered step
-    # (flash forward + dQ + dK/dV per layer when the tier is pallas and a
-    # device compiles it; none when the CPU interprets it).
+    # the per-device shape, and the flash kernels in the lowered step: one
+    # Mosaic body for each of forward, dQ and dK/dV, shared by the layers
+    # (none when the CPU interprets them), and a call of each a layer.
     head_dim = shape["hidden"] // shape["heads"]
     qk = jax.ShapeDtypeStruct(
         (shape["batch_per_chip"], shape["heads"], seq, head_dim),
@@ -240,14 +241,20 @@ def lm_leg(shape: dict, work: str, tracker):
         trainer.model, tx, jax.random.PRNGKey(0)))
     toks = jax.ShapeDtypeStruct((global_batch, seq), jnp.int32)
     lowered = step.lower(state, toks, toks, jax.random.PRNGKey(0)).as_text()
-    mosaic_calls = lowered.count("tpu_custom_call")
+    mosaic_bodies = lowered.count("tpu_custom_call")
+    kernel_calls = len(re.findall(r"call @_flash_(?:forward|dq|dkv)\b",
+                                  lowered))
     if tier != "pallas":
         raise AssertionError(f"attention tier is {tier!r}, not 'pallas' — "
                              f"the shape no longer exercises the kernels")
-    if jax.default_backend() != "cpu" and mosaic_calls != 3 * shape["depth"]:
-        raise AssertionError(f"{mosaic_calls} Mosaic calls in the lowered "
-                             f"step, expected {3 * shape['depth']}")
-    report.update(attention_tier=tier, mosaic_kernel_calls=mosaic_calls)
+    if kernel_calls != 3 * shape["depth"] or (
+            jax.default_backend() != "cpu" and mosaic_bodies != 3):
+        raise AssertionError(
+            f"{kernel_calls} flash kernel calls over {mosaic_bodies} Mosaic "
+            f"bodies in the lowered step, expected {3 * shape['depth']} "
+            f"over 3")
+    report.update(attention_tier=tier, flash_kernel_calls=kernel_calls,
+                  mosaic_kernel_bodies=mosaic_bodies)
     return ({"leg": "lm_trainer", "global_batch": global_batch, "seq": seq,
              **report}, lm_cfg, result.state.params)
 

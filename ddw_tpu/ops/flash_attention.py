@@ -2,15 +2,24 @@
 
 The reference stack has no attention anywhere (SURVEY.md §5 "Long-context ...
 Absent") — this op exists because long-context support is first-class in this
-framework: it is the local-block compute of :mod:`ddw_tpu.parallel.ring_attention`
-(sequence parallelism) and the attention path of the ViT model family.
+framework: it is the attention of the LM family from 512 tokens up, the
+local-block compute of :mod:`ddw_tpu.parallel.ring_attention` (sequence
+parallelism) and an arm of the ViT family's attention.
 
 Design (Dao et al. flash attention, TPU-first):
-- grid over (batch*heads, Q blocks); K/V streamed block-by-block inside a
-  ``fori_loop`` with running max / normalizer / accumulator in VMEM scratch —
-  O(S) memory instead of the O(S^2) score matrix, scores never leave VMEM;
-- block sizes default to 128 (MXU/VPU native tile), f32 accumulation with inputs
-  in bf16 or f32;
+- the kernels take q, k, v as the projections produce them, ``[B, S, H, D]``
+  viewed ``[B, S, H*D]``: a grid step loads a 128-lane block of it — two heads
+  at D = 64 — so every q/k/v/o tile fills its lane rows, nothing is transposed
+  or padded in HBM on the way in or out, and the residuals the backward keeps
+  are the tensors the model holds anyway. A head of the block is selected by
+  zeroing the other heads' lanes of one matmul operand (a 128-deep contraction
+  costs the MXU what a 64-deep one does) and by a lane select on the result;
+- grid over (batch, head blocks, Q blocks, K blocks); the K blocks are the
+  innermost grid dimension, streamed with running max / normalizer /
+  accumulator in VMEM scratch, and a loop inside the step walks key sub-blocks
+  — O(S) memory instead of the O(S^2) score matrix, scores never leave VMEM;
+- blocks are chosen by the code from the sequence lengths (``_pick_block``),
+  f32 accumulation with inputs in bf16 or f32;
 - causal masking by global position (supports the ring-attention case where this
   rank's K block sits at a rotated global offset);
 - backward pass as two Pallas kernels (FA2 schedule): the forward saves the
@@ -35,40 +44,48 @@ from jax.experimental.pallas import tpu as pltpu
 from ddw_tpu.ops.backend import interpret_by_default
 
 _NEG_INF = -1e30
+_LANES = 128
+# Scoped VMEM a kernel may use: the largest working set _pick_block allows
+# (f32 score tiles of 1 MiB, a handful live) passes the 16 MiB default.
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _bh_sharding(sharding, ndim):
-    """A NamedSharding keeping the suggested (batch, heads) axes and
-    replicating everything after them — the partition layout the kernels
-    support (seq and head_dim must be device-local)."""
+    """A NamedSharding keeping the suggested batch and heads axes — dims 0 and
+    2 of the ``[B, S, H, D]`` operands, dims 0 and 1 of the ``[B, H, Sq]`` rows
+    — and replicating the rest: the partition layout the kernels support (seq
+    and head_dim must be device-local)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    spec = tuple(sharding.spec)[:2]
-    spec = spec + (None,) * (ndim - len(spec))
+    spec = tuple(sharding.spec) + (None,) * 4
+    batch, heads = spec[0], spec[2]
+    spec = (batch, None, heads, None) if ndim == 4 else (batch, heads, None)
     return NamedSharding(sharding.mesh, P(*spec))
 
 
-def _def_bh_partition(fn, impl, rule, n_in, out_ndims):
+def _def_bh_partition(fn, impl, rule, out_ndims):
     """Register batch/head-sharded SPMD partitioning on ``fn``.
 
     GSPMD cannot auto-partition a Mosaic custom call, so without this the
     pjit TP/DP paths (VIT_TP_RULES, LM_TP_RULES shard attention heads over
     ``model``; DP shards batch) would all-gather the operands and run the
-    kernel replicated — or fail to lower. The rule declares the leading two
-    dims (batch, heads) freely shardable and everything else
-    need-replication; the per-shard lowering is the kernel itself on local
-    shapes. Under shard_map (the ring path) the op is already per-device and
-    partitioning never engages."""
+    kernel replicated — or fail to lower. The rule declares the batch and
+    heads dims freely shardable and everything else need-replication; the
+    per-shard lowering is the kernel itself on local shapes. Under shard_map
+    (the ring path, the data-parallel LM step) the op is already per-device
+    and partitioning never engages."""
+
+    def shardings(arg_shapes):
+        like = arg_shapes[0].sharding       # q [B, Sq, H, D]
+        return (tuple(_bh_sharding(like, n) for n in out_ndims),
+                tuple(_bh_sharding(like, s.ndim) for s in arg_shapes))
 
     def partition(mesh, arg_shapes, result_shape):
-        bh = _bh_sharding(arg_shapes[0].sharding, 2)
-        args = tuple(_bh_sharding(bh, s.ndim) for s in arg_shapes)
-        outs = tuple(_bh_sharding(bh, n) for n in out_ndims)
+        outs, args = shardings(arg_shapes)
         return mesh, impl, outs, args
 
     def infer(mesh, arg_shapes, result_shape):
-        bh = _bh_sharding(arg_shapes[0].sharding, 2)
-        return tuple(_bh_sharding(bh, n) for n in out_ndims)
+        return shardings(arg_shapes)[0]
 
     # NB: shardy requires the special-factor indices sorted, i.e. listed in
     # first-appearance order of the rule string (q before d before s).
@@ -81,21 +98,18 @@ def _def_bh_partition(fn, impl, rule, n_in, out_ndims):
 @functools.lru_cache(maxsize=None)
 def _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
                      interpret, k_valid):
-    """(q, k, v) -> (out [B,H,Sq,D], lse [B,H,Sq]) with SPMD partitioning over
+    """(q, k, v) -> (out [B,Sq,H,D], lse [B,H,Sq]) with SPMD partitioning over
     batch/heads. Cached per static config (the custom_partitioning object must
     be built once per config, not per trace)."""
 
     def impl(q, k, v):
-        out, lse = _flash_forward(q, k, v, causal, q_offset, k_offset,
-                                  sm_scale, block_q, block_k, interpret,
-                                  k_valid)
-        b, h, sq, _ = q.shape
-        return out, lse.reshape(b, h, sq)
+        return _flash_forward(q, k, v, causal, q_offset, k_offset, sm_scale,
+                              block_q, block_k, interpret, k_valid)
 
     fn = custom_partitioning(impl)
     return _def_bh_partition(
-        fn, impl, "b h q d, b h s d, b h s d -> b h q d, b h q",
-        n_in=3, out_ndims=(4, 3))
+        fn, impl, "b q h d, b s h d, b s h d -> b q h d, b h q",
+        out_ndims=(4, 3))
 
 
 def _resolve_defaults(sm_scale, interpret, head_dim):
@@ -124,433 +138,616 @@ def mha_reference(q, k, v, causal: bool = False, q_offset: int = 0,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _masked_scores(q, k_blk, q_start, k_start, causal, sm_scale,
-                   block_q, block_k, k_valid=None):
-    """QK^T with the causal + key-padding masks applied at global positions —
-    shared by the forward and both backward kernels so the masking can never
-    desynchronize. ``k_valid`` (static) masks keys at global position >= it
-    (the padded tail when the sequence was padded up to a block multiple)."""
-    sc = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * sm_scale
-    if causal or k_valid is not None:
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        keep = jnp.full((block_q, block_k), True)
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            keep = kpos <= qpos
-        if k_valid is not None:
-            keep = jnp.logical_and(keep, kpos < k_valid)
-        sc = jnp.where(keep, sc, _NEG_INF)
-    return sc
+def _sub_block_range(q0, k0, block_q: int, block_k: int, sub_k: int,
+                     causal: bool, k_valid: int | None):
+    """``(n_full, n_vis)``: of the ``block_k // sub_k`` key sub-blocks of one
+    (q block, k block) grid step, sub-blocks ``[0, n_full)`` are visible to
+    every query row (no mask needed) and ``[n_full, n_vis)`` are crossed by the
+    causal diagonal or by the padded tail (mask needed); the rest are skipped.
+    ``q0`` / ``k0`` are the global positions of the blocks' first row / key
+    (traced under causal or padding, so the bounds are then traced too)."""
+    full = vis = block_k
+    if causal:
+        vis = jnp.clip(q0 + block_q - k0, 0, block_k)    # keys <= the last row
+        full = jnp.clip(q0 - k0 + 1, 0, block_k)         # keys <= the first row
+    if k_valid is not None:
+        valid = jnp.clip(k_valid - k0, 0, block_k)
+        vis, full = jnp.minimum(vis, valid), jnp.minimum(full, valid)
+    return full // sub_k, (vis + sub_k - 1) // sub_k
 
 
-def _guarded_exp(sc, ref, masked):
-    """p = exp(s - ref) with the fully-masked-row guard: where s == _NEG_INF the
-    subtraction cancels in f32 (exp -> 1), so re-zero masked entries explicitly.
-    Load-bearing in all three kernels — keeps masked rows at zero output and
-    zero gradient."""
-    p = jnp.exp(sc - ref)
-    if masked:
-        p = jnp.where(sc > _NEG_INF / 2, p, 0.0)
-    return p
+def _for_sub_blocks(n_full, n_vis, step):
+    """Run ``step(j, masked)`` over the unmasked then the masked sub-blocks."""
+    jax.lax.fori_loop(0, n_full, lambda j, _: step(j, False), None)
+    if not (isinstance(n_vis, int) and n_vis == n_full):    # nothing masks
+        jax.lax.fori_loop(n_full, n_vis, lambda j, _: step(j, True), None)
+
+
+def _scores(a, b, sm_scale):
+    """``a b^T * sm_scale``: operands in the input dtype (bf16 -> full MXU
+    rate), f32 accumulation; contracting both minor dims, so no operand is
+    transposed in VMEM."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * sm_scale
+
+
+def _mask_scores(sc, q_start, k_start, causal, k_valid, k_axis: int):
+    """The causal + key-padding masks at global positions — shared by the
+    forward and both backward kernels so the masking can never desynchronize.
+    Keys run along ``k_axis`` of ``sc`` (1 in the forward and dQ kernels, 0 in
+    the dK/dV kernel, which works on transposed scores). ``k_valid`` (static)
+    masks keys at global position >= it (the padded tail when the sequence was
+    padded up to a block multiple). Only sub-blocks the diagonal or the padded
+    tail crosses come here."""
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, k_axis)
+    keep = None
+    if causal:
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, sc.shape,
+                                                  1 - k_axis)
+        keep = kpos <= qpos
+    if k_valid is not None:
+        valid = kpos < k_valid
+        keep = valid if keep is None else jnp.logical_and(keep, valid)
+    return jnp.where(keep, sc, _NEG_INF)
+
+
+def _finite_ref(ref):
+    """The fully-masked-row guard, on the row statistic instead of the score
+    tile: a row whose every key is masked keeps its running max (forward) or
+    logsumexp (backward) at ~_NEG_INF, where ``s - ref`` would cancel in f32
+    (exp -> 1). With the reference at 0 there, ``exp(_NEG_INF - 0)`` is 0, so
+    masked rows stay at zero output and zero gradient. Load-bearing in all
+    three kernels."""
+    return jnp.where(ref > _NEG_INF / 2, ref, 0.0)
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` statistic as ``[rows, n]``."""
+    reps = -(-n // _LANES)
+    if reps > 1:
+        x = jnp.tile(x, (1, reps))
+    return x if x.shape[1] == n else x[:, :n]
+
+
+def _head_masks(heads: int, head_dim: int, width: int):
+    """Lane masks ``[1, width]``, one a head of a lane block (None for a block
+    that is one head)."""
+    if heads == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [jnp.logical_and(lane >= t * head_dim, lane < (t + 1) * head_dim)
+            for t in range(heads)]
+
+
+def _only(mask, x):
+    """``x`` with the other heads' lanes zeroed: as a matmul operand contracted
+    over the lanes it yields this head's product alone."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                  block_k: int, causal: bool, q_offset: int, k_offset: int,
-                  sm_scale: float, block_q: int, k_valid: int | None):
-    """One (batch*head, q-block, k-block) grid step of online-softmax attention.
+                  heads: int, head_dim: int, block_q: int, block_k: int,
+                  sub_k: int, causal: bool, q_offset: int, k_offset: int,
+                  sm_scale: float, k_valid: int | None):
+    """One (batch, head block, q-block, k-block) grid step of online-softmax
+    attention, for the ``heads`` heads of the lane block in turn.
 
     The K loop is a GRID dimension (innermost), so Mosaic double-buffers the
-    K/V block DMAs across steps; the running (max, normalizer, accumulator)
-    lives in VMEM scratch that persists along the k dimension, initialized at
-    kb==0 and written to the output block at the last kb. QK^T and PV run in
-    the input dtype (bf16 -> full MXU rate) with f32 accumulation
-    (preferred_element_type); softmax bookkeeping is f32 on the VPU. Fully
-    -future K blocks under causal masking are skipped via pl.when."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    num_kb = pl.num_programs(2)
+    K/V block DMAs across steps; inside a step a loop walks the ``sub_k``-wide
+    key sub-blocks, so a block can be large (few grid steps) while causal
+    skipping and the masking work stay at sub-block granularity. The running
+    (max, normalizer) of each head live lane-replicated in ``[block_q, 128]``
+    VMEM scratch and the accumulator of all of them in ``[block_q, heads*d]``,
+    persisting along the k dimension: initialized at kb==0, written to the
+    output block at the last kb. QK^T and PV run in the input dtype (bf16 ->
+    full MXU rate) with f32 accumulation (preferred_element_type); softmax
+    bookkeeping is f32 on the VPU. Sub-blocks wholly in the causal future (or
+    the padded tail) are skipped."""
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+    num_kb = pl.num_programs(3)
+    width = acc_scr.shape[-1]
+    masks = _head_masks(heads, head_dim, width)
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_last = q_offset + qi * block_q + block_q - 1
-    k_first = k_offset + kb * block_k
-    visible = (k_first <= q_last) if causal else True
-    if k_valid is not None:
-        visible = visible & (k_first < k_valid)
+    q0 = q_offset + qi * block_q
+    k0 = k_offset + kb * block_k
+    bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    @pl.when(visible)
-    def _attend():
-        q = q_ref[0]                                     # [block_q, d]
-        k_blk = k_ref[0]                                 # [block_k, d]
-        v_blk = v_ref[0]
-        s = _masked_scores(q, k_blk, q_offset + qi * block_q,
-                           k_offset + kb * block_k, causal, sm_scale,
-                           block_q, block_k, k_valid)
-        m_prev = m_scr[:]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard keeps l at 0 on fully-masked rows so _finalize emits zeros
-        p = _guarded_exp(s, m_new, causal or k_valid is not None)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p.astype(q.dtype), v_blk, preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+    for t, mask in enumerate(masks):
+        q = _only(mask, q_ref[...])                      # [block_q, heads*d]
+
+        def _attend(j, masked):
+            ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
+            s = _scores(q, k_ref[ks, :], sm_scale)       # [block_q, sub_k]
+            if masked:
+                s = _mask_scores(s, q0, k0 + j * sub_k, causal, k_valid, 1)
+            m_prev = m_scr[t]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # guard keeps l at 0 on fully-masked rows so _finalize emits zeros
+            m_ref = _finite_ref(m_new) if masked else m_new
+            p = jnp.exp(s - _lanes(m_ref, sub_k))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[t] = alpha * l_scr[t] + jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.dot(p.astype(q.dtype), v_ref[ks, :],
+                         preferred_element_type=jnp.float32)
+            acc = acc_scr[...]
+            new = acc * _lanes(alpha, width) + pv        # this head's lanes
+            acc_scr[...] = new if mask is None else jnp.where(mask, new, acc)
+            m_scr[t] = m_new
+
+        _for_sub_blocks(*bounds, _attend)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
-        # logsumexp residual for the Pallas backward (FA2): L = m + log(l).
-        # Fully-masked rows keep L ~ _NEG_INF so backward p = exp(s - L) is
-        # re-zeroed there by the same s > _NEG_INF/2 guard.
-        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        norm = None
+        for t, mask in enumerate(masks):
+            l = jnp.maximum(l_scr[t], 1e-30)
+            l_wide = _lanes(l, width)
+            norm = l_wide if norm is None else jnp.where(mask, l_wide, norm)
+            # logsumexp residual for the Pallas backward (FA2): L = m + log(l).
+            # Fully-masked rows keep L ~ _NEG_INF; the backward re-zeroes p
+            # there (_finite_ref). Stored as a lane-dense row: the replicated
+            # [block_q, 128] statistic transposed, one sublane of it kept.
+            lse_ref[t:t + 1, :] = (m_scr[t] + jnp.log(l)).T[:1]
+        o_ref[...] = (acc_scr[...] / norm).astype(o_ref.dtype)
 
 
+def _compiler_params():
+    # batch, head-block and q-block steps are independent (scratch re-inits at
+    # the innermost dim's first step); only the innermost dim carries state.
+    # Declaring that lets Mosaic overlap DMA and compute across grid steps
+    # instead of serializing the whole grid.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _head_blocks(h: int, d: int):
+    """How ``h`` heads of dim ``d`` are laid over 128-lane blocks of the
+    ``[B, S, H*D]`` view: ``(heads a block, padded d, padded h)``. A d that
+    divides 128 packs 128 // d heads a block; any other is zero-padded up to
+    the next that does (or to a multiple of 128), which changes no score, and
+    the heads up to a whole block."""
+    if d >= _LANES:
+        return 1, -(-d // _LANES) * _LANES, h
+    per = _LANES // d
+    while _LANES % per:
+        per -= 1
+    return per, _LANES // per, -(-h // per) * per
+
+
+def _to_blocks(x, dp: int, hp: int):
+    """``[B, S, H, D]`` -> ``[B, S, hp*dp]`` (zero-padded where d or h grow)."""
+    b, s, h, d = x.shape
+    if (dp, hp) != (d, h):
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, hp - h), (0, dp - d)))
+    return x.reshape(b, s, hp * dp)
+
+
+def _from_blocks(x, h: int, d: int, dp: int):
+    b, s, width = x.shape
+    return x.reshape(b, s, width // dp, dp)[:, :, :h, :d]
+
+
+def _rows(x, per: int, hp: int):
+    """``[B, H, Sq]`` f32 rows -> ``[B, hp // per, per, Sq]``, a head block's
+    rows together (heads padded like the operands')."""
+    b, h, sq = x.shape
+    if hp != h:
+        x = jnp.pad(x, ((0, 0), (0, hp - h), (0, 0)))
+    return x.reshape(b, hp // per, per, sq)
+
+
+def _specs(per: int, dp: int, bq: int, bk: int, q_inner: bool):
+    """Block specs of the q-side tiles, their f32 rows and the k-side tiles
+    for a grid (batch, head block, outer, inner), the q blocks inner or not."""
+    qi, ki = (3, 2) if q_inner else (2, 3)      # their grid dimensions
+    qspec = pl.BlockSpec((None, bq, per * dp), lambda *g: (g[0], g[qi], g[1]),
+                         memory_space=pltpu.VMEM)
+    qrow = pl.BlockSpec((None, None, per, bq),
+                        lambda *g: (g[0], g[1], 0, g[qi]),
+                        memory_space=pltpu.VMEM)
+    kspec = pl.BlockSpec((None, bk, per * dp), lambda *g: (g[0], g[ki], g[1]),
+                         memory_space=pltpu.VMEM)
+    return qspec, qrow, kspec
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(3, 12)))
 def _flash_forward(q, k, v, causal, q_offset, k_offset, sm_scale, block_q,
-                   block_k, interpret, k_valid=None):
-    """Returns (out, lse) with lse [B*H, Sq, 1] f32 (the backward residual)."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:
-        raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
-    qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+                   block_k, interpret, k_valid=None, sub_k=None):
+    """q [B,Sq,H,D], k/v [B,Sk,H,D] -> (out [B,Sq,H,D], lse [B,H,Sq] f32, the
+    backward residual)."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
+    per, dp, hp = _head_blocks(h, d)
     kernel = functools.partial(
-        _flash_kernel, block_k=block_k, causal=causal, q_offset=q_offset,
-        k_offset=k_offset, sm_scale=sm_scale, block_q=block_q, k_valid=k_valid)
+        _flash_kernel, heads=per, head_dim=dp, block_q=bq, block_k=bk,
+        sub_k=sub_k, causal=causal, q_offset=q_offset, k_offset=k_offset,
+        sm_scale=sm_scale, k_valid=k_valid)
+    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=False)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // block_q, sk // block_k),  # k innermost: scratch carries
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kb: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        grid=(b, hp // per, sq // bq, sk // bk),  # k innermost: scratch carries
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, qrow],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
+            jax.ShapeDtypeStruct((b, hp // per, per, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((per, bq, _LANES), jnp.float32),
+            pltpu.VMEM((per, bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, per * dp), jnp.float32),
         ],
-        # bh and q-block steps are independent (scratch re-inits at kb==0);
-        # only the innermost k dim carries state. Declaring that lets Mosaic
-        # overlap DMA and compute across grid steps instead of serializing
-        # the whole grid.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(b, h, sq, d), lse
+        name="flash_fwd",
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp))
+    return _from_blocks(out, h, d, dp), lse.reshape(b, hp, sq)[:, :h]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def flash_attention(q, k, v, causal: bool = False, q_offset: int = 0,
-                    k_offset: int = 0, sm_scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None,
-                    k_valid: int | None = None):
-    """Flash attention: softmax(q k^T / sqrt(d)) v without materializing scores.
-
-    q [B,H,Sq,D], k/v [B,H,Sk,D] -> [B,H,Sq,D]. ``q_offset``/``k_offset`` are the
-    global positions of the local blocks (used by ring attention for causal
-    masking across rotated K/V shards). ``k_valid`` (static) masks keys at
-    global position >= it — the padded tail when Sk was padded to a block
-    multiple (see :func:`flash_mha`).
-    """
-    sm_scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
-    return _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
-                            block_k, interpret, k_valid)(q, k, v)[0]
+def _flash_lse(q, k, v, causal, q_offset, k_offset, sm_scale, block_q,
+               block_k, interpret, k_valid):
+    """The kernels' differentiable entry, sequence-major: q [B,Sq,H,D], k/v
+    [B,Sk,H,D] -> (out [B,Sq,H,D], lse [B,H,Sq] f32). Differentiable in both
+    outputs (the lse cotangent folds into the score gradient as
+    ``ds += p * g_lse``)."""
+    return _flash_lse_fwd(q, k, v, causal, q_offset, k_offset, sm_scale,
+                          block_q, block_k, interpret, k_valid)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash_lse_fwd(q, k, v, causal, q_offset, k_offset, sm_scale, block_q,
+                   block_k, interpret, k_valid):
+    out, lse = _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
+                                block_k, interpret, k_valid)(q, k, v)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_lse_bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
+                   interpret, k_valid, residuals, gs):
+    """``g_lse`` (the lse-output cotangent, [B,H,Sq]) folds into the score
+    gradient: d lse_i / d s_ij = p_ij, so ds = p * (dp - D + g_lse) — carried
+    by passing D' = D - g_lse through the unchanged kernels."""
+    q, k, v, out, lse = residuals
+    g, g_lse = gs
+    # D_i = dO_i . O_i (the softmax-normalizer correction), cheap elementwise
+    # — stays outside the partitioned call, GSPMD shards it fine.
+    dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    dvec = dvec.transpose(0, 2, 1) - g_lse.astype(jnp.float32)
+    return _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q,
+                            block_k, interpret, k_valid)(q, k, v, lse, g, dvec)
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _swap_sh(x):
+    """[B,H,S,D] <-> [B,S,H,D]."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def flash_attention_lse(q, k, v, causal: bool = False, q_offset: int = 0,
                         k_offset: int = 0, sm_scale: float | None = None,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: int | None = None, block_k: int | None = None,
                         interpret: bool | None = None,
                         k_valid: int | None = None):
     """Flash attention that also returns the per-row logsumexp.
 
-    Returns ``(out [B,H,Sq,D], lse [B,H,Sq] f32)`` with
+    q [B,H,Sq,D], k/v [B,H,Sk,D] -> ``(out [B,H,Sq,D], lse [B,H,Sq] f32)`` with
     ``lse = logsumexp_k(q.k * sm_scale)`` over this call's (masked) keys. The
     residual a caller needs to softmax-combine partial attention over disjoint
     key sets — :func:`ddw_tpu.parallel.ring_attention.ring_attention` folds one
-    of these per ring hop. Differentiable in both outputs (the lse cotangent
-    folds into the score gradient as ``ds += p * g_lse``)."""
+    of these per ring hop. ``q_offset``/``k_offset`` are the global positions
+    of the local blocks (used by ring attention for causal masking across
+    rotated K/V shards). ``k_valid`` (static) masks keys at global position >=
+    it — the padded tail when Sk was padded to a block multiple (see
+    :func:`flash_mha`). Differentiable in both outputs. The kernels work
+    sequence-major; this entry transposes in and out."""
     sm_scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
-    return _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
-                            block_k, interpret, k_valid)(q, k, v)
+    out, lse = _flash_lse(_swap_sh(q), _swap_sh(k), _swap_sh(v), causal,
+                          q_offset, k_offset, sm_scale, block_q, block_k,
+                          interpret, k_valid)
+    return _swap_sh(out), lse
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref, dq_scr,
-               *, block_q: int, block_k: int, causal: bool, q_offset: int,
-               k_offset: int, sm_scale: float, k_valid: int | None):
-    """dQ pass (FA2 backward): grid (BH, q-blocks, k-blocks), K innermost.
+def flash_attention(q, k, v, causal: bool = False, q_offset: int = 0,
+                    k_offset: int = 0, sm_scale: float | None = None,
+                    block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool | None = None,
+                    k_valid: int | None = None):
+    """Flash attention: softmax(q k^T / sqrt(d)) v without materializing scores.
 
-    p_ij = exp(s_ij - L_i) rematerialized per block from the saved logsumexp;
-    ds_ij = p_ij * (dO_i . v_j - D_i); dq_i += sm_scale * ds_ij k_j. The S x S
-    matrices exist only blockwise in VMEM.
+    q [B,H,Sq,D], k/v [B,H,Sk,D] -> [B,H,Sq,D]; the arguments of
+    :func:`flash_attention_lse`, its first output."""
+    return flash_attention_lse(q, k, v, causal, q_offset, k_offset, sm_scale,
+                               block_q, block_k, interpret, k_valid)[0]
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
+               dq_scr, lse_scr, dvec_scr, *, heads: int, head_dim: int,
+               block_q: int, block_k: int, sub_k: int, causal: bool,
+               q_offset: int, k_offset: int, sm_scale: float,
+               k_valid: int | None):
+    """dQ pass (FA2 backward): grid (B, head blocks, q-blocks, k-blocks), K
+    innermost.
+
+    p_ij = exp(s_ij - L_i) rematerialized per sub-block from the saved
+    logsumexp; ds_ij = p_ij * (dO_i . v_j - D_i); dq_i += sm_scale * ds_ij k_j.
+    The S x S matrices exist only sub-block-wise in VMEM. L and D arrive as
+    lane-dense rows and are turned once a q block into lane-replicated
+    ``[block_q, 128]`` columns (sublane broadcast + one aligned transpose).
     """
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    num_kb = pl.num_programs(2)
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+    num_kb = pl.num_programs(3)
+    masks = _head_masks(heads, head_dim, dq_scr.shape[-1])
 
     @pl.when(kb == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        for t in range(heads):
+            lse_scr[t] = jnp.broadcast_to(_finite_ref(lse_ref[t:t + 1, :]),
+                                          (_LANES, block_q)).T
+            dvec_scr[t] = jnp.broadcast_to(dvec_ref[t:t + 1, :],
+                                           (_LANES, block_q)).T
 
-    q_last = q_offset + qi * block_q + block_q - 1
-    k_first = k_offset + kb * block_k
-    visible = (k_first <= q_last) if causal else True
-    if k_valid is not None:
-        visible = visible & (k_first < k_valid)
+    q0 = q_offset + qi * block_q
+    k0 = k_offset + kb * block_k
+    bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    @pl.when(visible)
-    def _accum():
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        do = do_ref[0]
-        s = _masked_scores(q, k_blk, q_offset + qi * block_q,
-                           k_offset + kb * block_k, causal, sm_scale,
-                           block_q, block_k, k_valid)
-        p = _guarded_exp(s, lse_ref[0], causal or k_valid is not None)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0])
-        dq_scr[:] += sm_scale * jnp.dot(
-            ds.astype(q.dtype), k_blk, preferred_element_type=jnp.float32)
+    for t, mask in enumerate(masks):
+        q = _only(mask, q_ref[...])
+        do = _only(mask, do_ref[...])
+
+        def _accum(j, masked):
+            ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
+            k_blk = k_ref[ks, :]
+            s = _scores(q, k_blk, sm_scale)              # [block_q, sub_k]
+            if masked:
+                s = _mask_scores(s, q0, k0 + j * sub_k, causal, k_valid, 1)
+            p = jnp.exp(s - _lanes(lse_scr[t], sub_k))
+            dp = _scores(do, v_ref[ks, :], 1.0)
+            ds = p * (dp - _lanes(dvec_scr[t], sub_k))
+            dq = jnp.dot(ds.astype(q.dtype), k_blk,
+                         preferred_element_type=jnp.float32)
+            dq_scr[...] += dq if mask is None else jnp.where(mask, dq, 0.0)
+
+        _for_sub_blocks(*bounds, _accum)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[...] = (sm_scale * dq_scr[...]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, block_q: int, block_k: int, causal: bool,
-                q_offset: int, k_offset: int, sm_scale: float,
-                k_valid: int | None):
-    """dK/dV pass: grid (BH, k-blocks, q-blocks), Q innermost.
+                dk_scr, dv_scr, *, heads: int, head_dim: int, block_q: int,
+                block_k: int, sub_k: int, causal: bool, q_offset: int,
+                k_offset: int, sm_scale: float, k_valid: int | None):
+    """dK/dV pass: grid (B, head blocks, k-blocks, q-blocks), Q innermost.
 
-    dv_j += p_ij^T dO_i; dk_j += sm_scale * ds_ij^T q_i.
+    dv_j += p_ij^T dO_i; dk_j += sm_scale * ds_ij^T q_i. Works on TRANSPOSED
+    scores s^T = k q^T ``[sub_k, block_q]``: p^T and ds^T are then the left
+    operands of plain matmuls (no score tile is ever transposed) and L and D
+    broadcast along sublanes from their lane-dense rows.
     """
-    kj = pl.program_id(1)
-    qb = pl.program_id(2)
-    num_qb = pl.num_programs(2)
+    kj = pl.program_id(2)
+    qb = pl.program_id(3)
+    num_qb = pl.num_programs(3)
+    masks = _head_masks(heads, head_dim, dk_scr.shape[-1])
 
     @pl.when(qb == 0)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q_last = q_offset + qb * block_q + block_q - 1
-    k_first = k_offset + kj * block_k
-    visible = (k_first <= q_last) if causal else True
-    if k_valid is not None:
-        visible = visible & (k_first < k_valid)
+    q0 = q_offset + qb * block_q
+    k0 = k_offset + kj * block_k
+    bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    @pl.when(visible)
-    def _accum():
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0]
-        s = _masked_scores(q, k_blk, q_offset + qb * block_q,
-                           k_offset + kj * block_k, causal, sm_scale,
-                           block_q, block_k, k_valid)
-        p = _guarded_exp(s, lse_ref[0], causal or k_valid is not None)
-        dv_scr[:] += jnp.dot(p.astype(do.dtype).T, do,
-                             preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0])
-        dk_scr[:] += sm_scale * jnp.dot(
-            ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32)
+    for t, mask in enumerate(masks):
+        q = _only(mask, q_ref[...])
+        do = _only(mask, do_ref[...])
+        lse = _finite_ref(lse_ref[t:t + 1, :])           # [1, block_q]
+        dvec = dvec_ref[t:t + 1, :]
+
+        def _accum(j, masked):
+            ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
+            st = _scores(k_ref[ks, :], q, sm_scale)      # [sub_k, block_q]
+            if masked:
+                st = _mask_scores(st, q0, k0 + j * sub_k, causal, k_valid, 0)
+            pt = jnp.exp(st - lse)
+            dv = jnp.dot(pt.astype(do.dtype), do,
+                         preferred_element_type=jnp.float32)
+            dpt = _scores(v_ref[ks, :], do, 1.0)
+            dst = pt * (dpt - dvec)
+            dk = jnp.dot(dst.astype(q.dtype), q,
+                         preferred_element_type=jnp.float32)
+            dv_scr[ks, :] += dv          # q and do carry this head's lanes
+            dk_scr[ks, :] += dk          # only, so dv and dk do too
+
+        _for_sub_blocks(*bounds, _accum)
 
     @pl.when(qb == num_qb - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = (sm_scale * dk_scr[...]).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
+def _flash_dq(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
+              block_q, block_k, interpret, k_valid=None, sub_k=None):
+    """q, g [B,Sq,H,D]; k, v [B,Sk,H,D]; lse, dvec [B,H,Sq] f32 -> dq."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
+    per, dp, hp = _head_blocks(h, d)
+    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=False)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, heads=per, head_dim=dp, block_q=bq,
+                          block_k=bk, sub_k=sub_k, causal=causal,
+                          q_offset=q_offset, k_offset=k_offset,
+                          sm_scale=sm_scale, k_valid=k_valid),
+        grid=(b, hp // per, sq // bq, sk // bk),
+        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, per * dp), jnp.float32),
+                        pltpu.VMEM((per, bq, _LANES), jnp.float32),
+                        pltpu.VMEM((per, bq, _LANES), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="flash_dq",
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp),
+      _to_blocks(g, dp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
+    return _from_blocks(dq, h, d, dp)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
+def _flash_dkv(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
+               block_q, block_k, interpret, k_valid=None, sub_k=None):
+    """Same operands as :func:`_flash_dq` -> (dk, dv)."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
+    per, dp, hp = _head_blocks(h, d)
+    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=True)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, heads=per, head_dim=dp, block_q=bq,
+                          block_k=bk, sub_k=sub_k, causal=causal,
+                          q_offset=q_offset, k_offset=k_offset,
+                          sm_scale=sm_scale, k_valid=k_valid),
+        grid=(b, hp // per, sk // bk, sq // bq),
+        in_specs=[kspec, kspec, qspec, qspec, qrow, qrow],
+        out_specs=[kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct((b, sk, hp * dp), k.dtype),
+                   jax.ShapeDtypeStruct((b, sk, hp * dp), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, per * dp), jnp.float32),
+                        pltpu.VMEM((bk, per * dp), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="flash_dkv",
+    )(_to_blocks(k, dp, hp), _to_blocks(v, dp, hp), _to_blocks(q, dp, hp),
+      _to_blocks(g, dp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
+    return _from_blocks(dk, h, d, dp), _from_blocks(dv, h, d, dp)
 
 
 @functools.lru_cache(maxsize=None)
 def _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
                      interpret, k_valid):
-    """(q, k, v, lse3, g, dvec3) -> (dq, dk, dv), batch/head-partitioned.
+    """(q, k, v, lse, g, dvec) -> (dq, dk, dv), batch/head-partitioned.
 
     Pallas FA2 backward: two block kernels (dQ; dK/dV) over the saved
-    logsumexp — O(S) memory, the S x S matrices never leave VMEM. ``lse3`` and
-    ``dvec3`` arrive as [B,H,Sq] so every operand has the (b, h) leading dims
+    logsumexp — O(S) memory, the S x S matrices never leave VMEM. ``lse`` and
+    ``dvec`` arrive as [B,H,Sq] so every operand has the batch and heads dims
     the partition rule shards."""
 
-    def impl(q, k, v, lse3, g, dvec3):
-        b, h, sq, d = q.shape
-        sk = k.shape[2]
-        bq = min(block_q, sq)
-        bk = min(block_k, sk)
-
-        qr = q.reshape(b * h, sq, d)
-        kr = k.reshape(b * h, sk, d)
-        vr = v.reshape(b * h, sk, d)
-        gr = g.reshape(b * h, sq, d)
-        lse = lse3.reshape(b * h, sq, 1)
-        dvec = dvec3.reshape(b * h, sq, 1)
-
-        qspec = pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0),
-                             memory_space=pltpu.VMEM)
-        qrow = pl.BlockSpec((1, bq, 1), lambda i, j, kb: (i, j, 0),
-                            memory_space=pltpu.VMEM)
-        kspec_stream = pl.BlockSpec((1, bk, d), lambda i, j, kb: (i, kb, 0),
-                                    memory_space=pltpu.VMEM)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, block_q=bq, block_k=bk, causal=causal,
-                              q_offset=q_offset, k_offset=k_offset,
-                              sm_scale=sm_scale, k_valid=k_valid),
-            grid=(b * h, sq // bq, sk // bk),
-            in_specs=[qspec, kspec_stream, kspec_stream, qspec, qrow, qrow],
-            out_specs=qspec,
-            out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-        )(qr, kr, vr, gr, lse, dvec)
-
-        kspec = pl.BlockSpec((1, bk, d), lambda i, j, qb: (i, j, 0),
-                             memory_space=pltpu.VMEM)
-        qspec_stream = pl.BlockSpec((1, bq, d), lambda i, j, qb: (i, qb, 0),
-                                    memory_space=pltpu.VMEM)
-        qrow_stream = pl.BlockSpec((1, bq, 1), lambda i, j, qb: (i, qb, 0),
-                                   memory_space=pltpu.VMEM)
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, block_q=bq, block_k=bk, causal=causal,
-                              q_offset=q_offset, k_offset=k_offset,
-                              sm_scale=sm_scale, k_valid=k_valid),
-            grid=(b * h, sk // bk, sq // bq),
-            in_specs=[kspec, kspec, qspec_stream, qspec_stream, qrow_stream,
-                      qrow_stream],
-            out_specs=[kspec, kspec],
-            out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-        )(kr, vr, qr, gr, lse, dvec)
-
-        return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-                dv.reshape(b, h, sk, d))
+    def impl(q, k, v, lse, g, dvec):
+        args = (q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
+                block_q, block_k, interpret, k_valid)
+        return (_flash_dq(*args),) + _flash_dkv(*args)
 
     fn = custom_partitioning(impl)
     return _def_bh_partition(
         fn, impl,
-        "b h q d, b h s d, b h s d, b h q, b h q d, b h q -> "
-        "b h q d, b h s d, b h s d",
-        n_in=6, out_ndims=(4, 4, 4))
+        "b q h d, b s h d, b s h d, b h q, b q h d, b h q -> "
+        "b q h d, b s h d, b s h d",
+        out_ndims=(4, 4, 4))
 
 
-def _bwd_impl(causal, q_offset, k_offset, sm_scale, block_q, block_k, interpret,
-              k_valid, residuals, g, g_lse=None):
-    """Shared VJP body. ``g_lse`` (the lse-output cotangent, [B,H,Sq] or None)
-    folds into the score gradient: d lse_i / d s_ij = p_ij, so
-    ds = p * (dp - D + g_lse) — carried by passing D' = D - g_lse through the
-    unchanged kernels."""
-    q, k, v, out, lse3 = residuals
-    sm_scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
-    # D_i = dO_i . O_i (the softmax-normalizer correction), cheap elementwise
-    # — stays outside the partitioned call, GSPMD shards it fine.
-    dvec3 = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    if g_lse is not None:
-        dvec3 = dvec3 - g_lse.astype(jnp.float32)
-    return _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q,
-                            block_k, interpret, k_valid)(q, k, v, lse3, g, dvec3)
+# Block caps, from tools/fa2_sweep.py --preset blocks on one TPU v5e chip (PR 26;
+# [8,16,1024,64] bf16 causal; ms for forward / dQ / dK,dV at (block_q, block_k,
+# sub_k)): (512,1024,512) 0.57 / 0.73 / 0.84 — the fastest of 18 for each of the
+# three kernels; (512,512,512) 0.61 / 0.79 / 0.89; (256,1024,512) 0.63 / 0.82 /
+# 1.05; (1024,1024,512) 0.68 / 0.83 / 0.99; (512,1024,256) 0.73 / 0.85 / 0.89;
+# (512,1024,128) 1.09 / 1.06 / 1.04; (256,512,128) 1.44 / 1.37 / 1.63. The
+# parent's kernels ([B,H,S,D] operands, 128 x 128 blocks, (block, 1) statistics)
+# took 10.10 ms for the three together. Wide sub-blocks win (fewer loop steps,
+# longer MXU runs) until the causal work they cannot skip outweighs it; q
+# blocks of 512 keep two per head at S = 1024, so the upper-right key sub-block
+# of the first is skipped.
+_BLOCK_Q_MAX = 512
+_BLOCK_K_MAX = 1024
+_SUB_K_MAX = 512
 
 
-def _fwd(q, k, v, causal, q_offset, k_offset, sm_scale, block_q, block_k,
-         interpret, k_valid):
-    sm_scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
-    out, lse3 = _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
-                                 block_k, interpret, k_valid)(q, k, v)
-    return out, (q, k, v, out, lse3)
+def _pick_block(s: int, block: int | None, cap: int) -> int:
+    """The block for a sequence of length ``s``: a multiple of 128 — it is the
+    lane dim of the kernels' score tiles and of the logsumexp rows, and the
+    sublane dim of the q/k/v tiles — so ``s`` is padded UP to a block multiple
+    rather than the block shrunk to ``s`` (a block of exactly s=100 lowers in
+    interpret mode but fails Mosaic tiling on real TPU). ``block=None`` is the
+    code's own choice: the fewest blocks of at most ``cap`` rows, sized to
+    pad least. An explicit ``block`` (tests, the sweep tool) still clamps to a
+    short sequence."""
+    aligned = -(-max(s, 1) // _LANES) * _LANES
+    if block is not None:
+        return max(_LANES, min(block, aligned) // _LANES * _LANES)
+    n_blocks = -(-aligned // cap)
+    return -(-aligned // (n_blocks * _LANES)) * _LANES
 
 
-def _bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k, interpret,
-         k_valid, residuals, g):
-    return _bwd_impl(causal, q_offset, k_offset, sm_scale, block_q, block_k,
-                     interpret, k_valid, residuals, g)
+def _pick_sub_block(block_k: int) -> int:
+    """The widest multiple of 128 up to ``_SUB_K_MAX`` that divides the block."""
+    return max(c for c in range(_LANES, min(block_k, _SUB_K_MAX) + 1, _LANES)
+               if block_k % c == 0)
 
 
-flash_attention.defvjp(_fwd, _bwd)
-
-
-def _fwd_lse(q, k, v, causal, q_offset, k_offset, sm_scale, block_q, block_k,
-             interpret, k_valid):
-    sm_scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
-    out, lse3 = _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
-                                 block_k, interpret, k_valid)(q, k, v)
-    return (out, lse3), (q, k, v, out, lse3)
-
-
-def _bwd_lse(causal, q_offset, k_offset, sm_scale, block_q, block_k, interpret,
-             k_valid, residuals, gs):
-    g, g_lse = gs
-    return _bwd_impl(causal, q_offset, k_offset, sm_scale, block_q, block_k,
-                     interpret, k_valid, residuals, g, g_lse)
-
-
-flash_attention_lse.defvjp(_fwd_lse, _bwd_lse)
-
-
-def _pick_block(s: int, block: int, dtype) -> int:
-    """Choose a Mosaic-tile-aligned block size for a sequence of length ``s``.
-
-    The block is the second-minor dim of the kernel's VMEM tiles, so it must be
-    a multiple of the sublane tile (16 for bf16/f16, 8 otherwise); ``s`` is
-    then padded UP to a multiple of the block rather than the block shrunk to
-    ``s`` (a block of exactly s=100 lowers in interpret mode but fails Mosaic
-    tiling on real TPU)."""
-    tile = 16 if dtype in (jnp.bfloat16, jnp.float16) else 8
-    aligned = -(-max(s, 1) // tile) * tile
-    return max(tile, min(block, aligned) // tile * tile)
+def _resolve_blocks(sq: int, sk: int, block_q, block_k, sub_k):
+    block_q = _pick_block(sq, block_q, _BLOCK_Q_MAX)
+    block_k = _pick_block(sk, block_k, _BLOCK_K_MAX)
+    if sq % block_q or sk % block_k:
+        raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks "
+                         f"({block_q},{block_k}); flash_mha pads")
+    return block_q, block_k, sub_k or _pick_sub_block(block_k)
 
 
 def _pad_seq(x, mult):
-    """Zero-pad the sequence axis (dim 2 of [B,H,S,D]) up to a multiple."""
-    s = x.shape[2]
-    pad = (-s) % mult
+    """Zero-pad the sequence axis (dim 1 of [B,S,H,D]) up to a multiple."""
+    pad = (-x.shape[1]) % mult
     if pad == 0:
         return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
 
 # ---------------------------------------------------------------------------
-# Size-based dispatch: the Pallas kernels exist for long-context O(S) memory,
-# but at moderate S a plain XLA attention is FASTER on TPU (measured on v5e,
-# differential timing: ViT shapes [256,4,197,48] fwd+grad 3.2 ms XLA vs
-# 10.1 ms Pallas; LM shapes [8,8,2048,64] causal 14.5 ms vs 36.2 ms — the
-# FA2 backward's blockwise rematerialization can't beat one fused S² einsum
-# while the score matrix still fits). The model-facing entries therefore
-# dispatch on the score-matrix footprint: plain XLA when small, jax.checkpoint
-# XLA (O(S) residuals, S² transient in backward) when moderate, Pallas flash
-# when the S² matrix is genuinely memory-infeasible.
+# Dispatch. Three tiers compute the same attention: plain XLA (one fused
+# einsum chain, S² scores through HBM, saved for the backward), jax.checkpoint
+# XLA (O(S) residuals, the S² tensors transient in the backward) and the Pallas
+# flash kernels (scores never leave VMEM). What the kernels' advantage depends
+# on is the sequence length: the XLA tiers make memory-bound passes over
+# B*H*Sq*Sk float32 scores, the kernels do the same arithmetic from VMEM, and
+# batch and heads scale both sides alike. Measured on one TPU v5e chip
+# (tools/fa2_sweep.py --preset cells,ladder [--dim 128], PR 26; forward +
+# backward, bf16, 16 heads, B*S = 8192 tokens; ms for xla / xla_ckpt / pallas):
+#
+#      S    D = 64, causal          D = 64, not causal      D = 128, causal
+#    256    1.64 /  2.18 / 1.35     1.64 /  2.18 / 0.99     1.88 /  2.73 / 2.21
+#    512    3.51 /  4.64 / 1.39     3.50 /  4.64 / 1.15     3.67 /  5.11 / 2.15
+#   1024    6.88 /  9.27 / 1.87     6.87 /  9.27 / 2.30     7.07 /  9.65 / 2.69
+#   2048   13.47 / 18.03 / 2.98    13.45 / 17.98 / 4.31    13.79 / 18.47 / 4.15
+#   4096   26.69 / 35.49 / 5.11    26.72 / 35.49 / 8.22    27.55 / 36.24 / 6.54
+#   ViT-B/16's [128,12,196,64], not causal, [B,H,S,D] operands: 4.62 / 6.39 /
+#   4.57 (196 pads to 256, and the kernels' layout costs two transposes each way)
+#
+# (S = 1024, D = 64, causal is the GPT-2 medium cells' shape.) So sequences of
+# _FLASH_MIN_SEQ and more go to the kernels: 2.5x at 512 and 5.2x at 4096 at
+# D = 64, 1.7x and 4.2x at D = 128. At 256 the kernels win at D = 64 and lose
+# at D = 128, and a length that pads by a third (196) ties: the XLA tiers keep
+# those. Below the crossover the score footprint chooses between the XLA
+# tiers: plain while the saved S² tensors are small, checkpointed above that,
+# and the kernels again where even the transient S² tensor is
+# memory-infeasible.
 # ---------------------------------------------------------------------------
+
+_FLASH_MIN_SEQ = 512
 
 # Score-matrix bytes (B*H*Sq*Sk*4, f32) thresholds; env-overridable for tuning.
 _XLA_PLAIN_MAX = int(os.environ.get("DDW_ATTN_XLA_PLAIN_MAX", 256 * 1024**2))
@@ -592,10 +789,14 @@ def _xla_attention_lse(q, k, v, causal: bool, q_offset, k_offset,
 
 
 def _attn_impl(q, k, impl: str) -> str:
+    """The tier for q [B,H,Sq,D] and k [B,H,Sk,D] (shapes are all it reads)."""
     if impl != "auto":
         return impl
     b, h, sq, _ = q.shape
-    score_bytes = b * h * sq * k.shape[2] * 4
+    sk = k.shape[2]
+    if min(sq, sk) >= _FLASH_MIN_SEQ:
+        return "pallas"
+    score_bytes = b * h * sq * sk * 4
     if score_bytes <= _XLA_PLAIN_MAX:
         return "xla"
     if score_bytes <= _XLA_CKPT_MAX:
@@ -604,13 +805,14 @@ def _attn_impl(q, k, impl: str) -> str:
 
 
 def flash_mha(q, k, v, causal: bool = False, sm_scale: float | None = None,
-              block_q: int = 128, block_k: int = 128,
+              block_q: int | None = None, block_k: int | None = None,
               interpret: bool | None = None, impl: str = "auto") -> jnp.ndarray:
-    """Attention for arbitrary sequence lengths (the model-facing entry).
+    """Attention for arbitrary sequence lengths (the model-facing entry),
+    q [B,H,Sq,D], k/v [B,H,Sk,D] -> [B,H,Sq,D].
 
-    ``impl``: ``auto`` (size-based dispatch, see module comment), ``xla``,
-    ``xla_ckpt`` (rematerialized backward), or ``pallas`` (the flash kernel —
-    pads Sq/Sk to tile-aligned block multiples, masks padded keys via
+    ``impl``: ``auto`` (dispatch on the sequence length, see module comment),
+    ``xla``, ``xla_ckpt`` (rematerialized backward), or ``pallas`` (the flash
+    kernel — pads Sq/Sk to block multiples, masks padded keys via
     ``k_valid``, slices padded query rows back off, so ViT's 196-patch
     sequences or any other length run on the same kernel the LM uses)."""
     return flash_mha_lse(q, k, v, causal, sm_scale, block_q, block_k,
@@ -618,7 +820,7 @@ def flash_mha(q, k, v, causal: bool = False, sm_scale: float | None = None,
 
 
 def flash_mha_lse(q, k, v, causal: bool = False, sm_scale: float | None = None,
-                  block_q: int = 128, block_k: int = 128,
+                  block_q: int | None = None, block_k: int | None = None,
                   interpret: bool | None = None, impl: str = "auto"):
     """Padded-length attention with logsumexp — ``(out, lse [B,H,Sq])``.
 
@@ -627,28 +829,42 @@ def flash_mha_lse(q, k, v, causal: bool = False, sm_scale: float | None = None,
     per hop so arbitrary local shard lengths work."""
     with jax.named_scope("attention"):      # every tier, for a profile's split
         return _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k,
-                             interpret, impl)
+                             interpret, _attn_impl(q, k, impl),
+                             seq_major=False)
+
+
+def flash_mha_seq_major(q, k, v, causal: bool = False,
+                        sm_scale: float | None = None,
+                        impl: str = "auto") -> jnp.ndarray:
+    """:func:`flash_mha` for operands as the projections produce them:
+    q [B,Sq,H,D], k/v [B,Sk,H,D] -> [B,Sq,H,D]. The kernels take that layout
+    as it is; the XLA tiers get the ``[B,H,S,D]`` transposes they always got."""
+    with jax.named_scope("attention"):
+        tier = _attn_impl(_swap_sh(q), _swap_sh(k), impl)
+        return _dispatch_lse(q, k, v, causal, sm_scale, None, None, None,
+                             tier, seq_major=True)[0]
 
 
 def _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                  impl):
-    chosen = _attn_impl(q, k, impl)
+                  chosen, seq_major):
+    scale, interpret = _resolve_defaults(sm_scale, interpret, q.shape[-1])
     if chosen in ("xla", "xla_ckpt"):
-        scale, _ = _resolve_defaults(sm_scale, interpret, q.shape[-1])
         fn = functools.partial(_xla_attention_lse, causal=causal, q_offset=0,
                                k_offset=0, sm_scale=scale, k_valid=None)
         if chosen == "xla_ckpt":
             fn = jax.checkpoint(fn)
-        return fn(q, k, v)
-    sq, sk = q.shape[2], k.shape[2]
-    bq = _pick_block(sq, block_q, q.dtype)
-    bk = _pick_block(sk, block_k, k.dtype)
-    qp = _pad_seq(q, bq)
+        if not seq_major:
+            return fn(q, k, v)
+        out, lse = fn(_swap_sh(q), _swap_sh(k), _swap_sh(v))
+        return _swap_sh(out), lse
+    if not seq_major:
+        q, k, v = _swap_sh(q), _swap_sh(k), _swap_sh(v)
+    sq, sk = q.shape[1], k.shape[1]
+    bq = _pick_block(sq, block_q, _BLOCK_Q_MAX)
+    bk = _pick_block(sk, block_k, _BLOCK_K_MAX)
     kp = _pad_seq(k, bk)
-    vp = _pad_seq(v, bk)
-    k_valid = sk if kp.shape[2] != sk else None
-    out, lse = flash_attention_lse(qp, kp, vp, causal, 0, 0, sm_scale, bq, bk,
-                                   interpret, k_valid)
-    if qp.shape[2] != sq:
-        out, lse = out[:, :, :sq], lse[:, :, :sq]
-    return out, lse
+    out, lse = _flash_lse(_pad_seq(q, bq), kp, _pad_seq(v, bk), causal, 0, 0,
+                          scale, bq, bk, interpret,
+                          sk if kp.shape[1] != sk else None)
+    out, lse = out[:, :sq], lse[:, :, :sq]
+    return (out if seq_major else _swap_sh(out)), lse

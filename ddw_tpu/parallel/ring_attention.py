@@ -62,7 +62,7 @@ def _combine(o1, lse1, o2, lse2):
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
                    sm_scale: float | None = None,
-                   block_q: int = 128, block_k: int = 128,
+                   block_q: int | None = None, block_k: int | None = None,
                    impl: str = "auto") -> jnp.ndarray:
     """Blockwise ring attention over ``axis_name``.
 
@@ -71,8 +71,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     ``shard_map``/``pmap`` binding ``axis_name``. ``impl`` selects the per-hop
     attention arm (``auto``/``xla``/``xla_ckpt``/``pallas`` — see
     :func:`ddw_tpu.ops.flash_attention.flash_mha_lse`): auto picks by the
-    LOCAL S_local x S_local score footprint, so moderate shards get the fused
-    XLA arm and long-context shards the Pallas flash kernel.
+    LOCAL shard length, so short shards get the fused XLA arm and shards of
+    512 tokens and more the Pallas flash kernel.
     """
     n = axis_size(axis_name)
     me = lax.axis_index(axis_name)

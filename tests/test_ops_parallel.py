@@ -1,6 +1,8 @@
 """Pallas flash attention + ring attention + TP sharding tests (8-dev CPU mesh;
 pallas runs in interpret mode off-TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,47 +26,141 @@ def _qkv(b=2, h=2, s=256, d=64, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
-def test_flash_matches_reference():
-    q, k, v = _qkv()
-    out = flash_attention(q, k, v)
-    ref = mha_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+def _ref_attention_lse(q, k, v, causal=False, q_offset=0, k_offset=0):
+    """mha_reference's arithmetic (f32 logits, masked at global positions) with
+    the logsumexp beside the output — the oracle for out, lse and gradients."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        qpos = q_offset + jnp.arange(q.shape[2])[:, None]
+        kpos = k_offset + jnp.arange(k.shape[2])[None, :]
+        logits = jnp.where(kpos <= qpos, logits, -1e30)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(logits - lse[..., None]), v)
+    return out, lse
 
 
-def test_flash_causal():
+# Every kernel configuration the dispatch can choose, and the explicit blocks
+# that reach the same code paths at small sizes: sq/sk, head dim, dtype, mask,
+# ring offsets, blocks (None = the code's choice), and whether the call goes
+# through flash_mha's padding. Each is held to the oracle for out, lse and all
+# three gradients, with a cotangent on lse as well as on out.
+_KERNEL_CASES = {
+    "code_blocks": dict(s=256),
+    "code_blocks_causal": dict(s=256, causal=True),
+    "code_blocks_bf16": dict(s=256, causal=True, dtype=jnp.bfloat16),
+    # heads a 128-lane block: four at d = 32 (two of them padding here), one at
+    # d = 128, two at d = 64 with the odd third head's partner padded
+    "d32_three_blocks": dict(s=384, d=32, causal=True, block_q=128, block_k=128),
+    "d128_one_head_a_block": dict(s=256, d=128, causal=True),
+    "three_heads": dict(h=3, s=256, causal=True),
+    # one block, larger than the sequence: clamps to it
+    "block_larger_than_seq": dict(s=128, causal=True, block_q=512, block_k=1024),
+    "block_q_under_block_k": dict(s=512, causal=True, block_q=128, block_k=256),
+    "block_q_over_block_k": dict(s=512, causal=True, block_q=256, block_k=128),
+    # the LM cells' shape a head: q blocks of 512 over one key block of 1024 in
+    # 512-wide sub-blocks — one the diagonal crosses, one wholly above it
+    # (skipped), one wholly below (unmasked)
+    "cell_blocks": dict(b=1, h=1, s=1024, d=64, causal=True),
+    "cell_blocks_bf16": dict(b=1, h=1, s=1024, d=64, causal=True,
+                             dtype=jnp.bfloat16),
+    "cell_blocks_not_causal": dict(b=1, h=1, s=1024, d=64),
+    "two_key_blocks": dict(b=1, h=1, s=2048, d=64, causal=True),
+    # ring attention's hops: a shard in the past (fully visible), a misaligned
+    # one, and a rectangular one
+    "ring_past_hop": dict(s=256, causal=True, q_offset=256),
+    "ring_misaligned": dict(s=256, causal=True, q_offset=64),
+    "ring_rectangular": dict(sq=128, sk=384, causal=True, q_offset=256),
+    # flash_mha pads to a block multiple and masks the padded keys (k_valid)
+    "padded_vit": dict(s=196, d=48, mha=True),
+    "padded_causal": dict(s=160, d=32, causal=True, mha=True),
+    "padded_large_block": dict(b=1, h=1, s=1100, d=64, causal=True, mha=True),
+    "padded_explicit_blocks": dict(s=300, causal=True, mha=True, block_q=128,
+                                   block_k=256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_flash_kernels_match_reference(case):
+    from ddw_tpu.ops.flash_attention import flash_attention_lse, flash_mha_lse
+
+    c = dict(_KERNEL_CASES[case])
+    dtype = c.get("dtype", jnp.float32)
+    causal, q_off, k_off = c.get("causal", False), c.get("q_offset", 0), \
+        c.get("k_offset", 0)
+    sq, sk = c.get("sq", c.get("s")), c.get("sk", c.get("s"))
+    b, h, d = c.get("b", 2), c.get("h", 2), c.get("d", 64)
+    rng = np.random.RandomState(len(case))
+    q, k, v = (jnp.asarray(rng.randn(b, h, n, d).astype(np.float32), dtype)
+               for n in (sq, sk, sk))
+    w_lse = jnp.cos(jnp.arange(sq, dtype=jnp.float32))      # lse cotangent
+
+    def attend(q, k, v):
+        if c.get("mha"):
+            return flash_mha_lse(q, k, v, causal, None, c.get("block_q"),
+                                 c.get("block_k"), impl="pallas")
+        return flash_attention_lse(q, k, v, causal, q_off, k_off, None,
+                                   c.get("block_q"), c.get("block_k"))
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return (jnp.sum(out.astype(jnp.float32) ** 2)
+                    + jnp.sum(lse * w_lse)), (out, lse)
+        return f
+
+    ref = functools.partial(_ref_attention_lse, causal=causal, q_offset=q_off,
+                            k_offset=k_off)
+    (got_g, (out, lse)) = jax.grad(loss(attend), argnums=(0, 1, 2),
+                                   has_aux=True)(q, k, v)
+    (ref_g, (ref_out, ref_lse)) = jax.grad(loss(ref), argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    tol, gtol = (2e-5, 1e-4) if dtype == jnp.float32 else (3e-2, 0.1)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref_out),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=1e-5 if dtype == jnp.float32 else 1e-2,
+                               atol=1e-5 if dtype == jnp.float32 else 1e-2)
+    for a, r, what in zip(got_g, ref_g, "qkv"):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
+                                   rtol=gtol, atol=gtol, err_msg=f"d{what}")
+
+
+@pytest.mark.parametrize("s,tier", [(640, "pallas"), (128, "xla")])
+def test_flash_mha_seq_major_matches_flash_mha(s, tier):
+    """The entry the LM calls ([B,S,H,D] operands) is flash_mha on transposed
+    operands, on the kernels and on the XLA tiers, forward and gradients."""
+    from ddw_tpu.ops.flash_attention import (_attn_impl, flash_mha,
+                                             flash_mha_seq_major)
+
+    q, k, v = _qkv(b=1, h=2, s=s, d=64, seed=12)
+    assert _attn_impl(q, k, "auto") == tier
+    t = lambda x: x.transpose(0, 2, 1, 3)                       # noqa: E731
+
+    def loss_seq(q, k, v):
+        return jnp.sum(flash_mha_seq_major(t(q), t(k), t(v), causal=True) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(flash_mha(q, k, v, causal=True) ** 2)
+
+    np.testing.assert_allclose(
+        np.asarray(t(flash_mha_seq_major(t(q), t(k), t(v), causal=True))),
+        np.asarray(flash_mha(q, k, v, causal=True)), rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.grad(loss_seq, argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_flash_causal_ignores_later_keys():
     q, k, v = _qkv(s=256)
     out = flash_attention(q, k, v, True)
-    ref = mha_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
     # causality: output at position 0 must not depend on later keys
     v2 = v.at[:, :, 128:, :].set(0.0)
     out2 = flash_attention(q, k, v2, True)
     np.testing.assert_allclose(np.asarray(out[:, :, :128]), np.asarray(out2[:, :, :128]),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_flash_bf16():
-    q, k, v = _qkv(dtype=jnp.bfloat16)
-    out = flash_attention(q, k, v)
-    ref = mha_reference(q, k, v)
-    assert out.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
-                               rtol=3e-2, atol=3e-2)
-
-
-def test_flash_gradients():
-    q, k, v = _qkv(b=1, h=1, s=128, d=32)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
 
 
 def test_flash_offsets():
@@ -179,52 +275,6 @@ def test_tp_train_step_vit():
     assert mu_fc1.sharding.spec == P(None, "model")
 
 
-def test_flash_gradients_noncausal_and_offsets():
-    """Pallas backward == reference backward without causal masking and with
-    ring-style global offsets (the cross-shard case)."""
-    q, k, v = _qkv(b=2, h=2, s=256, d=32, seed=5)
-
-    # q_offset > k_offset keeps every q row partially visible; rows with ZERO
-    # visible keys diverge from the reference by design (its all-masked softmax
-    # degenerates to uniform) — that case is pinned by
-    # test_flash_gradients_fully_masked_rows_zero instead.
-    for kwargs in ({"causal": False}, {"causal": True, "q_offset": 256},
-                   {"causal": True, "q_offset": 64, "k_offset": 0}):
-        def lf(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, kwargs.get("causal", False),
-                                           kwargs.get("q_offset", 0),
-                                           kwargs.get("k_offset", 0)) ** 2)
-
-        def lr(q, k, v):
-            return jnp.sum(mha_reference(q, k, v, kwargs.get("causal", False),
-                                         kwargs.get("q_offset", 0),
-                                         kwargs.get("k_offset", 0)) ** 2)
-
-        gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4, err_msg=str(kwargs))
-
-
-def test_flash_gradients_bf16_multiblock():
-    """bf16 grads across multiple q/k blocks stay close to the f32 reference."""
-    q, k, v = _qkv(b=1, h=2, s=384, d=32, seed=7)
-    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-
-    def lf(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True).astype(jnp.float32) ** 2)
-
-    def lr(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
-
-    gf = jax.grad(lf, argnums=(0, 1, 2))(qb, kb, vb)
-    gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a).astype(np.float32),
-                                   np.asarray(b), rtol=0.1, atol=0.1)
-
-
 def test_flash_gradients_fully_masked_rows_zero():
     """Rows with zero visible keys must get zero dQ (and contribute nothing to
     dK/dV), not NaNs from the masked-softmax residuals."""
@@ -269,20 +319,6 @@ def test_ring_attention_gradients_match_full(causal):
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_flash_lse_matches_logsumexp():
-    """flash_attention_lse's second output == logsumexp of the scaled scores."""
-    from ddw_tpu.ops.flash_attention import flash_attention_lse
-
-    q, k, v = _qkv(b=1, h=2, s=256, d=32, seed=4)
-    out, lse = flash_attention_lse(q, k, v)
-    ref = mha_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    ref_lse = jax.scipy.special.logsumexp(scores, axis=-1)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_flash_lse_split_combine_gradients():
     """Splitting keys in two flash_attention_lse calls and softmax-combining
     them must match full attention in value AND gradients — the exact contract
@@ -312,23 +348,6 @@ def test_flash_lse_split_combine_gradients():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_flash_mha_padded_seq():
-    """flash_mha(impl='pallas') pads non-block-multiple lengths (ViT's 196) and
-    matches the reference on the unpadded region, fwd and grad."""
-    from ddw_tpu.ops.flash_attention import flash_mha
-
-    q, k, v = _qkv(b=1, h=2, s=196, d=48, seed=6)
-    out = flash_mha(q, k, v, impl="pallas")
-    ref = mha_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-    gq = jax.grad(lambda q: jnp.sum(flash_mha(q, k, v, impl="pallas") ** 2))(q)
-    gr = jax.grad(lambda q: jnp.sum(mha_reference(q, k, v) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(gq), np.asarray(gr),
-                               rtol=1e-4, atol=1e-4)
-
-
 def test_attention_impl_dispatch_equivalence():
     """Every dispatch arm (xla, xla_ckpt, pallas) computes the same attention
     — out, lse, and grads — so the auto rule can never change results."""
@@ -346,14 +365,25 @@ def test_attention_impl_dispatch_equivalence():
             np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                        err_msg=f"{impl} {what}")
 
-    # auto picks by score-matrix footprint
-    small = jnp.zeros((1, 1, 128, 16))      # 64 KiB of scores -> plain xla
-    big = jnp.zeros((8, 8, 2048, 16))       # 1 GiB -> checkpointed xla
-    huge = jnp.zeros((8, 8, 65536, 16))     # 1 TiB -> pallas flash
-    assert _attn_impl(small, small, "auto") == "xla"
-    assert _attn_impl(big, big, "auto") == "xla_ckpt"
-    assert _attn_impl(huge, huge, "auto") == "pallas"
-    assert _attn_impl(huge, huge, "xla") == "xla"
+    # auto: sequences of _FLASH_MIN_SEQ and more go to the kernels whatever
+    # the batch; below it the score footprint picks between the XLA tiers
+    from ddw_tpu.ops.flash_attention import _FLASH_MIN_SEQ
+
+    def auto(b, h, s, d=64, sk=None, dtype=jnp.bfloat16):
+        return _attn_impl(jax.ShapeDtypeStruct((b, h, s, d), dtype),
+                          jax.ShapeDtypeStruct((b, h, sk or s, d), dtype),
+                          "auto")
+
+    assert auto(8, 16, 1024) == "pallas"        # the LM cells, batch 8 a chip
+    assert auto(128, 12, 196) == "xla"          # vitb16_train_224
+    assert auto(1, 1, _FLASH_MIN_SEQ) == "pallas"
+    assert auto(1, 1, _FLASH_MIN_SEQ - 128) == "xla"
+    assert auto(64, 16, _FLASH_MIN_SEQ - 128) == "xla_ckpt"     # 576 MiB
+    assert auto(8, 16, 1024, sk=128) == "xla"   # a short side: no kernel
+    assert auto(1024, 16, 256) == "pallas"      # 4 GiB of scores do not fit
+    assert auto(8, 16, 1024, dtype=jnp.float32) == "pallas"
+    huge = jnp.zeros((1, 1, 128, 16))
+    assert _attn_impl(huge, huge, "xla_ckpt") == "xla_ckpt"
 
 
 def test_vit_flash_mha_matches_flax_attention():
@@ -387,7 +417,7 @@ def test_ring_attention_pallas_arm_matches_full():
 
     n = 4
     mesh = make_mesh(MeshSpec((("seq", n),)), devices=jax.devices()[:n])
-    q, k, v = _qkv(b=1, h=2, s=32 * n, d=32, seed=11)
+    q, k, v = _qkv(b=1, h=2, s=32 * n, d=64, seed=11)
 
     def ring_loss(q, k, v):
         fn = shard_map(

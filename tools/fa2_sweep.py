@@ -1,137 +1,229 @@
-"""Pallas FA2 block-size sweep vs fused-XLA attention (VERDICT r2 item 5).
+"""Attention tiers and flash-kernel blocks, timed on the chip — one JSON line an arm.
 
-Times causal attention fwd+bwd (the LM training shape family) for:
-- the Pallas flash kernels over a (block_q, block_k) grid,
-- plain fused XLA attention,
-- jax.checkpoint'd XLA (the O(S)-residual middle arm),
+The table this prints is what ``ops/flash_attention.py`` cites for its dispatch
+constant (``_FLASH_MIN_SEQ``) and its block caps, and what ``PERF.md`` section 5
+quotes. Presets (all bfloat16, D = 64 unless ``--dim``):
 
-at several sequence lengths, with bench.py's differential forced-fetch timing.
-The table feeds BASELINE.md and the `flash_mha` dispatch thresholds
-(DDW_ATTN_XLA_PLAIN_MAX / DDW_ATTN_XLA_CKPT_MAX).
+- ``cells``: the two attention shapes the benchmark's cells run —
+  ``[8,16,1024,64]`` causal (GPT-2 medium, batch 8) and ``[128,12,196,64]`` not
+  causal (ViT-B/16, batch 128) — forward + backward by tier (``impl=`` of
+  ``flash_mha``): ``xla``, ``xla_ckpt``, ``pallas`` at the blocks the code picks.
+- ``ladder``: S in 256..4096 at B*H*S = 131072 tokens, causal and not, the same
+  three tiers: where the kernels start to win.
+- ``blocks``: at one shape (``--shape``, default the LM cell's), each of the
+  three kernels alone over (block_q, block_k, sub_k), and the chosen blocks.
 
-Run on the TPU:  PYTHONPATH=. python tools/fa2_sweep.py
-(options: --seqs 2048,4096,8192  --batch 8 --heads 8 --dim 64)
+An arm that fails to compile or to fit prints ``"ms": null`` and the error.
+``--profile DIR`` also traces one forward + backward of the first shape's
+``pallas`` arm and prints the device operations by name, which is how the
+kernels' names in a profile were found (``benchmark/metrics/attention_kernel_ms.py``).
+
+Run on the TPU:  python tools/fa2_sweep.py --preset cells
 """
 
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import argparse
-import functools
+import importlib
+import json
+import statistics
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ddw_tpu.ops.flash_attention import (
-    _xla_attention_lse,
-    flash_attention,
-)
+# ddw_tpu.ops re-exports a flash_attention FUNCTION that shadows the module
+fa = importlib.import_module("ddw_tpu.ops.flash_attention")
 
-BLOCKS = (128, 256, 512, 1024)
+# shape [B,H,S,D], causal, sequence-major operands (the LM) or not (ViT)
+CELL_SHAPES = (((8, 16, 1024, 64), True, True),
+               ((128, 12, 196, 64), False, False))
+LADDER_SEQS = (256, 512, 1024, 2048, 4096)
+LADDER_TOKENS = 131072
+TIERS = ("xla", "xla_ckpt", "pallas")
+BLOCK_GRID = tuple((bq, bk, sub) for bq in (256, 512, 1024)
+                   for bk in (512, 1024) for sub in (128, 256, 512)
+                   if sub <= bk)
 
 
-from bench import _time_steps  # bench.py's differential forced-fetch timing
+def time_ms(fn, *args, min_s: float = 0.25, repeats: int = 3) -> float:
+    """Median milliseconds a call: ``n`` calls enqueued back to back, ended by
+    ``block_until_ready``, n grown until a batch takes ``min_s``."""
+    jax.block_until_ready(fn(*args))            # compile + warm up
 
-
-def _time_fn(fn, *args) -> float:
-    """Median seconds per call via bench.py's ``_time_steps`` (one timing
-    methodology across bench.py and both perf tools)."""
-    out = fn(*args)  # warmup/compile
-    np.asarray(jax.tree.leaves(out)[0]).ravel()[:1]
-
-    def run_n(n):
+    def batch(n):
         t0 = time.perf_counter()
+        out = None
         for _ in range(n):
             out = fn(*args)
-        np.asarray(jax.tree.leaves(out)[0]).ravel()[:1]  # forced D2H
+        jax.block_until_ready(out)
         return time.perf_counter() - t0
 
-    dt, n = _time_steps(run_n)
-    return max(dt, 1e-9) / n
+    n = 4
+    while batch(n) < min_s and n < 4096:
+        n *= 2
+    return statistics.median(batch(n) for _ in range(repeats)) / n * 1e3
 
 
-def make_arm(kind: str, bq: int = 128, bk: int = 128):
-    scale = None
+def qkv(shape, dtype=jnp.bfloat16, n=3):
+    rng = np.random.RandomState(0)
+    return [jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.5, dtype)
+            for _ in range(n)]
 
-    if kind == "pallas":
-        def attn(q, k, v):
-            return flash_attention(q, k, v, True, 0, 0, scale, bq, bk)
-    else:
-        def xla(q, k, v):
-            return _xla_attention_lse(q, k, v, causal=True, q_offset=0,
-                                      k_offset=0,
-                                      sm_scale=1.0 / q.shape[-1] ** 0.5,
-                                      k_valid=None)[0]
-        attn = jax.checkpoint(xla) if kind == "xla_ckpt" else xla
+
+def tier_fn(impl: str, causal: bool, seq_major: bool = False):
+    """Forward + backward of one attention call, the gradients folded into the
+    returned scalar (returning the loss alone would let XLA drop the backward
+    pass). ``seq_major``: operands ``[B,S,H,D]`` through the entry the LM
+    calls, else ``[B,H,S,D]`` through ``flash_mha`` (ViT, the ring)."""
+    attend = fa.flash_mha_seq_major if seq_major else fa.flash_mha
 
     @jax.jit
     def fwd_bwd(q, k, v):
         def loss(q, k, v):
-            return jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+            out = attend(q, k, v, causal=causal, impl=impl)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
         l, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-        # fold the grads into the returned scalar: returning only `l` would
-        # let XLA dead-code-eliminate the whole backward pass
         return l + sum(jnp.sum(g.astype(jnp.float32)) for g in grads)
 
     return fwd_bwd
 
 
-def attn_flops(b, h, s, d) -> float:
-    """Causal fwd+bwd matmul flops: fwd 2*(QK + PV)*0.5 causal; bwd ~2.5x fwd
-    (dP, dV, dS·K, dS^T·Q)."""
-    fwd = 2 * b * h * s * s * d * 2 * 0.5
-    return fwd * 3.5
+def matmul_flops(shape, causal: bool, units: float) -> float:
+    """``units`` S x S x D matmuls of 2 FLOPs a multiply-add (forward 2,
+    backward 5 without recomputation), halved where causal."""
+    b, h, s, d = shape
+    return units * 2.0 * b * h * s * s * d * (0.5 if causal else 1.0)
+
+
+def chosen_blocks(s: int):
+    """(block_q, block_k, sub_k) the code picks for self-attention of length
+    ``s``, after ``flash_mha``'s padding to a block multiple."""
+    bq = fa._pick_block(s, None, fa._BLOCK_Q_MAX)
+    bk = fa._pick_block(s, None, fa._BLOCK_K_MAX)
+    return list(fa._resolve_blocks(-(-s // bq) * bq, -(-s // bk) * bk,
+                                   bq, bk, None))
+
+
+def emit(row: dict, device: str):
+    print(json.dumps({"tool": "fa2_sweep", "device": device, **row}),
+          flush=True)
+
+
+def run_tiers(preset, shapes, device, tiers=TIERS):
+    """``shapes``: (B,H,S,D), causal, whether the operands are sequence-major."""
+    for shape, causal, seq_major in shapes:
+        b, h, s, d = shape
+        q, k, v = qkv((b, s, h, d) if seq_major else shape)
+        for impl in tiers:
+            row = {"preset": preset, "shape": list(shape), "dtype": "bfloat16",
+                   "causal": causal, "arm": impl, "pass": "fwd+bwd",
+                   "layout": "bshd" if seq_major else "bhsd"}
+            if impl == "pallas":
+                row["blocks"] = chosen_blocks(shape[2])
+            try:
+                fn = tier_fn(impl, causal, seq_major)
+                ms = time_ms(fn, q, k, v)
+                # loss + summed gradients: the tiers must agree on it
+                row.update(ms=round(ms, 4), value=float(fn(q, k, v)),
+                           tflops=round(
+                               matmul_flops(shape, causal, 7) / ms / 1e9, 2))
+            except Exception as e:      # an arm that cannot run is a row too
+                row.update(ms=None, error=f"{type(e).__name__}: {e}"[:300])
+            emit(row, device)
+
+
+def run_blocks(shape, causal, device, grid=BLOCK_GRID):
+    b, h, s, d = shape
+    q, k, v, g = qkv((b, s, h, d), n=4)          # as the kernels take them
+    scale, interpret = fa._resolve_defaults(None, None, d)
+    out, lse = jax.jit(lambda q, k, v: fa._flash_forward(
+        q, k, v, causal, 0, 0, scale, None, None, interpret))(q, k, v)
+    dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1).transpose(0, 2, 1)
+
+    def kernels(bq, bk, sub):
+        blocks = (causal, 0, 0, scale, bq, bk, interpret, None, sub)
+        return {
+            "fwd": (jax.jit(lambda q, k, v: fa._flash_forward(
+                q, k, v, *blocks)), (q, k, v), 2),
+            "dq": (jax.jit(lambda *a: fa._flash_dq(*a, *blocks)),
+                   (q, k, v, g, lse, dvec), 3),
+            "dkv": (jax.jit(lambda *a: fa._flash_dkv(*a, *blocks)),
+                    (q, k, v, g, lse, dvec), 4),
+        }
+
+    chosen = fa._resolve_blocks(s, s, None, None, None)
+    for bq, bk, sub in dict.fromkeys((chosen,) + tuple(grid)):
+        if bq > s or bk > s or bk % sub:
+            continue
+        for name, (fn, args, units) in kernels(bq, bk, sub).items():
+            row = {"preset": "blocks", "shape": list(shape),
+                   "dtype": "bfloat16", "causal": causal, "arm": name,
+                   "blocks": [bq, bk, sub],
+                   "chosen": (bq, bk, sub) == chosen}
+            try:
+                ms = time_ms(fn, *args, min_s=0.1)
+                row.update(ms=round(ms, 4), tflops=round(
+                    matmul_flops(shape, causal, units) / ms / 1e9, 2))
+            except Exception as e:
+                row.update(ms=None, error=f"{type(e).__name__}: {e}"[:300])
+            emit(row, device)
+
+
+def profile_names(shape, causal, trace_dir, device):
+    """One traced forward + backward of the kernels; the device operations'
+    names and summed times, longest first."""
+    from benchmark.harness import trace_reduce
+
+    b, h, s, d = shape
+    fn = tier_fn("pallas", causal, seq_major=True)
+    args = qkv((b, s, h, d))
+    jax.block_until_ready(fn(*args))
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(3):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    red = trace_reduce.reduce(
+        trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir)), top=24)
+    emit({"preset": "profile", "shape": list(shape), "causal": causal,
+          "calls": 3, "top_ops_ns": red["top_ops"],
+          "top_families_ns": red["top_families"]}, device)
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--seqs", default="2048,4096,8192")
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--heads", type=int, default=8)
-    ap.add_argument("--dim", type=int, default=64)
-    ap.add_argument("--blocks", default=",".join(map(str, BLOCKS)))
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--preset", default="cells",
+                    help="comma list of cells, ladder, blocks")
+    ap.add_argument("--shape", default="8,16,1024,64",
+                    help="B,H,S,D of the blocks preset")
+    ap.add_argument("--dim", type=int, default=64,
+                    help="head dimension of the ladder preset")
+    ap.add_argument("--not-causal", action="store_true",
+                    help="the blocks preset without the causal mask")
+    ap.add_argument("--tiers", default=",".join(TIERS))
+    ap.add_argument("--profile", default=None, metavar="DIR")
     args = ap.parse_args()
-    b, h, d = args.batch, args.heads, args.dim
-    blocks = [int(x) for x in args.blocks.split(",")]
     from ddw_tpu.utils.config import require_tpu_or_exit
-    kind = require_tpu_or_exit("sweep")
-    print(f"device: {kind}  shape B{b} H{h} D{d} "
-          f"causal fwd+bwd")
-
-    for s in (int(x) for x in args.seqs.split(",")):
-        rng = np.random.RandomState(0)
-        mk = lambda: jnp.asarray(  # noqa: E731
-            rng.randn(b, h, s, d).astype(np.float32) * 0.1, jnp.bfloat16)
-        q, k, v = mk(), mk(), mk()
-        fl = attn_flops(b, h, s, d)
-        rows = []
-        for kind in ("xla", "xla_ckpt"):
-            try:
-                dt = _time_fn(make_arm(kind), q, k, v)
-                rows.append((kind, dt))
-            except Exception as e:
-                rows.append((f"{kind} [{type(e).__name__}]", None))
-        for bq in blocks:
-            for bk in blocks:
-                if bq > s or bk > s:
-                    continue
-                try:
-                    dt = _time_fn(make_arm("pallas", bq, bk), q, k, v)
-                    rows.append((f"pallas q{bq} k{bk}", dt))
-                except Exception as e:
-                    rows.append((f"pallas q{bq} k{bk} [{type(e).__name__}]",
-                                 None))
-        best_xla = min((dt for kind, dt in rows[:2] if dt), default=None)
-        print(f"\nS={s}  ({fl / 1e9:.1f} GFLOP/step)")
-        for kind, dt in sorted(rows, key=lambda r: r[1] or 1e9):
-            if dt is None:
-                print(f"  {kind:<24} FAILED")
-                continue
-            ratio = f"  {dt / best_xla:5.2f}x vs XLA" if best_xla else ""
-            print(f"  {kind:<24}{dt * 1e3:9.2f} ms  "
-                  f"{fl / dt / 1e12:6.1f} TF/s{ratio}")
+    device = require_tpu_or_exit("sweep")
+    tiers = tuple(args.tiers.split(","))
+    shape = tuple(int(x) for x in args.shape.split(","))
+    for preset in args.preset.split(","):
+        if preset == "cells":
+            run_tiers(preset, CELL_SHAPES, device, tiers)
+        elif preset == "ladder":
+            run_tiers(preset, [((LADDER_TOKENS // s // 16, 16, s, args.dim),
+                                 causal, True) for s in LADDER_SEQS
+                               for causal in (True, False)], device, tiers)
+        elif preset == "blocks":
+            run_blocks(shape, not args.not_causal, device)
+        else:
+            ap.error(f"unknown preset {preset!r}")
+    if args.profile:
+        profile_names(CELL_SHAPES[0][0], True, args.profile, device)
 
 
 if __name__ == "__main__":
