@@ -16,18 +16,20 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ddw_tpu.ops.flash_attention import flash_mha
+from ddw_tpu.ops.flash_attention import flash_mha_seq_major
 
 
 class FlashMHA(nn.Module):
-    """Self-attention over the in-tree Pallas flash kernel.
+    """Self-attention over the in-tree attention dispatch.
 
     Param layout matches ``nn.MultiHeadDotProductAttention`` —
     ``{query,key,value}/kernel [embed, heads, head_dim]``, ``out/kernel
     [heads, head_dim, embed]`` — so :data:`ddw_tpu.parallel.sharding
     .VIT_TP_RULES` shards it unchanged and checkpoints stay layout-stable.
-    The kernel pads ViT's 196-patch sequences to a block multiple internally
-    (:func:`ddw_tpu.ops.flash_attention.flash_mha`). ``lora_rank > 0`` puts
+    q, k, v go to :func:`ddw_tpu.ops.flash_attention.flash_mha_seq_major` as
+    the projections give them, ``[B, S, H, head_dim]``, and its output to the
+    out-projection: nothing is transposed a head, and on the one-block flash
+    kernels (ViT-B/16's 196 patches) nothing is padded. ``lora_rank > 0`` puts
     adapters on the targeted projections (ddw_tpu.models.lora — base param
     paths unchanged)."""
 
@@ -54,9 +56,7 @@ class FlashMHA(nn.Module):
         q = dense("query")(x)   # [B, S, H, hd]
         k = dense("key")(x)
         v = dense("value")(x)
-        qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        out = flash_mha(qh, kh, vh, causal=False)
-        out = out.transpose(0, 2, 1, 3)  # [B, S, H, hd]
+        out = flash_mha_seq_major(q, k, v, causal=False)  # [B, S, H, hd]
         return maybe_lora_dense(d, "out", rank=self.lora_rank,
                                 alpha=self.lora_alpha,
                                 targets=self.lora_targets, dtype=self.dtype,

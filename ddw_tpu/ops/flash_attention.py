@@ -4,7 +4,8 @@ The reference stack has no attention anywhere (SURVEY.md §5 "Long-context ...
 Absent") — this op exists because long-context support is first-class in this
 framework: it is the attention of the LM family from 512 tokens up, the
 local-block compute of :mod:`ddw_tpu.parallel.ring_attention` (sequence
-parallelism) and an arm of the ViT family's attention.
+parallelism) and, in its one-block form for sequences under 512 tokens, the
+attention of the ViT family.
 
 Design (Dao et al. flash attention, TPU-first):
 - the kernels take q, k, v as the projections produce them, ``[B, S, H, D]``
@@ -26,6 +27,9 @@ Design (Dao et al. flash attention, TPU-first):
   per-row logsumexp; dQ streams K/V blocks, dK/dV streams Q/dO blocks, each
   rematerializing p = exp(s - L) blockwise in VMEM — O(S) HBM for the whole
   train step, the S x S matrices never exist in HBM;
+- sequences under 512 tokens take a one-block form of the same kernels (no
+  streaming, one backward kernel, nothing padded in HBM; see "Short
+  sequences" below);
 - ``interpret=None`` means the interpreter on the CPU backend (tests) and the
   Mosaic compiler on every other backend (:mod:`ddw_tpu.ops.backend`).
 """
@@ -718,10 +722,266 @@ def _pad_seq(x, mult):
 
 
 # ---------------------------------------------------------------------------
+# Short sequences: one block. Below _FLASH_MIN_SEQ a head's whole key range
+# fits one VMEM tile, so nothing is streamed: a grid step holds the whole
+# [Sq, 128] q tile and [Sk, 128] k and v tiles of a head block for a few batch
+# rows, the softmax is a plain one (no running max, no rescale, no scratch
+# carried between steps), and ONE backward kernel recomputes p once and gives
+# dQ, dK and dV — 5 matmul units where flash_dq + flash_dkv execute 7 — with
+# the D = rowsum(dO . O) reduction inside it. A sequence that is no multiple
+# of 128 is not padded in HBM: the block over-runs the array (Pallas reads the
+# boundary block and drops the writes past the edge), the rows past the edge
+# are undefined — NaN in the interpreter — and are replaced by zeros with a
+# select before anything can multiply them, and the keys past the edge are
+# masked like the streaming kernels' padded tail. Same arithmetic contract:
+# matmuls in the input dtype with float32 accumulation, softmax in float32,
+# lse lane-dense.
+# ---------------------------------------------------------------------------
+
+_SHORT_MAX_SEQ = 512        # the longest padded side one block takes
+_SHORT_IMAGES_MAX = 8       # batch rows a grid step, at most (the sweep)
+_SHORT_STEP_BYTES = 1024 * 1024     # ... and of q-tile bytes a grid step
+
+
+def _short_pad(s: int, align: int) -> int:
+    """The block's rows for a side of ``s``. The q side is the lane dim of the
+    backward's score tile and of the lse rows: a multiple of 128. The key
+    side is a sublane dim of the operand tiles and of the backward's score
+    tile and the lane dim of the forward's, which Mosaic takes at any
+    multiple of 16: 208 rows for 196 keys, not 256."""
+    return -(-s // align) * align
+
+
+def _pick_images(b: int, rows: int, width: int, itemsize: int) -> int:
+    """Batch rows a grid step of the one-block kernels: a grid step's work is
+    well under a microsecond of MXU time against a fixed cost of a few tenths
+    of one, so a step takes several — the largest divisor of ``b`` up to
+    ``_SHORT_IMAGES_MAX`` whose q tiles stay under ``_SHORT_STEP_BYTES`` (the
+    backward holds eight such tiles, double-buffered)."""
+    cap = max(1, min(_SHORT_IMAGES_MAX,
+                     _SHORT_STEP_BYTES // (rows * width * itemsize)))
+    return max(n for n in range(1, cap + 1) if b % n == 0)
+
+
+def _valid_rows(x, n_valid: int):
+    """``x`` with the rows from ``n_valid`` on replaced by zeros — a select,
+    not a multiply: rows past the array's edge are undefined and may be NaN."""
+    if n_valid == x.shape[0]:
+        return x
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row < n_valid, x, jnp.zeros_like(x))
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, heads: int,
+                      head_dim: int, sq: int, sk: int, causal: bool,
+                      sm_scale: float):
+    """One (batch rows, head block) grid step: whole-sequence attention of
+    each batch row of the step and each head of the lane block in turn."""
+    images, sqp, width = q_ref.shape
+    skp = k_ref.shape[1]
+    masks = _head_masks(heads, head_dim, width)
+    k_valid = sk if sk != skp else None
+
+    def image(n, _):
+        q = _valid_rows(q_ref[n], sq)
+        k = _valid_rows(k_ref[n], sk)
+        v = _valid_rows(v_ref[n], sk)
+        out = None
+        for t, mask in enumerate(masks):
+            s = _scores(_only(mask, q), k, sm_scale)         # [sqp, skp]
+            if causal or k_valid is not None:
+                s = _mask_scores(s, 0, 0, causal, k_valid, 1)
+            # every row sees key 0, so no row is fully masked: m is finite
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            o = jnp.dot(p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32) * (1.0 / l)
+            out = o if out is None else jnp.where(mask, o, out)
+            lse_ref[n, t:t + 1, :] = jnp.broadcast_to(
+                m + jnp.log(l), (sqp, _LANES)).T[:1]
+        o_ref[n] = out.astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, images, image, None)
+
+
+def _short_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, glse_ref,
+                      dq_ref, dk_ref, dv_ref, *, heads: int, head_dim: int,
+                      sq: int, sk: int, causal: bool, sm_scale: float):
+    """The whole backward of one (batch rows, head block) grid step, on
+    TRANSPOSED scores s^T = k q^T ``[skp, sqp]`` like the dK/dV kernel: lse,
+    D and the lse cotangent broadcast along sublanes from lane-dense rows, p^T
+    and ds^T are the left operands of plain matmuls for dV and dK, and dQ
+    contracts ds^T over its first dimension. p is recomputed once."""
+    images, sqp, width = q_ref.shape
+    masks = _head_masks(heads, head_dim, width)
+    k_valid = sk if sk != k_ref.shape[1] else None
+    # D_i = dO_i . O_i of each head as one matmul: row t of ``ones`` holds
+    # head t's lanes, so (ones (dO . O)^T)[t] is that head's D as a row
+    shape = (-(-heads // 8) * 8, width)
+    ones = jnp.where(jax.lax.broadcasted_iota(jnp.int32, shape, 1) // head_dim
+                     == jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+                     1.0, 0.0)
+
+    def image(n, _):
+        q = _valid_rows(q_ref[n], sq)
+        do = _valid_rows(do_ref[n], sq)
+        o = _valid_rows(o_ref[n], sq)
+        k = _valid_rows(k_ref[n], sk)
+        v = _valid_rows(v_ref[n], sk)
+        dvec = jax.lax.dot_general(
+            ones, do.astype(jnp.float32) * o.astype(jnp.float32),
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)              # [8, sqp]
+        dq = dk = dv = 0.0
+        for t, mask in enumerate(masks):
+            qm, dom = _only(mask, q), _only(mask, do)
+            st = _scores(k, qm, sm_scale)                    # [skp, sqp]
+            if causal or k_valid is not None:
+                st = _mask_scores(st, 0, 0, causal, k_valid, 0)
+            pt = jnp.exp(st - lse_ref[n, t:t + 1, :])
+            dpt = _scores(v, dom, 1.0)
+            # the lse cotangent folds in as D' = D - g_lse (see _flash_lse_bwd)
+            dst = (pt * (dpt - (dvec[t:t + 1] - glse_ref[n, t:t + 1, :]))
+                   ).astype(q.dtype)
+            # qm, dom and the masked k carry this head's lanes only, so the
+            # three products do too and the heads add up
+            dv += jnp.dot(pt.astype(do.dtype), dom,
+                          preferred_element_type=jnp.float32)
+            dk += jnp.dot(dst, qm, preferred_element_type=jnp.float32)
+            dq += jax.lax.dot_general(dst, _only(mask, k),
+                                      (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        dq_ref[n] = (sm_scale * dq).astype(dq_ref.dtype)
+        dk_ref[n] = (sm_scale * dk).astype(dk_ref.dtype)
+        dv_ref[n] = dv.astype(dv_ref.dtype)
+
+    jax.lax.fori_loop(0, images, image, None)
+
+
+def _short_specs(b, sq, sk, h, d, itemsize, images):
+    """Grid, block specs (q side, k side, f32 rows) and head layout of the
+    one-block kernels for q [b,sq,h,d] and k/v [b,sk,h,d]."""
+    sqp, skp = _short_pad(sq, _LANES), _short_pad(sk, 16)
+    if max(sqp, skp) > _SHORT_MAX_SEQ:
+        raise ValueError(f"seq lengths ({sq},{sk}) do not fit one block of "
+                         f"{_SHORT_MAX_SEQ}; the streaming kernels take them")
+    per, dp, hp = _head_blocks(h, d)
+    nb = images or _pick_images(b, max(sqp, skp), per * dp, itemsize)
+    tile = lambda rows: pl.BlockSpec(                            # noqa: E731
+        (nb, rows, per * dp), lambda i, j: (i, 0, j), memory_space=pltpu.VMEM)
+    rows = pl.BlockSpec((nb, None, per, sqp), lambda i, j: (i, j, 0, 0),
+                        memory_space=pltpu.VMEM)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+    return ((b // nb, hp // per), tile(sqp), tile(skp), rows, params,
+            (per, dp, hp, sqp))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _short_forward(q, k, v, causal, sm_scale, interpret, images=None):
+    """q [B,Sq,H,D], k/v [B,Sk,H,D], both sides at most _SHORT_MAX_SEQ ->
+    (out [B,Sq,H,D], lse [B,H,Sq] f32)."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    grid, qspec, kspec, rows, params, (per, dp, hp, sqp) = _short_specs(
+        b, sq, sk, h, d, q.dtype.itemsize, images)
+    out, lse = pl.pallas_call(
+        functools.partial(_short_fwd_kernel, heads=per, head_dim=dp, sq=sq,
+                          sk=sk, causal=causal, sm_scale=sm_scale),
+        grid=grid,
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, rows],
+        out_shape=[jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
+                   jax.ShapeDtypeStruct((b, hp // per, per, sqp),
+                                        jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_short_fwd",
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp))
+    return _from_blocks(out, h, d, dp), lse.reshape(b, hp, sqp)[:, :h, :sq]
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _short_backward(q, k, v, out, lse, g, g_lse, causal, sm_scale, interpret,
+                    images=None):
+    """The operands and the outputs of :func:`_short_forward`, and their
+    cotangents g [B,Sq,H,D], g_lse [B,H,Sq] -> (dq, dk, dv)."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    grid, qspec, kspec, rows, params, (per, dp, hp, sqp) = _short_specs(
+        b, sq, sk, h, d, q.dtype.itemsize, images)
+
+    def padded_rows(x):         # [B,H,Sq] -> [B, hp//per, per, sqp], zeros
+        return _rows(jnp.pad(x, ((0, 0), (0, 0), (0, sqp - sq))), per, hp)
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_short_bwd_kernel, heads=per, head_dim=dp, sq=sq,
+                          sk=sk, causal=causal, sm_scale=sm_scale),
+        grid=grid,
+        in_specs=[qspec, kspec, kspec, qspec, qspec, rows, rows],
+        out_specs=[qspec, kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
+                   jax.ShapeDtypeStruct((b, sk, hp * dp), k.dtype),
+                   jax.ShapeDtypeStruct((b, sk, hp * dp), v.dtype)],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_short_bwd",
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp),
+      _to_blocks(out, dp, hp), _to_blocks(g, dp, hp), padded_rows(lse),
+      padded_rows(g_lse.astype(jnp.float32)))
+    return tuple(_from_blocks(x, h, d, dp) for x in (dq, dk, dv))
+
+
+@functools.lru_cache(maxsize=None)
+def _partitioned_short(causal, sm_scale, interpret):
+    """The one-block forward and backward, batch/head-partitioned like the
+    streaming kernels (:func:`_def_bh_partition`)."""
+
+    def fwd(q, k, v):
+        return _short_forward(q, k, v, causal, sm_scale, interpret)
+
+    def bwd(q, k, v, out, lse, g, g_lse):
+        return _short_backward(q, k, v, out, lse, g, g_lse, causal, sm_scale,
+                               interpret)
+
+    return (_def_bh_partition(
+                custom_partitioning(fwd), fwd,
+                "b q h d, b s h d, b s h d -> b q h d, b h q",
+                out_ndims=(4, 3)),
+            _def_bh_partition(
+                custom_partitioning(bwd), bwd,
+                "b q h d, b s h d, b s h d, b q h d, b h q, b q h d, b h q -> "
+                "b q h d, b s h d, b s h d", out_ndims=(4, 4, 4)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_short(q, k, v, causal, sm_scale, interpret):
+    """The one-block kernels' differentiable entry: :func:`_flash_lse`'s
+    contract (sequence-major operands, both outputs differentiable) for
+    sequences of at most _SHORT_MAX_SEQ, keys from position 0."""
+    return _flash_short_fwd(q, k, v, causal, sm_scale, interpret)[0]
+
+
+def _flash_short_fwd(q, k, v, causal, sm_scale, interpret):
+    out, lse = _partitioned_short(causal, sm_scale, interpret)[0](q, k, v)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_short_bwd(causal, sm_scale, interpret, residuals, gs):
+    return _partitioned_short(causal, sm_scale, interpret)[1](*residuals, *gs)
+
+
+_flash_short.defvjp(_flash_short_fwd, _flash_short_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Dispatch. Three tiers compute the same attention: plain XLA (one fused
 # einsum chain, S² scores through HBM, saved for the backward), jax.checkpoint
 # XLA (O(S) residuals, the S² tensors transient in the backward) and the Pallas
-# flash kernels (scores never leave VMEM). What the kernels' advantage depends
+# flash kernels (scores never leave VMEM), in a streaming form and a one-block
+# form for short sequences. What the kernels' advantage depends
 # on is the sequence length: the XLA tiers make memory-bound passes over
 # B*H*Sq*Sk float32 scores, the kernels do the same arithmetic from VMEM, and
 # batch and heads scale both sides alike. Measured on one TPU v5e chip
@@ -738,16 +998,53 @@ def _pad_seq(x, mult):
 #   4.57 (196 pads to 256, and the kernels' layout costs two transposes each way)
 #
 # (S = 1024, D = 64, causal is the GPT-2 medium cells' shape.) So sequences of
-# _FLASH_MIN_SEQ and more go to the kernels: 2.5x at 512 and 5.2x at 4096 at
-# D = 64, 1.7x and 4.2x at D = 128. At 256 the kernels win at D = 64 and lose
-# at D = 128, and a length that pads by a third (196) ties: the XLA tiers keep
-# those. Below the crossover the score footprint chooses between the XLA
-# tiers: plain while the saved S² tensors are small, checkpointed above that,
-# and the kernels again where even the transient S² tensor is
-# memory-infeasible.
+# _FLASH_MIN_SEQ and more go to the streaming kernels: 2.5x at 512 and 5.2x at
+# 4096 at D = 64, 1.7x and 4.2x at D = 128.
+#
+# Below that the whole key range fits one block, and the one-block kernels
+# ("Short sequences" above; tools/fa2_sweep.py --preset short [--dim 128], PR
+# 29, same chip, forward + backward, bf16, 16 heads, B*S = 8192 tokens,
+# operands [B,S,H,D]; ms for xla / xla_ckpt / streaming kernels / one block;
+# not causal, causal where it differs by more than 0.01):
+#
+#      S    D = 64                                    D = 128
+#     32    1.38 / 1.79 / 7.88 / 2.31                 1.07 / 1.23 / 12.39 / 4.57
+#     64    1.30 / 1.75 / 3.74 / 1.28                 1.07 / 1.24 /  5.17 / 2.61
+#    128    0.56 / 0.69 / 1.07 (1.60) / 0.74          0.99 / 1.29 /  2.42 (2.65) / 1.63
+#    196    1.68 / 2.34 / 1.86 / 0.96                 1.97 / 2.57 /  2.61 / 1.54
+#    256    1.64 / 2.18 / 0.99 (1.35) / 0.77          1.88 / 2.71 /  1.98 (2.21) / 1.37
+#    384    2.51 / 3.16 / 1.05 (1.41) / 0.84 (0.82)   2.65 / 3.81 /  1.95 (2.18) / 1.43
+#    512    3.51 / 4.64 / 1.15 (1.39) / 0.95          3.67 / 5.11 /  1.90 (2.15) / 1.45
+#   ViT-B/16's [128,12,196,64], not causal: 4.67 / 6.45 / 4.58 / 2.23 with
+#   [B,S,H,D] operands, 4.62 / 6.39 / 4.57 / 2.30 with [B,H,S,D] ones (two
+#   transposes each way); the one-block forward / backward alone by batch
+#   rows a grid step: 1.104 / 1.806 at one, 1.119 / 1.766 at four, 1.114 /
+#   1.752 at eight (the code's choice), 1.110 / 1.746 at sixteen — the grid
+#   step's fixed cost is not what bounds them. With the key side padded to 16
+#   and not to 128 (208 rows for 196 keys, as the code does) 1.097 / 1.654
+#   alone, and in ViT's traced step a layer's forward 0.84 -> 0.83 ms and its
+#   backward 0.91 -> 0.81. A forward on transposed scores (reductions along
+#   sublanes, a transposed-LHS matmul for P V) lost: 1.398.
+#
+# The XLA tier jumps between S = 128 (0.56 ms, an aligned length) and S = 196
+# (1.68 ms for one and a half times the scores); the one-block kernels win from
+# 196 up at both head dims — 1.75x, 2.1x, 3.0x at D = 64 and 1.28x, 1.37x, 1.85x
+# at D = 128 for S = 196, 256, 384 — tie at 64 and lose at 128 and below, where
+# a sequence pads to a 128-row block and the call is overhead-bound. So "auto"
+# sends min(Sq, Sk) >= _SHORT_MIN_SEQ with max(Sq, Sk) <= _SHORT_MAX_SEQ (and
+# not both sides at _FLASH_MIN_SEQ) to them at the two head dims measured;
+# nothing between 128 and 196 was measured, so the edge sits just under 196.
+# At S = 512 the one-block form also beats the streaming kernels (0.95 against
+# 1.15 and 1.39 ms), but _FLASH_MIN_SEQ stays: the LM's shapes were not what
+# PR 29 measured end to end. Everything else below the crossover keeps the XLA
+# tiers, the score footprint choosing between them: plain while the saved S²
+# tensors are small, checkpointed above that, and the streaming kernels again
+# where even the transient S² tensor is memory-infeasible.
 # ---------------------------------------------------------------------------
 
 _FLASH_MIN_SEQ = 512
+_SHORT_MIN_SEQ = 192
+_SHORT_HEAD_DIMS = (64, 128)
 
 # Score-matrix bytes (B*H*Sq*Sk*4, f32) thresholds; env-overridable for tuning.
 _XLA_PLAIN_MAX = int(os.environ.get("DDW_ATTN_XLA_PLAIN_MAX", 256 * 1024**2))
@@ -796,6 +1093,9 @@ def _attn_impl(q, k, impl: str) -> str:
     sk = k.shape[2]
     if min(sq, sk) >= _FLASH_MIN_SEQ:
         return "pallas"
+    if (min(sq, sk) >= _SHORT_MIN_SEQ and max(sq, sk) <= _SHORT_MAX_SEQ
+            and q.shape[3] in _SHORT_HEAD_DIMS):
+        return "pallas_short"
     score_bytes = b * h * sq * sk * 4
     if score_bytes <= _XLA_PLAIN_MAX:
         return "xla"
@@ -810,11 +1110,13 @@ def flash_mha(q, k, v, causal: bool = False, sm_scale: float | None = None,
     """Attention for arbitrary sequence lengths (the model-facing entry),
     q [B,H,Sq,D], k/v [B,H,Sk,D] -> [B,H,Sq,D].
 
-    ``impl``: ``auto`` (dispatch on the sequence length, see module comment),
-    ``xla``, ``xla_ckpt`` (rematerialized backward), or ``pallas`` (the flash
-    kernel — pads Sq/Sk to block multiples, masks padded keys via
-    ``k_valid``, slices padded query rows back off, so ViT's 196-patch
-    sequences or any other length run on the same kernel the LM uses)."""
+    ``impl``: ``auto`` (dispatch on the shape, see the comment above
+    ``_attn_impl``), ``xla``, ``xla_ckpt`` (rematerialized backward),
+    ``pallas`` (the streaming flash kernels, any length: pads Sq/Sk to block
+    multiples in HBM, masks padded keys via ``k_valid``, slices padded query
+    rows back off) or ``pallas_short`` (the one-block kernels, both sides at
+    most 512: nothing padded in HBM). This entry transposes in and out of
+    the kernels' layout; :func:`flash_mha_seq_major` does not."""
     return flash_mha_lse(q, k, v, causal, sm_scale, block_q, block_k,
                          interpret, impl)[0]
 
@@ -836,11 +1138,14 @@ def flash_mha_lse(q, k, v, causal: bool = False, sm_scale: float | None = None,
 def flash_mha_seq_major(q, k, v, causal: bool = False,
                         sm_scale: float | None = None,
                         impl: str = "auto") -> jnp.ndarray:
-    """:func:`flash_mha` for operands as the projections produce them:
-    q [B,Sq,H,D], k/v [B,Sk,H,D] -> [B,Sq,H,D]. The kernels take that layout
-    as it is; the XLA tiers get the ``[B,H,S,D]`` transposes they always got."""
+    """:func:`flash_mha` for operands as the projections produce them (the LM
+    and ViT): q [B,Sq,H,D], k/v [B,Sk,H,D] -> [B,Sq,H,D]. The kernels take
+    that layout as it is; the XLA tiers get the ``[B,H,S,D]`` transposes they
+    always got."""
     with jax.named_scope("attention"):
-        tier = _attn_impl(_swap_sh(q), _swap_sh(k), impl)
+        (b, sq, h, d), sk = q.shape, k.shape[1]
+        tier = _attn_impl(jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+                          jax.ShapeDtypeStruct((b, h, sk, d), k.dtype), impl)
         return _dispatch_lse(q, k, v, causal, sm_scale, None, None, None,
                              tier, seq_major=True)[0]
 
@@ -860,6 +1165,9 @@ def _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     if not seq_major:
         q, k, v = _swap_sh(q), _swap_sh(k), _swap_sh(v)
     sq, sk = q.shape[1], k.shape[1]
+    if chosen == "pallas_short":
+        out, lse = _flash_short(q, k, v, causal, scale, interpret)
+        return (out if seq_major else _swap_sh(out)), lse
     bq = _pick_block(sq, block_q, _BLOCK_Q_MAX)
     bk = _pick_block(sk, block_k, _BLOCK_K_MAX)
     kp = _pad_seq(k, bk)

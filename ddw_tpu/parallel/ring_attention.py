@@ -69,10 +69,11 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     Per-device shapes: q/k/v [B, H, S_local, D] (the local sequence shard);
     returns the local shard of the attention output. Must be called inside
     ``shard_map``/``pmap`` binding ``axis_name``. ``impl`` selects the per-hop
-    attention arm (``auto``/``xla``/``xla_ckpt``/``pallas`` — see
-    :func:`ddw_tpu.ops.flash_attention.flash_mha_lse`): auto picks by the
-    LOCAL shard length, so short shards get the fused XLA arm and shards of
-    512 tokens and more the Pallas flash kernel.
+    attention arm (``auto``/``xla``/``xla_ckpt``/``pallas``/``pallas_short``
+    — see :func:`ddw_tpu.ops.flash_attention.flash_mha_lse`): auto picks by
+    the LOCAL shard's shape, so short shards get the fused XLA arm, shards of
+    512 tokens and more the streaming flash kernels and those between 192
+    and 512 the one-block kernels.
     """
     n = axis_size(axis_name)
     me = lax.axis_index(axis_name)

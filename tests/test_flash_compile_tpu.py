@@ -1,0 +1,85 @@
+"""The flash kernels compiled by the TPU's own compiler for a described v5e —
+no chip, nothing runs. The interpreter (every other flash test) accepts what
+Mosaic refuses: a slice off the tiling, a block it cannot lay out, more VMEM
+than a kernel may use. These are the shapes the benchmark's cells run and the
+corners of the one-block form (a block that over-runs the array, a key side
+padded to 16, head blocks with padding). Keep these tests in this one file:
+only one process may load the TPU's library (on-chip-measurement guide, 2)."""
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("ddw_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    """Compile without the persistent cache: an executable for a described
+    chip is written to it but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        cc.reset_cache()
+
+
+# q [B,Sq,H,D], Sk, causal, dtype
+_SHORT = {
+    "vit_b16": ((128, 196, 12, 64), 196, False, jnp.bfloat16),
+    "lm_384_d128": ((8, 384, 16, 128), 384, True, jnp.bfloat16),
+    "rectangular_f32": ((8, 300, 4, 64), 200, True, jnp.float32),
+    "three_heads_d48": ((4, 196, 3, 48), 196, False, jnp.bfloat16),
+    "one_block_of_512": ((4, 512, 4, 64), 512, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHORT))
+def test_one_block_kernels_compile_for_v5e(one_chip, case):
+    (b, sq, h, d), sk, causal, dtype = _SHORT[case]
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, k, rows = sds((b, sq, h, d)), sds((b, sk, h, d)), \
+        sds((b, h, sq), jnp.float32)
+    scale = 1.0 / d ** 0.5
+    fwd = _compile(lambda q, k, v: fa._short_forward(
+        q, k, v, causal, scale, False), q, k, k)
+    bwd = _compile(lambda *a: fa._short_backward(
+        *a, causal, scale, False), q, k, k, q, rows, q, rows)
+    assert fwd.count("tpu_custom_call") == 1
+    assert bwd.count("tpu_custom_call") == 1
+
+
+def test_streaming_kernels_compile_for_v5e(one_chip):
+    """The GPT-2 medium cells' attention: [8,1024,16,64] bf16 causal."""
+    b, s, h, d = 8, 1024, 16, 64
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one_chip)
+    blocks = (True, 0, 0, 0.125, None, None, False)
+    for fn, args in (
+            (lambda q, k, v: fa._flash_forward(q, k, v, *blocks), (q, q, q)),
+            (lambda *a: fa._flash_dq(*a, *blocks), (q, q, q, q, rows, rows)),
+            (lambda *a: fa._flash_dkv(*a, *blocks), (q, q, q, q, rows, rows))):
+        assert _compile(fn, *args).count("tpu_custom_call") == 1

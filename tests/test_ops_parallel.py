@@ -77,6 +77,22 @@ _KERNEL_CASES = {
     "padded_large_block": dict(b=1, h=1, s=1100, d=64, causal=True, mha=True),
     "padded_explicit_blocks": dict(s=300, causal=True, mha=True, block_q=128,
                                    block_k=256),
+    # the one-block kernels (sequences under 512): a block that over-runs the
+    # array where the length is no multiple of 128 — the interpreter fills the
+    # rows past the edge with NaN (pallas's uninitialized_value), so a row the
+    # kernels failed to replace by zeros would poison every gradient here
+    "short_vit": dict(b=3, s=196, short=True),
+    "short_vit_bf16": dict(b=2, s=196, dtype=jnp.bfloat16, short=True),
+    "short_causal_100": dict(s=100, causal=True, short=True),
+    "short_aligned_256": dict(b=1, s=256, causal=True, short=True),
+    "short_384_bf16": dict(b=1, s=384, causal=True, dtype=jnp.bfloat16,
+                           short=True),
+    "short_d128": dict(b=1, s=196, d=128, short=True),
+    "short_d48_three_heads": dict(b=1, h=3, s=100, d=48, causal=True,
+                                  short=True),
+    "short_q_under_k": dict(b=1, sq=100, sk=300, causal=True, short=True),
+    "short_q_over_k": dict(b=1, sq=384, sk=196, short=True),
+    "short_d32_four_heads_a_block": dict(b=1, h=5, s=130, d=32, short=True),
 }
 
 
@@ -96,9 +112,11 @@ def test_flash_kernels_match_reference(case):
     w_lse = jnp.cos(jnp.arange(sq, dtype=jnp.float32))      # lse cotangent
 
     def attend(q, k, v):
-        if c.get("mha"):
+        if c.get("mha") or c.get("short"):
             return flash_mha_lse(q, k, v, causal, None, c.get("block_q"),
-                                 c.get("block_k"), impl="pallas")
+                                 c.get("block_k"),
+                                 impl="pallas_short" if c.get("short")
+                                 else "pallas")
         return flash_attention_lse(q, k, v, causal, q_off, k_off, None,
                                    c.get("block_q"), c.get("block_k"))
 
@@ -127,10 +145,12 @@ def test_flash_kernels_match_reference(case):
                                    rtol=gtol, atol=gtol, err_msg=f"d{what}")
 
 
-@pytest.mark.parametrize("s,tier", [(640, "pallas"), (128, "xla")])
+@pytest.mark.parametrize("s,tier", [(640, "pallas"), (196, "pallas_short"),
+                                    (64, "xla")])
 def test_flash_mha_seq_major_matches_flash_mha(s, tier):
-    """The entry the LM calls ([B,S,H,D] operands) is flash_mha on transposed
-    operands, on the kernels and on the XLA tiers, forward and gradients."""
+    """The entry the LM and ViT call ([B,S,H,D] operands) is flash_mha on
+    transposed operands, on both kernel forms and on the XLA tiers, forward
+    and gradients."""
     from ddw_tpu.ops.flash_attention import (_attn_impl, flash_mha,
                                              flash_mha_seq_major)
 
@@ -355,19 +375,23 @@ def test_attention_impl_dispatch_equivalence():
 
     q, k, v = _qkv(b=2, h=2, s=160, d=32, seed=8)
     outs = {}
-    for impl in ("xla", "xla_ckpt", "pallas"):
+    for impl in ("xla", "xla_ckpt", "pallas", "pallas_short"):
         o, lse = flash_mha_lse(q, k, v, causal=True, impl=impl)
         g = jax.grad(lambda q: jnp.sum(
             flash_mha_lse(q, k, v, causal=True, impl=impl)[0] ** 2))(q)
         outs[impl] = (np.asarray(o), np.asarray(lse), np.asarray(g))
-    for impl in ("xla_ckpt", "pallas"):
+    for impl in ("xla_ckpt", "pallas", "pallas_short"):
         for a, b, what in zip(outs["xla"], outs[impl], ("out", "lse", "gq")):
             np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                        err_msg=f"{impl} {what}")
 
-    # auto: sequences of _FLASH_MIN_SEQ and more go to the kernels whatever
-    # the batch; below it the score footprint picks between the XLA tiers
-    from ddw_tpu.ops.flash_attention import _FLASH_MIN_SEQ
+    # auto reads shapes alone: both sides of _FLASH_MIN_SEQ and more go to the
+    # streaming kernels whatever the batch; sequences from _SHORT_MIN_SEQ up
+    # that fit one block go to the one-block kernels at the head dims the
+    # ladder measured; below that, and at any other head dim, the score
+    # footprint picks between the XLA tiers
+    from ddw_tpu.ops.flash_attention import (_FLASH_MIN_SEQ, _SHORT_MAX_SEQ,
+                                             _SHORT_MIN_SEQ)
 
     def auto(b, h, s, d=64, sk=None, dtype=jnp.bfloat16):
         return _attn_impl(jax.ShapeDtypeStruct((b, h, s, d), dtype),
@@ -375,35 +399,128 @@ def test_attention_impl_dispatch_equivalence():
                           "auto")
 
     assert auto(8, 16, 1024) == "pallas"        # the LM cells, batch 8 a chip
-    assert auto(128, 12, 196) == "xla"          # vitb16_train_224
-    assert auto(1, 1, _FLASH_MIN_SEQ) == "pallas"
-    assert auto(1, 1, _FLASH_MIN_SEQ - 128) == "xla"
-    assert auto(64, 16, _FLASH_MIN_SEQ - 128) == "xla_ckpt"     # 576 MiB
-    assert auto(8, 16, 1024, sk=128) == "xla"   # a short side: no kernel
-    assert auto(1024, 16, 256) == "pallas"      # 4 GiB of scores do not fit
     assert auto(8, 16, 1024, dtype=jnp.float32) == "pallas"
+    assert auto(1, 1, _FLASH_MIN_SEQ) == "pallas"
+    assert auto(128, 12, 196) == "pallas_short"     # vitb16_train_224
+    assert auto(1, 2, 196, dtype=jnp.float32) == "pallas_short"
+    assert auto(21, 16, 384, d=128) == "pallas_short"
+    assert auto(1024, 16, 256) == "pallas_short"    # 4 GiB of scores on XLA
+    assert auto(1, 1, _SHORT_MIN_SEQ) == "pallas_short"     # the lower edge
+    assert auto(8, 16, _SHORT_MAX_SEQ, sk=256) == "pallas_short"
+    assert auto(1, 1, _SHORT_MIN_SEQ - 1) == "xla"
+    assert auto(64, 16, 128) == "xla"           # measured: XLA 0.56, 0.74 ms
+    assert auto(8, 16, 1024, sk=128) == "xla"   # a short side: no kernel
+    assert auto(8, 16, 1024, sk=256) == "xla"   # fits no single block
+    assert auto(64, 16, 384, d=32) == "xla_ckpt"    # 576 MiB, head dim unmeasured
+    assert auto(1024, 16, 256, d=32) == "pallas"    # 4 GiB of scores do not fit
+    # the shapes the CPU suite trains ViT and the LM at stay on XLA, or
+    # tier-1 would pay the Pallas interpreter in every model test
+    assert auto(8, 4, 4, d=48) == "xla"         # 32 x 32 images, patch 16
+    assert auto(8, 2, 16, d=32) == "xla"        # 64 x 64 images
+    assert auto(8, 4, 128, d=16) == "xla"       # the smoke LM
     huge = jnp.zeros((1, 1, 128, 16))
     assert _attn_impl(huge, huge, "xla_ckpt") == "xla_ckpt"
 
 
-def test_vit_flash_mha_matches_flax_attention():
+def _primitives(jaxpr, name):
+    """Every equation of ``jaxpr`` and its sub-jaxprs whose primitive is
+    ``name``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _primitives(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("s,d,heads,tier", [(196, 128, 2, "pallas_short"),
+                                            (4, 64, 4, "xla")])
+def test_vit_flash_mha_matches_flax_attention(s, d, heads, tier):
     """FlashMHA (same param layout) must reproduce
-    nn.MultiHeadDotProductAttention to tolerance — the ViT swap is a drop-in."""
+    nn.MultiHeadDotProductAttention to tolerance — the ViT swap is a drop-in —
+    in its output and its parameter gradients, on the one-block kernels (ViT's
+    196 tokens reach them by the shape alone) and on the XLA tier (the sizes
+    the CPU suite trains ViT at); its parameters are flax's, to the byte."""
     import flax.linen as nn
 
-    from ddw_tpu.models.vit import FlashMHA
+    from ddw_tpu.models.vit import EncoderBlock, FlashMHA
+    from ddw_tpu.ops.flash_attention import _attn_impl
 
-    b, s, d, heads = 2, 196, 64, 4
+    b = 1
     rng = np.random.RandomState(7)
     x = jnp.asarray(rng.randn(b, s, d).astype(np.float32))
+    qk = jax.ShapeDtypeStruct((b, heads, s, d // heads), jnp.float32)
+    assert _attn_impl(qk, qk, "auto") == tier
     mod = FlashMHA(num_heads=heads, dtype=jnp.float32)
     params = mod.init(jax.random.PRNGKey(0), x)
-    out = mod.apply(params, x)
     ref_mod = nn.MultiHeadDotProductAttention(num_heads=heads, dtype=jnp.float32,
                                               name=None)
-    ref = ref_mod.apply(params, x, x)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    ref_params = ref_mod.init(jax.random.PRNGKey(0), x, x)
+    assert jax.tree.structure(params) == jax.tree.structure(ref_params)
+    for a, r in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params)):
+        assert a.dtype == r.dtype and a.shape == r.shape
+        assert np.asarray(a).tobytes() == np.asarray(r).tobytes()
+    p = params["params"]
+    assert p["query"]["kernel"].shape == (d, heads, d // heads)
+    assert p["out"]["kernel"].shape == (heads, d // heads, d)
+
+    w = jnp.asarray(rng.randn(b, s, d).astype(np.float32))
+    got, got_g = jax.value_and_grad(
+        lambda p: jnp.sum(mod.apply(p, x) * w))(params)
+    ref, ref_g = jax.value_and_grad(
+        lambda p: jnp.sum(ref_mod.apply(p, x, x) * w))(params)
+    np.testing.assert_allclose(np.asarray(mod.apply(params, x)),
+                               np.asarray(ref_mod.apply(params, x, x)),
                                rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(float(got), float(ref), rtol=1e-4)
+    for a, r in zip(jax.tree.leaves(got_g), jax.tree.leaves(ref_g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+    if tier == "pallas_short":
+        # on the kernel tier q, k, v go to the kernels as the projections
+        # give them: a ViT block, forward and backward, transposes no
+        # [B,S,H,hd] tensor (the kernels' own 2-D tile transposes remain)
+        block = EncoderBlock(num_heads=heads, mlp_dim=2 * d,
+                             dtype=jnp.float32)
+        bp = block.init(jax.random.PRNGKey(1), x, False)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(block.apply(p, x, False) ** 2)))(bp)
+        assert _primitives(jaxpr.jaxpr, "pallas_call")
+        assert not [e for e in _primitives(jaxpr.jaxpr, "transpose")
+                    if len(e.invars[0].aval.shape) == 4]
+
+
+def test_one_block_kernels_partition_over_batch_and_heads():
+    """The one-block pallas_calls sit under the streaming kernels' partition
+    rule: batch over ``data`` and heads over ``model`` (ViT under
+    VIT_TP_RULES) run the kernels on local shards — same loss and gradients,
+    sharded like the operands, nothing gathered."""
+    from ddw_tpu.ops.flash_attention import _attn_impl, flash_mha_seq_major
+
+    mesh = make_mesh(MeshSpec((("data", 2), ("model", 2))),
+                     devices=jax.devices()[:4])
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(2, 196, 4, 64).astype(np.float32))
+               for _ in range(3))
+    assert _attn_impl(*(jax.ShapeDtypeStruct((2, 4, 196, 64), jnp.float32),) * 2,
+                      "auto") == "pallas_short"
+
+    def loss(q, k, v):
+        return jnp.sum(flash_mha_seq_major(q, k, v, causal=False) ** 2)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    want, want_g = grad(q, k, v)
+    rows = NamedSharding(mesh, P("data", None, "model", None))
+    sharded = jax.jit(grad, in_shardings=(rows,) * 3)
+    got, got_g = sharded(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, b in zip(got_g, want_g):
+        assert a.sharding.spec == P("data", None, "model")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+    assert "all-gather" not in sharded.lower(q, k, v).compile().as_text()
 
 
 def test_ring_attention_pallas_arm_matches_full():

@@ -10,6 +10,11 @@ quotes. Presets (all bfloat16, D = 64 unless ``--dim``):
   ``flash_mha``): ``xla``, ``xla_ckpt``, ``pallas`` at the blocks the code picks.
 - ``ladder``: S in 256..4096 at B*H*S = 131072 tokens, causal and not, the same
   three tiers: where the kernels start to win.
+- ``short``: the ladder under ``_FLASH_MIN_SEQ`` — S in 32..512 at B*S = 8192
+  tokens x 16 heads, causal and not, and ViT's shape in both operand layouts —
+  with a fourth arm, ``pallas_short`` (the one-block kernels), beside the
+  streaming kernels; then the one-block forward and backward alone at ViT's
+  shape over the batch rows a grid step takes.
 - ``blocks``: at one shape (``--shape``, default the LM cell's), each of the
   three kernels alone over (block_q, block_k, sub_k), and the chosen blocks.
 
@@ -43,6 +48,9 @@ CELL_SHAPES = (((8, 16, 1024, 64), True, True),
 LADDER_SEQS = (256, 512, 1024, 2048, 4096)
 LADDER_TOKENS = 131072
 TIERS = ("xla", "xla_ckpt", "pallas")
+SHORT_SEQS = (32, 64, 128, 196, 256, 384, 512)
+SHORT_TIERS = TIERS + ("pallas_short",)
+SHORT_IMAGES = (1, 2, 4, 8, 16)
 BLOCK_GRID = tuple((bq, bk, sub) for bq in (256, 512, 1024)
                    for bk in (512, 1024) for sub in (128, 256, 512)
                    if sub <= bk)
@@ -107,6 +115,11 @@ def chosen_blocks(s: int):
                                    bq, bk, None))
 
 
+def chosen_images(shape) -> int:
+    """Batch rows a grid step the one-block kernels pick for ``[B,H,S,D]``."""
+    return fa._pick_images(shape[0], fa._short_pad(shape[2], 128), 128, 2)
+
+
 def emit(row: dict, device: str):
     print(json.dumps({"tool": "fa2_sweep", "device": device, **row}),
           flush=True)
@@ -123,6 +136,8 @@ def run_tiers(preset, shapes, device, tiers=TIERS):
                    "layout": "bshd" if seq_major else "bhsd"}
             if impl == "pallas":
                 row["blocks"] = chosen_blocks(shape[2])
+            elif impl == "pallas_short":
+                row["images"] = chosen_images(shape)
             try:
                 fn = tier_fn(impl, causal, seq_major)
                 ms = time_ms(fn, q, k, v)
@@ -173,6 +188,36 @@ def run_blocks(shape, causal, device, grid=BLOCK_GRID):
             emit(row, device)
 
 
+def run_short_images(shape, causal, device, grid=SHORT_IMAGES):
+    """The one-block forward and backward alone, by batch rows a grid step."""
+    b, h, s, d = shape
+    q, k, v, g = qkv((b, s, h, d), n=4)
+    scale, interpret = fa._resolve_defaults(None, None, d)
+    out, lse = fa._short_forward(q, k, v, causal, scale, interpret)
+    chosen = chosen_images(shape)
+    for images in grid:
+        if b % images:
+            continue
+        arms = {
+            "short_fwd": (jax.jit(lambda q, k, v: fa._short_forward(
+                q, k, v, causal, scale, interpret, images)), (q, k, v), 2),
+            "short_bwd": (jax.jit(lambda *a: fa._short_backward(
+                *a, causal, scale, interpret, images)),
+                (q, k, v, out, lse, g, jnp.zeros_like(lse)), 5),
+        }
+        for name, (fn, args, units) in arms.items():
+            row = {"preset": "short", "shape": list(shape),
+                   "dtype": "bfloat16", "causal": causal, "arm": name,
+                   "images": images, "chosen": images == chosen}
+            try:
+                ms = time_ms(fn, *args, min_s=0.1)
+                row.update(ms=round(ms, 4), tflops=round(
+                    matmul_flops(shape, causal, units) / ms / 1e9, 2))
+            except Exception as e:
+                row.update(ms=None, error=f"{type(e).__name__}: {e}"[:300])
+            emit(row, device)
+
+
 def profile_names(shape, causal, trace_dir, device):
     """One traced forward + backward of the kernels; the device operations'
     names and summed times, longest first."""
@@ -197,27 +242,36 @@ def profile_names(shape, causal, trace_dir, device):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--preset", default="cells",
-                    help="comma list of cells, ladder, blocks")
+                    help="comma list of cells, ladder, short, blocks")
     ap.add_argument("--shape", default="8,16,1024,64",
                     help="B,H,S,D of the blocks preset")
     ap.add_argument("--dim", type=int, default=64,
-                    help="head dimension of the ladder preset")
+                    help="head dimension of the ladder and short presets")
     ap.add_argument("--not-causal", action="store_true",
                     help="the blocks preset without the causal mask")
-    ap.add_argument("--tiers", default=",".join(TIERS))
+    ap.add_argument("--tiers", default=None,
+                    help="comma list of arms (default: the preset's own)")
     ap.add_argument("--profile", default=None, metavar="DIR")
     args = ap.parse_args()
     from ddw_tpu.utils.config import require_tpu_or_exit
     device = require_tpu_or_exit("sweep")
-    tiers = tuple(args.tiers.split(","))
+    tiers = tuple(args.tiers.split(",")) if args.tiers else None
     shape = tuple(int(x) for x in args.shape.split(","))
     for preset in args.preset.split(","):
         if preset == "cells":
-            run_tiers(preset, CELL_SHAPES, device, tiers)
+            run_tiers(preset, CELL_SHAPES, device, tiers or TIERS)
         elif preset == "ladder":
             run_tiers(preset, [((LADDER_TOKENS // s // 16, 16, s, args.dim),
                                  causal, True) for s in LADDER_SEQS
-                               for causal in (True, False)], device, tiers)
+                               for causal in (True, False)], device,
+                      tiers or TIERS)
+        elif preset == "short":
+            vit = CELL_SHAPES[1][0]
+            run_tiers(preset, [(vit, False, False), (vit, False, True)]
+                      + [((8192 // s, 16, s, args.dim), causal, True)
+                         for s in SHORT_SEQS for causal in (False, True)],
+                      device, tiers or SHORT_TIERS)
+            run_short_images(vit, False, device)
         elif preset == "blocks":
             run_blocks(shape, not args.not_causal, device)
         else:
