@@ -22,18 +22,14 @@ seeded permutation, mirroring the reference's seed-42 split discipline.
 
 from __future__ import annotations
 
-import dataclasses
 import time
 import warnings
 
 import jax
 import numpy as np
 
-from ddw_tpu.checkpoint.ckpt import CheckpointManager
 from ddw_tpu.models.lm import build_lm
-from ddw_tpu.obs.trace import span_lane
-from ddw_tpu.runtime.elastic import maybe_elastic_restart, process_topology
-from ddw_tpu.runtime.faults import Preempted, maybe_fault, preemption_requested
+from ddw_tpu.runtime.elastic import process_topology
 from ddw_tpu.runtime.mesh import (DATA_AXIS, PIPE_AXIS, SEQ_AXIS, MeshSpec,
                                   make_data_mesh, make_mesh)
 from ddw_tpu.train.lm_step import (
@@ -42,20 +38,11 @@ from ddw_tpu.train.lm_step import (
     make_lm_train_chain,
     make_lm_train_step,
 )
+from ddw_tpu.train import loop
+from ddw_tpu.train.loop import TrainResult
 from ddw_tpu.train.schedule import ScheduleSuite
-from ddw_tpu.train.step import (TrainState, chain_plan, ema_params,
-                                fetch_metrics_mean, get_lr, make_optimizer,
-                                set_lr)
-from ddw_tpu.utils.config import LMCfg, TrainCfg, to_dict
-
-
-@dataclasses.dataclass
-class LMTrainResult:
-    val_loss: float
-    val_accuracy: float
-    history: list[dict[str, float]]
-    state: TrainState
-    epochs_run: int
+from ddw_tpu.train.step import chain_plan, make_optimizer
+from ddw_tpu.utils.config import LMCfg, TrainCfg
 
 
 class LMTrainer:
@@ -183,7 +170,7 @@ class LMTrainer:
 
     # ------------------------------------------------------------------
     def fit(self, tokens: np.ndarray, val_fraction: float = 0.1,
-            resume: bool = False) -> LMTrainResult:
+            resume: bool = False) -> TrainResult:
         """Train from an in-memory token corpus ``[num_seqs, seq_len+1]``."""
         t_fit = time.monotonic()
         cfg = self.train_cfg
@@ -211,7 +198,7 @@ class LMTrainer:
             raise ValueError(f"{len(train)} train sequences < global batch "
                              f"{global_batch}")
 
-        def make_providers(start_epoch, step, plan, chained):
+        def make_providers(start_epoch, step, plan, chained, tracer):
             def train_batches(epoch):
                 order = np.random.RandomState(cfg.seed + 1 + epoch
                                               ).permutation(len(train))
@@ -241,11 +228,11 @@ class LMTrainer:
 
             return train_batches, val_batches
 
-        return self._run(seq_len, steps_per_epoch, val_steps, global_batch,
+        return self._run(seq_len, steps_per_epoch, global_batch,
                          make_providers, resume, t_fit)
 
     def fit_tables(self, train_table, val_table,
-                   resume: bool = False) -> LMTrainResult:
+                   resume: bool = False) -> TrainResult:
         """Train from materialized token tables (``prep.write_token_table``)
         — the LM family through the same store -> sharded-loader path the
         vision families use: shard-selected reads, seeded shuffle, infinite
@@ -299,7 +286,7 @@ class LMTrainer:
                              f"{n_proc} processes")
         host_batch = global_batch // n_proc
 
-        def make_providers(start_epoch, step, plan, chained):
+        def make_providers(start_epoch, step, plan, chained, tracer):
             prefetch_to = getattr(step, "batch_sharding", None)
             if n_proc > 1 and prefetch_to is None:
                 raise ValueError("multi-process fit_tables needs a step "
@@ -310,7 +297,7 @@ class LMTrainer:
                                  "needs a step with a batch sharding — the "
                                  "loader stacks super-batches on device")
             shard_kw = dict(cur_shard=cur_proc, shard_count=n_proc,
-                            prefetch_to=prefetch_to, tracer=self.tracer)
+                            prefetch_to=prefetch_to, tracer=tracer)
             train_iter = iter(ShardedLoader(
                 train_table, batch_size=host_batch, num_epochs=None,
                 shuffle=True, seed=cfg.seed + 1,
@@ -340,18 +327,15 @@ class LMTrainer:
 
             return train_batches, val_batches
 
-        return self._run(seq_len, steps_per_epoch, val_steps, global_batch,
+        return self._run(seq_len, steps_per_epoch, global_batch,
                          make_providers, resume, t_fit)
 
-    def _run(self, seq_len, steps_per_epoch, val_steps, global_batch,
-             make_providers, resume, t_fit) -> LMTrainResult:
+    def _run(self, seq_len, steps_per_epoch, global_batch, make_providers,
+             resume, t_fit) -> TrainResult:
         cfg = self.train_cfg
         mesh = self.mesh
         dp = mesh.shape[DATA_AXIS]
-        # every boundary below is stamped once; the stamps feed the span tree
-        # (a no-op lane without a tracer) and the telemetry hub alike
-        sp = span_lane(self.tracer, "train", "train")
-        setup_id = sp.open()
+        tracer, sp, setup_id = loop.open_fit(cfg, self.tracer)
 
         t0 = time.monotonic()
         tx = make_optimizer(cfg)
@@ -364,12 +348,14 @@ class LMTrainer:
         # Fused K-step dispatch: chain plan covering one epoch exactly
         # (PP refused in __init__; all-ones plan keeps the per-step path).
         plan = chain_plan(steps_per_epoch, cfg.steps_per_dispatch)
-        chained = cfg.steps_per_dispatch > 1 and any(k > 1 for k in plan)
+        chained = any(k > 1 for k in plan)
         rng = jax.random.PRNGKey(cfg.seed)
         t1 = time.monotonic()
         sp.span("optimizer_init", t0, t1, setup_id)
+        row_extra = None
         if self.pp:
-            from ddw_tpu.parallel.pipeline import (init_pp_state,
+            from ddw_tpu.parallel.pipeline import (bubble_fraction,
+                                                   init_pp_state,
                                                    make_pp_lm_train_step)
 
             vstages = (cfg.pipeline_virtual_stages
@@ -383,80 +369,63 @@ class LMTrainer:
                 donate=True, schedule=cfg.pipeline_schedule,
                 virtual_stages=vstages)
             eval_step = step.eval_step
-        elif self.sharded:
-            from ddw_tpu.parallel.zero import (make_fsdp_train_chain,
-                                               make_fsdp_train_step,
-                                               make_zero_train_chain,
-                                               make_zero_train_step)
-
-            state = init_lm_state(self.model, tx, rng,
-                                  seq_len=min(8, seq_len))
-            t2 = time.monotonic()
-            make_sharded = (make_fsdp_train_step if cfg.fsdp
-                            else make_zero_train_step)
-            # DATA_AXIS, not cfg.data_axis: LMTrainer builds (and validates)
-            # its meshes with the constant throughout.
-            step = make_sharded(self.model, tx, mesh, DATA_AXIS,
-                                grad_accum_steps=cfg.grad_accum_steps)
-            if chained:
-                make_sharded_chain = (make_fsdp_train_chain if cfg.fsdp
-                                      else make_zero_train_chain)
-                chain = make_sharded_chain(
-                    self.model, tx, mesh, DATA_AXIS,
-                    grad_accum_steps=cfg.grad_accum_steps)
-            # Eval reads the sharded params through the shard_map eval step's
-            # replicated in-spec: GSPMD gathers per eval call (same trade the
-            # vision Trainer makes).
-            eval_step = make_lm_eval_step(self.model, mesh,
-                                          seq_axis=self.seq_axis)
+            # schedule idle fraction, logged beside loss (the step's own
+            # ``pp_bubble_fraction`` metric is this number)
+            row_extra = {"pp_bubble_fraction": bubble_fraction(
+                cfg.pipeline_stages, cfg.pipeline_microbatches, vstages)}
         else:
             state = init_lm_state(self.model, tx, rng,
                                   seq_len=min(8, seq_len))
             t2 = time.monotonic()
-            step = make_lm_train_step(self.model, tx, mesh,
-                                      seq_axis=self.seq_axis,
-                                      grad_accum_steps=cfg.grad_accum_steps)
-            if chained:
-                chain = make_lm_train_chain(
+            if self.sharded:
+                from ddw_tpu.parallel.zero import (make_fsdp_train_chain,
+                                                   make_fsdp_train_step,
+                                                   make_zero_train_chain,
+                                                   make_zero_train_step)
+
+                make_step, make_chain = (
+                    (make_fsdp_train_step, make_fsdp_train_chain) if cfg.fsdp
+                    else (make_zero_train_step, make_zero_train_chain))
+                # DATA_AXIS, not cfg.data_axis: LMTrainer builds (and
+                # validates) its meshes with the constant throughout.
+                step = make_step(self.model, tx, mesh, DATA_AXIS,
+                                 grad_accum_steps=cfg.grad_accum_steps)
+                if chained:
+                    chain = make_chain(self.model, tx, mesh, DATA_AXIS,
+                                       grad_accum_steps=cfg.grad_accum_steps)
+            else:
+                step = make_lm_train_step(
                     self.model, tx, mesh, seq_axis=self.seq_axis,
                     grad_accum_steps=cfg.grad_accum_steps)
+                if chained:
+                    chain = make_lm_train_chain(
+                        self.model, tx, mesh, seq_axis=self.seq_axis,
+                        grad_accum_steps=cfg.grad_accum_steps)
+            # Under ZeRO/FSDP eval reads the sharded params through the
+            # shard_map eval step's replicated in-spec: GSPMD gathers per
+            # eval call (same trade the vision Trainer makes).
             eval_step = make_lm_eval_step(self.model, mesh,
                                           seq_axis=self.seq_axis)
         t3 = time.monotonic()
         sp.span("model_init", t1, t2, setup_id)
         sp.span("build_step", t2, t3, setup_id)
 
-        if not cfg.checkpoint_dir:
-            ckpt = None
-        elif self.sharded:
-            # per-process sharded format: saving must NOT all-gather the
-            # ZeRO/FSDP leaves into one host
-            from ddw_tpu.train.trainer import _ZeroCheckpointAdapter
-
-            ckpt = _ZeroCheckpointAdapter(
-                cfg.checkpoint_dir, mesh, DATA_AXIS, fsdp=cfg.fsdp,
-                async_write=cfg.async_checkpoint,
-                max_inflight=cfg.async_checkpoint_inflight)
-        else:
-            ckpt = CheckpointManager(
-                cfg.checkpoint_dir, async_write=cfg.async_checkpoint,
-                max_inflight=cfg.async_checkpoint_inflight)
-        start_epoch = 0
-        restored_meta = None
+        ckpt, best = loop.open_checkpoints(cfg, mesh, DATA_AXIS)
+        start_epoch, restored_meta = 0, None
         if ckpt and resume:
-            state, at_step = ckpt.restore(state)
-            if at_step is not None:
-                start_epoch = int(at_step) // steps_per_epoch
-                restored_meta = ckpt.read_metadata(at_step)
+            state, start_epoch, restored_meta = loop.restore(
+                ckpt, state, steps_per_epoch)
             sp.span("restore", t3, time.monotonic(), setup_id)
 
-        if ckpt and resume and start_epoch > 0 and start_epoch >= cfg.epochs:
+        if start_epoch > 0 and start_epoch >= cfg.epochs:
             # The restored checkpoint already covers every requested epoch —
             # the loop below would not run and the result would silently be
             # NaN. Surface the checkpoint's own last metrics so callers
             # gating on val_loss see the real numbers.
             saved = (restored_meta or {}).get("metrics")
             ckpt.close()
+            if best is not None:
+                best.close()
             if saved is None:
                 raise ValueError(
                     f"resume=True restored a checkpoint at epoch "
@@ -472,10 +441,10 @@ class LMTrainer:
                 # callers that keep training or serving from result.state
                 # must not see placement depend on which path returned.
                 state = step.place_state(state)
-            return LMTrainResult(val_loss=saved["val_loss"],
-                                 val_accuracy=saved["val_accuracy"],
-                                 history=[saved], state=state,
-                                 epochs_run=start_epoch)
+            return TrainResult(val_loss=saved["val_loss"],
+                               val_accuracy=saved["val_accuracy"],
+                               history=[saved], state=state,
+                               epochs_run=start_epoch)
 
         if self.pp or self.sharded:
             # Placement AFTER restore: the checkpoint template is the
@@ -484,219 +453,31 @@ class LMTrainer:
             # a restored already-sharded state.
             state = step.place_state(state)
 
-        best = None
-        if cfg.checkpoint_keep_best:
-            if not ckpt:
-                raise ValueError("checkpoint_keep_best needs a "
-                                 "checkpoint_dir")
-            from ddw_tpu.checkpoint.ckpt import BestCheckpointKeeper
-            from ddw_tpu.train.trainer import _ZeroCheckpointAdapter
-
-            best = BestCheckpointKeeper(
-                cfg.checkpoint_dir,
-                (lambda d: _ZeroCheckpointAdapter(
-                    d, mesh, DATA_AXIS, fsdp=cfg.fsdp, keep=1,
-                    async_write=cfg.async_checkpoint))
-                if self.sharded else
-                (lambda d: CheckpointManager(
-                    d, keep=1, async_write=cfg.async_checkpoint)))
-
         sched = ScheduleSuite.build(cfg, dp, restored_meta)
-
-        if self.run is not None:
-            self.run.log_params(
-                {f"train.{k}": v for k, v in to_dict(cfg).items()})
-            self.run.log_params(
-                {f"lm.{k}": v for k, v in to_dict(self.lm_cfg).items()})
-            self.run.log_params({"mesh": dict(mesh.shape),
-                                 "steps_per_epoch": steps_per_epoch,
-                                 "global_batch": global_batch})
+        loop.log_fit_params(self.run, {"mesh": dict(mesh.shape),
+                                       "steps_per_epoch": steps_per_epoch,
+                                       "global_batch": global_batch},
+                            train=cfg, lm=self.lm_cfg)
 
         t0 = time.monotonic()
+        run_step = chain if chained else step
         train_batches, val_batches = make_providers(
-            start_epoch, chain if chained else step, plan, chained)
+            start_epoch, run_step, plan, chained, tracer)
         sp.span("build_loaders", t0, time.monotonic(), setup_id)
 
-        history: list[dict[str, float]] = []
         step_rng = jax.random.PRNGKey(cfg.seed + 1)
-        epochs_run = start_epoch
-        # telemetry plane: a Run wrapped by obs.telemetry.tee_run exposes
-        # its hub — chain dispatch and checkpoint-write latencies become
-        # windowed dist series beside the serving fleet's (same ladder)
-        hub = (getattr(self.run, "telemetry_hub", None)
-               if self.run is not None else None)
-        resumed = ckpt is not None and resume and start_epoch > 0
-        state = sched.initial_state(state, start_epoch, resumed)
-        # Host-side step counter: folding the device counter into the rng
-        # would force a blocking device_get every step (serializing async
-        # dispatch); the host knows it exactly.
-        host_step = int(jax.device_get(state.step))
-        try:
-            for epoch in range(start_epoch, cfg.epochs):
-                t_epoch = time.monotonic()
-                epoch_id = sp.open()
-                tlosses, taccs = [], []
-                batch_it = train_batches(epoch)
-                step_i = 0
-                for k_chain in plan:
-                    t_chain = time.monotonic()
-                    chain_id = sp.open()
-                    if setup_id is not None:
-                        # set-up ends where the first chain starts
-                        sp.span("fit_setup", t_fit, t_chain, span=setup_id)
-                        setup_id = None
-                    inputs, targets = next(batch_it)
-                    t_data = time.monotonic()
-                    sp.span("data_wait", t_chain, t_data, chain_id,
-                            args=sp.on and {"step": host_step})
-                    # Fault-injection hook (runtime.faults): free no-op
-                    # unless DDW_FAULT targets this rank/step/generation.
-                    # Under chained dispatch the hook (and the preemption
-                    # check / per-batch LR write) fires at CHAIN boundaries —
-                    # the host only regains control every k_chain steps.
-                    maybe_fault("step", step=host_step,
-                                ckpt_dir=cfg.checkpoint_dir or None)
-                    # Elastic park point (no-op outside an elastic gang): a
-                    # dead peer re-forms the gang — leave via ElasticRestart
-                    # at the chain boundary and re-enter fit(resume=True)
-                    # in-process from the latest durable checkpoint.
-                    maybe_elastic_restart(step=host_step)
-                    if preemption_requested():
-                        # Graceful preemption (SIGTERM): checkpoint mid-epoch
-                        # and leave via Preempted; the gang worker converts it
-                        # to EXIT_PREEMPTED (restart outside the crash
-                        # budget). The finally block joins the async writer.
-                        if ckpt:
-                            t0 = time.monotonic()
-                            ckpt.save(state, host_step,
-                                      metadata={"epoch": epoch,
-                                                "preempted": True,
-                                                "callbacks": sched.state_dicts()})
-                            sp.span("ckpt_save", t0, time.monotonic(),
-                                    epoch_id,
-                                    args=sp.on and {"step": host_step})
-                        raise Preempted(host_step)
-                    lr = sched.lr_for_batch(epoch, step_i, steps_per_epoch)
-                    if lr is not None:
-                        state = set_lr(state, lr)
-                    t_disp = time.monotonic()
-                    if self.pp:  # the pipeline step is deterministic: no rng
-                        state, m = step(state, inputs, targets)
-                    elif chained:
-                        # [k, B, S] super-batch through the fused scan
-                        # program; metrics come back [k] per step
-                        state, m = chain(state, inputs, targets,
-                                         jax.random.fold_in(step_rng,
-                                                            host_step))
-                    else:
-                        state, m = step(state, inputs, targets,
-                                        jax.random.fold_in(step_rng,
-                                                           host_step))
-                    t_end = time.monotonic()
-                    # enqueue plus back-pressure from the device queue
-                    sp.span("dispatch", t_disp, t_end, chain_id,
-                            args=sp.on and {"step": host_step, "k": k_chain})
-                    # the chain boundary as the host sees it; its self time
-                    # (less data_wait and dispatch) is the loop's own work
-                    sp.span("train_chain", t_chain, t_end, epoch_id, chain_id,
-                            args=sp.on and {"epoch": epoch, "step": host_step,
-                                            "k": k_chain,
-                                            "chained": bool(chained)})
-                    if hub is not None:
-                        hub.observe("train.chain_ms", (t_end - t_chain) * 1e3)
-                    host_step += k_chain
-                    step_i += k_chain
-                    tlosses.append(m["loss"])
-                    taccs.append(m["accuracy"])
 
-                vlosses, vaccs = [], []
-                eval_state = state
-                if self.sharded:
-                    # eval reads only params: dropping the sharded moments
-                    # keeps the eval jit from all-gathering them to match
-                    # its replicated in-spec (FSDP params DO get gathered —
-                    # eval wants full weights)
-                    eval_state = eval_state.replace(opt_state=())
-                if cfg.ema_decay:
-                    # evaluate the Polyak shadow (what serving should ship)
-                    eval_state = eval_state.replace(
-                        params=ema_params(state), opt_state=())
-                # the first wait holds the building of the epoch's
-                # validation loader (fit_tables makes one anew every epoch)
-                t0 = t_val = time.monotonic()
-                val_id = sp.open()
-                for i, (vin, vtg) in enumerate(val_batches()):
-                    t1 = time.monotonic()
-                    sp.span("val_data_wait", t0, t1, val_id,
-                            args=sp.on and {"i": i, "first": i == 0})
-                    vm = eval_step(eval_state, vin, vtg)
-                    vlosses.append(vm["loss"])
-                    vaccs.append(vm["accuracy"])
-                    t0 = time.monotonic()
-                    sp.span("val_dispatch", t1, t0, val_id,
-                            args=sp.on and {"i": i})
-                sp.span("validation", t_val, t0, epoch_id, val_id,
-                        args=sp.on and {"steps": len(vlosses)})
-                # ONE device reduction + fetch per metric for the whole epoch
-                # (fetch_metrics_mean) instead of a device_get per scalar —
-                # exact per-step mean whether entries are scalars or [k]
-                # chain arrays. The first fetch is the epoch's barrier: it
-                # returns when the device has run every step before it.
-                t0 = time.monotonic()
-                row = {
-                    "epoch": epoch,
-                    "loss": fetch_metrics_mean(tlosses),
-                    "accuracy": fetch_metrics_mean(taccs),
-                    "val_loss": fetch_metrics_mean(vlosses),
-                    "val_accuracy": fetch_metrics_mean(vaccs),
-                    "lr": get_lr(state),
-                }
-                if self.pp:  # schedule idle fraction, logged beside loss
-                    row["pp_bubble_fraction"] = float(
-                        jax.device_get(m["pp_bubble_fraction"]))
-                t1 = time.monotonic()
-                sp.span("epoch_fetch", t0, t1, epoch_id)
-                history.append(row)
-                epochs_run = epoch + 1
-                if self.run is not None:
-                    self.run.log_metrics(row, step=epoch)
-                t0 = time.monotonic()
-                sp.span("epoch_report", t1, t0, epoch_id)
+        def dispatch(state, batch, host_step):
+            if self.pp:  # the pipeline step is deterministic: no rng
+                return step(state, *batch)
+            # the loop's host-side step counter: folding the device's into
+            # the rng would be a blocking device_get every step
+            return run_step(state, *batch,
+                            jax.random.fold_in(step_rng, host_step))
 
-                # Callbacks consume this epoch's metrics FIRST, then the
-                # checkpoint saves the post-callback counters/LR — resume =
-                # continuation (ScheduleSuite holds the ordering rules).
-                end_id = sp.open()
-                state, stop = sched.epoch_end(state, row["val_loss"], epoch)
-                if ckpt and (epoch + 1) % cfg.checkpoint_every_epochs == 0:
-                    t_ck = time.monotonic()
-                    ckpt.save(state, host_step,
-                              metadata={"epoch": epoch,
-                                        "callbacks": sched.state_dicts(),
-                                        "metrics": row})
-                    t1 = time.monotonic()
-                    sp.span("ckpt_save", t_ck, t1, end_id,
-                            args=sp.on and {"step": host_step})
-                    if hub is not None:
-                        hub.observe("train.ckpt_write_ms", (t1 - t_ck) * 1e3)
-                if best is not None:
-                    best.maybe_save(state, host_step, row, {"epoch": epoch})
-                t1 = time.monotonic()
-                sp.span("epoch_end", t0, t1, epoch_id, end_id)
-                sp.span("epoch", t_epoch, t1, span=epoch_id,
-                        args=sp.on and {"epoch": epoch,
-                                        "steps": steps_per_epoch})
-                if stop:
-                    break
-        finally:
-            if ckpt:
-                ckpt.close()
-            if best is not None:
-                best.close()
-
-        last = history[-1] if history else {"val_loss": float("nan"),
-                                            "val_accuracy": float("nan")}
-        return LMTrainResult(val_loss=last["val_loss"],
-                             val_accuracy=last["val_accuracy"],
-                             history=history, state=state,
-                             epochs_run=epochs_run)
+        return loop.run_epochs(
+            cfg=cfg, state=state, sched=sched, plan=plan,
+            start_epoch=start_epoch, train_batches=train_batches,
+            val_batches=val_batches, dispatch=dispatch, eval_step=eval_step,
+            ckpt=ckpt, best=best, run=self.run, tracer=tracer,
+            setup_id=setup_id, t_fit=t_fit, row_extra=row_extra)

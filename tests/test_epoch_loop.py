@@ -1,0 +1,164 @@
+"""The seam between the trainers and ``train/loop.py``: every kind of fit runs
+its epochs through the one ``run_epochs``; a checkpoint's metadata and a
+preemption are the same whichever trainer wrote or met them."""
+
+import dataclasses
+import inspect
+import re
+import threading
+
+import numpy as np
+import pytest
+
+from ddw_tpu.checkpoint.ckpt import CheckpointManager, latest_step
+from ddw_tpu.obs.trace import Tracer
+from ddw_tpu.runtime import faults
+from ddw_tpu.runtime.faults import Preempted
+from ddw_tpu.runtime.mesh import MeshSpec, make_mesh
+from ddw_tpu.train import lm_trainer, loop, trainer
+from ddw_tpu.train.lm_trainer import LMTrainer
+from ddw_tpu.train.step import chain_plan
+from ddw_tpu.train.trainer import Trainer
+from ddw_tpu.utils.config import LMCfg, TrainCfg
+
+SPE = 4         # steps an epoch, both trainers
+
+
+def _tokens(n=36, seq=17):
+    starts = np.random.RandomState(0).randint(0, 32, size=(n, 1))
+    return ((starts + np.arange(seq)[None]) % 32).astype(np.int32)
+
+
+def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, **train_kw):
+    """One tiny fit of SPE steps an epoch: ``vision``, or the LM by its
+    step (``lm``, ``lm-zero``, ``lm-fsdp``, ``lm-pp``)."""
+    if kind == "vision":
+        data, model, train = small_cfgs
+        mesh = make_mesh(MeshSpec((("data", 8),)))
+        train = dataclasses.replace(train, **{
+            "checkpoint_dir": "",
+            "batch_size": silver[0].num_records // (8 * SPE), **train_kw})
+        return Trainer(data, model, train, mesh=mesh, run=run,
+                       tracer=tracer).fit(silver[0], silver[1])
+    lm = LMCfg(vocab_size=32, max_len=16, hidden=16, num_heads=2, mlp_dim=32,
+               depth=2 if kind == "lm-pp" else 1, dropout=0.0,
+               dtype="float32")
+    extra = {"lm-zero": {"zero": True}, "lm-fsdp": {"fsdp": True},
+             "lm-pp": {"pipeline_stages": 2, "pipeline_microbatches": 2},
+             "lm": {}}[kind]
+    train = TrainCfg(batch_size=4, epochs=2, warmup_epochs=0, seed=0,
+                     learning_rate=1e-2, num_devices=2, **extra)
+    train = dataclasses.replace(train, **train_kw)
+    if kind == "lm-pp":     # the batch is over 'data' alone: 1 of 2 devices
+        train = dataclasses.replace(train, batch_size=8)
+    # 36 sequences: 4 held out, 32 = SPE batches of 8
+    return LMTrainer(lm, train, run=run, tracer=tracer).fit(
+        _tokens(), val_fraction=0.1)
+
+
+@pytest.fixture()
+def loop_calls(monkeypatch):
+    """The keyword arguments of every ``run_epochs`` call of the test."""
+    calls, run_epochs = [], loop.run_epochs
+
+    def counted(**kw):
+        calls.append(kw)
+        return run_epochs(**kw)
+
+    monkeypatch.setattr(loop, "run_epochs", counted)
+    return calls
+
+
+# the plain steps, chained and not, are the cases of the two tests below
+@pytest.mark.parametrize("kind,k", [("vision", 2), ("lm-zero", 1),
+                                    ("lm-fsdp", 2), ("lm-pp", 1)])
+def test_every_fit_runs_the_one_loop(kind, k, small_cfgs, silver, loop_calls):
+    res = _fit(kind, small_cfgs, silver, steps_per_dispatch=k)
+    (kw,) = loop_calls
+    assert res.epochs_run == 2 and len(res.history) == 2
+    assert list(kw["plan"]) == list(chain_plan(SPE, k))
+    assert all(np.isfinite(r["loss"]) and np.isfinite(r["val_loss"])
+               for r in res.history)
+    # what only one trainer reports rides on the one row
+    assert ("epoch_seconds" in res.history[0]) == (kind == "vision")
+    assert ("pp_bubble_fraction" in res.history[0]) == (kind == "lm-pp")
+
+
+def test_the_loop_lives_in_one_file():
+    """The hooks, the fetch, the span names and the profiler's start are the
+    loop's alone; the LM trainer does not import from its sibling."""
+    sources = {m.__name__: inspect.getsource(m)
+               for m in (trainer, lm_trainer, loop)}
+    for needle in ("maybe_fault(", "preemption_requested(",
+                   "maybe_elastic_restart(", "fetch_metrics_mean(",
+                   '"train_chain"', '"val_data_wait"', '"epoch_fetch"',
+                   '"ckpt_save"', "jax.profiler.start_trace"):
+        assert [n for n, s in sources.items() if needle in s] == [
+            loop.__name__], needle
+    assert not re.search(r"^\s*(from|import) ddw_tpu\.train\.trainer",
+                         sources[lm_trainer.__name__], re.M)
+
+
+class _Metrics:
+    """A tracker run that keeps the rows it is sent."""
+
+    def __init__(self, after_row=lambda: None):
+        self.rows, self.after_row = [], after_row
+
+    def log_params(self, params):
+        pass
+
+    def log_metrics(self, row, step=None):
+        self.rows.append((step, dict(row)))
+        self.after_row()
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm"])
+def test_checkpoint_metadata_is_the_union(kind, small_cfgs, silver, tmp_path,
+                                          loop_calls):
+    ck = str(tmp_path / "ck")
+    run = _Metrics()
+    res = _fit(kind, small_cfgs, silver, run=run, checkpoint_dir=ck,
+               checkpoint_every_epochs=1)
+    assert len(loop_calls) == 1
+    assert latest_step(ck) == 2 * SPE           # saved under the host step
+    meta = CheckpointManager(ck).read_metadata()
+    assert meta["epoch"] == 1 and "plateau" in meta["callbacks"]
+    assert meta["metrics"] == res.history[-1]
+    assert meta["val_loss"] == res.history[-1]["val_loss"]
+    assert meta["val_accuracy"] == res.history[-1]["val_accuracy"]
+    # the report: once an epoch, the row less its number
+    assert [step for step, _ in run.rows] == [0, 1]
+    assert run.rows[-1][1] == {k: v for k, v in res.history[-1].items()
+                               if k != "epoch"}
+
+
+@pytest.mark.parametrize("kind,k", [("vision", 1), ("lm", 1), ("lm", 2)])
+def test_preemption_leaves_at_the_next_chain_boundary(kind, k, small_cfgs,
+                                                      silver, tmp_path,
+                                                      loop_calls):
+    """Asked for while epoch 0 is reported, the stop comes at epoch 1's first
+    chain, before its batch is asked for: a checkpoint under the host step,
+    ``Preempted`` carrying it, and no writer left behind."""
+    ck = str(tmp_path / "ck")
+    tracer = Tracer(capacity=4096)
+    try:
+        with pytest.raises(Preempted) as exc:
+            _fit(kind, small_cfgs, silver, tracer=tracer,
+                 run=_Metrics(after_row=faults.request_preemption),
+                 steps_per_dispatch=k, checkpoint_dir=ck,
+                 checkpoint_every_epochs=2, async_checkpoint=True)
+    finally:
+        faults.reset_preemption()
+    assert exc.value.step == SPE and len(loop_calls) == 1
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("ckpt-writer")]
+    assert latest_step(ck) == SPE
+    meta = CheckpointManager(ck).read_metadata()
+    assert meta["preempted"] is True and meta["epoch"] == 1
+    events = [e for e in tracer.drain() if e["tid"] == "train"]
+    named = lambda name: [e for e in events if e["name"] == name]
+    assert len(named("data_wait")) == len(chain_plan(SPE, k))  # epoch 0's
+    (save,) = named("ckpt_save")
+    assert save["args"]["step"] == SPE
+    assert [e["args"]["epoch"] for e in named("epoch")] == [0]

@@ -134,21 +134,41 @@ def test_on_epoch_hook(small_cfgs, silver, tmp_path):
             train_tbl, val_tbl)
 
 
-@pytest.mark.slow  # ~17s; artifact-presence check (no numeric pin) —
-# the profiler-trace drill moves wholesale to the slow tier
-def test_profiler_trace_writes_files(small_cfgs, silver, tmp_path):
+# vision: ~17s; artifact-presence check (no numeric pin) — the vision
+# trainer's profiler-trace drill rides the slow tier, the LM's (a few
+# seconds) stays in tier-1
+@pytest.mark.parametrize("kind", [
+    pytest.param("vision", marks=pytest.mark.slow), "lm"])
+def test_profiler_trace_writes_files(kind, small_cfgs, silver, tmp_path):
     """TrainCfg.trace_dir (Horovod-Timeline role): the first settled epoch
     runs under jax.profiler, a trace lands on disk, openable in
-    TensorBoard/Perfetto, and the trainer's own span tree beside it."""
+    TensorBoard/Perfetto, and the fit's own span tree beside it — the loop's
+    doing, so whichever trainer was given the field."""
     import os
 
     from ddw_tpu.obs.trace import load_events
 
-    train_tbl, val_tbl, _ = silver
     trace_dir = str(tmp_path / "trace")
-    tr = _mk_trainer(small_cfgs, silver, tmp_path, epochs=2,
-                     trace_dir=trace_dir)
-    tr.fit(train_tbl, val_tbl)
+    if kind == "vision":
+        train_tbl, val_tbl, _ = silver
+        tr = _mk_trainer(small_cfgs, silver, tmp_path, epochs=2,
+                         trace_dir=trace_dir)
+        tr.fit(train_tbl, val_tbl)
+    else:
+        from ddw_tpu.data.prep import write_token_table
+        from ddw_tpu.data.store import TableStore
+        from ddw_tpu.train.lm_trainer import LMTrainer
+        from ddw_tpu.utils.config import LMCfg, TrainCfg
+
+        store = TableStore(str(tmp_path / "tok"))
+        toks = np.random.RandomState(0).randint(0, 32, (40, 9)).astype(np.int32)
+        lm = LMCfg(vocab_size=32, max_len=16, hidden=16, depth=1, num_heads=2,
+                   mlp_dim=32, dropout=0.0, dtype="float32")
+        tr = LMTrainer(lm, TrainCfg(batch_size=4, epochs=2, warmup_epochs=0,
+                                    seed=0, num_devices=2,
+                                    trace_dir=trace_dir))
+        tr.fit_tables(write_token_table(store, "train", toks[:32], shard_size=8),
+                      write_token_table(store, "val", toks[32:], shard_size=8))
     assert tr.tracer is None            # the fit's own, not left on the trainer
     found = [os.path.join(r, f) for r, _, fs in os.walk(trace_dir) for f in fs]
     assert any(f.endswith((".trace.json.gz", ".xplane.pb"))
