@@ -462,6 +462,9 @@ def make_pp_lm_train_step(
         return _fns(state)[1](state, inputs, targets)
 
     stepper.eval_step = eval_step  # type: ignore[attr-defined]
+    # the train half's executables, as a jitted function counts its own
+    stepper._cache_size = lambda: sum(  # type: ignore[attr-defined]
+        fns[0]._cache_size() for fns in _jits.values())
 
     def place_state(state: TrainState) -> TrainState:
         _check_layout(state.params)

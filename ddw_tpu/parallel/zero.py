@@ -245,6 +245,13 @@ def _make_sharded_step_body(model, tx: optax.GradientTransformation,
     return _step
 
 
+def _held(jits: dict) -> int:
+    """The executables the jitted functions of ``jits`` hold, by their own
+    count, summed over the state structures met: what a plain jitted step's
+    ``_cache_size()`` says, and the loop's ``step_variants`` reads."""
+    return sum(fn._cache_size() for fn in jits.values())
+
+
 def _make_sharded_state_step(
     shardings_fn,
     model,
@@ -292,6 +299,7 @@ def _make_sharded_state_step(
 
     stepper.place_state = place_state  # type: ignore[attr-defined]
     stepper.batch_sharding = batch_sh  # type: ignore[attr-defined]
+    stepper._cache_size = lambda: _held(_jits)  # type: ignore[attr-defined]
     return stepper
 
 
@@ -353,6 +361,7 @@ def _make_sharded_state_chain(
         return fn(state, images, labels, rng)
 
     chain.place_state = place_state  # type: ignore[attr-defined]
+    chain._cache_size = lambda: _held(_jits)  # type: ignore[attr-defined]
     chain.batch_sharding = NamedSharding(mesh, P(axis))  # per-step batches
     chain.super_batch_sharding = sup_sh  # type: ignore[attr-defined]
     return chain

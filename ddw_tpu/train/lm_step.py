@@ -24,7 +24,8 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ddw_tpu.train.step import TrainState, cross_entropy_loss
+from ddw_tpu.train.step import (TrainState, cross_entropy_loss,
+                                replicated_placer)
 
 # next-token CE is the same sparse CE (it broadcasts over [B, S, V] vs [B, S])
 lm_loss = cross_entropy_loss
@@ -97,7 +98,10 @@ def make_lm_train_step(
     model's ``expert_axis`` must be one of the step's mesh axes (its all_to_alls
     then ride that axis). Metrics (loss, token accuracy) come back
     world-averaged; for MoE models the Switch load-balance aux loss is added
-    with ``aux_loss_weight`` and reported as ``metrics['aux_loss']``.
+    with ``aux_loss_weight`` and reported as ``metrics['aux_loss']``. It
+    compiles once for each placement of its arguments:
+    ``step.place_state(state)`` before the first call gives the state the
+    placement the step returns it in, and one executable serves.
     """
     tx = _maybe_lora_tx(model, tx)
     axes, moe = _lm_axes(model, data_axis, seq_axis)
@@ -113,6 +117,7 @@ def make_lm_train_step(
     )
     step = jax.jit(smapped, donate_argnums=(0,) if donate else ())
     step.batch_sharding = NamedSharding(mesh, tok_spec)  # type: ignore[attr-defined]
+    step.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
     return step
 
 
@@ -240,6 +245,7 @@ def make_lm_train_chain(
     chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else ())
     chain.batch_sharding = NamedSharding(mesh, tok_spec)  # type: ignore[attr-defined]
     chain.super_batch_sharding = NamedSharding(mesh, sup_spec)  # type: ignore[attr-defined]
+    chain.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
     return chain
 
 

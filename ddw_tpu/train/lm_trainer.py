@@ -406,6 +406,7 @@ class LMTrainer:
             # eval call (same trade the vision Trainer makes).
             eval_step = make_lm_eval_step(self.model, mesh,
                                           seq_axis=self.seq_axis)
+        run_step = chain if chained else step
         t3 = time.monotonic()
         sp.span("model_init", t1, t2, setup_id)
         sp.span("build_step", t2, t3, setup_id)
@@ -436,22 +437,18 @@ class LMTrainer:
                 f"resume=True restored a checkpoint at epoch {start_epoch} "
                 f">= cfg.epochs={cfg.epochs}; the run is already complete — "
                 f"returning the checkpointed metrics, no training performed")
-            if self.pp or self.sharded:
-                # Same placement contract as every normal completion:
-                # callers that keep training or serving from result.state
-                # must not see placement depend on which path returned.
-                state = step.place_state(state)
+            # Same placement contract as every normal completion: callers
+            # that keep training or serving from result.state must not see
+            # placement depend on which path returned.
+            state = loop.place_state(run_step, state, sp, setup_id)
             return TrainResult(val_loss=saved["val_loss"],
                                val_accuracy=saved["val_accuracy"],
                                history=[saved], state=state,
                                epochs_run=start_epoch)
 
-        if self.pp or self.sharded:
-            # Placement AFTER restore: the checkpoint template is the
-            # unplaced pytree; placing shards stage leaves over 'pipe' (PP)
-            # or params/moments over the data axis (ZeRO/FSDP) — a no-op on
-            # a restored already-sharded state.
-            state = step.place_state(state)
+        # Placement AFTER restore: the checkpoint template is the unplaced
+        # pytree; a no-op on a restored already-sharded state.
+        state = loop.place_state(run_step, state, sp, setup_id)
 
         sched = ScheduleSuite.build(cfg, dp, restored_meta)
         loop.log_fit_params(self.run, {"mesh": dict(mesh.shape),
@@ -460,7 +457,6 @@ class LMTrainer:
                             train=cfg, lm=self.lm_cfg)
 
         t0 = time.monotonic()
-        run_step = chain if chained else step
         train_batches, val_batches = make_providers(
             start_epoch, run_step, plan, chained, tracer)
         sp.span("build_loaders", t0, time.monotonic(), setup_id)
@@ -469,7 +465,7 @@ class LMTrainer:
 
         def dispatch(state, batch, host_step):
             if self.pp:  # the pipeline step is deterministic: no rng
-                return step(state, *batch)
+                return run_step(state, *batch)
             # the loop's host-side step counter: folding the device's into
             # the rng would be a blocking device_get every step
             return run_step(state, *batch,
@@ -478,6 +474,7 @@ class LMTrainer:
         return loop.run_epochs(
             cfg=cfg, state=state, sched=sched, plan=plan,
             start_epoch=start_epoch, train_batches=train_batches,
-            val_batches=val_batches, dispatch=dispatch, eval_step=eval_step,
-            ckpt=ckpt, best=best, run=self.run, tracer=tracer,
-            setup_id=setup_id, t_fit=t_fit, row_extra=row_extra)
+            val_batches=val_batches, dispatch=dispatch, run_step=run_step,
+            eval_step=eval_step, ckpt=ckpt, best=best, run=self.run,
+            tracer=tracer, setup_id=setup_id, t_fit=t_fit,
+            row_extra=row_extra)

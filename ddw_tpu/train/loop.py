@@ -126,6 +126,27 @@ def restore(ckpt, state, steps_per_epoch: int):
             ckpt.read_metadata(at_step))
 
 
+def place_state(step, state, lane, setup_id):
+    """``state`` as ``step`` returns it (``step.place_state``), before the
+    step sees it: every fit's, whatever its layout, after restore. The
+    ``place_state`` child of ``fit_setup`` says how many leaves were not yet
+    where the step puts them, and their bytes. A step that is not one of
+    this package's (a test's double) and says nothing of its placement gets
+    the state as it is, and ``step_variants`` will show what that costs."""
+    t0 = time.monotonic()
+    before = lane.on and [
+        (x.sharding, x.committed) if isinstance(x, jax.Array) else None
+        for x in jax.tree.leaves(state)]
+    state = getattr(step, "place_state", lambda state: state)(state)
+    args = None
+    if lane.on:
+        moved = [x for x, was in zip(jax.tree.leaves(state), before)
+                 if was != (getattr(x, "sharding", None), True)]
+        args = {"leaves": len(moved), "bytes": sum(x.nbytes for x in moved)}
+    lane.span("place_state", t0, time.monotonic(), setup_id, args=args)
+    return state
+
+
 def log_fit_params(run, sizes: dict, **cfgs) -> None:
     """``<section>.<field>`` of each config, then the fit's sizes."""
     if run is not None:
@@ -137,9 +158,9 @@ def log_fit_params(run, sizes: dict, **cfgs) -> None:
 
 # -- the loop -----------------------------------------------------------------
 def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
-               train_batches, val_batches, dispatch, eval_step, ckpt, best,
-               run, tracer, setup_id, t_fit: float, timed_row=None,
-               row_extra=None, on_epoch=None) -> TrainResult:
+               train_batches, val_batches, dispatch, run_step, eval_step,
+               ckpt, best, run, tracer, setup_id, t_fit: float,
+               timed_row=None, row_extra=None, on_epoch=None) -> TrainResult:
     """Epochs ``start_epoch .. cfg.epochs`` of one fit, and all that goes with
     them: the chain loop with the fault, elastic and preemption hooks,
     validation, the fetches, the row and its report, the schedule's epoch
@@ -152,8 +173,14 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     ``val_batches()`` the epoch's validation batches (a loader it builds is
     built on its first ``next``, inside the first ``val_data_wait``);
     ``dispatch(state, batch, host_step) -> (state, metrics)`` is the
-    trainer's closure over its step or chain and its rng rule;
+    trainer's closure over its step or chain and its rng rule, and
+    ``run_step`` that step or chain, of which the loop asks only how many
+    executables it holds at each epoch's end (``step_variants``);
     ``eval_step(eval_state, *batch)`` gives ``loss`` and ``accuracy``.
+    ``state`` comes placed as ``run_step`` returns it (:func:`place_state`)
+    and the loop keeps it so — what it writes into the state (``set_lr``)
+    takes the placement of the leaf it replaces — so one executable of the
+    step serves the whole fit.
 
     A row is ``epoch``, the four means and ``lr``, then ``row_extra`` (a
     dict), then ``timed_row(train_seconds)``. A trainer that gives
@@ -169,6 +196,7 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     sp = span_lane(tracer, "train", "train")
     steps_per_epoch = sum(plan)
     chained = any(k > 1 for k in plan)
+    step_variants = getattr(run_step, "_cache_size", None)
     # telemetry plane: a Run wrapped by obs.telemetry.tee_run exposes its
     # hub — chain dispatch and checkpoint-write latencies become windowed
     # dist series beside the serving fleet's (docs/observability.md)
@@ -364,8 +392,12 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                 best.maybe_save(state, host_step, row, {"epoch": epoch})
             t1 = time.monotonic()
             sp.span("epoch_end", t_cb, t1, epoch_id, end_id)
+            # step_variants: the executables jit holds of the step; more
+            # than one means something took the state out of its placement
             sp.span("epoch", t_epoch, t1, span=epoch_id,
-                    args=sp.on and {"epoch": epoch, "steps": steps_per_epoch})
+                    args=sp.on and {"epoch": epoch, "steps": steps_per_epoch,
+                                    "step_variants": step_variants
+                                    and step_variants()})
             if epoch == profile_epoch:
                 # the spans of everything so far, the profiled epoch whole,
                 # in the form Perfetto loads beside the profile

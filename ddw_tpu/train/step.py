@@ -34,6 +34,7 @@ from typing import Any, Callable, NamedTuple
 import flax.struct
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -227,8 +228,21 @@ def get_lr(state: TrainState) -> float:
     return float(os_.hyperparams["learning_rate"])
 
 
+def _rate_like(old, lr: float):
+    """``lr`` as the float32 scalar that takes ``old``'s place in the state:
+    where ``old`` is committed to a sharding, so is the new leaf, built from
+    this process's own value (no transfer between devices, no cross-host
+    check), so the step's arguments keep their placement."""
+    if isinstance(old, jax.Array) and old.committed:
+        return jax.make_array_from_callback((), old.sharding,
+                                            lambda _: np.float32(lr))
+    return jnp.asarray(lr, jnp.float32)
+
+
 def set_lr(state: TrainState, lr: float) -> TrainState:
-    """Set the dynamic LR (callback suite writes; no recompilation)."""
+    """Set the dynamic LR (callback suite writes; no recompilation: the new
+    leaf has the sharding and committedness of the one it replaces, so a
+    placed state stays as its step returns it)."""
     os_ = state.opt_state
     ema = None
     if isinstance(os_, EmaState):
@@ -236,14 +250,14 @@ def set_lr(state: TrainState, lr: float) -> TrainState:
     if isinstance(os_, optax.MultiTransformState):
         inner = os_.inner_states["train"]
         new_hp = dict(inner.inner_state.hyperparams)
-        new_hp["learning_rate"] = jnp.asarray(lr, jnp.float32)
+        new_hp["learning_rate"] = _rate_like(new_hp["learning_rate"], lr)
         new_inner_state = inner.inner_state._replace(hyperparams=new_hp)
         new_states = dict(os_.inner_states)
         new_states["train"] = inner._replace(inner_state=new_inner_state)
         new_os = os_._replace(inner_states=new_states)
     else:
         new_hp = dict(os_.hyperparams)
-        new_hp["learning_rate"] = jnp.asarray(lr, jnp.float32)
+        new_hp["learning_rate"] = _rate_like(new_hp["learning_rate"], lr)
         new_os = os_._replace(hyperparams=new_hp)
     if ema is not None:
         new_os = ema._replace(inner=new_os)
@@ -370,6 +384,25 @@ def _dp_step_body(model, tx: optax.GradientTransformation, axis_name: str,
     return apply_gradients(state, tx, grads, new_bs), metrics
 
 
+def replicated_placer(mesh: Mesh, donate: bool = True) -> Callable:
+    """The ``place_state`` of the plain data-parallel steps and chains (here
+    and in :mod:`ddw_tpu.train.lm_step`): every leaf committed to
+    ``NamedSharding(mesh, P())``, which is how those steps return their
+    state. ``jit`` keys an executable on the placement of its arguments, so
+    a state placed before the first call gives that call the signature of
+    every later one. Leaf by leaf, and a leaf that is already on a device of
+    the mesh is shared there, not copied; with ``donate`` (the step's own)
+    the unplaced state is given up as the step's first call would have
+    taken it."""
+    repl = replicated_sharding(mesh)
+
+    def place_state(state: TrainState) -> TrainState:
+        return jax.tree.map(
+            lambda x: jax.device_put(x, repl, donate=donate), state)
+
+    return place_state
+
+
 def make_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -384,7 +417,9 @@ def make_train_step(
     labels are globally-sharded arrays split along ``axis_name`` and metrics are
     already world-averaged (loss, accuracy). ``grad_accum_steps > 1`` runs each
     device's batch as that many sequential microbatches (see
-    :func:`accumulate_grads`).
+    :func:`accumulate_grads`). It compiles once for each placement of its
+    arguments: ``step.place_state(state)`` before the first call gives the
+    state the placement the step returns it in, and one executable serves.
     """
     _step = functools.partial(_dp_step_body, model, tx, axis_name,
                               grad_accum_steps)
@@ -398,7 +433,9 @@ def make_train_step(
         out_specs=(repl, repl),
         check_vma=False,
     )
-    return jax.jit(smapped, donate_argnums=(0,) if donate else ())
+    step = jax.jit(smapped, donate_argnums=(0,) if donate else ())
+    step.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
+    return step
 
 
 def make_train_chain(
@@ -446,7 +483,9 @@ def make_train_chain(
         out_specs=(repl, repl),
         check_vma=False,
     )
-    return jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else ())
+    chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else ())
+    chain.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
+    return chain
 
 
 def chain_plan(steps_per_epoch: int, k: int) -> tuple[int, ...]:

@@ -226,10 +226,7 @@ class Trainer:
             state, start_epoch, restored_meta = loop.restore(
                 ckpt, state, steps_per_epoch)
             sp.span("restore", t1, time.monotonic(), setup_id)
-        if sharded_state:
-            # leaves onto their data-axis shards (no-op on a restored
-            # already-sharded state)
-            state = train_step.place_state(state)
+        state = loop.place_state(run_step, state, sp, setup_id)
 
         # warmup/cosine/plateau/early + counter restore, shared with the LM
         # trainer (train/schedule.py holds the ordering/resume rules)
@@ -276,8 +273,8 @@ class Trainer:
                 val_batches=val_batches,
                 dispatch=lambda state, batch, host_step: run_step(
                     state, *batch, step_rng),
-                eval_step=eval_step, ckpt=ckpt, best=best, run=self.run,
-                tracer=tracer, setup_id=setup_id, t_fit=t_fit,
+                run_step=run_step, eval_step=eval_step, ckpt=ckpt, best=best,
+                run=self.run, tracer=tracer, setup_id=setup_id, t_fit=t_fit,
                 # epoch_seconds is the training part of the epoch
                 timed_row=lambda s: {"epoch_seconds": s,
                                      "images_per_sec": items / s},

@@ -1,6 +1,7 @@
 """The seam between the trainers and ``train/loop.py``: every kind of fit runs
 its epochs through the one ``run_epochs``; a checkpoint's metadata and a
-preemption are the same whichever trainer wrote or met them."""
+preemption are the same whichever trainer wrote or met them; and the step a
+fit runs is one executable from its first call to its last."""
 
 import dataclasses
 import inspect
@@ -12,6 +13,7 @@ import pytest
 
 from ddw_tpu.checkpoint.ckpt import CheckpointManager, latest_step
 from ddw_tpu.obs.trace import Tracer
+from ddw_tpu.parallel import pipeline, zero
 from ddw_tpu.runtime import faults
 from ddw_tpu.runtime.faults import Preempted
 from ddw_tpu.runtime.mesh import MeshSpec, make_mesh
@@ -29,7 +31,8 @@ def _tokens(n=36, seq=17):
     return ((starts + np.arange(seq)[None]) % 32).astype(np.int32)
 
 
-def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, **train_kw):
+def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
+         **train_kw):
     """One tiny fit of SPE steps an epoch: ``vision``, or the LM by its
     step (``lm``, ``lm-zero``, ``lm-fsdp``, ``lm-pp``)."""
     if kind == "vision":
@@ -39,7 +42,7 @@ def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, **train_kw):
             "checkpoint_dir": "",
             "batch_size": silver[0].num_records // (8 * SPE), **train_kw})
         return Trainer(data, model, train, mesh=mesh, run=run,
-                       tracer=tracer).fit(silver[0], silver[1])
+                       tracer=tracer).fit(silver[0], silver[1], resume=resume)
     lm = LMCfg(vocab_size=32, max_len=16, hidden=16, num_heads=2, mlp_dim=32,
                depth=2 if kind == "lm-pp" else 1, dropout=0.0,
                dtype="float32")
@@ -53,7 +56,7 @@ def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, **train_kw):
         train = dataclasses.replace(train, batch_size=8)
     # 36 sequences: 4 held out, 32 = SPE batches of 8
     return LMTrainer(lm, train, run=run, tracer=tracer).fit(
-        _tokens(), val_fraction=0.1)
+        _tokens(), val_fraction=0.1, resume=resume)
 
 
 @pytest.fixture()
@@ -162,3 +165,95 @@ def test_preemption_leaves_at_the_next_chain_boundary(kind, k, small_cfgs,
     (save,) = named("ckpt_save")
     assert save["args"]["step"] == SPE
     assert [e["args"]["epoch"] for e in named("epoch")] == [0]
+
+
+# -- one executable of the step a fit -----------------------------------------
+STEP_FACTORIES = [(trainer, "make_train_step"), (trainer, "make_train_chain"),
+                  (lm_trainer, "make_lm_train_step"),
+                  (lm_trainer, "make_lm_train_chain"),
+                  (zero, "make_zero_train_step"),
+                  (zero, "make_zero_train_chain"),
+                  (zero, "make_fsdp_train_step"),
+                  (zero, "make_fsdp_train_chain"),
+                  (pipeline, "make_pp_lm_train_step")]
+
+
+class _Seen:
+    """Stands where a step stands, as the benchmark's probe does
+    (``benchmark/harness/step_probe.py``): calls and attributes go through."""
+
+    def __init__(self, inner):
+        self._inner, self.calls = inner, 0
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def __call__(self, *args):
+        self.calls += 1
+        return self._inner(*args)
+
+
+@pytest.fixture()
+def steps_made(monkeypatch):
+    """Every step and chain the trainers' factories make in the test."""
+    made = []
+    for module, name in STEP_FACTORIES:
+        def wrapped(*a, _factory=getattr(module, name), **kw):
+            made.append(_Seen(_factory(*a, **kw)))
+            return made[-1]
+
+        monkeypatch.setattr(module, name, wrapped)
+    return made
+
+
+def _one_executable(steps_made, tracer, epochs):
+    """The step that ran holds one executable, every ``epoch`` span says so,
+    and the fit placed its state once, inside ``fit_setup``."""
+    (run_step,) = [s for s in steps_made if s.calls]
+    assert run_step._cache_size() == 1
+    events = [e for e in tracer.drain() if e["tid"] == "train"]
+    named = lambda name: [e for e in events if e["name"] == name]
+    assert [(e["args"]["epoch"], e["args"]["step_variants"])
+            for e in named("epoch")] == [(i, 1) for i in epochs]
+    (placed,), (setup,) = named("place_state"), named("fit_setup")
+    assert placed["parent"] == setup["span"]
+    return placed["args"]
+
+
+@pytest.mark.parametrize("kind,k", [("vision", 1), ("vision", 2), ("lm", 1),
+                                    ("lm", 2), ("lm-zero", 1),
+                                    ("lm-fsdp", 2), ("lm-pp", 1)])
+def test_a_fit_builds_one_executable_of_its_step(kind, k, small_cfgs, silver,
+                                                 steps_made):
+    tracer = Tracer(capacity=4096)
+    _fit(kind, small_cfgs, silver, tracer=tracer, steps_per_dispatch=k)
+    placed = _one_executable(steps_made, tracer, [0, 1])
+    # a fresh state: every leaf had to be placed
+    assert placed["leaves"] > 0 and placed["bytes"] > 0
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm"])
+def test_the_schedule_leaves_the_placement_alone(kind, small_cfgs, silver,
+                                                 steps_made):
+    """More than one device: the warmup writes the rate before every batch of
+    epoch 0, the plateau regime takes over, and (the rate being 0, no epoch
+    improves on the one before) a cut comes at epoch 1's end."""
+    tracer = Tracer(capacity=4096)
+    res = _fit(kind, small_cfgs, silver, tracer=tracer, epochs=3,
+               warmup_epochs=1, plateau_patience=1, learning_rate=0.0)
+    assert [r["lr"] for r in res.history] == [0.0, 0.0,
+                                              pytest.approx(1e-7)]
+    _one_executable(steps_made, tracer, [0, 1, 2])
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm", "lm-zero"])
+def test_a_resumed_fit_builds_one_executable(kind, small_cfgs, silver,
+                                             steps_made, tmp_path):
+    kw = {"checkpoint_dir": str(tmp_path / "ck"), "checkpoint_every_epochs": 1}
+    _fit(kind, small_cfgs, silver, epochs=1, **kw)
+    del steps_made[:]
+    tracer = Tracer(capacity=4096)
+    res = _fit(kind, small_cfgs, silver, tracer=tracer, resume=True, epochs=3,
+               **kw)
+    assert [r["epoch"] for r in res.history] == [1, 2]
+    _one_executable(steps_made, tracer, [1, 2])

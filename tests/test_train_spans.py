@@ -21,8 +21,9 @@ from ddw_tpu.train.trainer import Trainer
 from ddw_tpu.utils.config import LMCfg, TrainCfg
 
 EPOCHS = 2
-SETUP = {"vision": {"model_init", "build_step", "build_loaders"},
-         "lm": {"optimizer_init", "model_init", "build_step",
+SETUP = {"vision": {"model_init", "build_step", "place_state",
+                    "build_loaders"},
+         "lm": {"optimizer_init", "model_init", "build_step", "place_state",
                 "build_loaders"}}
 # spans every epoch has, by trainer
 IN_EPOCH = {"vision": {"train_chain", "train_fetch", "validation",
