@@ -15,7 +15,11 @@ module runs three ways off one definition:
   ``mlp/{fc1,fc2}``) match :data:`ddw_tpu.parallel.sharding.LM_TP_RULES`, so the
   GSPMD path shards heads/MLP over the ``model`` axis with no model changes.
 
-Pre-LN blocks, learned positional embeddings, weight-untied vocab head.
+Pre-LN blocks, learned positional embeddings, weight-untied vocab head. What a
+layer is made of beyond that — RMSNorm, a gated MLP, heads wider than
+``hidden / num_heads``, normed queries and keys, multi-axis RoPE, attention
+over keys an indexer chose, routed experts on a chip's share — is one
+:class:`ddw_tpu.utils.config.LayerSpec` handed from the model to its blocks.
 """
 
 from __future__ import annotations
@@ -30,6 +34,19 @@ from jax.lax import axis_size
 
 from ddw_tpu.ops.flash_attention import flash_mha_seq_major
 from ddw_tpu.parallel.ring_attention import ring_attention
+from ddw_tpu.utils.config import LayerSpec
+
+
+def layer_norm(spec: LayerSpec, name: str | None = None):
+    """The spec's norm, computed and returned in float32."""
+    if spec.norm == "layernorm":
+        return nn.LayerNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                            name=name)
+    if spec.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                          name=name)
+    raise ValueError(f"unknown norm {spec.norm!r}; use 'layernorm' or "
+                     f"'rmsnorm'")
 
 
 class CausalSelfAttention(nn.Module):
@@ -68,14 +85,16 @@ class CausalSelfAttention(nn.Module):
     lora_rank: int = 0       # >0: rank-r adapters on lora_targets projections
     lora_alpha: float = 16.0
     lora_targets: tuple[str, ...] = ("query", "value")
+    layer: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x, positions=None, block_tables=None, start_pos=None,
                  adapters=None):
         from ddw_tpu.models.lora import maybe_lora_dense, row_lora_delta
 
+        spec = self.layer
         b, s, d = x.shape
-        head_dim = d // self.num_heads
+        head_dim = spec.head_dim or d // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
         if self.num_heads % kv_heads:
             raise ValueError(f"num_heads {self.num_heads} not divisible by "
@@ -85,7 +104,8 @@ class CausalSelfAttention(nn.Module):
         def dense(name, heads=self.num_heads):
             return maybe_lora_dense((heads, head_dim), name,
                                     rank=self.lora_rank, alpha=self.lora_alpha,
-                                    targets=self.lora_targets, dtype=self.dtype)
+                                    targets=self.lora_targets, dtype=self.dtype,
+                                    use_bias=spec.bias)
 
         def with_delta(name, y, x_in, cn=1):
             # hot-swapped per-row adapter delta (serving path); the delta is
@@ -99,14 +119,37 @@ class CausalSelfAttention(nn.Module):
         q = with_delta("query", dense("query")(x), x)         # [B, S, H, hd]
         k = with_delta("key", dense("key", kv_heads)(x), x)   # [B, S, KV, hd]
         v = with_delta("value", dense("value", kv_heads)(x), x)
+        if spec.qk_norm:
+            # RMSNorm over each head's own dims, one gain shared by the heads
+            q = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                           name="k_norm")(k)
         if positions is not None:
             # RoPE: rotate q/k by ABSOLUTE position before any cache write or
             # ring hop — scores then depend only on relative distance, so the
             # cached/ring-shipped K needs no further position plumbing.
             from ddw_tpu.ops.rope import apply_rope
 
-            q = apply_rope(q, positions, seq_axis=1)
-            k = apply_rope(k, positions, seq_axis=1)
+            rope_at = positions
+            if spec.mrope_section:
+                # token rows: the position components (temporal, height,
+                # width) of a text token are all its index
+                rope_at = jnp.broadcast_to(
+                    positions, (len(spec.mrope_section), *positions.shape))
+            rope_kw = dict(seq_axis=1, theta=spec.rope_theta,
+                           sections=spec.mrope_section)
+            q = apply_rope(q, rope_at, **rope_kw).astype(self.dtype)
+            k = apply_rope(k, rope_at, **rope_kw).astype(self.dtype)
+        if spec.attention not in ("full", "indexed"):
+            raise ValueError(f"unknown attention {spec.attention!r}; use "
+                             f"'full' or 'indexed'")
+        if spec.attention == "indexed" and (
+                self.decode or self.seq_axis is not None or positions is None):
+            raise NotImplementedError(
+                "indexed attention trains on one device's whole sequence "
+                "with RoPE; a cache for the indexer's keys (serving) and a "
+                "ring over its scores are not written (ROADMAP M8)")
 
         if self.decode:
             # KV cache: accepts S tokens per call (S>1 = batched prefill, S=1 =
@@ -261,6 +304,38 @@ class CausalSelfAttention(nn.Module):
                 if self.slot_decode:
                     overflow = overflow[:, None, None, None]
             out = jnp.where(overflow, jnp.nan, out).astype(x.dtype)
+        elif spec.attention == "indexed":
+            from ddw_tpu.ops.indexed_attention import indexed_attention
+            from ddw_tpu.ops.rope import apply_rope
+
+            # the indexer reads the normed hidden state as a constant: its
+            # three matrices learn from the KL term alone. Float32 at full
+            # precision, scores included: they decide which keys are seen.
+            with jax.named_scope("indexer"):
+                seen = lax.stop_gradient(x).astype(jnp.float32)
+                proj = dict(use_bias=False, dtype=jnp.float32,
+                            precision=lax.Precision.HIGHEST)
+                qi = nn.DenseGeneral((spec.index_heads, spec.index_head_dim),
+                                     name="index_q", **proj)(seen)
+                ki = nn.LayerNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                                  name="index_k_norm")(
+                    nn.Dense(spec.index_head_dim, name="index_k",
+                             **proj)(seen))
+                wi = nn.Dense(spec.index_heads, name="index_w", **proj)(seen)
+                qi = apply_rope(qi, positions, seq_axis=1,
+                                theta=spec.rope_theta)
+                ki = apply_rope(ki[:, :, None], positions, seq_axis=1,
+                                theta=spec.rope_theta)[:, :, 0]
+            out, kl, chosen, choice = indexed_attention(
+                q, k, v, qi, ki, wi,
+                topk=spec.index_topk, tile=spec.index_tile)
+            self.sow("intermediates", "indexer_kl", jnp.mean(kl))
+            self.sow("intermediates", "keys_per_query",
+                     jnp.mean(chosen.astype(jnp.float32)))
+            # which keys each query saw: for a reader that asks (the
+            # benchmark's reference follows the same choice); a step that
+            # does not read it never builds it
+            self.sow("intermediates", "key_choice", choice)
         else:
             if groups > 1:
                 # broadcast KV heads to the full head count: the flash/ring
@@ -285,7 +360,7 @@ class CausalSelfAttention(nn.Module):
             maybe_lora_dense(d, "out", rank=self.lora_rank,
                              alpha=self.lora_alpha,
                              targets=self.lora_targets, dtype=self.dtype,
-                             contract_ndim=2)(out),
+                             contract_ndim=2, use_bias=spec.bias)(out),
             out, cn=2)
 
 
@@ -309,11 +384,13 @@ class DecoderBlock(nn.Module):
     paged_decode: bool = False
     kv_cache_blocks: int = 0
     kv_block_size: int = 0
+    layer: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x, train: bool, positions=None, block_tables=None,
                  start_pos=None, adapters=None):
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
+        spec = self.layer
+        h = layer_norm(spec)(x)
         h = CausalSelfAttention(self.num_heads, self.dtype, self.seq_axis,
                                 self.decode, self.max_len,
                                 slot_decode=self.slot_decode,
@@ -324,14 +401,27 @@ class DecoderBlock(nn.Module):
                                 paged_decode=self.paged_decode,
                                 kv_cache_blocks=self.kv_cache_blocks,
                                 kv_block_size=self.kv_block_size,
+                                layer=spec,
                                 name="attn")(h, positions=positions,
                                              block_tables=block_tables,
                                              start_pos=start_pos,
                                              adapters=adapters)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         x = x + h
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
-        if self.num_experts:
+        h = layer_norm(spec)(x)
+        if spec.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown mlp {spec.mlp!r}; use 'gelu' or "
+                             f"'swiglu'")
+        if self.num_experts and spec.experts_per_token:
+            from ddw_tpu.models.moe import RoutedExperts
+
+            h = RoutedExperts(self.num_experts, self.mlp_dim,
+                              k=spec.experts_per_token,
+                              router_width=spec.router_width,
+                              offset=spec.expert_offset,
+                              normalise=spec.norm_topk, act=spec.mlp,
+                              dtype=self.dtype, name="moe")(h)
+        elif self.num_experts:
             from ddw_tpu.models.moe import MoEMlp
 
             h = MoEMlp(self.num_experts, self.mlp_dim,
@@ -347,15 +437,21 @@ class DecoderBlock(nn.Module):
                 y = maybe_lora_dense(feats, name, rank=self.lora_rank,
                                      alpha=self.lora_alpha,
                                      targets=self.lora_targets,
-                                     dtype=self.dtype)(inp)
+                                     dtype=self.dtype,
+                                     use_bias=spec.bias)(inp)
                 ab = (adapters or {}).get(name)
                 if ab is not None:
                     y = y + row_lora_delta(inp, ab[0], ab[1]).astype(y.dtype)
                 return y
 
-            h = mlp_dense(self.mlp_dim, "fc1", h)
-            h = nn.gelu(h)
-            h = mlp_dense(d, "fc2", h)
+            if spec.mlp == "swiglu":
+                h = mlp_dense(d, "down", nn.silu(
+                    mlp_dense(self.mlp_dim, "gate", h))
+                    * mlp_dense(self.mlp_dim, "up", h))
+            else:
+                h = mlp_dense(self.mlp_dim, "fc1", h)
+                h = nn.gelu(h)
+                h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         return x + h
 
@@ -408,6 +504,7 @@ class TransformerLM(nn.Module):
                              # block in backward) | "dots" (save matmul
                              # outputs, recompute elementwise). Ignored in
                              # decode mode (no backward there).
+    layer: LayerSpec = LayerSpec()  # what every block is made of
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, block_tables=None,
@@ -426,11 +523,14 @@ class TransformerLM(nn.Module):
         if self.pos_encoding not in ("learned", "rope"):
             raise ValueError(f"unknown pos_encoding {self.pos_encoding!r}; "
                              f"use 'learned' or 'rope'")
-        if self.pos_encoding == "rope" and (self.hidden // self.num_heads) % 2:
+        if self.pos_encoding == "rope" and (
+                self.layer.head_dim or self.hidden // self.num_heads) % 2:
             raise ValueError("RoPE needs an even head_dim")
         b, s_local = tokens.shape
         x = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
                      name="tok_embed")(tokens)
+        if self.layer.embed_scale != 1.0:
+            x = x * self.layer.embed_scale
         if self.pos_encoding == "learned":
             pos_table = self.param("pos_embed", nn.initializers.normal(0.02),
                                    (self.max_len, self.hidden), jnp.float32)
@@ -501,7 +601,14 @@ class TransformerLM(nn.Module):
             # live activations for ~1/3 more FLOPs ('full' keeps nothing;
             # 'dots' keeps matmul outputs, recomputing only elementwise ops).
             # The decode path never differentiates, so it stays un-wrapped.
-            policy = (jax.checkpoint_policies.nothing_saveable
+            # "full" keeps two things of a layer that chooses its keys
+            # (ops/indexed_attention.py names them): the choice, one byte a
+            # pair, and what the attention under it gave, so that neither is
+            # made a second time; and of a layer that routes (models/moe.py)
+            # the experts' first products, whose time follows the routing;
+            # nothing where no layer chooses or routes
+            policy = (jax.checkpoint_policies.save_only_these_names(
+                          "key_mask", "attention_out", "expert_hidden")
                       if self.remat == "full"
                       else jax.checkpoint_policies.checkpoint_dots)
             Block = nn.remat(DecoderBlock, static_argnums=(2,), policy=policy)
@@ -534,11 +641,13 @@ class TransformerLM(nn.Module):
                       paged_decode=self.paged_decode,
                       kv_cache_blocks=self.kv_cache_blocks,
                       kv_block_size=self.kv_block_size,
+                      layer=self.layer,
                       name=f"backbone_block{i}")(x, train, positions,
                                                  **blk_kw)
-        x = nn.LayerNorm(dtype=jnp.float32)(x)
+        x = layer_norm(self.layer)(x)
         # vocab head in f32: logits feed a softmax CE, keep full precision
-        return nn.Dense(self.vocab_size, dtype=jnp.float32, name="head")(x)
+        return nn.Dense(self.vocab_size, use_bias=self.layer.bias,
+                        dtype=jnp.float32, name="head")(x)
 
     @staticmethod
     def frozen_prefixes(freeze_base: bool) -> tuple[str, ...]:
@@ -560,7 +669,8 @@ def build_lm(cfg, seq_axis: str | None = None,
         lora_alpha=getattr(cfg, "lora_alpha", 16.0),
         lora_targets=tuple(getattr(cfg, "lora_targets", ("query", "value"))),
         pos_encoding=getattr(cfg, "pos_encoding", "learned"),
-        remat=getattr(cfg, "remat", "none"))
+        remat=getattr(cfg, "remat", "none"),
+        layer=getattr(cfg, "layer", LayerSpec()))
 
 
 def init_cache(decode_model: TransformerLM, batch: int):

@@ -124,16 +124,18 @@ def validate_lora_targets(targets: Sequence[str],
 
 
 def maybe_lora_dense(features, name: str, *, rank: int, alpha: float,
-                     targets: Sequence[str], dtype, contract_ndim: int = 1):
+                     targets: Sequence[str], dtype, contract_ndim: int = 1,
+                     use_bias: bool = True):
     """The one dispatch point between a plain projection and its LoRA
     version: returns ``LoRADenseGeneral`` when ``name`` is targeted, else the
     equivalent ``nn.DenseGeneral`` — identical param paths either way, so the
     checkpoint format does not fork on the flag."""
     if rank and name in tuple(targets):
         return LoRADenseGeneral(features, rank=rank, alpha=alpha, dtype=dtype,
-                                contract_ndim=contract_ndim, name=name)
+                                contract_ndim=contract_ndim,
+                                use_bias=use_bias, name=name)
     return nn.DenseGeneral(features, axis=tuple(range(-contract_ndim, 0)),
-                           dtype=dtype, name=name)
+                           use_bias=use_bias, dtype=dtype, name=name)
 
 
 def lora_mask(params, extra_trainable: Sequence[str] = ("head",)):

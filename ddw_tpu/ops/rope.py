@@ -37,23 +37,47 @@ def rope_angles(positions: jnp.ndarray, head_dim: int,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def mrope_angles(positions: jnp.ndarray, head_dim: int, theta: float,
+                 sections: tuple[int, ...]) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Multi-axis RoPE: ``positions [C, ..., S]`` holds one position a
+    component (temporal, height, width) and ``sections`` says how many of the
+    ``hd/2`` frequency pairs, in order, each component turns. Every pair keeps
+    the frequency plain RoPE gives it, so equal components are plain RoPE."""
+    if sum(sections) != head_dim // 2 or len(sections) != positions.shape[0]:
+        raise ValueError(f"sections {sections} must split the {head_dim // 2} "
+                         f"frequency pairs over {positions.shape[0]} "
+                         f"position components")
+    cos, sin = rope_angles(positions, head_dim, theta)    # [C, ..., S, hd/2]
+    component = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
+                           total_repeat_length=head_dim // 2)
+    pick = lambda t: jnp.take_along_axis(                 # noqa: E731
+        t, component.reshape((1,) * (t.ndim - 1) + (-1,)), axis=0)[0]
+    return pick(cos), pick(sin)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, seq_axis: int = -2,
-               theta: float = 10000.0) -> jnp.ndarray:
+               theta: float = 10000.0,
+               sections: tuple[int, ...] = ()) -> jnp.ndarray:
     """Rotate ``x`` by its positions. The last axis is the head dim;
     ``seq_axis`` is where S lives (``-2`` for ``[B, H, S, hd]``, ``1`` for
     the pre-transpose ``[B, S, H, hd]`` projection layout). ``positions`` is
     ``[S]`` (shared across the batch) or ``[B, S]`` (per-row positions — the
-    serving slot pool decodes rows at independent depths). Returns the same
-    dtype as ``x``."""
+    serving slot pool decodes rows at independent depths); with ``sections``
+    (:func:`mrope_angles`) it carries a leading axis of position components.
+    Returns the same dtype as ``x``."""
     hd = x.shape[-1]
     axis = seq_axis % x.ndim
     if axis == x.ndim - 1:
         raise ValueError("seq_axis cannot be the head dim")
     s = x.shape[axis]
-    if positions.shape not in ((s,), (x.shape[0], s)):
+    if positions.shape[bool(sections):] not in ((s,), (x.shape[0], s)):
         raise ValueError(f"positions {positions.shape} must match seq dim "
                          f"{s} (axis {seq_axis}) or be [batch, {s}]")
-    cos, sin = rope_angles(positions, hd, theta)
+    if sections:
+        cos, sin = mrope_angles(positions, hd, theta, tuple(sections))
+        positions = positions[0]
+    else:
+        cos, sin = rope_angles(positions, hd, theta)
     # broadcast cos/sin to x's layout: S at `axis`, hd/2 at the last axis
     # (and B leading when positions are per-row)
     bshape = [1] * x.ndim

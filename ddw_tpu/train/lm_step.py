@@ -64,20 +64,40 @@ def init_lm_state(model, tx: optax.GradientTransformation,
     return TrainState(params, {}, tx.init(params), jnp.zeros((), jnp.int32))
 
 
+def layer_terms(mods: dict) -> dict:
+    """The loss terms and counters the layers sowed, by name, each a mean over
+    the layers that sowed it. ``moe_aux_loss`` (the capacity dispatch's balance
+    term) and ``indexer_kl`` (ops/indexed_attention.py) are the two that
+    carry a gradient."""
+    from ddw_tpu.models.moe import collect_sown
+
+    mean = lambda xs: sum(xs) / len(xs)                   # noqa: E731
+    out = {name: mean(sown) for name in ("moe_aux_loss", "indexer_kl",
+                                         "keys_per_query")
+           if (sown := collect_sown(mods, name))}
+    counts = collect_sown(mods, "moe_counts")
+    if counts:
+        out.update({"moe_" + name: mean([c[name] for c in counts])
+                    for name in counts[0]})
+    return out
+
+
 def _lm_axes(model, data_axis: str, seq_axis: str | None) -> tuple:
     """Validate the model/step axis contract shared by the per-step and
-    chained factories; returns ``(axes, moe)``."""
+    chained factories; returns ``(axes, sows)``: whether the model's layers
+    sow loss terms or counters the step has to collect."""
     axes = (data_axis,) if seq_axis is None else (data_axis, seq_axis)
     if (model.seq_axis or None) != (seq_axis or None):
         raise ValueError(f"model.seq_axis={model.seq_axis!r} but step "
                          f"seq_axis={seq_axis!r} — construct the model with the "
                          f"axis it will run under")
-    moe = getattr(model, "num_experts", 0) > 0
+    sows = (getattr(model, "num_experts", 0) > 0
+            or getattr(model, "layer", None) is not None and model.layer.sows)
     expert_axis = getattr(model, "expert_axis", None)
     if expert_axis and expert_axis not in axes:
         raise ValueError(f"model.expert_axis={expert_axis!r} is not a step "
                          f"mesh axis {axes}")
-    return axes, moe
+    return axes, sows
 
 
 def make_lm_train_step(
@@ -104,8 +124,8 @@ def make_lm_train_step(
     placement the step returns it in, and one executable serves.
     """
     tx = _maybe_lora_tx(model, tx)
-    axes, moe = _lm_axes(model, data_axis, seq_axis)
-    _step = _make_lm_step_body(model, tx, axes, moe, aux_loss_weight,
+    axes, sows = _lm_axes(model, data_axis, seq_axis)
+    _step = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
                                grad_accum_steps)
 
     tok_spec = P(data_axis) if seq_axis is None else P(data_axis, seq_axis)
@@ -121,7 +141,7 @@ def make_lm_train_step(
     return step
 
 
-def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
+def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
                        aux_loss_weight: float, grad_accum_steps: int):
     """The per-update shard_map body shared by :func:`make_lm_train_step`
     and :func:`make_lm_train_chain` (which scans it K times)."""
@@ -133,25 +153,26 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
         dropout_rng = jax.random.fold_in(rng, state.step)
 
         def loss_fn(params, in_mb, tg_mb, rng_mb):
-            if moe:
+            terms = {}
+            if sows:
                 logits, mods = model.apply(
                     {"params": params}, in_mb, train=True,
                     rngs={"dropout": rng_mb}, mutable=["intermediates"])
-                # one sown scalar per MoE block; mean over blocks. Selected by
+                # one sown value a layer, a mean over the layers. Selected by
                 # name — blocks also sow routing telemetry (drop rate,
                 # balance entropy, gate logits) that must not leak in.
-                from ddw_tpu.models.moe import collect_sown
-
-                sown = collect_sown(mods, "moe_aux_loss")
-                aux = sum(sown) / len(sown)
+                terms = layer_terms(mods)
             else:
                 logits = model.apply({"params": params}, in_mb, train=True,
                                      rngs={"dropout": rng_mb})
-                aux = jnp.zeros((), jnp.float32)
+            aux = terms.pop("moe_aux_loss", jnp.zeros((), jnp.float32))
             with jax.named_scope("loss"):
                 ce = lm_loss(logits, tg_mb)
             acc = jnp.mean((jnp.argmax(logits, -1) == tg_mb).astype(jnp.float32))
-            return ce + aux_loss_weight * aux, (ce, acc, aux)
+            # ``loss`` stays the cross-entropy; the indexer's KL term reaches
+            # its three matrices alone (the layer stops every other path)
+            total = ce + aux_loss_weight * aux + terms.get("indexer_kl", 0.0)
+            return total, (ce, acc, aux, terms)
 
         def grad_fn(*args):
             # the scopes of train/step.py, for the same split of a profile
@@ -170,26 +191,30 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
             s = inputs.shape[1]
 
             def body(carry, xs):
-                gsum, lsum, asum, xsum = carry
+                gsum, ssum = carry
                 in_i, tg_i, idx = xs
-                (_, (l, a, x)), g = grad_fn(
+                (_, stats), g = grad_fn(
                     state.params, in_i, tg_i,
                     jax.random.fold_in(dropout_rng, idx))
-                return (jax.tree.map(jnp.add, gsum, g), lsum + l, asum + a,
-                        xsum + x), None
+                return (jax.tree.map(jnp.add, gsum, g),
+                        jax.tree.map(jnp.add, ssum, stats)), None
 
-            zero = jnp.zeros((), jnp.float32)
-            (gsum, lsum, asum, xsum), _ = lax.scan(
+            stats_like = jax.eval_shape(
+                lambda: loss_fn(state.params, inputs[:mb], targets[:mb],
+                                dropout_rng)[1])
+            (gsum, ssum), _ = lax.scan(
                 body,
-                (jax.tree.map(jnp.zeros_like, state.params), zero, zero, zero),
+                (jax.tree.map(jnp.zeros_like, state.params),
+                 jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                              stats_like)),
                 (inputs.reshape(grad_accum_steps, mb, s),
                  targets.reshape(grad_accum_steps, mb, s),
                  jnp.arange(grad_accum_steps)))
             inv = 1.0 / grad_accum_steps
             grads = jax.tree.map(lambda g: g * inv, gsum)
-            loss, acc, aux = lsum * inv, asum * inv, xsum * inv
+            loss, acc, aux, terms = jax.tree.map(lambda x: x * inv, ssum)
         else:
-            (_, (loss, acc, aux)), grads = grad_fn(
+            (_, (loss, acc, aux, terms)), grads = grad_fn(
                 state.params, inputs, targets, dropout_rng)
         with jax.named_scope("grad_sync"):
             grads = lax.pmean(grads, axes)
@@ -198,8 +223,13 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, moe,
             new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": lax.pmean(loss, axes),
                    "accuracy": lax.pmean(acc, axes)}
-        if moe:
+        if getattr(model, "num_experts", 0) > 0:
             metrics["aux_loss"] = lax.pmean(aux, axes)
+        if terms:
+            # what the layers sowed, under one key: the loop fetches whatever
+            # is there once an epoch (train/loop.py)
+            metrics["layers"] = {k: lax.pmean(v, axes)
+                                 for k, v in terms.items()}
         return TrainState(new_params, {}, new_opt, state.step + 1), metrics
 
     return _step
@@ -223,8 +253,8 @@ def make_lm_train_chain(
     and the super-batch donate through the program. K is read from the input
     shape — one callable serves the full and the trailing partial chain."""
     tx = _maybe_lora_tx(model, tx)
-    axes, moe = _lm_axes(model, data_axis, seq_axis)
-    body = _make_lm_step_body(model, tx, axes, moe, aux_loss_weight,
+    axes, sows = _lm_axes(model, data_axis, seq_axis)
+    body = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
                               grad_accum_steps)
 
     def _chain(state: TrainState, inputs, targets, rng):

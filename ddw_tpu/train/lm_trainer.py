@@ -79,12 +79,14 @@ class LMTrainer:
             if seq_devices != 1:
                 raise ValueError(f"{flag} uses the GSPMD DP step (no "
                                  f"sequence axis) — seq_devices must be 1")
-            if lm_cfg.num_experts:
+            if lm_cfg.num_experts or lm_cfg.layer.sows:
                 raise ValueError(
-                    f"{flag} does not support MoE models: the GSPMD step's "
-                    f"forward discards the sown Switch aux loss, which would "
-                    f"silently train an unbalanced router — use the plain "
-                    f"DP/EP step (no zero/fsdp) for MoE")
+                    f"{flag} does not support MoE models or layers that "
+                    f"choose their keys: the GSPMD step's forward discards "
+                    f"what the layers sow (the Switch aux loss, the "
+                    f"indexer's KL term), which would silently train an "
+                    f"unbalanced router or an untrained indexer — use the "
+                    f"plain DP/EP step (no zero/fsdp)")
         if train_cfg.steps_per_dispatch < 1:
             raise ValueError(f"train.steps_per_dispatch must be >= 1, got "
                              f"{train_cfg.steps_per_dispatch}")
@@ -102,6 +104,11 @@ class LMTrainer:
             if lm_cfg.dropout:
                 raise ValueError("pipeline training requires lm.dropout == 0 "
                                  "(the pipeline step is deterministic)")
+            if lm_cfg.layer.sows:
+                raise ValueError("pipeline_stages does not support layers "
+                                 "that sow a loss term (lm.layer: indexed "
+                                 "attention, routed experts): the pipeline "
+                                 "step does not collect it")
             if train_cfg.grad_accum_steps > 1:
                 raise ValueError("pipeline_stages does not compose with "
                                  "grad_accum_steps — microbatching IS the "

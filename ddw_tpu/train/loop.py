@@ -232,6 +232,9 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                         {"trace_dir": os.path.abspath(cfg.trace_dir)})
             t_train = time.monotonic()
             losses, accs = [], []
+            # what the model's layers sowed (the step's metrics["layers"],
+            # train/lm_step.py), fetched once an epoch with the losses
+            counted: dict = {}
             batches = train_batches(epoch)
             step_i = 0
             for k_chain in plan:
@@ -293,6 +296,8 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                         args=sp.on and {"step": host_step, "k": k_chain})
                 losses.append(metrics["loss"])
                 accs.append(metrics["accuracy"])
+                for name, value in metrics.get("layers", {}).items():
+                    counted.setdefault(name, []).append(value)
                 # the chain boundary as the host sees it (device time for the
                 # chain lives in the jax.profiler trace, not here); its self
                 # time, less data_wait and dispatch, is the loop's own work
@@ -349,7 +354,9 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
             row = {"epoch": epoch, "loss": train_loss, "accuracy": train_acc,
                    "val_loss": fetch_metrics_mean(vlosses),
                    "val_accuracy": fetch_metrics_mean(vaccs),
-                   "lr": get_lr(state), **(row_extra or {}), **timed}
+                   "lr": get_lr(state), **(row_extra or {}), **timed,
+                   **{name: fetch_metrics_mean(values)
+                      for name, values in counted.items()}}
             t_rep = time.monotonic()
             sp.span("epoch_fetch", t0v, t_rep, epoch_id)
             if tracing:
@@ -397,7 +404,8 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
             sp.span("epoch", t_epoch, t1, span=epoch_id,
                     args=sp.on and {"epoch": epoch, "steps": steps_per_epoch,
                                     "step_variants": step_variants
-                                    and step_variants()})
+                                    and step_variants(),
+                                    **{name: row[name] for name in counted}})
             if epoch == profile_epoch:
                 # the spans of everything so far, the profiled epoch whole,
                 # in the form Perfetto loads beside the profile

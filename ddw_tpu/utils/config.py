@@ -212,6 +212,54 @@ class TrainCfg:
                                         # tracker (Ganglia role, SURVEY §5)
 
 
+@dataclass(frozen=True)
+class LayerSpec:
+    """What one decoder layer is made of (ROADMAP D3): the kind of norm, of
+    attention and of MLP, and the sizes each kind reads. One object that
+    ``TransformerLM`` hands down to its blocks and their attention, in place
+    of a field re-declared on each of the three. The default is the block the
+    LM family always had (float32 LayerNorm, biases, full causal attention at
+    ``hidden // num_heads`` a head, GELU), parameter names included.
+    """
+
+    norm: str = "layernorm"             # "layernorm" | "rmsnorm", in float32
+    norm_eps: float = 1e-6
+    bias: bool = True                   # on every projection, MLP and the head
+    head_dim: int = 0                   # 0: hidden // num_heads
+    qk_norm: bool = False               # RMSNorm over each head of q and of k
+    rope_theta: float = 10000.0
+    mrope_section: tuple[int, ...] = ()  # frequency pairs a position
+                                        # component (temporal, height, width);
+                                        # (): one component, plain RoPE
+    attention: str = "full"             # "full" | "indexed": a lightning
+                                        # indexer scores every causal key and
+                                        # each query attends to its index_topk
+                                        # best (ops/indexed_attention.py)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_tile: int = 512               # queries a tile of the scores
+    mlp: str = "gelu"                   # "gelu" (fc1, fc2) | "swiglu" (gate,
+                                        # up, down); the experts' too
+    # routed MLP without dropped tokens (models/moe.py RoutedExperts), taken
+    # when LMCfg.num_experts > 0 and experts_per_token > 0; num_experts is
+    # then the number this chip HOLDS and mlp_dim one expert's width
+    experts_per_token: int = 0          # 0: moe_router's capacity dispatch
+    router_width: int = 0               # experts the router scores: the whole
+                                        # deployment's; 0 = num_experts
+    expert_offset: int = 0              # first expert held here
+    norm_topk: bool = True              # chosen weights renormalised to 1
+    embed_scale: float = 1.0            # token embeddings times this (the
+                                        # original Transformer's and Gemma's
+                                        # sqrt(hidden)); a trained table can
+                                        # fold it in
+
+    @property
+    def sows(self) -> bool:
+        """Whether a layer of this spec sows a loss term or counters."""
+        return self.attention == "indexed" or self.experts_per_token > 0
+
+
 @dataclass
 class LMCfg:
     """Decoder-only LM config (:class:`ddw_tpu.models.lm.TransformerLM`).
@@ -254,6 +302,13 @@ class LMCfg:
                                         # bwd) or "dots" (keep matmul outputs)
                                         # — long contexts past HBM at ~1/3
                                         # more FLOPs; decode unaffected
+    layer: LayerSpec = field(default_factory=LayerSpec)
+
+    def __post_init__(self):
+        if isinstance(self.layer, dict):    # from a package's JSON
+            self.layer = LayerSpec(**{
+                k: tuple(v) if isinstance(v, list) else v
+                for k, v in self.layer.items()})
 
 
 @dataclass

@@ -292,3 +292,72 @@ def test_top2_lm_trains_and_validates():
     with pytest.raises(ValueError, match="at least 2 experts"):
         MoEMlp(num_experts=1, mlp_dim=8, router="top2").init(
             jax.random.PRNGKey(0), jnp.zeros((1, 2, 8)))
+
+
+@pytest.mark.parametrize("router,cf", [("top1", 16.0), ("top1", 0.5),
+                                       ("top2", 16.0), ("top2", 0.5)])
+def test_local_experts_sorted_dispatch_matches_the_dense_tensors(router, cf):
+    """``MoEMlp`` runs local experts through the sorted, grouped dispatch;
+    the ``[T, E, C]`` dispatch and combine tensors (what the expert-parallel
+    exchange ships) give the same layer, dropped assignments and all."""
+    from ddw_tpu.models.moe import expert_capacity, router_fn
+
+    layer = MoEMlp(num_experts=4, mlp_dim=32, capacity_factor=cf,
+                   dtype=jnp.float32, router=router)
+    x = jnp.asarray(np.random.RandomState(5).randn(4, 6, 16), jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    p = jax.tree.map(lambda a: a + 0.1, variables["params"])   # biases too
+    out, mods = layer.apply({"params": p}, x, mutable=["intermediates"])
+
+    route, k = router_fn(router)
+    xt = x.reshape(-1, 16)
+    logits = xt @ p["gate"]["kernel"] + p["gate"]["bias"]
+    cap = expert_capacity(cf, k, xt.shape[0], 4)
+    dispatch, combine, _, stats = route(logits, cap)
+    blocks = jnp.einsum("tec,td->ecd", dispatch, xt)
+    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", blocks, p["w1"])
+                    + p["b1"][:, None])
+    y = jnp.einsum("ech,ehd->ecd", h, p["w2"]) + p["b2"][:, None]
+    want = jnp.einsum("tec,ecd->td", combine, y)
+    np.testing.assert_allclose(np.asarray(out).reshape(-1, 16),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+    counts = mods["intermediates"]["moe_counts"][0]
+    np.testing.assert_allclose(float(counts["dropped"]),
+                               float(stats["drop_rate"]) * k * xt.shape[0])
+    assert (float(counts["dropped"]) > 0) == (cf < 1)
+
+
+@pytest.mark.parametrize("router", ["top1", "top2"])
+def test_local_experts_sorted_dispatch_gradients_match_the_dense_tensors(router):
+    """The same pair in the backward pass: every leaf's gradient and the
+    input's, through the sorted dispatch's gathers (rows, gates and their
+    inverses) and through the dense tensors, with assignments dropped."""
+    from ddw_tpu.models.moe import expert_capacity, router_fn
+
+    layer = MoEMlp(num_experts=4, mlp_dim=32, capacity_factor=0.75,
+                   dtype=jnp.float32, router=router)
+    x = jnp.asarray(np.random.RandomState(6).randn(4, 6, 16), jnp.float32)
+    p = jax.tree.map(lambda a: a + 0.1,
+                     layer.init(jax.random.PRNGKey(0), x)["params"])
+    probe = jnp.asarray(np.random.RandomState(7).randn(24, 16), jnp.float32)
+    route, k = router_fn(router)
+
+    def sorted_form(p, x):
+        return jnp.sum(layer.apply({"params": p}, x).reshape(-1, 16) * probe)
+
+    def dense_form(p, x):
+        xt = x.reshape(-1, 16)
+        logits = xt @ p["gate"]["kernel"] + p["gate"]["bias"]
+        dispatch, combine, _, _ = route(
+            logits, expert_capacity(0.75, k, xt.shape[0], 4))
+        blocks = jnp.einsum("tec,td->ecd", dispatch, xt)
+        h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", blocks, p["w1"])
+                        + p["b1"][:, None])
+        y = jnp.einsum("ech,ehd->ecd", h, p["w2"]) + p["b2"][:, None]
+        return jnp.sum(jnp.einsum("tec,ecd->td", combine, y) * probe)
+
+    got = jax.grad(sorted_form, argnums=(0, 1))(p, x)
+    want = jax.grad(dense_form, argnums=(0, 1))(p, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
