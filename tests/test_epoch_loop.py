@@ -21,7 +21,7 @@ from ddw_tpu.train import lm_trainer, loop, trainer
 from ddw_tpu.train.lm_trainer import LMTrainer
 from ddw_tpu.train.step import chain_plan
 from ddw_tpu.train.trainer import Trainer
-from ddw_tpu.utils.config import LMCfg, TrainCfg
+from ddw_tpu.utils.config import LayerSpec, LMCfg, TrainCfg
 
 SPE = 4         # steps an epoch, both trainers
 
@@ -34,7 +34,8 @@ def _tokens(n=36, seq=17):
 def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
          **train_kw):
     """One tiny fit of SPE steps an epoch: ``vision``, or the LM by its
-    step (``lm``, ``lm-zero``, ``lm-fsdp``, ``lm-pp``)."""
+    step (``lm``, ``lm-zero``, ``lm-fsdp``, ``lm-pp``), or (``lm-indexed``) an
+    LM that attends to chosen keys at a length the Pallas kernels take."""
     if kind == "vision":
         data, model, train = small_cfgs
         mesh = make_mesh(MeshSpec((("data", 8),)))
@@ -46,9 +47,18 @@ def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
     lm = LMCfg(vocab_size=32, max_len=16, hidden=16, num_heads=2, mlp_dim=32,
                depth=2 if kind == "lm-pp" else 1, dropout=0.0,
                dtype="float32")
+    seq = 17
+    if kind == "lm-indexed":
+        seq = 513
+        lm = dataclasses.replace(
+            lm, max_len=512, hidden=64, num_kv_heads=1, pos_encoding="rope",
+            remat="full", layer=LayerSpec(
+                norm="rmsnorm", bias=False, head_dim=64, attention="indexed",
+                index_heads=2, index_head_dim=8, index_topk=128,
+                index_tile=128, mlp="swiglu"))
     extra = {"lm-zero": {"zero": True}, "lm-fsdp": {"fsdp": True},
              "lm-pp": {"pipeline_stages": 2, "pipeline_microbatches": 2},
-             "lm": {}}[kind]
+             "lm": {}, "lm-indexed": {}}[kind]
     train = TrainCfg(batch_size=4, epochs=2, warmup_epochs=0, seed=0,
                      learning_rate=1e-2, num_devices=2, **extra)
     train = dataclasses.replace(train, **train_kw)
@@ -56,7 +66,7 @@ def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
         train = dataclasses.replace(train, batch_size=8)
     # 36 sequences: 4 held out, 32 = SPE batches of 8
     return LMTrainer(lm, train, run=run, tracer=tracer).fit(
-        _tokens(), val_fraction=0.1, resume=resume)
+        _tokens(seq=seq), val_fraction=0.1, resume=resume)
 
 
 @pytest.fixture()
@@ -230,6 +240,23 @@ def test_a_fit_builds_one_executable_of_its_step(kind, k, small_cfgs, silver,
     placed = _one_executable(steps_made, tracer, [0, 1])
     # a fresh state: every leaf had to be placed
     assert placed["leaves"] > 0 and placed["bytes"] > 0
+
+
+def test_the_indexed_step_is_one_executable_with_the_kernels_in_it(
+        small_cfgs, silver, steps_made, monkeypatch):
+    """A layer that attends to chosen keys takes the Pallas kernels from 512
+    tokens (``ops/indexed_attention.py`` reads the shapes), and the fit still
+    builds its step once."""
+    from ddw_tpu.ops import indexed_kernels
+
+    calls, attend = [], indexed_kernels.attend_chosen
+    monkeypatch.setattr(
+        indexed_kernels, "attend_chosen",
+        lambda q, *a, **kw: calls.append(q.shape) or attend(q, *a, **kw))
+    tracer = Tracer(capacity=4096)
+    _fit("lm-indexed", small_cfgs, silver, tracer=tracer)
+    _one_executable(steps_made, tracer, [0, 1])
+    assert calls and {shape[1:] for shape in calls} == {(512, 2, 64)}
 
 
 @pytest.mark.parametrize("kind", ["vision", "lm"])

@@ -211,10 +211,15 @@ def test_no_grouped_product_is_made_twice(both, remat):
         6 * CONFIG["num_hidden_layers"])
 
 
-def test_fewer_queries_than_topk_is_causal_attention():
+# once a tier: the XLA tiles at a size they alone take, the kernels at one
+# block and at four
+@pytest.mark.parametrize("impl,s,d,tile", [("xla", 32, 16, 16),
+                                           ("pallas", 128, 64, 32),
+                                           ("pallas", 256, 128, 64)])
+def test_fewer_queries_than_topk_is_causal_attention(impl, s, d, tile):
     """While ``t < topk`` every earlier key is chosen, whatever the indexer
     says: the path then equals the causal attention the other cells run."""
-    b, s, h, kv, d = 2, 32, 4, 2, 16
+    b, h, kv = 2, 4, 2
     keys = jax.random.split(jax.random.PRNGKey(5), 6)
     q = jax.random.normal(keys[0], (b, s, h, d))
     k, v = (jax.random.normal(kk, (b, s, kv, d)) for kk in keys[1:3])
@@ -223,16 +228,19 @@ def test_fewer_queries_than_topk_is_causal_attention():
     qi = jax.random.normal(keys[3], (b, s, 16, 8))
     ki = jax.random.normal(keys[4], (b, s, 8))
     wi = jax.random.normal(keys[5], (b, s, 16))
-    out, _, chosen, choice = indexed_attention(q, k, v, qi, ki, wi, topk=64,
-                                               tile=16)
+    out, _, chosen, choice = indexed_attention(q, k, v, qi, ki, wi,
+                                               topk=2 * s, tile=tile,
+                                               impl=impl)
     np.testing.assert_array_equal(choice[1], jnp.tril(jnp.ones((s, s), bool)))
     want = flash_mha_seq_major(q, jnp.repeat(k, h // kv, 2),
-                               jnp.repeat(v, h // kv, 2), causal=True)
+                               jnp.repeat(v, h // kv, 2), causal=True,
+                               impl="xla")
     np.testing.assert_allclose(out, want, atol=1e-5)
     np.testing.assert_array_equal(chosen, jnp.broadcast_to(
         jnp.arange(1, s + 1), (b, s)))
     # and with fewer allowed, each query keeps exactly its topk best
-    _, _, chosen, _ = indexed_attention(q, k, v, qi, ki, wi, topk=8, tile=16)
+    _, _, chosen, _ = indexed_attention(q, k, v, qi, ki, wi, topk=8,
+                                        tile=tile, impl=impl)
     np.testing.assert_array_equal(chosen[0], jnp.minimum(jnp.arange(s) + 1, 8))
 
 
