@@ -364,6 +364,21 @@ class CausalSelfAttention(nn.Module):
             out, cn=2)
 
 
+def routed_experts(spec: LayerSpec, num_held: int, mlp_dim: int, dtype,
+                   name: str):
+    """The spec's no-drop routed layer on the ``num_held`` experts this chip
+    holds (``models/moe.py::RoutedExperts``), for either kind of block."""
+    from ddw_tpu.models.moe import RoutedExperts
+
+    return RoutedExperts(num_held, mlp_dim, k=spec.experts_per_token,
+                         router_width=spec.router_width,
+                         offset=spec.expert_offset, normalise=spec.norm_topk,
+                         act=spec.mlp, dtype=dtype, score=spec.router_score,
+                         scale=spec.router_scale,
+                         bias_rate=spec.router_bias_rate,
+                         shared_dim=spec.shared_expert_dim, name=name)
+
+
 class DecoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
@@ -409,18 +424,14 @@ class DecoderBlock(nn.Module):
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         x = x + h
         h = layer_norm(spec)(x)
-        if spec.mlp not in ("gelu", "swiglu"):
+        if spec.mlp not in ("gelu", "swiglu", "relu2") or (
+                spec.mlp == "relu2" and not (self.num_experts
+                                             and spec.experts_per_token)):
             raise ValueError(f"unknown mlp {spec.mlp!r}; use 'gelu' or "
-                             f"'swiglu'")
+                             f"'swiglu' ('relu2' for routed experts only)")
         if self.num_experts and spec.experts_per_token:
-            from ddw_tpu.models.moe import RoutedExperts
-
-            h = RoutedExperts(self.num_experts, self.mlp_dim,
-                              k=spec.experts_per_token,
-                              router_width=spec.router_width,
-                              offset=spec.expert_offset,
-                              normalise=spec.norm_topk, act=spec.mlp,
-                              dtype=self.dtype, name="moe")(h)
+            h = routed_experts(spec, self.num_experts, self.mlp_dim,
+                               self.dtype, "moe")(h)
         elif self.num_experts:
             from ddw_tpu.models.moe import MoEMlp
 
@@ -454,6 +465,44 @@ class DecoderBlock(nn.Module):
                 h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         return x + h
+
+
+MIXERS = {"M": "a Mamba-2 mixer", "E": "routed experts", "*": "attention"}
+
+
+class MixerBlock(nn.Module):
+    """One layer of a model with a ``pattern``: ONE mixer behind one norm,
+    ``h + mixer(norm(h))``, the mixer's kind this layer's character of the
+    pattern — ``M`` (:class:`ddw_tpu.models.mamba.Mamba2Mixer`), ``E``
+    (:class:`ddw_tpu.models.moe.RoutedExperts` on the chip's share, with the
+    spec's shared expert) or ``*`` (:class:`CausalSelfAttention`). Every kind
+    reads its sizes from the one ``LayerSpec``. Training only."""
+
+    kind: str
+    num_heads: int
+    mlp_dim: int
+    dtype: Any = jnp.bfloat16
+    num_experts: int = 0
+    num_kv_heads: int = 0
+    layer: LayerSpec = LayerSpec()
+
+    @nn.compact
+    def __call__(self, x, train: bool, positions=None):
+        spec = self.layer
+        h = layer_norm(spec)(x)
+        if self.kind == "M":
+            from ddw_tpu.models.mamba import Mamba2Mixer
+
+            h = Mamba2Mixer(spec, self.dtype, name="mixer")(h)
+        elif self.kind == "E":
+            h = routed_experts(spec, self.num_experts, self.mlp_dim,
+                               self.dtype, "mixer")(h)
+        else:
+            h = CausalSelfAttention(self.num_heads, self.dtype,
+                                    num_kv_heads=self.num_kv_heads,
+                                    layer=spec, name="mixer")(
+                                        h, positions=positions)
+        return x + h.astype(x.dtype)
 
 
 class TransformerLM(nn.Module):
@@ -505,6 +554,8 @@ class TransformerLM(nn.Module):
                              # outputs, recompute elementwise). Ignored in
                              # decode mode (no backward there).
     layer: LayerSpec = LayerSpec()  # what every block is made of
+    pattern: str = ""        # one mixer a layer (MixerBlock), a character
+                             # each; "": every layer a DecoderBlock
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, block_tables=None,
@@ -520,9 +571,19 @@ class TransformerLM(nn.Module):
             from ddw_tpu.models.lora import validate_lora_targets
 
             validate_lora_targets(self.lora_targets)
-        if self.pos_encoding not in ("learned", "rope"):
+        if self.pos_encoding not in ("learned", "rope", "none"):
             raise ValueError(f"unknown pos_encoding {self.pos_encoding!r}; "
-                             f"use 'learned' or 'rope'")
+                             f"use 'learned', 'rope' or 'none'")
+        if self.pattern:
+            if set(self.pattern) - set(MIXERS) or len(self.pattern) != self.depth:
+                raise ValueError(
+                    f"pattern {self.pattern!r} must give one of "
+                    f"{sorted(MIXERS)} for each of the {self.depth} layers")
+            if self.decode or self.seq_axis is not None or self.lora_rank:
+                raise NotImplementedError(
+                    "a model with a pattern trains on one device's whole "
+                    "sequence: a cache of the state-space layers' state, a "
+                    "ring over it and adapters are not written (ROADMAP M5)")
         if self.pos_encoding == "rope" and (
                 self.layer.head_dim or self.hidden // self.num_heads) % 2:
             raise ValueError("RoPE needs an even head_dim")
@@ -582,6 +643,8 @@ class TransformerLM(nn.Module):
                                                axis=0)
                 x = x + pos.astype(self.dtype)[None]
             positions = None
+        elif self.pos_encoding == "none":
+            positions = None
         else:
             # RoPE: absolute positions feed the per-layer q/k rotation; no
             # table, no additive embedding. Works unchanged under SP (offset
@@ -605,15 +668,21 @@ class TransformerLM(nn.Module):
             # (ops/indexed_attention.py names them): the choice, one byte a
             # pair, and what the attention under it gave, so that neither is
             # made a second time; and of a layer that routes (models/moe.py)
-            # the experts' first products, whose time follows the routing;
+            # the choice of experts where the router names it (the sigmoid
+            # router: models/moe.py::chosen), which the backward pass must
+            # not make otherwise, and the experts' first products, whose rows
+            # lie in that choice's order and whose time follows the routing;
             # nothing where no layer chooses or routes
             policy = (jax.checkpoint_policies.save_only_these_names(
-                          "key_mask", "attention_out", "expert_hidden")
+                          "key_mask", "attention_out", "expert_choice",
+                          "expert_hidden")
                       if self.remat == "full"
                       else jax.checkpoint_policies.checkpoint_dots)
-            Block = nn.remat(DecoderBlock, static_argnums=(2,), policy=policy)
+            remat = lambda block: nn.remat(                # noqa: E731
+                block, static_argnums=(2,), policy=policy)
         else:
-            Block = DecoderBlock
+            remat = lambda block: block                     # noqa: E731
+        Block = remat(DecoderBlock)
         paged_kw = (dict(block_tables=block_tables, start_pos=start_pos)
                     if self.paged_decode else {})
         row_adapters = None
@@ -622,7 +691,14 @@ class TransformerLM(nn.Module):
             aidx = jnp.asarray(aidx, jnp.int32)
             row_adapters = jax.tree.map(lambda st: jnp.asarray(st)[aidx],
                                         stacks)
-        for i in range(self.depth):
+        for i, kind in enumerate(self.pattern):
+            x = remat(MixerBlock)(
+                kind, self.num_heads, self.mlp_dim, self.dtype,
+                num_experts=self.num_experts, num_kv_heads=self.num_kv_heads,
+                layer=self.layer, name=f"backbone_block{i}")(x, train,
+                                                             positions)
+        # without a pattern: depth blocks of an attention and an MLP each
+        for i in range(0 if self.pattern else self.depth):
             blk_kw = dict(paged_kw)
             if row_adapters is not None:
                 blk_kw["adapters"] = row_adapters.get(f"backbone_block{i}")
@@ -670,7 +746,8 @@ def build_lm(cfg, seq_axis: str | None = None,
         lora_targets=tuple(getattr(cfg, "lora_targets", ("query", "value"))),
         pos_encoding=getattr(cfg, "pos_encoding", "learned"),
         remat=getattr(cfg, "remat", "none"),
-        layer=getattr(cfg, "layer", LayerSpec()))
+        layer=getattr(cfg, "layer", LayerSpec()),
+        pattern=getattr(cfg, "pattern", ""))
 
 
 def init_cache(decode_model: TransformerLM, batch: int):

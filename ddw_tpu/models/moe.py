@@ -60,15 +60,70 @@ def collect_sown(mods: dict, name: str) -> list:
             for x in (leaf if isinstance(leaf, (tuple, list)) else (leaf,))]
 
 
+def chosen(ranked: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The ``k`` largest of ``ranked [T, E]`` a token, the lower-numbered of
+    equals: ``[T, k]`` indices, named ``expert_choice`` (the sigmoid router's
+    choice; :func:`route_topk` says why the softmax router's is not). A block
+    rematerialised whole keeps them (``models/lm.py``'s ``remat="full"``): a
+    choice is not continuous in the scores, and a backward pass that made it
+    again could, where two scores all but tie and the compiler rounds the
+    second making otherwise, sort its rows by another choice than the one the
+    kept ``expert_hidden`` lies in (v5e, 16,384 tokens, 4 layers of 128
+    experts top-6: the first step of two seeds in some thirty, and that
+    layer's experts' gradient then missed by its own length; PERF.md section
+    6, PR 34)."""
+    _, top_i = lax.top_k(lax.stop_gradient(ranked), k)
+    return checkpoint_name(top_i, "expert_choice")
+
+
 def route_topk(gate_logits: jnp.ndarray, k: int, normalise: bool):
     """Softmax over every expert the router scores, then the ``k`` largest:
     ``(weights [T, k], experts [T, k], probs [T, E])``, weights renormalised
-    to sum to one where asked. Float32 throughout."""
+    to sum to one where asked. Float32 throughout. This choice is NOT kept
+    across a block's rematerialisation as :func:`chosen`'s is, though it is
+    open to the same fault: kept, with the weights gathered by it, the one
+    benchmark cell that routes so lost 1.0 % of its rate for no reason found
+    yet, and none of its seeds has shown the fault (PERF.md section 7, PR
+    34)."""
     probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     top_p, top_i = lax.top_k(probs, k)
     if normalise:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p, top_i, probs
+
+
+def route_sigmoid(gate_logits: jnp.ndarray, k: int, normalise: bool,
+                  bias=None, scale: float = 1.0):
+    """A sigmoid an expert in place of the softmax (DeepSeek-V3,
+    arXiv:2412.19437): the ``k`` experts of largest ``score + bias`` are
+    chosen, the lower-numbered of equals; the weights are the chosen SCORES
+    (the bias steers the choice and nothing else), renormalised to sum to one
+    where asked, times ``scale``. Same returns as :func:`route_topk`."""
+    scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    ranked = scores if bias is None else scores + bias.astype(jnp.float32)
+    top_i = chosen(ranked, k)
+    top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    if normalise:
+        top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    return top_s * scale, top_i, scores
+
+
+def step_router_bias(buffers: dict, loads: dict, rate: float, mean=None):
+    """The correction biases after a step: ``b_e + rate * sign(mean load -
+    load_e)`` over every expert the router scores. ``loads``: what the
+    routers sowed on this step's tokens as ``router_load``, by flattened path
+    (a layer's lies beside the ``router_bias`` it read). ``mean`` (optional)
+    averages a load over the chips that saw other tokens of the step, so
+    that their biases stay one. No gradient reaches a bias and the optimizer
+    never sees one."""
+    from flax import traverse_util
+
+    out = {}
+    for path, bias in traverse_util.flatten_dict(buffers).items():
+        load = loads[path[:-1] + ("router_load",)][0]
+        load = mean(load) if mean is not None else load
+        out[path] = bias + rate * jnp.sign(jnp.mean(load) - load)
+    return traverse_util.unflatten_dict(out)
 
 
 def capacity_routing(gate_logits: jnp.ndarray, capacity: int, k: int):
@@ -254,12 +309,28 @@ def _gates_bwd(slot, g):
 gates_in_order.defvjp(_gates_fwd, _gates_bwd)
 
 
+GROUPED_TILE = 512
+
+
+def grouped_pad(width: int) -> int:
+    """Columns of zeros that bring an expert's hidden width to the next
+    multiple of the grouped products' tile, where that is cheap (an eighth of
+    the width at most). The compiler's grouped-matmul kernel runs a width of
+    1,856 at half the rate of 2,048 (v5e, 8 experts 2688 x F, 9,216 rows,
+    forward and backward: 23.85 ms against 13.03; 1,920 buys nothing: my chip
+    runs, PR 34), and the grouped products are the part of a step whose time
+    follows the routing."""
+    pad = -width % GROUPED_TILE
+    return pad if 0 < pad <= width // 8 else 0
+
+
 def grouped_experts(xt, local, weights, w_in, w_down, act: str, dtype,
                     b_in=None, b_down=None):
     """The experts' part of a routed layer without dense dispatch tensors:
     ``xt [T, D]``, ``local [T, k]`` (:func:`sort_by_expert`'s), ``weights
-    [T, k]``; ``w_in`` one ``[E, D, F]`` stack (``act="gelu"``) or the gate's
-    and the up projection's (``"swiglu"``), ``w_down [E, F, D]``, optional
+    [T, k]``; ``w_in`` one ``[E, D, F]`` stack (``act="gelu"``, ``"relu2"``: the
+    fused first product in its one-matrix form) or the gate's and the up
+    projection's (``"swiglu"``), ``w_down [E, F, D]``, optional
     biases ``[E, F]`` / ``[E, D]``. The assignments to run are sorted by
     expert and go through grouped matrix products (``lax.ragged_dot``) over a
     buffer of ``k * T`` rows — the worst case, every choice of every token
@@ -273,28 +344,40 @@ def grouped_experts(xt, local, weights, w_in, w_down, act: str, dtype,
     (``expert_hidden``), and a row's gate goes in BEFORE the down product, so
     that the backward pass asks for no product's output — and the gate's and
     the up projection's go as one product of twice the width, which runs a
-    sixth faster a row than the two. ``b_in`` is as wide as that product."""
+    sixth faster a row than the two. ``b_in`` is as wide as that product.
+    An ungated expert's hidden width is padded with zero columns to the
+    products' tile (:func:`grouped_pad`): ``act(0) = 0`` meets zero rows of
+    ``w_down``, so nothing changes but the products' rate."""
     order, slot, group_sizes, valid, expert = sort_by_expert(
         local, w_down.shape[0])
     mask = valid[:, None]
+    pad = grouped_pad(w_down.shape[1]) if len(w_in) == 1 else 0
     # rows past the assignments to run are whatever the gather left there:
     # selected away on both sides of the products, never multiplied, so
     # nothing they hold reaches a sum or a gradient
     rows = jnp.where(mask, rows_in_order(xt.astype(dtype), order, slot), 0)
-    h = checkpoint_name(lax.ragged_dot(
-        rows, jnp.concatenate([w.astype(dtype) for w in w_in], axis=-1),
-        group_sizes), "expert_hidden")
+    w_first = jnp.concatenate([w.astype(dtype) for w in w_in], axis=-1)
+    if pad:
+        w_first = jnp.pad(w_first, ((0, 0), (0, 0), (0, pad)))
+    h = checkpoint_name(lax.ragged_dot(rows, w_first, group_sizes),
+                        "expert_hidden")
     if b_in is not None:
-        h = h + b_in.astype(dtype)[expert]
+        b_in = b_in.astype(dtype)
+        h = h + (jnp.pad(b_in, ((0, 0), (0, pad))) if pad else b_in)[expert]
     if act == "swiglu":
         h_gate, h_up = jnp.split(h, 2, axis=-1)
         h = nn.silu(h_gate) * h_up
+    elif act == "relu2":
+        h = jnp.square(nn.relu(h))
     else:
         h = nn.gelu(h)
     gate = jnp.where(mask, gates_in_order(weights.astype(dtype), order, slot),
                      0)
-    y = lax.ragged_dot(jnp.where(mask, h * gate, 0), w_down.astype(dtype),
-                       group_sizes)
+    gated = jnp.where(mask, h * gate, 0)
+    w_down = w_down.astype(dtype)
+    if pad:
+        w_down = jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))
+    y = lax.ragged_dot(gated, w_down, group_sizes)
     if b_down is not None:
         y = y + gate * b_down.astype(dtype)[expert]
     y = jnp.where(mask, y, 0)
@@ -408,12 +491,22 @@ class RoutedExperts(nn.Module):
     through :func:`grouped_experts`. What the experts held elsewhere would
     have added is left out: nothing here stands in for the other chips or
     their exchange. Experts are gated (``swiglu``: ``down(silu(gate x) * up
-    x)``) or plain (``gelu``), without biases.
+    x)``), plain (``gelu``) or squared (``relu2``: ``down(relu(up x)^2)``),
+    without biases.
+
+    ``score="sigmoid"`` (:func:`route_sigmoid`) scores each expert alone;
+    with ``bias_rate > 0`` the choice also reads a correction bias an expert,
+    a variable of the ``buffers`` collection: no gradient, no optimizer
+    state, moved after each step by :func:`step_router_bias` from the loads
+    sown here as ``router_load``. ``shared_dim > 0`` adds an expert of that
+    width that every token takes; every chip of a deployment computes it
+    whole, so across shares it counts once.
 
     Sows ``moe_counts`` (:func:`load_counts`: assignments to held experts a
     token, the largest load of a held expert over their mean load, and
     assignments to a held expert that found no row of the buffer: zero by
-    construction) and ``expert_choice``, for a reader that asks.
+    construction), the counter ``router_bias_range`` where there is a bias,
+    and ``expert_choice``, for a reader that asks.
     """
 
     num_held: int
@@ -424,6 +517,10 @@ class RoutedExperts(nn.Module):
     normalise: bool = True
     act: str = "swiglu"
     dtype: Any = jnp.bfloat16
+    score: str = "softmax"
+    scale: float = 1.0
+    bias_rate: float = 0.0
+    shared_dim: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -437,6 +534,12 @@ class RoutedExperts(nn.Module):
             raise ValueError(f"held experts [{self.offset}, "
                              f"{self.offset + self.num_held}) lie outside "
                              f"the router's {width}")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.score!r}; use "
+                             f"'softmax' or 'sigmoid'")
+        if self.bias_rate and self.score != "sigmoid":
+            raise ValueError("the correction bias steers a sigmoid router's "
+                             "choice; router_score is 'softmax'")
         xt = x.reshape(t, d)
         with jax.named_scope("router"):
             # float32 all the way: which expert is 8th decides a token's path
@@ -444,8 +547,23 @@ class RoutedExperts(nn.Module):
                 width, use_bias=False, dtype=jnp.float32,
                 precision=lax.Precision.HIGHEST, name="gate")(
                     xt.astype(jnp.float32))
-            weights, experts, _ = route_topk(gate_logits, self.k,
-                                             self.normalise)
+            if self.score == "sigmoid":
+                bias = None
+                if self.bias_rate:
+                    bias = self.variable("buffers", "router_bias", jnp.zeros,
+                                         (width,), jnp.float32).value
+                    self.sow("intermediates", "counters", {
+                        "router_bias_range": jnp.max(bias) - jnp.min(bias)})
+                weights, experts, _ = route_sigmoid(
+                    gate_logits, self.k, self.normalise, bias, self.scale)
+            else:
+                weights, experts, _ = route_topk(gate_logits, self.k,
+                                                 self.normalise)
+                weights = weights * self.scale if self.scale != 1.0 else weights
+            if self.bias_rate:
+                self.sow("intermediates", "router_load", jnp.sum(
+                    experts[..., None] == jnp.arange(width), axis=(0, 1),
+                    dtype=jnp.float32))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         e, f = self.num_held, self.mlp_dim
@@ -455,6 +573,16 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("experts"):
             out, loads = grouped_experts(xt, experts - self.offset, weights,
                                          w_in, w_down, self.act, self.dtype)
+        if self.shared_dim:
+            if self.act != "relu2":
+                raise NotImplementedError("the shared expert is written for "
+                                          "mlp='relu2' (up, down)")
+            with jax.named_scope("shared_expert"):
+                dense = lambda width, name: nn.Dense(     # noqa: E731
+                    width, use_bias=False, dtype=self.dtype, name=name)
+                hidden = jnp.square(nn.relu(dense(self.shared_dim,
+                                                  "shared_up")(xt)))
+                out = out + dense(d, "shared_down")(hidden)
         self.sow("intermediates", "expert_choice", experts)
         here = (experts >= self.offset) & (experts < self.offset + e)
         self.sow("intermediates", "moe_counts",

@@ -60,8 +60,13 @@ def init_lm_state(model, tx: optax.GradientTransformation,
         init_model = model.clone(**unbind)
     else:
         init_model = model
-    params = init_model.init({"params": rng}, dummy, train=False)["params"]
-    return TrainState(params, {}, tx.init(params), jnp.zeros((), jnp.int32))
+    variables = init_model.init({"params": rng}, dummy, train=False)
+    params = variables["params"]
+    # leaves without a gradient, moved by their layer's rule after each step
+    # (the routers' correction biases), ride where the vision models' batch
+    # statistics do: outside ``params``, so the optimizer never sees them
+    return TrainState(params, dict(variables.get("buffers", {})),
+                      tx.init(params), jnp.zeros((), jnp.int32))
 
 
 def layer_terms(mods: dict) -> dict:
@@ -79,6 +84,12 @@ def layer_terms(mods: dict) -> dict:
     if counts:
         out.update({"moe_" + name: mean([c[name] for c in counts])
                     for name in counts[0]})
+    # counters a layer names itself: a mean over the layers that sowed each
+    sown: dict = {}
+    for layer in collect_sown(mods, "counters"):
+        for name, value in layer.items():
+            sown.setdefault(name, []).append(value)
+    out.update({name: mean(values) for name, values in sown.items()})
     return out
 
 
@@ -109,6 +120,7 @@ def make_lm_train_step(
     donate: bool = True,
     aux_loss_weight: float = 0.01,
     grad_accum_steps: int = 1,
+    hand_out: tuple[str, ...] = (),
 ) -> Callable:
     """Build the jitted DP(xSP)(xEP) LM train step.
 
@@ -122,11 +134,19 @@ def make_lm_train_step(
     compiles once for each placement of its arguments:
     ``step.place_state(state)`` before the first call gives the state the
     placement the step returns it in, and one executable serves.
+
+    ``hand_out`` names what the layers sow for a reader that asks (a routed
+    layer's ``expert_choice``): the step then returns, as
+    ``metrics["handed"][name]``, what the layers sowed under it ON THIS STEP,
+    stacked over the layers in their order and gathered over the mesh — the
+    discrete choices the update was computed with, which no second forward
+    pass reproduces to the last token (the benchmark's reference follows
+    them). Nobody else asks: a step without it builds none of it.
     """
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
     _step = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                               grad_accum_steps)
+                               grad_accum_steps, hand_out)
 
     tok_spec = P(data_axis) if seq_axis is None else P(data_axis, seq_axis)
     smapped = shard_map(
@@ -142,9 +162,18 @@ def make_lm_train_step(
 
 
 def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
-                       aux_loss_weight: float, grad_accum_steps: int):
+                       aux_loss_weight: float, grad_accum_steps: int,
+                       hand_out: tuple[str, ...] = ()):
     """The per-update shard_map body shared by :func:`make_lm_train_step`
     and :func:`make_lm_train_chain` (which scans it K times)."""
+    from flax.traverse_util import flatten_dict
+
+    from ddw_tpu.models.moe import collect_sown
+
+    if hand_out and (not sows or grad_accum_steps > 1):
+        raise ValueError("hand_out returns what the layers sow on a step: "
+                         "the model's layers sow nothing, or "
+                         "grad_accum_steps splits the step into several")
 
     def _step(state: TrainState, inputs, targets, rng):
         # independent dropout masks per (data shard, seq shard, step)
@@ -152,16 +181,28 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
             rng = jax.random.fold_in(rng, lax.axis_index(ax))
         dropout_rng = jax.random.fold_in(rng, state.step)
 
+        buffers = state.batch_stats
+
         def loss_fn(params, in_mb, tg_mb, rng_mb):
-            terms = {}
+            terms, loads, handed = {}, {}, {}
             if sows:
+                variables = {"params": params}
+                if buffers:
+                    variables["buffers"] = buffers
                 logits, mods = model.apply(
-                    {"params": params}, in_mb, train=True,
+                    variables, in_mb, train=True,
                     rngs={"dropout": rng_mb}, mutable=["intermediates"])
                 # one sown value a layer, a mean over the layers. Selected by
                 # name — blocks also sow routing telemetry (drop rate,
                 # balance entropy, gate logits) that must not leak in.
                 terms = layer_terms(mods)
+                # every routed layer's load by expert, which its correction
+                # bias moves by after the update
+                loads = {path: sown for path, sown in flatten_dict(
+                    mods["intermediates"]).items()
+                    if path[-1] == "router_load"}
+                handed = {name: jnp.stack(collect_sown(mods, name))
+                          for name in hand_out}
             else:
                 logits = model.apply({"params": params}, in_mb, train=True,
                                      rngs={"dropout": rng_mb})
@@ -172,7 +213,7 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
             # ``loss`` stays the cross-entropy; the indexer's KL term reaches
             # its three matrices alone (the layer stops every other path)
             total = ce + aux_loss_weight * aux + terms.get("indexer_kl", 0.0)
-            return total, (ce, acc, aux, terms)
+            return total, (ce, acc, aux, terms, loads, handed)
 
         def grad_fn(*args):
             # the scopes of train/step.py, for the same split of a profile
@@ -212,15 +253,22 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
                  jnp.arange(grad_accum_steps)))
             inv = 1.0 / grad_accum_steps
             grads = jax.tree.map(lambda g: g * inv, gsum)
-            loss, acc, aux, terms = jax.tree.map(lambda x: x * inv, ssum)
+            loss, acc, aux, terms, loads, handed = jax.tree.map(
+                lambda x: x * inv, ssum)
         else:
-            (_, (loss, acc, aux, terms)), grads = grad_fn(
+            (_, (loss, acc, aux, terms, loads, handed)), grads = grad_fn(
                 state.params, inputs, targets, dropout_rng)
         with jax.named_scope("grad_sync"):
             grads = lax.pmean(grads, axes)
         with jax.named_scope("optimizer"):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
+            if buffers:
+                from ddw_tpu.models.moe import step_router_bias
+
+                buffers = step_router_bias(
+                    buffers, loads, model.layer.router_bias_rate,
+                    lambda load: lax.pmean(load, axes))
         metrics = {"loss": lax.pmean(loss, axes),
                    "accuracy": lax.pmean(acc, axes)}
         if getattr(model, "num_experts", 0) > 0:
@@ -230,7 +278,14 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
             # is there once an epoch (train/loop.py)
             metrics["layers"] = {k: lax.pmean(v, axes)
                                  for k, v in terms.items()}
-        return TrainState(new_params, {}, new_opt, state.step + 1), metrics
+        if handed:
+            # [layers, this shard's rows of the sown value, ...]: every
+            # shard's, in the mesh's order
+            metrics["handed"] = {
+                k: lax.all_gather(v, axes, axis=1, tiled=True)
+                for k, v in handed.items()}
+        return TrainState(new_params, buffers, new_opt,
+                          state.step + 1), metrics
 
     return _step
 
@@ -285,7 +340,10 @@ def make_lm_eval_step(model, mesh: Mesh, data_axis: str = "data",
     axes = (data_axis,) if seq_axis is None else (data_axis, seq_axis)
 
     def _eval(state: TrainState, inputs, targets):
-        logits = model.apply({"params": state.params}, inputs, train=False)
+        variables = {"params": state.params}
+        if state.batch_stats:
+            variables["buffers"] = state.batch_stats
+        logits = model.apply(variables, inputs, train=False)
         loss = lm_loss(logits, targets)
         acc = jnp.mean((jnp.argmax(logits, -1) == targets).astype(jnp.float32))
         return {"loss": lax.pmean(loss, axes), "accuracy": lax.pmean(acc, axes)}

@@ -240,7 +240,9 @@ class LayerSpec:
     index_topk: int = 0
     index_tile: int = 512               # queries a tile of the scores
     mlp: str = "gelu"                   # "gelu" (fc1, fc2) | "swiglu" (gate,
-                                        # up, down); the experts' too
+                                        # up, down) | "relu2" (up, down:
+                                        # relu(x)^2, routed and shared experts
+                                        # only); the experts' too
     # routed MLP without dropped tokens (models/moe.py RoutedExperts), taken
     # when LMCfg.num_experts > 0 and experts_per_token > 0; num_experts is
     # then the number this chip HOLDS and mlp_dim one expert's width
@@ -253,11 +255,31 @@ class LayerSpec:
                                         # original Transformer's and Gemma's
                                         # sqrt(hidden)); a trained table can
                                         # fold it in
+    router_score: str = "softmax"       # "softmax" over router_width | a
+                                        # "sigmoid" an expert (DeepSeek-V3)
+    router_scale: float = 1.0           # the chosen weights times this
+    router_bias_rate: float = 0.0       # > 0: a correction bias an expert that
+                                        # only the choice sees, no gradient,
+                                        # moved after each step by this much
+                                        # towards an even load (models/moe.py
+                                        # step_router_bias)
+    shared_expert_dim: int = 0          # > 0: an expert of this width that
+                                        # every token takes, beside the routed
+    # the Mamba-2 mixer of an "M" layer (models/mamba.py, ops/ssd.py)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1                 # groups of heads that share B and C
+    ssm_state: int = 0                  # N, a head's state is head_dim x N
+    ssm_conv: int = 4                   # taps of the causal depthwise conv
+    ssm_chunk: int = 128                # tokens a chunk of the scan
+    ssm_dt_shift: float = 0.0           # added to dt_bias inside the softplus;
+                                        # a trained bias can fold it in
 
     @property
     def sows(self) -> bool:
         """Whether a layer of this spec sows a loss term or counters."""
-        return self.attention == "indexed" or self.experts_per_token > 0
+        return (self.attention == "indexed" or self.experts_per_token > 0
+                or self.ssm_heads > 0)
 
 
 @dataclass
@@ -293,10 +315,17 @@ class LMCfg:
                                         # adapters (+head) update
     lora_alpha: float = 16.0
     lora_targets: tuple[str, ...] = ("query", "value")
-    pos_encoding: str = "learned"       # "learned" absolute table or "rope"
+    pos_encoding: str = "learned"       # "learned" absolute table, "rope"
                                         # rotary relative positions
                                         # (ddw_tpu.ops.rope — extrapolates
                                         # past max_len, SP/decode-composable)
+                                        # or "none" (the position lives in
+                                        # the pattern's state-space layers)
+    pattern: str = ""                   # one mixer a layer, a character each:
+                                        # "M" Mamba-2, "E" experts, "*"
+                                        # attention (h + mixer(norm(h)));
+                                        # depth = its length. "": every layer
+                                        # an attention and an MLP
     remat: str = "none"                 # per-block activation remat: "full"
                                         # (keep nothing; recompute block in
                                         # bwd) or "dots" (keep matmul outputs)
