@@ -668,14 +668,17 @@ class TransformerLM(nn.Module):
             # (ops/indexed_attention.py names them): the choice, one byte a
             # pair, and what the attention under it gave, so that neither is
             # made a second time; and of a layer that routes (models/moe.py)
-            # the choice of experts where the router names it (the sigmoid
-            # router: models/moe.py::chosen), which the backward pass must
-            # not make otherwise, and the experts' first products, whose rows
-            # lie in that choice's order and whose time follows the routing;
-            # nothing where no layer chooses or routes
+            # the experts' first products, whose time follows the routing,
+            # written in the chunks of the dispatch that ran; the sort their
+            # rows lie in (order, slots and group sizes, int32), so that the
+            # backward pass runs those chunks and lays its rows against the
+            # kept product whatever a second making of the router would say;
+            # and the choice of experts where the router names it (the
+            # sigmoid router: models/moe.py::chosen), which the gates are
+            # gathered by; nothing where no layer chooses or routes
             policy = (jax.checkpoint_policies.save_only_these_names(
                           "key_mask", "attention_out", "expert_choice",
-                          "expert_hidden")
+                          "expert_sort", "expert_hidden")
                       if self.remat == "full"
                       else jax.checkpoint_policies.checkpoint_dots)
             remat = lambda block: nn.remat(                # noqa: E731

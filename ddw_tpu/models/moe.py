@@ -38,6 +38,7 @@ Lepikhin et al. 2006.16668):
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -67,11 +68,14 @@ def chosen(ranked: jnp.ndarray, k: int) -> jnp.ndarray:
     rematerialised whole keeps them (``models/lm.py``'s ``remat="full"``): a
     choice is not continuous in the scores, and a backward pass that made it
     again could, where two scores all but tie and the compiler rounds the
-    second making otherwise, sort its rows by another choice than the one the
-    kept ``expert_hidden`` lies in (v5e, 16,384 tokens, 4 layers of 128
-    experts top-6: the first step of two seeds in some thirty, and that
-    layer's experts' gradient then missed by its own length; PERF.md section
-    6, PR 34)."""
+    second making otherwise, choose another expert than the forward pass did.
+    While the sort was made again from it, that laid the backward pass's rows
+    one off against the kept ``expert_hidden`` (v5e, 16,384 tokens, 4 layers
+    of 128 experts top-6: the first step of two seeds in some thirty, and
+    that layer's experts' gradient then missed by its own length; PERF.md
+    section 6, PR 34). Since PR 36 the sort itself is kept
+    (:func:`sort_by_expert`); the kept choice still says which score a row's
+    gate is."""
     _, top_i = lax.top_k(lax.stop_gradient(ranked), k)
     return checkpoint_name(top_i, "expert_choice")
 
@@ -80,11 +84,12 @@ def route_topk(gate_logits: jnp.ndarray, k: int, normalise: bool):
     """Softmax over every expert the router scores, then the ``k`` largest:
     ``(weights [T, k], experts [T, k], probs [T, E])``, weights renormalised
     to sum to one where asked. Float32 throughout. This choice is NOT kept
-    across a block's rematerialisation as :func:`chosen`'s is, though it is
-    open to the same fault: kept, with the weights gathered by it, the one
-    benchmark cell that routes so lost 1.0 % of its rate for no reason found
-    yet, and none of its seeds has shown the fault (PERF.md section 7, PR
-    34)."""
+    across a block's rematerialisation as :func:`chosen`'s is: kept, with
+    the weights gathered by it, the one benchmark cell that routes so lost
+    1.0 % of its rate for no reason found yet (PERF.md section 7, PR 34).
+    The rows' order is kept (:func:`sort_by_expert`), so a second making
+    that differs at a near tie costs one row its gate's last digits and no
+    longer the layer its gradient."""
     probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     top_p, top_i = lax.top_k(probs, k)
     if normalise:
@@ -233,80 +238,25 @@ def sort_by_expert(local: jnp.ndarray, held: int):
     or anything outside ``[0, held)`` for one that is not to be run here (an
     expert that lives elsewhere, an assignment past capacity). Returns
     ``order [T*k]`` (assignments sorted by held expert, the others last),
-    ``slot [T, k]`` (where each assignment landed in that order),
-    ``group_sizes [held]``, ``valid [T*k]`` (rows of the order that hold an
-    assignment to a held expert) and ``expert [T*k]`` (the held expert of a
-    valid row)."""
+    ``slot [T*k]`` (where each assignment landed in that order: the inverse
+    permutation, flat, since a ``[T, k]`` array of int32 is stored ``k`` to a
+    tile's 128 lanes) and ``group_sizes [held]``, so that the rows of the
+    order that hold an assignment to a held expert are the first
+    ``sum(group_sizes)``. Named ``expert_sort``: a block rematerialised whole
+    keeps the three beside the first product whose rows lie in that order
+    (``models/lm.py``'s ``remat="full"``; 0.8 MB a layer at 16,384 tokens
+    top-6), and its backward pass runs the chunks the forward pass ran
+    whatever a second making of the router would say."""
     flat = local.reshape(-1)
     here = (flat >= 0) & (flat < held)
     key = jnp.where(here, flat, held)
     order = jnp.argsort(key, stable=True)
     slot = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(local.shape)
+        jnp.arange(order.shape[0], dtype=order.dtype))
     group_sizes = jnp.sum(
         key[:, None] == jnp.arange(held, dtype=key.dtype)[None], axis=0,
         dtype=jnp.int32)
-    valid = jnp.arange(order.shape[0]) < jnp.sum(group_sizes)
-    return order, slot, group_sizes, valid, jnp.minimum(key[order], held - 1)
-
-
-@jax.custom_vjp
-def rows_in_order(x, order, slot):
-    """``x [T, D]`` -> the ``k * T`` rows of the dispatch buffer, row ``r`` the
-    token of assignment ``order[r]``. ``slot [T, k]`` is the inverse: where
-    each assignment landed. Both ways are gathers: the cotangent of a token is
-    the sum of its ``k`` rows, read through ``slot`` — no scatter-add, whose
-    time on a TPU follows the routing."""
-    return x[order // slot.shape[1]]
-
-
-def _rows_fwd(x, order, slot):
-    return rows_in_order(x, order, slot), slot
-
-
-def _rows_bwd(slot, g):
-    return jnp.sum(g[slot], axis=1), None, None
-
-
-rows_in_order.defvjp(_rows_fwd, _rows_bwd)
-
-
-@jax.custom_vjp
-def rows_by_token(y, order, slot):
-    """``y [k * T, D]`` (buffer order) -> ``[T, k, D]``, each token's ``k``
-    rows. The inverse permutation of :func:`rows_in_order`, and like it a
-    gather both ways."""
-    return y[slot]
-
-
-def _slots_fwd(y, order, slot):
-    return rows_by_token(y, order, slot), order
-
-
-def _slots_bwd(order, g):
-    return g.reshape(-1, g.shape[-1])[order], None, None
-
-
-rows_by_token.defvjp(_slots_fwd, _slots_bwd)
-
-
-@jax.custom_vjp
-def gates_in_order(weights, order, slot):
-    """``weights [T, k]`` -> ``[k * T, 1]``, row ``r`` the gate of assignment
-    ``order[r]``: :func:`rows_by_token`'s permutation the other way, a gather
-    both ways."""
-    return weights.reshape(-1, 1)[order]
-
-
-def _gates_fwd(weights, order, slot):
-    return gates_in_order(weights, order, slot), slot
-
-
-def _gates_bwd(slot, g):
-    return g[slot][..., 0], None, None
-
-
-gates_in_order.defvjp(_gates_fwd, _gates_bwd)
+    return checkpoint_name((order, slot, group_sizes), "expert_sort")
 
 
 GROUPED_TILE = 512
@@ -324,67 +274,261 @@ def grouped_pad(width: int) -> int:
     return pad if 0 < pad <= width // 8 else 0
 
 
+def chunk_rows(rows: int, held: int, width: int) -> int:
+    """Rows a chunk of the dispatch takes (:func:`grouped_experts`), from
+    shapes alone: twice the load ``rows * held / width`` a router that is
+    indifferent between its ``width`` experts puts on the ``held`` ones, in
+    whole tiles of the grouped products, and the whole buffer at most (a
+    layer that holds every expert its router scores runs one chunk)."""
+    twice = -(-2 * rows * held // width)
+    return min(rows, -(-twice // GROUPED_TILE) * GROUPED_TILE)
+
+
+def expert_act(h, act: str):
+    """An expert's activation on its first product's rows: gated
+    (``swiglu``: the gate's half times the up projection's), squared
+    (``relu2``) or ``gelu``."""
+    if act == "swiglu":
+        h_gate, h_up = jnp.split(h, 2, axis=-1)
+        return nn.silu(h_gate) * h_up
+    if act == "relu2":
+        return jnp.square(nn.relu(h))
+    return nn.gelu(h)
+
+
+def _chunk(i, rows, order, group_sizes):
+    """Chunk ``i`` of the sorted order, rows ``[i * rows, (i + 1) * rows)``:
+    the assignments there (``order``'s entries), the groups' sizes clipped to
+    that window, which rows hold an assignment to a held expert ``[rows, 1]``,
+    and each such row's held expert."""
+    lo = i * rows
+    ends = jnp.cumsum(group_sizes)
+    sizes = (jnp.clip(ends, lo, lo + rows)
+             - jnp.clip(ends - group_sizes, lo, lo + rows))
+    at = lo + jnp.arange(rows, dtype=ends.dtype)
+    expert = jnp.minimum(jnp.sum(at[:, None] >= ends[None], axis=1),
+                         group_sizes.shape[0] - 1)
+    return (lax.dynamic_slice_in_dim(order, lo, rows), sizes,
+            (at < ends[-1])[:, None], expert)
+
+
+def _chunks(order, group_sizes, rows):
+    """``order`` padded to whole chunks of ``rows``, and how many chunks hold
+    an assignment to a held expert: the loops' trip count, read on the
+    device."""
+    pad = -order.shape[0] % rows
+    return jnp.pad(order, (0, pad)), -(-jnp.sum(group_sizes) // rows)
+
+
+SCATTERED_ROW = 2
+
+
+def _to_tokens(buf, order, slot, chunks, rows, k):
+    """The buffer's rows back in token order, ``[T, D]``: each token the sum
+    of its ``k`` rows, a row no chunk wrote being zero. Two ways, chosen on
+    the device by how much of the buffer ran: a gather through ``slot`` over
+    all ``k * T`` slots, whose time is the buffer's whatever the routing, or
+    a scatter-add of the chunks that ran, whose time follows the routing and
+    which costs :data:`SCATTERED_ROW` gathered slots a row (v5e, rows of
+    2,688 and 2,048 bfloat16: 0.32 us a scattered row, 0.155 a gathered
+    slot; my chip runs, PR 36): the scatter-add while under half the buffer
+    ran."""
+    def by_slot(buf):
+        return jnp.sum(buf[slot.reshape(-1, k)], axis=1)
+
+    def by_row(buf):
+        def add(i, acc):
+            picked = lax.dynamic_slice_in_dim(order, i * rows, rows)
+            return acc.at[picked // k].add(lax.dynamic_slice_in_dim(
+                buf, i * rows, rows).astype(jnp.float32))
+
+        return lax.fori_loop(0, chunks, add, jnp.zeros(
+            (slot.shape[0] // k, buf.shape[1]), jnp.float32)).astype(buf.dtype)
+
+    return lax.cond(SCATTERED_ROW * chunks * rows < slot.shape[0], by_row,
+                    by_slot, buf)
+
+
+def _dispatch_fwd_loop(act, rows, keep, xt, gates, w_first, w_down, b_in,
+                       b_down, order, slot, group_sizes):
+    k = gates.shape[1]
+    order, chunks = _chunks(order, group_sizes, rows)
+    flat_gates = gates.reshape(-1, 1)
+
+    def body(i, carry):
+        picked, sizes, mask, expert = _chunk(i, rows, order, group_sizes)
+        # rows past the assignments to run are whatever the gather left
+        # there: selected away on both sides of the products, never
+        # multiplied, so nothing they hold reaches a sum or a gradient
+        x = jnp.where(mask, xt[picked // k], 0)
+        h = jnp.where(mask, lax.ragged_dot(x, w_first, sizes), 0)
+        if keep:
+            carry = (carry[0], lax.dynamic_update_slice_in_dim(
+                carry[1], h, i * rows, 0))
+        if b_in is not None:
+            h = h + b_in[expert]
+        gate = jnp.where(mask, flat_gates[picked], 0)
+        y = lax.ragged_dot(jnp.where(mask, expert_act(h, act) * gate, 0),
+                           w_down, sizes)
+        if b_down is not None:
+            y = y + gate * b_down[expert]
+        y = jnp.where(mask, y, 0)
+        return (lax.dynamic_update_slice_in_dim(carry[0], y, i * rows, 0),
+                *carry[1:])
+
+    init = (jnp.zeros((order.shape[0], xt.shape[1]), xt.dtype),)
+    if keep:
+        init += (jnp.zeros((order.shape[0], w_first.shape[-1]), xt.dtype),)
+    y, *hidden = lax.fori_loop(0, chunks, body, init)
+    return (_to_tokens(y, order, slot, chunks, rows, k), chunks), hidden
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _dispatch(act, rows, xt, gates, w_first, w_down, b_in, b_down, order,
+              slot, group_sizes):
+    """:func:`grouped_experts` behind its sort: ``(out [T, D], chunks run)``.
+    One ``custom_vjp``, because a loop whose trip count is read on the device
+    has no reverse-mode rule: the backward pass is the same loop over the
+    same chunks."""
+    return _dispatch_fwd_loop(act, rows, False, xt, gates, w_first, w_down,
+                              b_in, b_down, order, slot, group_sizes)[0]
+
+
+def _dispatch_fwd(act, rows, xt, gates, w_first, w_down, b_in, b_down, order,
+                  slot, group_sizes):
+    out, (hidden,) = _dispatch_fwd_loop(
+        act, rows, True, xt, gates, w_first, w_down, b_in, b_down, order,
+        slot, group_sizes)
+    # a rematerialised block keeps the first product's rows, written in the
+    # chunks that ran and read in no other
+    hidden = checkpoint_name(hidden, "expert_hidden")
+    return out, (xt, gates, w_first, w_down, b_in, b_down, order, slot,
+                 group_sizes, hidden)
+
+
+def _dispatch_bwd(act, rows, res, cotangents):
+    (xt, gates, w_first, w_down, b_in, b_down, order, slot, group_sizes,
+     hidden) = res
+    g = cotangents[0]
+    k = gates.shape[1]
+    order, chunks = _chunks(order, group_sizes, rows)
+    flat_gates = gates.reshape(-1, 1)
+    held = group_sizes.shape[0]
+
+    def product_vjp(x, w, sizes, ct):
+        # the two products of the backward pass for one of the forward's
+        d_x, = jax.linear_transpose(
+            lambda x_: lax.ragged_dot(x_, w, sizes), x)(ct)
+        d_w, = jax.linear_transpose(
+            lambda w_: lax.ragged_dot(x, w_, sizes), w)(ct)
+        return d_x, d_w.astype(jnp.float32)
+
+    def by_expert(rows_, expert):
+        return jnp.zeros((held, rows_.shape[1]), jnp.float32).at[expert].add(
+            rows_.astype(jnp.float32))
+
+    def body(i, carry):
+        d_rows, d_gate, d_first, d_down, d_b_in, d_b_down = carry
+        picked, sizes, mask, expert = _chunk(i, rows, order, group_sizes)
+        token = picked // k
+        x = jnp.where(mask, xt[token], 0)
+        g_y = jnp.where(mask, g[token], 0)
+        h = lax.dynamic_slice_in_dim(hidden, i * rows, rows)
+        if b_in is not None:
+            h = h + b_in[expert]
+        a, act_vjp = jax.vjp(lambda h_: expert_act(h_, act), h)
+        gate = jnp.where(mask, flat_gates[picked], 0)
+        g_gated, d_w = product_vjp(a * gate, w_down, sizes, g_y)
+        g_gated = jnp.where(mask, g_gated, 0)
+        d_down = d_down + d_w
+        g_gate = jnp.sum((g_gated * a).astype(jnp.float32), axis=1)
+        if b_down is not None:
+            g_gate = g_gate + jnp.sum(
+                (g_y * b_down[expert]).astype(jnp.float32), axis=1)
+            d_b_down = d_b_down + by_expert(g_y * gate, expert)
+        g_h, = act_vjp(g_gated * gate)
+        if b_in is not None:
+            d_b_in = d_b_in + by_expert(g_h, expert)
+        g_x, d_w = product_vjp(x, w_first, sizes, g_h)
+        d_first = d_first + d_w
+        return (lax.dynamic_update_slice_in_dim(
+                    d_rows, jnp.where(mask, g_x, 0), i * rows, 0),
+                lax.dynamic_update_slice_in_dim(
+                    d_gate, g_gate.astype(d_gate.dtype), i * rows, 0),
+                d_first, d_down, d_b_in, d_b_down)
+
+    zeros = lambda like: (None if like is None           # noqa: E731
+                          else jnp.zeros(like.shape, jnp.float32))
+    d_rows, d_gate, *d_leaves = lax.fori_loop(0, chunks, body, (
+        jnp.zeros((order.shape[0], xt.shape[1]), xt.dtype),
+        jnp.zeros(order.shape, gates.dtype),
+        zeros(w_first), zeros(w_down), zeros(b_in), zeros(b_down)))
+    # a token's cotangent is the sum of its k rows', a gate's its own row's
+    # (read through ``slot``, a row no chunk wrote zero)
+    d_first, d_down, d_b_in, d_b_down = (
+        None if d is None else d.astype(like.dtype)
+        for d, like in zip(d_leaves, (w_first, w_down, b_in, b_down)))
+    return (_to_tokens(d_rows, order, slot, chunks, rows, k),
+            d_gate[slot.reshape(-1, k)], d_first, d_down, d_b_in, d_b_down,
+            None, None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
 def grouped_experts(xt, local, weights, w_in, w_down, act: str, dtype,
-                    b_in=None, b_down=None):
+                    b_in=None, b_down=None, rows: int | None = None):
     """The experts' part of a routed layer without dense dispatch tensors:
     ``xt [T, D]``, ``local [T, k]`` (:func:`sort_by_expert`'s), ``weights
     [T, k]``; ``w_in`` one ``[E, D, F]`` stack (``act="gelu"``, ``"relu2"``: the
     fused first product in its one-matrix form) or the gate's and the up
     projection's (``"swiglu"``), ``w_down [E, F, D]``, optional
     biases ``[E, F]`` / ``[E, D]``. The assignments to run are sorted by
-    expert and go through grouped matrix products (``lax.ragged_dot``) over a
-    buffer of ``k * T`` rows — the worst case, every choice of every token
-    run here — so no routing can drop a token for want of room. Returns
-    ``(out [T, D], group_sizes [E])``.
+    expert and go through grouped matrix products (``lax.ragged_dot``).
+    Returns ``(out [T, D], group_sizes [E], share)``.
+
+    The buffer has ``k * T`` rows — the worst case, every choice of every
+    token run here — so no routing can drop a token for want of room, but the
+    dispatch follows the rows that fell here: everything between the gather
+    in and the gather back runs in chunks of ``rows`` rows (static; the
+    callers take it from their shapes, :func:`chunk_rows`; all ``k * T``
+    where none is given) up to the assigned count, ``ceil(sum(group_sizes) /
+    rows)`` times, a trip count read on the device. The worst case runs
+    every chunk. ``share`` is the rows that ran over ``k * T`` (1.0: the
+    whole buffer). The way back, and a token's cotangent on the way in, are
+    :func:`_to_tokens`': a gather through ``slot`` over all ``k * T`` slots,
+    a slot that was not run reading a zero row, or, while under half the
+    buffer ran, a scatter-add of the chunks that ran.
 
     The grouped products are the one part of a step whose time follows the
     routing (v5e, 16 experts 2048 x 768, 16,384 tokens, forward and backward:
     3.7 ms for every 16,384 assignments), so none is made twice — a
     rematerialised block keeps the first product's output
-    (``expert_hidden``), and a row's gate goes in BEFORE the down product, so
-    that the backward pass asks for no product's output — and the gate's and
-    the up projection's go as one product of twice the width, which runs a
-    sixth faster a row than the two. ``b_in`` is as wide as that product.
-    An ungated expert's hidden width is padded with zero columns to the
-    products' tile (:func:`grouped_pad`): ``act(0) = 0`` meets zero rows of
-    ``w_down``, so nothing changes but the products' rate."""
-    order, slot, group_sizes, valid, expert = sort_by_expert(
-        local, w_down.shape[0])
-    mask = valid[:, None]
+    (``expert_hidden``) and the sort its rows lie in (``expert_sort``), and a
+    row's gate goes in BEFORE the down product, so that the backward pass
+    asks for no product's output — and the gate's and the up projection's go
+    as one product of twice the width, which runs a sixth faster a row than
+    the two. ``b_in`` is as wide as that product. An ungated expert's hidden
+    width is padded with zero columns to the products' tile
+    (:func:`grouped_pad`): ``act(0) = 0`` meets zero rows of ``w_down``, so
+    nothing changes but the products' rate."""
+    order, slot, group_sizes = sort_by_expert(local, w_down.shape[0])
+    rows = min(rows or order.shape[0], order.shape[0])
     pad = grouped_pad(w_down.shape[1]) if len(w_in) == 1 else 0
-    # rows past the assignments to run are whatever the gather left there:
-    # selected away on both sides of the products, never multiplied, so
-    # nothing they hold reaches a sum or a gradient
-    rows = jnp.where(mask, rows_in_order(xt.astype(dtype), order, slot), 0)
     w_first = jnp.concatenate([w.astype(dtype) for w in w_in], axis=-1)
-    if pad:
-        w_first = jnp.pad(w_first, ((0, 0), (0, 0), (0, pad)))
-    h = checkpoint_name(lax.ragged_dot(rows, w_first, group_sizes),
-                        "expert_hidden")
+    w_down = w_down.astype(dtype)
     if b_in is not None:
         b_in = b_in.astype(dtype)
-        h = h + (jnp.pad(b_in, ((0, 0), (0, pad))) if pad else b_in)[expert]
-    if act == "swiglu":
-        h_gate, h_up = jnp.split(h, 2, axis=-1)
-        h = nn.silu(h_gate) * h_up
-    elif act == "relu2":
-        h = jnp.square(nn.relu(h))
-    else:
-        h = nn.gelu(h)
-    gate = jnp.where(mask, gates_in_order(weights.astype(dtype), order, slot),
-                     0)
-    gated = jnp.where(mask, h * gate, 0)
-    w_down = w_down.astype(dtype)
     if pad:
+        w_first = jnp.pad(w_first, ((0, 0), (0, 0), (0, pad)))
         w_down = jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))
-    y = lax.ragged_dot(gated, w_down, group_sizes)
-    if b_down is not None:
-        y = y + gate * b_down.astype(dtype)[expert]
-    y = jnp.where(mask, y, 0)
-    # back to token order: the sum of each token's k slots; a slot that was
-    # not run here reads a zeroed row
-    out = jnp.sum(rows_by_token(y, order, slot), axis=1)
-    return out, group_sizes
+        if b_in is not None:
+            b_in = jnp.pad(b_in, ((0, 0), (0, pad)))
+    out, chunks = _dispatch(
+        act, rows, xt.astype(dtype), weights.astype(dtype), w_first, w_down,
+        b_in, None if b_down is None else b_down.astype(dtype), order, slot,
+        group_sizes)
+    return out, group_sizes, chunks * rows / order.shape[0]
 
 
 class MoEMlp(nn.Module):
@@ -441,7 +585,7 @@ class MoEMlp(nn.Module):
         b2 = self.param("b2", nn.initializers.zeros, (e, d), jnp.float32)
 
         if self.expert_axis is None:
-            out, loads = grouped_experts(
+            out, loads, _ = grouped_experts(
                 xt, jnp.where(keep, experts, -1), weights, [w1], w2, "gelu",
                 self.dtype, b1, b2)
             self.sow("intermediates", "moe_counts",
@@ -571,8 +715,10 @@ class RoutedExperts(nn.Module):
         w_in = [self.param(n, init, (e, d, f), jnp.float32) for n in names]
         w_down = self.param("w_down", init, (e, f, d), jnp.float32)
         with jax.named_scope("experts"):
-            out, loads = grouped_experts(xt, experts - self.offset, weights,
-                                         w_in, w_down, self.act, self.dtype)
+            out, loads, share = grouped_experts(
+                xt, experts - self.offset, weights, w_in, w_down, self.act,
+                self.dtype, rows=chunk_rows(self.k * t, e, width))
+        self.sow("intermediates", "counters", {"moe_rows_run_share": share})
         if self.shared_dim:
             if self.act != "relu2":
                 raise NotImplementedError("the shared expert is written for "

@@ -361,3 +361,112 @@ def test_local_experts_sorted_dispatch_gradients_match_the_dense_tensors(router)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
+
+
+def one_pass_experts(xt, local, weights, w_in, w_down, act, b_in=None,
+                     b_down=None):
+    """The dispatch as it ran before it was chunked, the plain reference:
+    once over all ``k * T`` rows of the sorted order, rows past the assigned
+    count selected to zero on both sides of the products; no padded width,
+    plain gathers, autodiff's own backward pass."""
+    held, k = w_down.shape[0], local.shape[1]
+    flat = local.reshape(-1)
+    key = jnp.where((flat >= 0) & (flat < held), flat, held)
+    order = jnp.argsort(key, stable=True)
+    slot = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0])).reshape(local.shape)
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(held)[None], axis=0,
+                          dtype=jnp.int32)
+    mask = (jnp.arange(order.shape[0]) < jnp.sum(group_sizes))[:, None]
+    expert = jnp.minimum(key[order], held - 1)
+    h = jax.lax.ragged_dot(jnp.where(mask, xt[order // k], 0),
+                           jnp.concatenate(w_in, axis=-1), group_sizes)
+    if b_in is not None:
+        h = h + b_in[expert]
+    if act == "swiglu":
+        h = jax.nn.silu(h[:, :h.shape[1] // 2]) * h[:, h.shape[1] // 2:]
+    else:
+        h = jnp.square(jax.nn.relu(h)) if act == "relu2" else jax.nn.gelu(h)
+    gate = jnp.where(mask, weights.reshape(-1, 1)[order], 0)
+    y = jax.lax.ragged_dot(jnp.where(mask, h * gate, 0), w_down, group_sizes)
+    if b_down is not None:
+        y = y + gate * b_down[expert]
+    return jnp.sum(jnp.where(mask, y, 0)[slot], axis=1)
+
+
+CHUNK, CHOICES, TOKENS = 8, 2, 16           # R, k, T: a buffer of four chunks
+
+
+# the assigned count: nothing, one row, around one chunk's edge, a third
+# chunk begun, and every choice of every token held here (the worst case)
+@pytest.mark.parametrize("count", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1,
+                                   2 * CHUNK + 3, CHOICES * TOKENS])
+@pytest.mark.parametrize("act,width,biases", [("swiglu", 24, False),
+                                              ("relu2", 960, False),
+                                              ("gelu", 24, True)])
+def test_the_chunked_dispatch_is_the_one_pass_form(act, width, biases, count):
+    """``grouped_experts`` runs its buffer in chunks of ``rows`` up to the
+    assigned count and no further (``moe_rows_run_share``: the chunks that
+    ran over ``k * T``), and gives what one pass over all ``k * T`` rows
+    gives: the output and every gradient, for gated experts, for squared ones
+    whose width is padded to the products' tile (960 runs as 1,024) and for
+    ``MoEMlp``'s with biases. The worst case runs every chunk and drops
+    nothing."""
+    from ddw_tpu.models.moe import grouped_experts
+
+    d, e, rows = 16, 3, CHOICES * TOKENS
+    rng = np.random.RandomState(count)
+    local = np.full(rows, -1)
+    local[rng.permutation(rows)[:count]] = rng.randint(0, e, count)
+    local[local < 0] = rng.choice([-1, e, e + 5], rows - count)  # elsewhere
+    local = jnp.asarray(local.reshape(TOKENS, CHOICES))
+    leaf = lambda *shape: jnp.asarray(0.3 * rng.randn(*shape),      # noqa: E731
+                                      jnp.float32)
+    args = {"xt": leaf(TOKENS, d), "weights": jnp.abs(leaf(TOKENS, CHOICES)),
+            "w_in": [leaf(e, d, width) for _ in range(1 + (act == "swiglu"))],
+            "w_down": leaf(e, width, d)}
+    if biases:
+        args.update(b_in=leaf(e, width), b_down=leaf(e, d))
+    probe = leaf(TOKENS, d)
+
+    def chunked(args):
+        out, loads, share = grouped_experts(
+            args["xt"], local, args["weights"], args["w_in"], args["w_down"],
+            act, jnp.float32, args.get("b_in"), args.get("b_down"),
+            rows=CHUNK)
+        return jnp.sum(out * probe), (out, loads, share)
+
+    def one_pass(args):
+        out = one_pass_experts(
+            args["xt"], local, args["weights"], args["w_in"], args["w_down"],
+            act, args.get("b_in"), args.get("b_down"))
+        return jnp.sum(out * probe), out
+
+    (_, (out, loads, share)), got = jax.jit(
+        jax.value_and_grad(chunked, has_aux=True))(args)
+    (_, want_out), want = jax.value_and_grad(one_pass, has_aux=True)(args)
+    assert int(jnp.sum(loads)) == count
+    assert float(share) == -(-count // CHUNK) * CHUNK / rows
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-5)
+    assert sorted(got) == sorted(want)
+    for name in want:
+        for g, w in zip(jax.tree.leaves(got[name]),
+                        jax.tree.leaves(want[name])):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("rows,held,width,want", [
+    (6 * 16384, 8, 128, 12288),      # a sixteenth of the experts: an eighth
+    (8 * 16384, 16, 128, 32768),     # an eighth: a quarter of the buffer
+    (2 * 24, 4, 4, 48),              # every expert held: one chunk, the buffer
+    (3 * 1000, 2, 16, 1024),         # 750 rows, in whole tiles of 512
+])
+def test_a_chunk_is_twice_the_expected_load_in_whole_tiles(rows, held, width,
+                                                           want):
+    """The dispatch's chunk comes from shapes alone: twice what an
+    indifferent router puts on the held experts, rounded up to the grouped
+    products' tile, and never more than the buffer."""
+    from ddw_tpu.models.moe import chunk_rows
+
+    assert chunk_rows(rows, held, width) == want

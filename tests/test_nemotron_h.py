@@ -333,6 +333,15 @@ def test_an_ungated_experts_width_is_padded_to_the_products_tile():
     assert [g.shape for g in grads] == [w1.shape, w2.shape]
 
 
+def count(jaxpr, names) -> int:
+    """How many equations of ``jaxpr``, its sub-programs included, are of a
+    primitive in ``names``."""
+    return sum((eqn.primitive.name in names)
+               + sum(count(sub, names)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 @pytest.mark.parametrize("remat", ["none", "full"])
 def test_a_layer_chooses_its_experts_once_a_step(both, remat):
     """A block rematerialised whole keeps its choice of experts beside the
@@ -348,16 +357,42 @@ def test_a_layer_chooses_its_experts_once_a_step(both, remat):
                              both["inputs"], train=True)
         return lm_loss(logits, both["targets"])
 
-    def count(jaxpr, names) -> int:
-        return sum((eqn.primitive.name in names)
-                   + sum(count(sub, names)
-                         for sub in jax.core.jaxprs_in_params(eqn.params))
-                   for eqn in jaxpr.eqns)
-
     jaxpr = jax.make_jaxpr(jax.grad(loss))(both["params"]).jaxpr
     layers = CONFIG["hybrid_override_pattern"].count("E")
     assert count(jaxpr, ("top_k",)) == layers
     assert count(jaxpr, ("ragged_dot", "ragged_dot_general")) == 6 * layers
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def test_a_layer_sorts_its_assignments_once_a_step(small, score):
+    """A block rematerialised whole keeps the sort beside the first product
+    (``expert_sort``, ``expert_hidden``), whichever router chose: the
+    backward pass runs the chunks of the dispatch the forward pass ran and
+    lays its rows against the kept product whatever a second making of the
+    router would say. So the gradient's program holds one ``sort`` a routed
+    layer, and the dispatch's loops of two passes, forward and backward (the
+    chunks' and the scatter-add's of the way back, each), not of three."""
+    import dataclasses
+
+    cfg = family._lm_cfg(small["config"], {"remat": "full"})
+    if score == "softmax":
+        cfg = dataclasses.replace(cfg, layer=dataclasses.replace(
+            cfg.layer, router_score="softmax", router_bias_rate=0.0))
+    model = build_lm(cfg)
+    variables = {"params": small["params"]}
+    if score == "sigmoid":
+        variables["buffers"] = small["buffers"]
+
+    def loss(params):
+        logits = model.apply(dict(variables, params=params), small["inputs"],
+                             train=True)
+        return lm_loss(logits, small["targets"])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(small["params"]).jaxpr
+    layers = small["config"]["hybrid_override_pattern"].count("E")
+    assert count(jaxpr, ("sort",)) == layers
+    assert count(jaxpr, ("while",)) == 4 * layers
+    assert count(jaxpr, ("top_k",)) == (1 if score == "sigmoid" else 2) * layers
 
 
 # -- the reference's own plumbing ----------------------------------------------
