@@ -156,8 +156,9 @@ class ChoiceProbe:
     ``reference_batch``. From the third step on calls only pass through, and
     the loop never sees the extra entry."""
 
-    def __init__(self, inner, keep_gradient=None):
+    def __init__(self, inner, keep_gradient=None, start_bias=None):
         self._inner, self._keep_gradient = inner, keep_gradient
+        self._start_bias = start_bias   # [E layers, width] or None
         self._calls = 0
 
     def __getattr__(self, name):        # batch_sharding, place_state, ...
@@ -173,6 +174,9 @@ class ChoiceProbe:
             self._keep_gradient(state.opt_state)
         self._calls += 1
         rows = np.asarray(inputs) if follow else None
+        if self._calls == 1 and self._start_bias is not None:
+            state = state.replace(batch_stats=place_bias(
+                state.batch_stats, self._start_bias))
         state, metrics = self._inner(state, inputs, *rest)
         chosen = metrics.pop("handed")["expert_choice"]
         if follow:
@@ -180,6 +184,60 @@ class ChoiceProbe:
                 attach_choices(rows, chosen.reshape(
                     chosen.shape[0], *rows.shape, chosen.shape[-1])))
         return state, metrics
+
+
+def place_bias(buffers, bias):
+    """The program's buffers with routed layer ``j``'s ``router_bias`` set to
+    ``bias[j]`` (``j`` counts the buffers' blocks by their number in the
+    backbone, which is the pattern's order), each leaf where and as the
+    program keeps it."""
+    import jax
+
+    blocks = sorted(buffers, key=lambda name: int(name.rpartition("block")[2]))
+    if len(blocks) != len(bias):
+        raise ValueError(f"the program keeps {len(blocks)} correction biases, "
+                         f"the start has {len(bias)}")
+
+    def put(path, leaf):
+        own = bias[blocks.index(path[0].key)]
+        return jax.device_put(own.reshape(leaf.shape).astype(leaf.dtype),
+                              leaf.sharding)
+
+    return jax.tree_util.tree_map_with_path(put, buffers)
+
+
+def start_bias(config: dict, traffic: dict, seed: int, devices: list):
+    """The correction biases the cell starts from, ``[E layers, width]`` on
+    the host, or None where the configuration asks for none
+    (``router_bias_start``): found by the reference at the seeded weights on
+    the corpus's first training batch (``nemotron_h.balanced_bias``), handed
+    to the program before step 1 (``ChoiceProbe``) and to the reference's
+    ``choice_margins``. Runs, and frees what it made, before the trainer is
+    built."""
+    if config.get("router_bias_start", "zeros") != "balanced":
+        return None
+    import time
+
+    import jax
+    import numpy as np
+
+    from benchmark.harness.weights import seed_key, seeded_weights
+    from benchmark.reference import nemotron_h
+
+    t0 = time.time()
+    # the rows ``prepare`` writes first into the training table (it is handed
+    # the seed as ``harness/train_cell.run`` cuts it)
+    rows = traffic["batch_per_chip"] * len(devices)
+    corpus = _corpus(config, traffic, int(seed) % (2 ** 31 - 1), rows)
+    tokens = np.asarray(corpus[rows:2 * rows, :-1], np.int32)
+    spec = reference_spec(config)
+    bias = np.asarray(jax.jit(lambda key, x: nemotron_h.balanced_bias(
+        seeded_weights(key, spec), x, config))(seed_key(seed), tokens))
+    print(f"router_bias_start balanced: {bias.shape[0]} layers on "
+          f"{tokens.size} tokens, range by layer "
+          f"{[round(float(b.max() - b.min()), 4) for b in bias]}, took "
+          f"{time.time() - t0:.1f} s", flush=True)
+    return bias
 
 
 def reference_batch(batch: tuple):
@@ -193,10 +251,11 @@ def reference_batch(batch: tuple):
     return np.asarray(inputs, np.int32), np.asarray(batch[1], np.int32)
 
 
-def choice_margins(config: dict, traffic: dict, seed: int, rows) -> dict:
+def choice_margins(config: dict, traffic: dict, seed: int, rows,
+                   bias=None) -> dict:
     """The choices read on the first step's rows against the reference's own
-    float32 scores at the seeded weights: ``nemotron_h.choice_margins``'s two
-    numbers."""
+    float32 scores at the seeded weights under the start's correction biases:
+    ``nemotron_h.choice_margins``'s two numbers."""
     import jax
 
     from benchmark.harness.weights import seed_key, seeded_weights
@@ -204,8 +263,8 @@ def choice_margins(config: dict, traffic: dict, seed: int, rows) -> dict:
 
     spec = reference_spec(config)
     weights = jax.jit(lambda key: seeded_weights(key, spec))(seed_key(seed))
-    margins = jax.jit(lambda w, x: nemotron_h.choice_margins(
-        w, x, traffic["seq_len"], config))(weights, rows)
+    margins = jax.jit(lambda w, x, b: nemotron_h.choice_margins(
+        w, x, traffic["seq_len"], config, b))(weights, rows, bias)
     return {k: float(v) for k, v in margins.items()}
 
 
@@ -244,6 +303,12 @@ def _lm_cfg(config: dict, traffic: dict):
                  pattern=config["hybrid_override_pattern"])
 
 
+def _corpus(config: dict, traffic: dict, seed: int, global_batch: int):
+    """One validation batch, then ``steps_per_epoch`` training batches."""
+    return make_corpus(seed, (traffic["steps_per_epoch"] + 1) * global_batch,
+                       traffic["seq_len"], config["vocab_size"])
+
+
 def prepare(config: dict, traffic: dict, seed: int, work: str, devices: list):
     from ddw_tpu.data.prep import write_token_table
     from ddw_tpu.data.store import TableStore
@@ -253,8 +318,7 @@ def prepare(config: dict, traffic: dict, seed: int, work: str, devices: list):
     seq = traffic["seq_len"]
     global_batch = traffic["batch_per_chip"] * len(devices)
     spe = traffic["steps_per_epoch"]
-    corpus = make_corpus(seed, (spe + 1) * global_batch, seq,
-                         config["vocab_size"])
+    corpus = _corpus(config, traffic, seed, global_batch)
     store = TableStore(os.path.join(work, "lm_tables"))
     train_tbl = write_token_table(store, "train", corpus[global_batch:])
     val_tbl = write_token_table(store, "val", corpus[:global_batch])
@@ -295,10 +359,13 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     sparse._CHOSEN.clear()
     optim_donating.hold_against(None)
     controls = kw.get("controls", ())
+    traffic = dict(cell.traffic, **(kw.get("tiny") or {}).get("traffic", {}))
+    bias = start_bias(config, traffic, seed, devices)
     setattr(module, STEP_FACTORY[1], lambda *a, **k: ChoiceProbe(
         real(*a, hand_out=("expert_choice",), **k),
         lambda opt_state: optim_donating.hold_against(
-            sparse.first_gradient(opt_state, mapping, spec), bool(controls))))
+            sparse.first_gradient(opt_state, mapping, spec), bool(controls)),
+        bias))
     optim.run_steps = optim_lean.run_steps
     try:
         result = train_cell.run(cell, seed, seconds, trace, t_start, devices,
@@ -309,13 +376,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     ctx = result["ctx"]
     config, traffic = ctx["config"], ctx["traffic"]
     ctx["flops_per_item"] = required_flops_per_item(config, traffic["seq_len"])
-    # a step's time follows the assignments to the held experts: say how
-    # they moved from epoch to epoch (PERF.md section 5)
-    print("counters by epoch " + str([
-        {k: round(r[k], 4) for k in ("moe_assignments_per_token",
-                                     "moe_load_max_over_mean",
-                                     "router_bias_range", "ssm_chunk_carry")
-         if k in r} for r in ctx["rows"]]), flush=True)
+    sparse.print_counters(ctx["rows"], ("router_bias_range",
+                                        "ssm_chunk_carry"))
     nan = float("nan")      # a step without the counters is not correct
     carried = [r.get("ssm_chunk_carry", nan) for r in ctx["rows"]]
     counted = {
@@ -325,7 +387,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         "ssm_chunk_carry_most": sparse._worst(carried)}
     first = next(iter(sparse._CHOSEN.values()), None)   # the first step's rows
     counted.update(
-        choice_margins(config, traffic, seed, first) if first is not None
+        choice_margins(config, traffic, seed, first, bias)
+        if first is not None
         else dict.fromkeys(("expert_choice_margin",
                             "experts_misplaced_share"), nan))
     sparse._CHOSEN.clear()

@@ -357,6 +357,17 @@ def _worst(values) -> float:
             else float("nan"))
 
 
+def print_counters(rows: list, more: tuple = ()):
+    """A step's time follows the assignments to the held experts: say how
+    they, and the family's ``more`` counters, moved from epoch to epoch, in
+    every run (PERF.md section 2; ``tools/epoch_table.py`` reads the line)."""
+    names = ("moe_assignments_per_token", "moe_load_max_over_mean",
+             "moe_rows_run_share") + more
+    print("counters by epoch " + str([
+        {k: round(r[k], 4) for k in names if k in r} for r in rows]),
+        flush=True)
+
+
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         devices: list, peaks: dict | None, **kw) -> dict:
     """``harness/train_cell.run`` with the program's choices on the followed
@@ -403,6 +414,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     ctx = result["ctx"]
     config, traffic = ctx["config"], ctx["traffic"]
     ctx["flops_per_item"] = required_flops_per_item(config, traffic["seq_len"])
+    print_counters(ctx["rows"])
     want = keys_per_query(traffic["seq_len"], config["sa_config"]["topk"])
     nan = float("nan")      # a step without the counters is not correct
     counted = {

@@ -46,9 +46,12 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
 
             tracer = Tracer(capacity=65536, process="bench")
         probes: list = []
+        warm = int(traffic.get("warm_epochs", WARM_EPOCHS))
         clock = EpochClock(seconds, compiles, request_preemption,
                            trace_dir=(work + "/trace") if trace else "",
-                           probe_calls=lambda: probes[0].calls if probes else 0)
+                           probe_calls=lambda: probes[0].calls if probes else 0,
+                           warm_epochs=warm,
+                           window_epochs=int(traffic.get("window_epochs", 0)))
         t_trainer = time.time()
         spec = family.reference_spec(config)
         mapping = family.leaf_map(config)
@@ -62,7 +65,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                 clock.abandon_trace()
                 reset_preemption()
         probe = probes[0]
-        t_first, t_open = clock.ends[0], clock.ends[WARM_EPOCHS - 1]
+        t_first, t_open = clock.ends[0], clock.ends[warm - 1]
         steps_per_epoch = job.steps_per_epoch
         window = clock.window(steps_per_epoch, job.items_per_step)
         program = probe.collect()
@@ -92,7 +95,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         offset = time.time() - time.perf_counter()
         setup_s = (t_open + offset) - t_start
         rows = clock.rows
-        nonfinite = sum(1 for r in rows[WARM_EPOCHS:]
+        nonfinite = sum(1 for r in rows[warm:]
                         if not all(math.isfinite(r[k])
                                    for k in ("loss", "val_loss")))
         print("setup: process start -> tables "
@@ -130,7 +133,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             "tables_s": t_tables - t_start,
             "first_epoch_s": (t_first + offset) - t_trainer,
             "peak_bytes": peak_bytes, "spans": spans, "traced": traced,
-            "record": record, "rows": rows,
+            "record": record, "rows": rows, "warm_epochs": warm,
             "flops_per_item": family.required_flops_per_item(config),
             "peaks": peaks, "steps_per_epoch": steps_per_epoch,
             "reduced": None,        # run.py fills it from ``record``

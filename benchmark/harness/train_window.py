@@ -7,16 +7,24 @@ call marks a moment at which all of the epoch's work is done. ``EpochClock``
 stands in the run's place, stamps those moments, and steers:
 
 - epoch 0 compiles (or loads executables) and epoch 1 runs the same shapes
-  once more, settled; both are set-up. At epoch 1's end the clock collects
-  Python's garbage and freezes what is left (tracing two step programs
-  leaves millions of objects; a full collection striking between two epochs
-  stalls the dispatch, and one run in fourteen read a first window epoch of
-  3.79 s for 2.83 s before this was here);
-- the window opens at epoch 1's end and holds whole epochs until ``seconds``
-  have passed; then the clock asks for the program's graceful stop
-  (``runtime.faults.request_preemption``), which ``fit`` honours at its next
-  dispatch by raising ``Preempted``;
+  once more, settled; both are set-up, and so are further settled epochs
+  where the traffic asks for them (``warm_epochs``, 2 where it says nothing).
+  At the last warm epoch's end the clock collects Python's garbage and
+  freezes what is left (tracing two step programs leaves millions of
+  objects; a full collection striking between two epochs stalls the
+  dispatch, and one run in fourteen read a first window epoch of 3.79 s for
+  2.83 s before this was here);
+- the window opens at the last warm epoch's end and holds whole epochs until
+  ``seconds`` have passed AND it holds ``window_epochs`` of them (0 where the
+  traffic says nothing: the seconds alone decide); then the clock asks for
+  the program's graceful stop (``runtime.faults.request_preemption``), which
+  ``fit`` honours at its next dispatch by raising ``Preempted``;
 - with ``trace_dir`` set, the profiler runs over the window's second epoch.
+
+A cell whose required work drifts as it trains (routed experts on a chip's
+share: the router learns the held experts, PERF.md section 2) takes both keys
+in its traffic file, so that its window holds the same epochs on every seed
+and machine; the other cells' windows are what they were.
 
 Compilations are counted by ``jax.monitoring``; one inside the window makes
 the run incorrect.
@@ -27,8 +35,8 @@ from __future__ import annotations
 import gc
 import time
 
-WARM_EPOCHS = 2     # the compiling epoch and one settled epoch are set-up
-TRACE_EPOCH = WARM_EPOCHS + 1   # the profiler runs over this epoch
+WARM_EPOCHS = 2     # the compiling epoch and one settled epoch are set-up,
+                    # where the traffic does not say ``warm_epochs``
 
 
 class CompileCounter:
@@ -52,7 +60,14 @@ class CompileCounter:
 class EpochClock:
     def __init__(self, seconds: float, compiles: CompileCounter,
                  request_stop, trace_dir: str = "",
-                 probe_calls=lambda: 0):
+                 probe_calls=lambda: 0, warm_epochs: int = WARM_EPOCHS,
+                 window_epochs: int = 0):
+        if warm_epochs < 2:
+            raise ValueError("warm_epochs counts the compiling epoch and at "
+                             f"least one settled one, got {warm_epochs}")
+        self.warm = warm_epochs             # epochs that are set-up
+        self.window_epochs = window_epochs  # the least the window holds
+        self.trace_epoch = warm_epochs + 1  # the profiler runs over this one
         self.seconds = seconds
         self.compiles = compiles
         self.request_stop = request_stop
@@ -72,7 +87,7 @@ class EpochClock:
 
     def log_metrics(self, row, step=None):
         epoch = len(self.ends)
-        if epoch == WARM_EPOCHS - 1:
+        if epoch == self.warm - 1:
             gc.collect()
             gc.freeze()
             self.compiles.armed = True
@@ -80,12 +95,13 @@ class EpochClock:
         self.ends.append(now)
         self.rows.append(dict(row))
         if self.trace_dir:
-            if epoch == TRACE_EPOCH - 1:
+            if epoch == self.trace_epoch - 1:
                 self._start_trace()
-            elif epoch == TRACE_EPOCH:
+            elif epoch == self.trace_epoch:
                 self._stop_trace()
-        if (epoch >= WARM_EPOCHS - 1 and not self._tracing
-                and now - self.ends[WARM_EPOCHS - 1] >= self.seconds
+        if (epoch >= self.warm - 1 and not self._tracing
+                and now - self.ends[self.warm - 1] >= self.seconds
+                and epoch - (self.warm - 1) >= self.window_epochs
                 and (not self.trace_dir or self.traced is not None)):
             self.compiles.armed = False
             self.request_stop()
@@ -122,7 +138,7 @@ class EpochClock:
 
     # the window, once fit has returned
     def window(self, steps_per_epoch: int, items_per_step: int) -> dict:
-        ends = self.ends[WARM_EPOCHS - 1:]
+        ends = self.ends[self.warm - 1:]
         epochs = len(ends) - 1
         if epochs < 1:
             raise RuntimeError("the window holds no whole epoch")

@@ -9,7 +9,8 @@ from benchmark.harness.train_window import WARM_EPOCHS
 
 
 def window_mean(ctx, counter):
-    values = [row[counter] for row in ctx["rows"][WARM_EPOCHS:]
+    values = [row[counter]
+              for row in ctx["rows"][ctx.get("warm_epochs", WARM_EPOCHS):]
               if counter in row]
     return sum(values) / len(values) if values else None
 

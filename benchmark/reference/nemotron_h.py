@@ -56,6 +56,10 @@ from benchmark.reference.keye_vl2 import (kth_largest, shifted_choice,
 from benchmark.reference.matmul import make_einsum
 
 MASKED = -1e30
+# the correction biases at balance (:func:`balance`): rounds of the published
+# rule, the step halved every BALANCE_HOLD rounds from BALANCE_STEP down to
+# BALANCE_STEP / 2**11, far under the distance of neighbouring scores
+BALANCE_STEP, BALANCE_HOLD, BALANCE_ROUNDS = 0.05, 8, 96
 QUERY_BLOCK = 128   # queries scored at a time; no result depends on it
 SCAN_BLOCK = 128    # tokens between kept states of the recurrence; nor on it
 
@@ -192,6 +196,27 @@ def attention(u, p: dict, z: dict, einsum):
     return einsum("se,ed->sd", out, p["wo"])
 
 
+def balance(scores, k: int):
+    """The correction biases ``[width]`` at which every expert of a layer is
+    chosen equally often on these tokens: ``scores [T, width]`` (the router's
+    sigmoids) -> the fixed point of the published rule (arXiv:2412.19437,
+    section 2.1.2: raise the bias of an expert under the mean load, lower it
+    over), the step halved as it goes, mean zero. What a checkpoint that is
+    being continued has learnt over its steps and a seeded state lacks: at no
+    bias the normed state's common component times a random router loads the
+    experts 2-4 times the mean by layer (PERF.md section 7, PR 34 (iii))."""
+    mean = scores.shape[0] * k / scores.shape[1]
+
+    def round_(i, bias):
+        load = jnp.sum(shifted_choice(scores + bias, k, 0), axis=0)
+        step = BALANCE_STEP * 0.5 ** (i // BALANCE_HOLD)
+        return bias + step * jnp.sign(mean - load)
+
+    bias = jax.lax.fori_loop(0, BALANCE_ROUNDS, round_,
+                             jnp.zeros(scores.shape[1], jnp.float32))
+    return bias - jnp.mean(bias)
+
+
 def experts(x, p: dict, z: dict, einsum, held: tuple | None = None,
             given=None, shift: int = 0, bias=None):
     """``x [T, d]`` (normed) -> what the shared expert and the held routed
@@ -239,14 +264,17 @@ def experts(x, p: dict, z: dict, einsum, held: tuple | None = None,
 
 
 def forward(w: dict, tokens, c: dict, precision: str = "f32", choices=None,
-            shift: int = 0, bias=None):
+            shift: int = 0, bias=None, found: list | None = None):
     """Logits ``[B, S, V]``; ``{"expert_choice_margin",
     "experts_misplaced_share"}`` (the worst and the mean over the ``E``
     layers); the choices made ``[E layers, B, S, k]``; the mean over ``M``
     layers of the share of a state that crosses a chunk (the program's
     ``ssm_chunk_carry``); and every ``E`` layer's loads
     ``[E layers, width]``. ``choices`` to follow, else the reference's own off
-    by ``shift`` ranks; ``bias [E layers, width]`` as :func:`experts`."""
+    by ``shift`` ranks; ``bias [E layers, width]`` as :func:`experts`.
+    ``found``: a list that receives every ``E`` layer's bias at balance on
+    these tokens (:func:`balance`), each layer choosing under its own as the
+    pass goes on; ``bias`` is not read then."""
     einsum = make_einsum(precision)
     z = sizes_of(c)
     b, s = tokens.shape
@@ -274,6 +302,11 @@ def forward(w: dict, tokens, c: dict, precision: str = "f32", choices=None,
                      else choices[routed].reshape(b * s, -1))
             own_bias = None if bias is None else bias[routed]
             routed += 1
+            if found is not None:
+                own_bias = balance(jax.nn.sigmoid(einsum(
+                    "td,de->te", rms_norm(h, p["ln.g"], z["eps"]).reshape(
+                        b * s, -1), p["router"])), z["k"])
+                found.append(own_bias)
 
             @jax.checkpoint
             def layer(h, p, given, own_bias):
@@ -313,15 +346,24 @@ def split_choices(inputs, c: dict, seq: int):
     return inputs[:, :seq], jnp.moveaxis(packed, 1, 0)
 
 
-def choice_margins(w: dict, inputs, seq: int, c: dict) -> dict:
+def balanced_bias(w: dict, tokens, c: dict):
+    """``[E layers, width]``: every routed layer's correction biases at
+    balance on ``tokens [B, S]``, layer by layer in one float32 pass."""
+    found: list = []
+    forward(w, tokens, c, "f32", found=found)
+    return jnp.stack(found)
+
+
+def choice_margins(w: dict, inputs, seq: int, c: dict, bias=None) -> dict:
     """The choices ``inputs`` carries against this reference's own float32
-    scores at no bias (the seeded start). ``expert_choice_margin``: the worst
+    scores under the seeded start's ``bias`` (none: zeros).
+    ``expert_choice_margin``: the worst
     misplaced expert, in units of its token's k-th largest score, infinite
     where a choice handed in is none; ``experts_misplaced_share``: the share
     of a token's experts that the reference itself did not choose, a mean
     over tokens and layers."""
     tokens, choices = split_choices(inputs, c, seq)
-    return forward(w, tokens, c, "f32", choices)[1]
+    return forward(w, tokens, c, "f32", choices, bias=bias)[1]
 
 
 def own_choices(w: dict, tokens, c: dict, precision: str = "f32",

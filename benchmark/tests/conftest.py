@@ -3,7 +3,8 @@
 own sizes (``families/<family>.py::TINY``); they are put into the table here,
 for the tests alone."""
 
-from benchmark.families import lm_sparse_moe_train
+from benchmark.families import lm_hybrid_ssm_moe_train, lm_sparse_moe_train
 from benchmark.tests import tiny
 
 tiny.TINY.setdefault("lm_sparse_moe_train", lm_sparse_moe_train.TINY)
+tiny.TINY.setdefault("lm_hybrid_ssm_moe_train", lm_hybrid_ssm_moe_train.TINY)

@@ -1,16 +1,10 @@
 """Family ``lm_hybrid_ssm_moe_train``'s required-FLOP function against hand
-arithmetic at the cell's sizes, and its readers of the layers' counters. The
-family came after ``tiny.py``'s table and ``conftest.py``'s hand-kept list:
-its tiny sizes reach the table from here, as this module is collected, before
-any test of the directory runs."""
+arithmetic at the cell's sizes, and its readers of the layers' counters."""
 
 import pytest
 
 from benchmark.families import lm_hybrid_ssm_moe_train as family
 from benchmark.harness.manifest import ROOT, Cell, load_json, load_manifest
-from benchmark.tests import tiny
-
-tiny.TINY.setdefault("lm_hybrid_ssm_moe_train", family.TINY)
 
 CONFIG = load_json(ROOT + "/benchmark/configs/nemotron-3-nano-30b-a3b.json")
 CELL = "nemotron3nano_train_s8192"

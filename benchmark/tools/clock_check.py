@@ -57,7 +57,6 @@ def report(ctx: dict, read, session: dict) -> dict:
     """The line's content from a traced run's ``ctx`` (``reduced`` filled),
     ``read(metric)`` and the profile's session times."""
     from benchmark.harness import span_clock
-    from benchmark.harness.train_window import TRACE_EPOCH, WARM_EPOCHS
 
     found = span_clock.idle_by_span(ctx)
     if found is None:
@@ -134,7 +133,7 @@ def report(ctx: dict, read, session: dict) -> dict:
         # tracker's report, after the epoch's end was stamped, so the start
         # is in the traced epoch's seconds and the stop in the next one's
         "epoch_s": ctx["window"]["epoch_s"],
-        "traced_epoch_index": TRACE_EPOCH - WARM_EPOCHS,
+        "traced_epoch_index": 1,       # the window's second epoch
         "traced_epoch_spans_ms": traced,
         "spans": len(ctx["spans"]),
     }
