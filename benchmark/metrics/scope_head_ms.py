@@ -1,0 +1,14 @@
+"""Layer: train step, device. Device time a step of the operations under the
+``head`` and ``loss`` scopes: the final norm and the logits product (``head``,
+``models/lm.py::TransformerLM``, ``models/vit.py::ViT``) with the softmax
+cross-entropy over them (``loss``, ``train/lm_step.py``, ``train/step.py``).
+Read by ``scope_time.py`` from the device trace joined with the program's
+``step_scopes`` table (self times, the train step's module only, a mean over
+the chips). Nothing to read where the program recorded no table or nothing ran
+in the scope."""
+
+from benchmark.metrics.scope_time import read_metric
+
+
+def read(ctx):
+    return read_metric(ctx, "scope_head_ms")

@@ -116,31 +116,36 @@ class CausalSelfAttention(nn.Module):
                 return y
             return y + row_lora_delta(x_in, ab[0], ab[1], cn).astype(y.dtype)
 
-        q = with_delta("query", dense("query")(x), x)         # [B, S, H, hd]
-        k = with_delta("key", dense("key", kv_heads)(x), x)   # [B, S, KV, hd]
-        v = with_delta("value", dense("value", kv_heads)(x), x)
-        if spec.qk_norm:
-            # RMSNorm over each head's own dims, one gain shared by the heads
-            q = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
-                           name="q_norm")(q)
-            k = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
-                           name="k_norm")(k)
-        if positions is not None:
-            # RoPE: rotate q/k by ABSOLUTE position before any cache write or
-            # ring hop — scores then depend only on relative distance, so the
-            # cached/ring-shipped K needs no further position plumbing.
-            from ddw_tpu.ops.rope import apply_rope
+        # the projections with their norms and rotary turn: one layer scope
+        # of the compiled step (obs/step_scopes.py), metadata only
+        with jax.named_scope("attn_proj"):
+            q = with_delta("query", dense("query")(x), x)       # [B, S, H, hd]
+            k = with_delta("key", dense("key", kv_heads)(x), x)  # [B,S,KV,hd]
+            v = with_delta("value", dense("value", kv_heads)(x), x)
+            if spec.qk_norm:
+                # RMSNorm over each head's own dims, one gain shared by the
+                # heads
+                q = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                               name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                               name="k_norm")(k)
+            if positions is not None:
+                # RoPE: rotate q/k by ABSOLUTE position before any cache write
+                # or ring hop — scores then depend only on relative distance,
+                # so the cached/ring-shipped K needs no further position
+                # plumbing.
+                from ddw_tpu.ops.rope import apply_rope
 
-            rope_at = positions
-            if spec.mrope_section:
-                # token rows: the position components (temporal, height,
-                # width) of a text token are all its index
-                rope_at = jnp.broadcast_to(
-                    positions, (len(spec.mrope_section), *positions.shape))
-            rope_kw = dict(seq_axis=1, theta=spec.rope_theta,
-                           sections=spec.mrope_section)
-            q = apply_rope(q, rope_at, **rope_kw).astype(self.dtype)
-            k = apply_rope(k, rope_at, **rope_kw).astype(self.dtype)
+                rope_at = positions
+                if spec.mrope_section:
+                    # token rows: the position components (temporal, height,
+                    # width) of a text token are all its index
+                    rope_at = jnp.broadcast_to(
+                        positions, (len(spec.mrope_section), *positions.shape))
+                rope_kw = dict(seq_axis=1, theta=spec.rope_theta,
+                               sections=spec.mrope_section)
+                q = apply_rope(q, rope_at, **rope_kw).astype(self.dtype)
+                k = apply_rope(k, rope_at, **rope_kw).astype(self.dtype)
         if spec.attention not in ("full", "indexed"):
             raise ValueError(f"unknown attention {spec.attention!r}; use "
                              f"'full' or 'indexed'")
@@ -341,8 +346,9 @@ class CausalSelfAttention(nn.Module):
                 # broadcast KV heads to the full head count: the flash/ring
                 # kernels stay head-symmetric (the GQA win here is params,
                 # not compute)
-                k = jnp.repeat(k, groups, axis=2)
-                v = jnp.repeat(v, groups, axis=2)
+                with jax.named_scope("attn_proj"):
+                    k = jnp.repeat(k, groups, axis=2)
+                    v = jnp.repeat(v, groups, axis=2)
             if self.seq_axis is not None:
                 # [B, S, H, hd] -> [B, H, S, hd] for the ring's per-hop kernels
                 qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
@@ -355,13 +361,14 @@ class CausalSelfAttention(nn.Module):
                 # 2.1 ms against 6.87 plain and 9.28 checkpointed XLA), fused
                 # XLA attention below (ops/flash_attention.py has the table).
                 out = flash_mha_seq_major(q, k, v, causal=True)
-        return with_delta(
-            "out",
-            maybe_lora_dense(d, "out", rank=self.lora_rank,
-                             alpha=self.lora_alpha,
-                             targets=self.lora_targets, dtype=self.dtype,
-                             contract_ndim=2, use_bias=spec.bias)(out),
-            out, cn=2)
+        with jax.named_scope("attn_proj"):
+            return with_delta(
+                "out",
+                maybe_lora_dense(d, "out", rank=self.lora_rank,
+                                 alpha=self.lora_alpha,
+                                 targets=self.lora_targets, dtype=self.dtype,
+                                 contract_ndim=2, use_bias=spec.bias)(out),
+                out, cn=2)
 
 
 def routed_experts(spec: LayerSpec, num_held: int, mlp_dim: int, dtype,
@@ -455,14 +462,15 @@ class DecoderBlock(nn.Module):
                     y = y + row_lora_delta(inp, ab[0], ab[1]).astype(y.dtype)
                 return y
 
-            if spec.mlp == "swiglu":
-                h = mlp_dense(d, "down", nn.silu(
-                    mlp_dense(self.mlp_dim, "gate", h))
-                    * mlp_dense(self.mlp_dim, "up", h))
-            else:
-                h = mlp_dense(self.mlp_dim, "fc1", h)
-                h = nn.gelu(h)
-                h = mlp_dense(d, "fc2", h)
+            with jax.named_scope("mlp"):
+                if spec.mlp == "swiglu":
+                    h = mlp_dense(d, "down", nn.silu(
+                        mlp_dense(self.mlp_dim, "gate", h))
+                        * mlp_dense(self.mlp_dim, "up", h))
+                else:
+                    h = mlp_dense(self.mlp_dim, "fc1", h)
+                    h = nn.gelu(h)
+                    h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         return x + h
 
@@ -588,10 +596,11 @@ class TransformerLM(nn.Module):
                 self.layer.head_dim or self.hidden // self.num_heads) % 2:
             raise ValueError("RoPE needs an even head_dim")
         b, s_local = tokens.shape
-        x = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
-                     name="tok_embed")(tokens)
-        if self.layer.embed_scale != 1.0:
-            x = x * self.layer.embed_scale
+        with jax.named_scope("embed"):      # the look-up; positions below
+            x = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
+                         name="tok_embed")(tokens)
+            if self.layer.embed_scale != 1.0:
+                x = x * self.layer.embed_scale
         if self.pos_encoding == "learned":
             pos_table = self.param("pos_embed", nn.initializers.normal(0.02),
                                    (self.max_len, self.hidden), jnp.float32)
@@ -636,12 +645,14 @@ class TransformerLM(nn.Module):
                 # (jnp.take clamps out-of-range rows — harmless, attention
                 # NaN-poisons those rows anyway)
                 rows = offset[:, None] + jnp.arange(s_local)  # [B, S]
-                pos = jnp.take(pos_table, rows, axis=0)       # [B, S, hidden]
-                x = x + pos.astype(self.dtype)
+                with jax.named_scope("embed"):
+                    pos = jnp.take(pos_table, rows, axis=0)   # [B, S, hidden]
+                    x = x + pos.astype(self.dtype)
             else:
-                pos = lax.dynamic_slice_in_dim(pos_table, offset, s_local,
-                                               axis=0)
-                x = x + pos.astype(self.dtype)[None]
+                with jax.named_scope("embed"):
+                    pos = lax.dynamic_slice_in_dim(pos_table, offset, s_local,
+                                                   axis=0)
+                    x = x + pos.astype(self.dtype)[None]
             positions = None
         elif self.pos_encoding == "none":
             positions = None
@@ -723,10 +734,11 @@ class TransformerLM(nn.Module):
                       layer=self.layer,
                       name=f"backbone_block{i}")(x, train, positions,
                                                  **blk_kw)
-        x = layer_norm(self.layer)(x)
-        # vocab head in f32: logits feed a softmax CE, keep full precision
-        return nn.Dense(self.vocab_size, use_bias=self.layer.bias,
-                        dtype=jnp.float32, name="head")(x)
+        with jax.named_scope("head"):
+            x = layer_norm(self.layer)(x)
+            # vocab head in f32: logits feed a softmax CE, keep full precision
+            return nn.Dense(self.vocab_size, use_bias=self.layer.bias,
+                            dtype=jnp.float32, name="head")(x)
 
     @staticmethod
     def frozen_prefixes(freeze_base: bool) -> tuple[str, ...]:

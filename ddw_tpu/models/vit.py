@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ddw_tpu.ops.flash_attention import flash_mha_seq_major
@@ -53,14 +54,17 @@ class FlashMHA(nn.Module):
                                     rank=self.lora_rank, alpha=self.lora_alpha,
                                     targets=self.lora_targets, dtype=self.dtype)
 
-        q = dense("query")(x)   # [B, S, H, hd]
-        k = dense("key")(x)
-        v = dense("value")(x)
+        # layer scopes of the compiled step (obs/step_scopes.py): metadata
+        with jax.named_scope("attn_proj"):
+            q = dense("query")(x)   # [B, S, H, hd]
+            k = dense("key")(x)
+            v = dense("value")(x)
         out = flash_mha_seq_major(q, k, v, causal=False)  # [B, S, H, hd]
-        return maybe_lora_dense(d, "out", rank=self.lora_rank,
-                                alpha=self.lora_alpha,
-                                targets=self.lora_targets, dtype=self.dtype,
-                                contract_ndim=2)(out)
+        with jax.named_scope("attn_proj"):
+            return maybe_lora_dense(d, "out", rank=self.lora_rank,
+                                    alpha=self.lora_alpha,
+                                    targets=self.lora_targets,
+                                    dtype=self.dtype, contract_ndim=2)(out)
 
 
 class MlpBlock(nn.Module):
@@ -82,9 +86,10 @@ class MlpBlock(nn.Module):
                                     targets=self.lora_targets,
                                     dtype=self.dtype)
 
-        h = dense(self.mlp_dim, "fc1")(x)
-        h = nn.gelu(h)
-        return dense(d, "fc2")(h)
+        with jax.named_scope("mlp"):
+            h = dense(self.mlp_dim, "fc1")(x)
+            h = nn.gelu(h)
+            return dense(d, "fc2")(h)
 
 
 class EncoderBlock(nn.Module):
@@ -133,23 +138,29 @@ class ViT(nn.Module):
             from ddw_tpu.models.lora import validate_lora_targets
 
             validate_lora_targets(self.lora_targets)
-        x = x.astype(self.dtype)
-        x = nn.Conv(self.hidden, (self.patch, self.patch), strides=self.patch,
-                    name="backbone_patch_embed", dtype=self.dtype)(x)
-        b, h, w, c = x.shape
-        x = x.reshape(b, h * w, c)
-        pos = self.param("pos_embed", nn.initializers.normal(0.02), (1, h * w, c), jnp.float32)
-        x = x + pos.astype(self.dtype)
+        with jax.named_scope("embed"):      # patches and positions
+            x = x.astype(self.dtype)
+            x = nn.Conv(self.hidden, (self.patch, self.patch),
+                        strides=self.patch, name="backbone_patch_embed",
+                        dtype=self.dtype)(x)
+            b, h, w, c = x.shape
+            x = x.reshape(b, h * w, c)
+            pos = self.param("pos_embed", nn.initializers.normal(0.02),
+                             (1, h * w, c), jnp.float32)
+            x = x + pos.astype(self.dtype)
         for i in range(self.depth):
             x = EncoderBlock(self.num_heads, self.mlp_dim, dtype=self.dtype,
                              lora_rank=self.lora_rank,
                              lora_alpha=self.lora_alpha,
                              lora_targets=self.lora_targets,
                              name=f"backbone_block{i}")(x, train)
-        x = nn.LayerNorm(dtype=jnp.float32)(x)
-        hfeat = jnp.mean(x.astype(jnp.float32), axis=1)
-        hfeat = nn.Dropout(self.dropout, deterministic=not train, name="head_dropout")(hfeat)
-        return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(hfeat)
+        with jax.named_scope("head"):       # final norm, pooling, logits
+            x = nn.LayerNorm(dtype=jnp.float32)(x)
+            hfeat = jnp.mean(x.astype(jnp.float32), axis=1)
+            hfeat = nn.Dropout(self.dropout, deterministic=not train,
+                               name="head_dropout")(hfeat)
+            return nn.Dense(self.num_classes, dtype=jnp.float32,
+                            name="head")(hfeat)
 
     @staticmethod
     def frozen_prefixes(freeze_base: bool) -> tuple[str, ...]:

@@ -10,8 +10,12 @@ The same components each hold a :class:`~ddw_tpu.obs.telemetry.
 TelemetryHub` sampling counters/gauges/latency observations into windowed
 time series (fleet-merged by the gateway), which the
 :class:`~ddw_tpu.obs.slo.SLOMonitor` evaluates into error budgets,
-burn-rate alerts, and degradation forensics dumps. See
-docs/observability.md.
+burn-rate alerts, and degradation forensics dumps.
+
+For the trainers, :mod:`ddw_tpu.obs.step_scopes` reads from the compiled
+step which layer each of its operations belongs to, and the epoch loop
+records that table as one span of a traced fit, so that a device profile can
+be split by the program's own scopes. See docs/observability.md.
 """
 
 from ddw_tpu.obs.slo import (  # noqa: F401

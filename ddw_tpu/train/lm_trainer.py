@@ -470,18 +470,17 @@ class LMTrainer:
 
         step_rng = jax.random.PRNGKey(cfg.seed + 1)
 
-        def dispatch(state, batch, host_step):
+        def step_args(batch, host_step):
             if self.pp:  # the pipeline step is deterministic: no rng
-                return run_step(state, *batch)
+                return batch
             # the loop's host-side step counter: folding the device's into
             # the rng would be a blocking device_get every step
-            return run_step(state, *batch,
-                            jax.random.fold_in(step_rng, host_step))
+            return (*batch, jax.random.fold_in(step_rng, host_step))
 
         return loop.run_epochs(
             cfg=cfg, state=state, sched=sched, plan=plan,
             start_epoch=start_epoch, train_batches=train_batches,
-            val_batches=val_batches, dispatch=dispatch, run_step=run_step,
+            val_batches=val_batches, step_args=step_args, run_step=run_step,
             eval_step=eval_step, ckpt=ckpt, best=best, run=self.run,
             tracer=tracer, setup_id=setup_id, t_fit=t_fit,
             row_extra=row_extra)

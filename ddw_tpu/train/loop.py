@@ -19,6 +19,7 @@ import time
 import jax
 
 from ddw_tpu.checkpoint.ckpt import BestCheckpointKeeper, CheckpointManager
+from ddw_tpu.obs.step_scopes import step_table
 from ddw_tpu.obs.trace import Tracer, chrome_trace, span_lane
 from ddw_tpu.runtime.elastic import maybe_elastic_restart, process_topology
 from ddw_tpu.runtime.faults import Preempted, maybe_fault, preemption_requested
@@ -158,7 +159,7 @@ def log_fit_params(run, sizes: dict, **cfgs) -> None:
 
 # -- the loop -----------------------------------------------------------------
 def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
-               train_batches, val_batches, dispatch, run_step, eval_step,
+               train_batches, val_batches, step_args, run_step, eval_step,
                ckpt, best, run, tracer, setup_id, t_fit: float,
                timed_row=None, row_extra=None, on_epoch=None) -> TrainResult:
     """Epochs ``start_epoch .. cfg.epochs`` of one fit, and all that goes with
@@ -172,10 +173,13 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     ``train_batches(epoch)`` gives an iterator with one item a chain and
     ``val_batches()`` the epoch's validation batches (a loader it builds is
     built on its first ``next``, inside the first ``val_data_wait``);
-    ``dispatch(state, batch, host_step) -> (state, metrics)`` is the
-    trainer's closure over its step or chain and its rng rule, and
-    ``run_step`` that step or chain, of which the loop asks only how many
-    executables it holds at each epoch's end (``step_variants``);
+    ``run_step`` is the trainer's step or chain and ``step_args(batch,
+    host_step)`` what it is called with after the state — the batch and the
+    trainer's rng rule — so ``run_step(state, *step_args(batch, host_step))
+    -> (state, metrics)`` is the dispatch. The loop makes that call itself
+    because, under a tracer, it first asks which layer each operation of that
+    one executable belongs to (the ``step_scopes`` span, below), and at each
+    epoch's end how many executables the step holds (``step_variants``);
     ``eval_step(eval_state, *batch)`` gives ``loss`` and ``accuracy``.
     ``state`` comes placed as ``run_step`` returns it (:func:`place_state`)
     and the loop keeps it so — what it writes into the state (``set_lr``)
@@ -192,11 +196,20 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     checkpoint; a true return stops the fit, an exception leaves it.
 
     ``setup_id`` is the open ``fit_setup`` span, begun at ``t_fit``; it ends
-    where the first chain starts."""
+    where the first chain starts.
+
+    Under a tracer the fit's first chain holds one more span, ``step_scopes``,
+    between its ``data_wait`` and its ``dispatch``: the table of the step's
+    executable (``obs/step_scopes.py``), made from the very arguments of the
+    first dispatch before the step is loaded, so that the table is of the
+    executable that runs and the device never holds two. Its arguments are
+    the table, its length what the table cost. Without a tracer nothing is
+    lowered or built."""
     sp = span_lane(tracer, "train", "train")
     steps_per_epoch = sum(plan)
     chained = any(k > 1 for k in plan)
     step_variants = getattr(run_step, "_cache_size", None)
+    table_due = sp.on       # the step's table: once a fit, traced fits only
     # telemetry plane: a Run wrapped by obs.telemetry.tee_run exposes its
     # hub — chain dispatch and checkpoint-write latencies become windowed
     # dist series beside the serving fleet's (docs/observability.md)
@@ -286,10 +299,18 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                 t_disp = time.monotonic()
                 sp.span("data_wait", t_wait, t_disp, chain_id,
                         args=sp.on and {"step": host_step})
+                rest = step_args(batch, host_step)
+                if table_due:
+                    table_due = False
+                    table = step_table(run_step, (state, *rest))
+                    if table is not None:
+                        t_table, t_disp = t_disp, time.monotonic()
+                        sp.span("step_scopes", t_table, t_disp, chain_id,
+                                args=table)
                 # chained: a [k, B, ...] super-batch through the fused scan
                 # program; metrics come back as [k] per-step arrays — no
                 # per-step host work at all
-                state, metrics = dispatch(state, batch, host_step)
+                state, metrics = run_step(state, *rest)
                 t_end = time.monotonic()
                 # enqueue plus back-pressure from the device queue
                 sp.span("dispatch", t_disp, t_end, chain_id,
@@ -400,7 +421,10 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
             t1 = time.monotonic()
             sp.span("epoch_end", t_cb, t1, epoch_id, end_id)
             # step_variants: the executables jit holds of the step; more
-            # than one means something took the state out of its placement
+            # than one means something took the state out of its placement,
+            # and that the step_scopes table, which is of the first
+            # dispatch's executable, is of one of several (making the table
+            # adds none: it compiles ahead of time, outside jit's own cache)
             sp.span("epoch", t_epoch, t1, span=epoch_id,
                     args=sp.on and {"epoch": epoch, "steps": steps_per_epoch,
                                     "step_variants": step_variants

@@ -271,8 +271,7 @@ class Trainer:
                 # the one infinite stream: an item a chain, epoch after epoch
                 train_batches=lambda epoch: train_iter,
                 val_batches=val_batches,
-                dispatch=lambda state, batch, host_step: run_step(
-                    state, *batch, step_rng),
+                step_args=lambda batch, host_step: (*batch, step_rng),
                 run_step=run_step, eval_step=eval_step, ckpt=ckpt, best=best,
                 run=self.run, tracer=tracer, setup_id=setup_id, t_fit=t_fit,
                 # epoch_seconds is the training part of the epoch
