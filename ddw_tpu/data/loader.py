@@ -16,7 +16,10 @@ codec *is* the cache format), decodes/resizes JPEGs per batch in the native C++
 pipeline (:mod:`ddw_tpu.native.decode` — libjpeg + std::thread pool, one GIL
 release per batch — the tf.data/petastorm worker-pool role), and prefetches
 batches to device HBM on a background thread (double buffering), so the TPU
-never waits on host IO.
+never waits on host IO. That thread belongs to a :class:`LoaderStream`, one
+pass over the loader's batches, and works from the moment the stream is
+opened: a pass that will be asked for later (an epoch's validation batches)
+is opened ahead and found waiting.
 
 Preprocessing is THE shared implementation for training and serving —
 :func:`preprocess_image` is the single decode path ``ddw_tpu.serving`` packages with
@@ -27,6 +30,7 @@ models — deliberately fixing the reference's train/serve skew (tf.image in tra
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -110,7 +114,7 @@ _DEQUANT_JIT = None
 
 def _dequant_jitted():
     """Process-wide jitted dequantize — one compilation shared by every loader
-    iterator (a fresh val-loader per epoch must not re-trace)."""
+    stream (a fresh validation pass per epoch must not re-trace)."""
     global _DEQUANT_JIT
     if _DEQUANT_JIT is None:
         import jax
@@ -159,6 +163,10 @@ class ShardedLoader:
         parameters, reference ``:332-337``). Defaults to 0/1 (single worker).
       num_epochs: None = infinite repeat (training default, reference ``:199-200``);
         an int for finite passes (eval).
+      num_batches: None, or the number of batches after which a pass over
+        the loader ends whatever ``num_epochs`` still holds: a validation
+        pass of ``val_steps`` batches reads, decodes and transfers those and
+        no more. Counted before ``super_batch`` stacks them.
       shuffle: shuffle shard order and a record-level buffer, seeded; epoch-varying.
       drop_remainder: keep shapes static for XLA (always True under jit).
       workers: decode thread pool size (petastorm ``workers_count`` role, ``:200``).
@@ -193,6 +201,7 @@ class ShardedLoader:
         cur_shard: int = 0,
         shard_count: int = 1,
         num_epochs: int | None = None,
+        num_batches: int | None = None,
         shuffle: bool = True,
         seed: int = 0,
         shuffle_buffer: int = 1024,
@@ -230,6 +239,7 @@ class ShardedLoader:
         self.cur_shard = cur_shard
         self.shard_count = shard_count
         self.num_epochs = num_epochs
+        self.num_batches = num_batches
         self.shuffle = shuffle
         self.seed = seed
         self.shuffle_buffer = shuffle_buffer
@@ -403,7 +413,7 @@ class ShardedLoader:
             # Materialized fast path: reinterpret + dequantize, no JPEG work.
             # With a device prefetcher downstream, batches stay uint8 (pure
             # memcpy here; 4x smaller host->device transfer) and the
-            # dequantize runs on device (see __iter__/transfer).
+            # dequantize runs on device (see open/transfer).
             device_side = self.prefetch_to is not None
             buf = np.empty((self.batch_size, self.height, self.width, 3),
                            np.uint8 if device_side else np.float32)
@@ -440,59 +450,56 @@ class ShardedLoader:
                 contents = []
         # drop remainder: static shapes for XLA
 
-    def __iter__(self):
-        """Yield batches; when ``prefetch_to`` is set, a background thread runs the
-        host pipeline + device transfer ``prefetch`` batches ahead."""
-        if self.prefetch_to is None:
-            yield from self._iter_batches()
-            return
+    def _host_batches(self) -> Iterator[tuple[np.ndarray, np.ndarray]]:
+        """The host pipeline's batches, ``num_batches`` of them at most."""
+        return itertools.islice(self._iter_batches(), self.num_batches)
 
+    def __iter__(self):
+        """The batches: with ``prefetch_to`` the stream of :meth:`open`, at
+        work from this call; without it the host pipeline, which does its
+        work as it is asked. The host-only path stays lazy and threadless
+        because its users iterate it in place and often leave early without
+        a close (``tools/loader_bench.py``'s host leg, the loader's tests,
+        ``islice`` over an endless loader): only a caller that wants the
+        host's batches made AHEAD, as the epoch loop's validation does, asks
+        for :meth:`open`."""
+        if self.prefetch_to is None:
+            return self._host_batches()
+        return self.open()
+
+    def open(self) -> "LoaderStream":
+        """A new pass over the loader's batches whose producer thread is at
+        work when this returns: it runs the host pipeline and the transfer
+        until ``prefetch`` batches wait in its queue, rests there, and ends
+        with the batches (``num_batches``, or the records). Without
+        ``prefetch_to`` the queue holds the host's batches as they are."""
         import jax
 
-        q: queue.Queue = queue.Queue(maxsize=self.prefetch)
-        stop = threading.Event()
-        _SENTINEL = object()
+        transfer = None
+        if self.prefetch_to is not None:
+            multihost = jax.process_count() > 1
+            # raw_u8 tables arrive as uint8 (4x smaller transfer); dequantize
+            # on device — one process-wide compilation (_dequant_jitted).
+            dequant = _dequant_jitted() if self._raw_u8 else None
 
-        multihost = jax.process_count() > 1
-        # raw_u8 tables arrive as uint8 (4x smaller transfer); dequantize on
-        # device — one process-wide compilation (_dequant_jitted).
-        dequant = _dequant_jitted() if self._raw_u8 else None
+            def transfer(imgs, lbls):
+                if multihost:
+                    # Per-host local batches assemble into one global sharded
+                    # array (global batch = local batch * process_count along
+                    # dim 0).
+                    imgs = jax.make_array_from_process_local_data(
+                        self.prefetch_to, imgs)
+                    lbls = jax.make_array_from_process_local_data(
+                        self.prefetch_to, lbls)
+                else:
+                    imgs, lbls = jax.device_put((imgs, lbls),
+                                                self.prefetch_to)
+                if dequant is not None:
+                    imgs = dequant(imgs)
+                return imgs, lbls
 
-        def transfer(imgs, lbls):
-            if multihost:
-                # Per-host local batches assemble into one global sharded array
-                # (global batch = local batch * process_count along dim 0).
-                imgs = jax.make_array_from_process_local_data(self.prefetch_to, imgs)
-                lbls = jax.make_array_from_process_local_data(self.prefetch_to, lbls)
-            else:
-                imgs, lbls = jax.device_put((imgs, lbls), self.prefetch_to)
-            if dequant is not None:
-                imgs = dequant(imgs)
-            return imgs, lbls
-
-        sp = span_lane(self.tracer, "data", "loader")
-
-        def put_or_stop(item) -> bool:
-            # Never block forever on a full queue: an abandoned consumer (e.g. the
-            # trainer dropping a val iterator after val_steps) sets `stop`; re-check
-            # it between bounded put attempts so the thread can exit.
-            try:
-                q.put_nowait(item)
-                return True
-            except queue.Full:
-                t_full = time.monotonic()
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    sp.span("loader_blocked", t_full, time.monotonic())
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        plan = self._super_plan
         stack_fn = None
-        if plan is not None:
+        if self._super_plan is not None:
             # Device-side super-batch stacking (steps_per_dispatch): K
             # already-transferred batches concatenate into [k, B, ...] with
             # the chain dim unsharded — one tiny fused device program per
@@ -511,63 +518,145 @@ class ShardedLoader:
             stack_fn = jax.jit(
                 lambda g: jax.tree.map(lambda *xs: jax.numpy.stack(xs), *g),
                 out_shardings=(sup_sh, sup_sh))
+        return LoaderStream(self, transfer, stack_fn)
+
+    def _produce(self, q: queue.Queue, stop: threading.Event, transfer,
+                 stack_fn) -> None:
+        """The producer thread of one stream: batches into ``q`` until they
+        end or ``stop`` is set, then the end mark; an exception goes to the
+        consumer through the queue. Holds the loader, the queue and the
+        event and never the stream, so a dropped stream is collected."""
+        sp = span_lane(self.tracer, "data", "loader")
+
+        def put_or_stop(item) -> bool:
+            # Never block forever on a full queue: a closed or dropped stream
+            # sets `stop`; re-check it between bounded put attempts so the
+            # thread can exit.
+            if stop.is_set():
+                return False
+            try:
+                q.put_nowait(item)
+                return True
+            except queue.Full:
+                t_full = time.monotonic()
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    sp.span("loader_blocked", t_full, time.monotonic())
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
         def transferred():
             """Batches on the device until the stream ends or the consumer
             is gone; one stamp a boundary, so a batch's host work ends where
             its transfer starts."""
-            batches = self._iter_batches()
+            batches = self._host_batches()
             while True:
                 t0 = time.monotonic()
                 try:
-                    imgs, lbls = next(batches)
+                    item = next(batches)
                 except StopIteration:
                     return
                 t1 = time.monotonic()
                 sp.span("loader_batch", t0, t1)
                 if stop.is_set():
                     return
-                item = transfer(imgs, lbls)
-                sp.span("loader_h2d", t1, time.monotonic())
+                if transfer is not None:
+                    item = transfer(*item)
+                    sp.span("loader_h2d", t1, time.monotonic())
                 yield item
 
-        def producer():
-            try:
-                if plan is None:
-                    for item in transferred():
-                        if not put_or_stop(item):
-                            return
-                else:
-                    group: list = []
-                    ci = 0
-                    for item in transferred():
-                        group.append(item)
-                        if len(group) == plan[ci % len(plan)]:
-                            if not put_or_stop(stack_fn(tuple(group))):
-                                return
-                            group = []
-                            ci += 1
-                    # finite stream: a trailing incomplete group is dropped
-                    # (drop_remainder semantics at chain granularity)
-                put_or_stop(_SENTINEL)
-            except Exception as e:  # surface errors on the consumer side
-                put_or_stop(e)
-
-        t = threading.Thread(target=producer, daemon=True)
-        t.start()
+        plan = self._super_plan
         try:
-            while True:
-                item = q.get()
-                if item is _SENTINEL:
-                    return
-                if isinstance(item, Exception):
-                    raise item
-                yield item
+            if plan is None:
+                for item in transferred():
+                    if not put_or_stop(item):
+                        return
+            else:
+                group: list = []
+                ci = 0
+                for item in transferred():
+                    group.append(item)
+                    if len(group) == plan[ci % len(plan)]:
+                        if not put_or_stop(stack_fn(tuple(group))):
+                            return
+                        group = []
+                        ci += 1
+                # finite stream: a trailing incomplete group is dropped
+                # (drop_remainder semantics at chain granularity)
+            put_or_stop(_END)
+        except Exception as e:  # surface errors on the consumer side
+            put_or_stop(e)
         finally:
-            stop.set()
-            # Drain so device-resident batches are released promptly.
-            while True:
-                try:
-                    q.get_nowait()
-                except queue.Empty:
-                    break
+            if stop.is_set():
+                # a put that was waiting when the consumer left may have
+                # landed after the consumer's own drain
+                _drain(q)
+
+
+_END = object()     # after a stream's last batch
+
+
+def _drain(q: queue.Queue) -> None:
+    """Empty ``q`` so device-resident batches are released promptly."""
+    while True:
+        try:
+            q.get_nowait()
+        except queue.Empty:
+            return
+
+
+class LoaderStream:
+    """One pass over a :class:`ShardedLoader`'s batches (``loader.open()``):
+    an iterator whose producer thread works from construction, at most
+    ``prefetch`` batches ahead of the consumer. ``ready()`` says whether a
+    ``next`` now would be served from the queue without waiting; ``close()``
+    stops the producer, waits for it and releases what it had queued. A
+    stream that is dropped unclosed stops its producer too, without the
+    wait."""
+
+    def __init__(self, loader: ShardedLoader, transfer, stack_fn):
+        self._q: queue.Queue = queue.Queue(maxsize=loader.prefetch)
+        self._stop = threading.Event()
+        self._ended = False
+        self._thread = threading.Thread(
+            target=loader._produce, name="loader (producer)", daemon=True,
+            args=(self._q, self._stop, transfer, stack_fn))
+        self._thread.start()
+
+    def ready(self) -> bool:
+        return not self._q.empty()
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._ended:
+            raise StopIteration
+        item = self._q.get()
+        if item is _END or isinstance(item, Exception):
+            self._release()
+            if item is _END:
+                raise StopIteration
+            raise item
+        return item
+
+    def _release(self) -> None:
+        self._ended = True
+        self._stop.set()
+        _drain(self._q)
+
+    def close(self) -> None:
+        self._release()
+        # the producer looks at `stop` after every batch and every 0.1 s of
+        # a full queue, and drains what it put after the line above
+        self._thread.join()
+
+    def __del__(self):
+        # no join: at interpreter exit a daemon thread may never run again;
+        # an ended stream has nothing left to release (and at exit no
+        # `queue.Empty` to drain with)
+        if not self._ended:
+            self._release()

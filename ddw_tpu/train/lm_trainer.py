@@ -320,19 +320,13 @@ class LMTrainer:
                 for _ in range(len(plan)):
                     yield next(train_iter)
 
-            def val_batches():
-                # fresh unshuffled single pass per epoch: every eval sees
-                # the SAME leading val_steps full batches (no window drift
-                # across epochs or resumes)
-                loader = ShardedLoader(val_table, batch_size=host_batch,
-                                       num_epochs=1, shuffle=False,
-                                       **shard_kw)
-                for i, batch in enumerate(loader):
-                    if i >= val_steps:
-                        break
-                    yield batch
-
-            return train_batches, val_batches
+            # a fresh unshuffled single pass per epoch, opened by the loop:
+            # every eval sees the SAME leading val_steps full batches (no
+            # window drift across epochs or resumes)
+            val_loader = ShardedLoader(val_table, batch_size=host_batch,
+                                       num_epochs=1, num_batches=val_steps,
+                                       shuffle=False, **shard_kw)
+            return train_batches, val_loader
 
         return self._run(seq_len, steps_per_epoch, global_batch,
                          make_providers, resume, t_fit)

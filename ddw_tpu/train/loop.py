@@ -158,6 +158,19 @@ def log_fit_params(run, sizes: dict, **cfgs) -> None:
 
 
 # -- the loop -----------------------------------------------------------------
+def _asked(stream, ready: list):
+    """The batches of an opened validation stream; before each is asked for,
+    whether it waited in the stream's queue goes on ``ready``."""
+    while True:
+        waiting = stream.ready()
+        try:
+            batch = next(stream)
+        except StopIteration:
+            return
+        ready.append(waiting)
+        yield batch
+
+
 def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                train_batches, val_batches, step_args, run_step, eval_step,
                ckpt, best, run, tracer, setup_id, t_fit: float,
@@ -170,9 +183,18 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     ``TrainCfg.trace_dir`` profile and the ``finally`` that joins the writers.
 
     ``plan`` is the epoch's chain lengths (``chain_plan``);
-    ``train_batches(epoch)`` gives an iterator with one item a chain and
-    ``val_batches()`` the epoch's validation batches (a loader it builds is
-    built on its first ``next``, inside the first ``val_data_wait``);
+    ``train_batches(epoch)`` gives an iterator with one item a chain.
+    ``val_batches`` gives the epoch's validation batches, and is one of two
+    things. A loader (anything with ``open()``, as ``data/loader.py``'s
+    ``ShardedLoader``) is opened when the epoch's training begins, right
+    after the dispatch of the epoch's first chain (the chip then has work,
+    also where that chain is the epoch's only one): the stream's thread reads,
+    decodes and transfers the pass while the chip trains and rests on its
+    full queue, so validation asks a stream that is ahead (an epoch that
+    dispatches no chain opens it at validation); the stream is closed when
+    validation ends and on every other way out of the epoch.
+    Anything else is called, at validation, for an iterable (the in-memory
+    ``LMTrainer.fit``: nothing to open).
     ``run_step`` is the trainer's step or chain and ``step_args(batch,
     host_step)`` what it is called with after the state — the batch and the
     trainer's rng rule — so ``run_step(state, *step_args(batch, host_step))
@@ -187,10 +209,15 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
     step serves the whole fit.
 
     A row is ``epoch``, the four means and ``lr``, then ``row_extra`` (a
-    dict), then ``timed_row(train_seconds)``. A trainer that gives
+    dict), then ``timed_row(train_seconds)``, then, where the validation
+    batches came from an opened stream, ``val_ready_share``: of the batches
+    the loop asked for, the share that waited in the stream's queue at the
+    ask (``LoaderStream.ready``; the ``epoch`` span carries it too, and each
+    ``val_data_wait`` its own ``ready``). A trainer that gives
     ``timed_row`` reports the training part of the epoch as a time, so for it
     the training means are fetched before validation (``train_fetch``: the
-    device drains there, and validation starts on an idle chip); otherwise
+    device drains there, and validation starts on an idle chip, with a
+    ``next`` on the opened stream's queue and one dispatch); otherwise
     every mean is fetched at the epoch's one barrier, ``epoch_fetch``.
     ``on_epoch(row, state)`` runs after the schedule's epoch end, before the
     checkpoint; a true return stops the fit, an exception leaves it.
@@ -227,6 +254,9 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                      if _profiling(cfg) else -1)
     tracing = False
     history: list[dict[str, float]] = []
+    # a provider with a loader behind it is opened ahead; the epoch's stream
+    open_val = getattr(val_batches, "open", None)
+    val_stream = None
     try:
         for epoch in range(start_epoch, cfg.epochs):
             t_epoch = time.monotonic()
@@ -327,6 +357,13 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                                         "k": k_chain, "chained": chained})
                 if hub is not None:
                     hub.observe("train.chain_ms", (t_end - t_chain) * 1e3)
+                if open_val is not None and val_stream is None:
+                    # the epoch's first chain is on the device: the
+                    # validation pass is made from here on, behind the
+                    # training (opened before that dispatch, its thread
+                    # would take the host from an idle chip: 1.5 ms an epoch
+                    # in the LM cells, PERF.md section 6, PR 40)
+                    val_stream = open_val()
                 host_step += k_chain
                 step_i += k_chain
 
@@ -355,10 +392,18 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                 eval_state = eval_state.replace(params=ema_params(state),
                                                 opt_state=())
             t0v = t_val
-            for i, vbatch in enumerate(val_batches()):
+            ready = []
+            if open_val is not None and val_stream is None:
+                # an epoch that dispatched no chain: nothing to work behind
+                val_stream = open_val()
+            for i, vbatch in enumerate(
+                    val_batches() if open_val is None
+                    else _asked(val_stream, ready)):
                 t1v = time.monotonic()
                 sp.span("val_data_wait", t0v, t1v, val_id,
-                        args=sp.on and {"i": i, "first": i == 0})
+                        args=sp.on and {"i": i, "first": i == 0,
+                                        **({"ready": ready[i]} if ready
+                                           else {})})
                 m = eval_step(eval_state, *vbatch)
                 vlosses.append(m["loss"])
                 vaccs.append(m["accuracy"])
@@ -367,6 +412,11 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                         args=sp.on and {"i": i})
             sp.span("validation", t_val, t0v, epoch_id, val_id,
                     args=sp.on and {"steps": len(vlosses)})
+            if val_stream is not None:
+                val_stream.close()
+                val_stream = None
+            ahead = ({"val_ready_share": sum(ready) / len(ready)} if ready
+                     else {})
             # The first fetch here is the epoch's barrier: it returns when
             # the device has run every step before it.
             if timed_row is None:
@@ -376,6 +426,7 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                    "val_loss": fetch_metrics_mean(vlosses),
                    "val_accuracy": fetch_metrics_mean(vaccs),
                    "lr": get_lr(state), **(row_extra or {}), **timed,
+                   **ahead,
                    **{name: fetch_metrics_mean(values)
                       for name, values in counted.items()}}
             t_rep = time.monotonic()
@@ -428,7 +479,7 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
             sp.span("epoch", t_epoch, t1, span=epoch_id,
                     args=sp.on and {"epoch": epoch, "steps": steps_per_epoch,
                                     "step_variants": step_variants
-                                    and step_variants(),
+                                    and step_variants(), **ahead,
                                     **{name: row[name] for name in counted}})
             if epoch == profile_epoch:
                 # the spans of everything so far, the profiled epoch whole,
@@ -449,7 +500,11 @@ def run_epochs(*, cfg: TrainCfg, state, sched, plan, start_epoch: int,
                 jax.profiler.stop_trace()
         finally:
             # unconditional even if stop_trace raises: the writer thread
-            # must be joined either way
+            # must be joined either way, and an epoch left before its
+            # validation (Preempted, ElasticRestart, an exception in the
+            # step) leaves no producer thread and no device batch behind
+            if val_stream is not None:
+                val_stream.close()
             if ckpt is not None:
                 ckpt.close()
             if best is not None:

@@ -101,8 +101,8 @@ class Trainer:
 
     def _loaders(self, train_table: Table, val_table: Table, val_steps: int,
                  consumed_batches: int = 0, super_plan=None, tracer=None):
-        """``(train_loader, val_batches)``: the one infinite training stream,
-        and a validation pass, its loader built anew on its first ``next``."""
+        """``(train_loader, val_loader)``: the one infinite training stream,
+        and the validation pass, which the loop opens anew each epoch."""
         # Elastic-aware topology: under an elastic gang the data-parallel
         # ranks live in the rendezvous (jax.distributed is per-process), and
         # after a shrink recovery the re-derived loaders re-partition the
@@ -133,25 +133,24 @@ class Trainer:
             tracer=tracer,
         )
 
-        def val_batches():
-            viter = iter(ShardedLoader(
-                val_table,
-                batch_size=per_host_batch,
-                image_size=(self.data_cfg.img_height, self.data_cfg.img_width),
-                cur_shard=cur_proc,
-                shard_count=n_proc,
-                num_epochs=None,  # infinite repeat: floor-divided val_steps can exceed
-                                  # one pass when shards are small (reference :199-200)
-                shuffle=False,
-                workers=self.data_cfg.loader_workers,
-                prefetch=self.data_cfg.prefetch,
-                prefetch_to=sharding,
-                tracer=tracer,
-            ))
-            for _ in range(val_steps):
-                yield next(viter)
-
-        return train_loader, val_batches
+        # a pass of val_steps batches from the table's start, opened anew an
+        # epoch (loop.run_epochs): the same records in the same order
+        val_loader = ShardedLoader(
+            val_table,
+            batch_size=per_host_batch,
+            image_size=(self.data_cfg.img_height, self.data_cfg.img_width),
+            cur_shard=cur_proc,
+            shard_count=n_proc,
+            num_epochs=None,  # infinite repeat: floor-divided val_steps can exceed
+                              # one pass when shards are small (reference :199-200)
+            num_batches=val_steps,
+            shuffle=False,
+            workers=self.data_cfg.loader_workers,
+            prefetch=self.data_cfg.prefetch,
+            prefetch_to=sharding,
+            tracer=tracer,
+        )
+        return train_loader, val_loader
 
     # -- main loop ------------------------------------------------------------
     def fit(self, train_table: Table, val_table: Table, resume: bool = False) -> TrainResult:
@@ -247,7 +246,7 @@ class Trainer:
 
         with monitor if monitor is not None else contextlib.nullcontext():
             t0 = time.monotonic()
-            train_loader, val_batches = self._loaders(
+            train_loader, val_loader = self._loaders(
                 train_table, val_table, val_steps,
                 consumed_batches=start_epoch * steps_per_epoch,
                 super_plan=plan if chained else None, tracer=tracer)
@@ -270,7 +269,7 @@ class Trainer:
                 start_epoch=start_epoch,
                 # the one infinite stream: an item a chain, epoch after epoch
                 train_batches=lambda epoch: train_iter,
-                val_batches=val_batches,
+                val_batches=val_loader,
                 step_args=lambda batch, host_step: (*batch, step_rng),
                 run_step=run_step, eval_step=eval_step, ckpt=ckpt, best=best,
                 run=self.run, tracer=tracer, setup_id=setup_id, t_fit=t_fit,

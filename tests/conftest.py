@@ -2,9 +2,11 @@
 
 import os
 
+import numpy as np
 import pytest
 
-from ddw_tpu.data.prep import generate_synthetic_flowers, prepare_flowers
+from ddw_tpu.data.prep import (generate_synthetic_flowers, prepare_flowers,
+                               write_token_table)
 from ddw_tpu.data.store import TableStore
 from ddw_tpu.utils.config import DataCfg, ModelCfg, TrainCfg
 
@@ -24,6 +26,16 @@ def store(tmp_path_factory):
 def silver(flowers_dir, store):
     """(train_table, val_table, label_to_idx) over the synthetic tree."""
     return prepare_flowers(flowers_dir, store, sample_fraction=1.0, shard_size=16)
+
+
+@pytest.fixture(scope="module")
+def token_tables(tmp_path_factory):
+    """(train_table, val_table) of 17-token rows: 56 rows (three batches of 16
+    and a remainder) and 16 (one validation batch)."""
+    store = TableStore(str(tmp_path_factory.mktemp("tok")))
+    toks = np.random.RandomState(0).randint(0, 32, (72, 17)).astype(np.int32)
+    return (write_token_table(store, "train", toks[:56], shard_size=8),
+            write_token_table(store, "val", toks[56:], shard_size=8))
 
 
 @pytest.fixture()

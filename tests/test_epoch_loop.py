@@ -32,7 +32,7 @@ def _tokens(n=36, seq=17):
 
 
 def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
-         **train_kw):
+         on_epoch=None, **train_kw):
     """One tiny fit of SPE steps an epoch: ``vision``, or the LM by its
     step (``lm``, ``lm-zero``, ``lm-fsdp``, ``lm-pp``), or (``lm-indexed``) an
     LM that attends to chosen keys at a length the Pallas kernels take."""
@@ -42,8 +42,9 @@ def _fit(kind, small_cfgs, silver, *, run=None, tracer=None, resume=False,
         train = dataclasses.replace(train, **{
             "checkpoint_dir": "",
             "batch_size": silver[0].num_records // (8 * SPE), **train_kw})
-        return Trainer(data, model, train, mesh=mesh, run=run,
-                       tracer=tracer).fit(silver[0], silver[1], resume=resume)
+        return Trainer(data, model, train, mesh=mesh, run=run, tracer=tracer,
+                       on_epoch=on_epoch).fit(silver[0], silver[1],
+                                              resume=resume)
     lm = LMCfg(vocab_size=32, max_len=16, hidden=16, num_heads=2, mlp_dim=32,
                depth=2 if kind == "lm-pp" else 1, dropout=0.0,
                dtype="float32")
@@ -284,3 +285,227 @@ def test_a_resumed_fit_builds_one_executable(kind, small_cfgs, silver,
                **kw)
     assert [r["epoch"] for r in res.history] == [1, 2]
     _one_executable(steps_made, tracer, [1, 2])
+
+
+# -- the epoch's validation stream is opened ahead ------------------------------
+def _fit_loaders(kind, small_cfgs, silver, token_tables, **kw):
+    """A fit whose validation batches come from a loader: ``vision`` as in
+    ``_fit``, ``lm-tables`` the LM through ``fit_tables`` (3 steps an
+    epoch)."""
+    if kind == "vision":
+        return _fit(kind, small_cfgs, silver, **kw)
+    lm = LMCfg(vocab_size=32, max_len=16, hidden=16, num_heads=2, mlp_dim=32,
+               depth=1, dropout=0.0, dtype="float32")
+    run, tracer, resume = (kw.pop(k, d) for k, d in (
+        ("run", None), ("tracer", None), ("resume", False)))
+    train = TrainCfg(**{"batch_size": 4, "epochs": 2, "warmup_epochs": 0,
+                        "seed": 0, "learning_rate": 1e-2, "num_devices": 4,
+                        **kw})
+    return LMTrainer(lm, train, run=run, tracer=tracer).fit_tables(
+        *token_tables, resume=resume)
+
+
+@pytest.fixture()
+def val_streams(monkeypatch):
+    """Every validation pass the test's fits open (the loaders with
+    ``num_batches``): ``(loader, stream, batches)``, the batches as host
+    arrays in the order the loop took them."""
+    from ddw_tpu.data import loader as loader_mod
+
+    opened, open_ = [], loader_mod.ShardedLoader.open
+
+    class Kept:
+        """Stands where the stream stands and keeps what it hands out."""
+
+        def __init__(self, inner, taken):
+            self._inner, self._taken = inner, taken
+
+        def __getattr__(self, name):
+            return getattr(self._inner, name)
+
+        def __next__(self):
+            batch = next(self._inner)
+            self._taken.append(tuple(np.asarray(x) for x in batch))
+            return batch
+
+    def kept_open(self):
+        stream = open_(self)
+        if self.num_batches is None:
+            return stream
+        opened.append((self, stream, []))
+        return Kept(stream, opened[-1][2])
+
+    monkeypatch.setattr(loader_mod.ShardedLoader, "open", kept_open)
+    return opened
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm-tables"])
+def test_the_validation_stream_is_at_work_while_the_epoch_trains(
+        kind, small_cfgs, silver, token_tables, val_streams, monkeypatch):
+    """A slow decode (30 ms a validation batch) under a slower epoch (40 ms a
+    chain): every epoch's pass is begun between the dispatch of the epoch's
+    first chain and that of its last, exactly ``val_steps`` batches of it, and
+    when validation asks
+    every batch waits in the queue — ``val_ready_share`` 1.0 on the row, the
+    ``epoch`` span and each ``val_data_wait``."""
+    import time
+
+    from ddw_tpu.data.loader import ShardedLoader
+
+    log, host_batches = [], ShardedLoader._iter_batches
+
+    def slow_decode(self):
+        for batch in host_batches(self):
+            if self.num_batches is not None:
+                log.append("val_batch")
+                time.sleep(0.03)
+            yield batch
+
+    def slow_chain(kind_, step, **kw):
+        log.append(step)
+        time.sleep(0.04)
+
+    monkeypatch.setattr(ShardedLoader, "_iter_batches", slow_decode)
+    monkeypatch.setattr(loop, "maybe_fault", slow_chain)
+    tracer = Tracer(capacity=4096)
+    res = _fit_loaders(kind, small_cfgs, silver, token_tables, tracer=tracer)
+    spe = SPE if kind == "vision" else 3
+    assert [r["val_ready_share"] for r in res.history] == [1.0, 1.0]
+    for _, stream, batches in val_streams:
+        assert len(batches) == 1 and not stream._thread.is_alive()
+    assert len(val_streams) == 2 and log.count("val_batch") == 2
+    # a chain's mark is made before its dispatch: the pass is begun after
+    # the epoch's first chain and before its last is dispatched
+    chains = [i for i, e in enumerate(log) if e != "val_batch"]
+    made = [i for i, e in enumerate(log) if e == "val_batch"]
+    assert [log[i] for i in chains] == list(range(2 * spe))
+    assert chains[0] < made[0] < chains[spe - 1]
+    assert chains[spe] < made[1] < chains[-1]
+    events = [e for e in tracer.drain() if e["tid"] == "train"]
+    assert [e["args"]["val_ready_share"] for e in events
+            if e["name"] == "epoch"] == [1.0, 1.0]
+    assert [e["args"]["ready"] for e in events
+            if e["name"] == "val_data_wait"] == [True, True]
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm-tables"])
+def test_every_epoch_validates_a_fresh_pass_from_the_tables_start(
+        kind, small_cfgs, silver, token_tables, val_streams, tmp_path):
+    """Epochs 0 and 1 of one fit, and epochs 1 and 2 of a resumed one: the
+    batches validation took are the leading ``val_steps`` batches of an
+    unshuffled pass over the validation table, the same each time."""
+    from ddw_tpu.data.loader import ShardedLoader
+
+    kw = {"checkpoint_dir": str(tmp_path / "ck"), "checkpoint_every_epochs": 1}
+    _fit_loaders(kind, small_cfgs, silver, token_tables, epochs=2, **kw)
+    res = _fit_loaders(kind, small_cfgs, silver, token_tables, epochs=4,
+                       resume=True, **kw)
+    assert [r["epoch"] for r in res.history] == [2, 3]
+    assert len(val_streams) == 4
+    if kind == "vision":
+        fresh = ShardedLoader(
+            silver[1], batch_size=8 * (silver[0].num_records // (8 * SPE)),
+            image_size=(32, 32), shuffle=False, num_epochs=None, workers=2)
+    else:
+        fresh = ShardedLoader(token_tables[1], batch_size=16, shuffle=False,
+                              num_epochs=1)
+    want = next(iter(fresh))
+    for loader, _, batches in val_streams:
+        assert loader.shuffle is False and loader.skip_records == 0
+        assert len(batches) == loader.num_batches == 1
+        for got, ref in zip(batches[0], want):
+            np.testing.assert_array_equal(got, ref)
+
+
+def _raise_in_step(monkeypatch):
+    """The vision step raises at its third call."""
+    make = trainer.make_train_step
+
+    def made(*a, **kw):
+        step, calls = make(*a, **kw), []
+
+        class Step(_Seen):
+            def __call__(self, *args):
+                calls.append(1)
+                if len(calls) == 3:
+                    raise FloatingPointError("step 3")
+                return self._inner(*args)
+
+        return Step(step)
+
+    monkeypatch.setattr(trainer, "make_train_step", made)
+
+
+@pytest.mark.parametrize("way", ["preempted", "on_epoch", "step_raises"])
+def test_no_validation_stream_outlives_its_epoch(way, small_cfgs, silver,
+                                                 token_tables, val_streams,
+                                                 monkeypatch):
+    """Whichever way the fit leaves — ``Preempted`` at epoch 1's second
+    chain, an ``on_epoch`` that stops after epoch 0, an exception in epoch 0's
+    third step — every validation stream it opened is closed: producer
+    thread gone, queue empty."""
+    if way == "preempted":
+        # asked for at the mark of epoch 1's second chain, whose check meets
+        # it at once: epoch 1's stream is open and nobody has asked it
+        fault = loop.maybe_fault
+        monkeypatch.setattr(loop, "maybe_fault", lambda kind, step, **kw: (
+            step == SPE + 1 and faults.request_preemption(),
+            fault(kind, step=step, **kw)))
+        try:
+            with pytest.raises(Preempted) as exc:
+                _fit_loaders("vision", small_cfgs, silver, token_tables)
+        finally:
+            faults.reset_preemption()
+        assert exc.value.step == SPE + 1
+        opened = 2          # epoch 0's, consumed; epoch 1's, never asked
+    elif way == "on_epoch":
+        res = _fit_loaders("vision", small_cfgs, silver, token_tables,
+                           on_epoch=lambda row: True)
+        assert res.epochs_run == 1
+        opened = 1
+    else:
+        _raise_in_step(monkeypatch)
+        with pytest.raises(FloatingPointError):
+            _fit_loaders("vision", small_cfgs, silver, token_tables)
+        opened = 1
+    assert len(val_streams) == opened
+    for _, stream, _ in val_streams:
+        assert not stream._thread.is_alive() and stream._q.empty()
+        with pytest.raises(StopIteration):
+            next(stream)
+    # taken: epoch 0's one batch, unless the fit left before its validation
+    assert [len(b) for _, _, b in val_streams] == {
+        "preempted": [1, 0], "on_epoch": [1], "step_raises": [0]}[way]
+
+
+@pytest.mark.parametrize("kind", ["vision", "lm-tables"])
+def test_an_epoch_without_a_chain_opens_its_stream_at_validation(
+        kind, small_cfgs, silver, token_tables, val_streams, monkeypatch):
+    """The loop tells a loader's provider by what it is at validation too: an
+    epoch that dispatches no chain never reached the place the stream is
+    opened ahead, so it is opened at the ask (a loader is not callable), and
+    read, counted and closed like any other."""
+    run_epochs = loop.run_epochs
+    monkeypatch.setattr(loop, "run_epochs",
+                        lambda **kw: run_epochs(**{**kw, "plan": []}))
+    res = _fit_loaders(kind, small_cfgs, silver, token_tables, epochs=1)
+    assert [len(b) for _, _, b in val_streams] == [1]
+    assert not val_streams[0][1]._thread.is_alive()
+    row, = res.history
+    assert "val_ready_share" in row and np.isfinite(row["val_loss"])
+
+
+def test_a_provider_without_a_loader_puts_no_counter_on_the_row(small_cfgs,
+                                                                silver,
+                                                                val_streams):
+    """``LMTrainer.fit`` over arrays in memory: nothing to open, the provider
+    is asked at validation as before; no ``val_ready_share``, no ``ready``."""
+    tracer = Tracer(capacity=4096)
+    res = _fit("lm", small_cfgs, silver, tracer=tracer)
+    assert not val_streams
+    assert all("val_ready_share" not in r for r in res.history)
+    events = [e for e in tracer.drain() if e["tid"] == "train"]
+    waits = [e for e in events if e["name"] == "val_data_wait"]
+    assert waits and all("ready" not in e["args"] for e in waits)
+    assert all("val_ready_share" not in e["args"] for e in events
+               if e["name"] == "epoch")

@@ -1,6 +1,8 @@
 """Loader contract tests: shard selection, infinite repeat, static shapes,
 prefetch-to-device (the Petastorm make_tf_dataset semantics, SURVEY §2b.8)."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -248,3 +250,136 @@ def test_token_table_loader(tmp_path):
     assert len(a) + len(b) == len(toks)
     merged = {row.tobytes() for row in np.concatenate([a, b])}
     assert merged == {row.tobytes() for row in toks}
+
+
+# -- a stream: one pass, at work from the moment it is opened ------------------
+def _sharding(n=2):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:n]).reshape(n), ("data",))
+    return NamedSharding(mesh, P("data"))
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+def _validation_loader(table, **kw):
+    return ShardedLoader(table, batch_size=8, image_size=(16, 16),
+                         shuffle=False, num_epochs=None, workers=2,
+                         prefetch=2, **kw)
+
+
+def test_an_opened_stream_works_before_it_is_asked(silver):
+    """``open()`` starts the producer: with no ``next`` at all the queue
+    fills to ``prefetch`` batches, the producer rests there, and ``ready()``
+    reads the queue."""
+    from ddw_tpu.obs.trace import Tracer
+
+    tracer = Tracer(capacity=256)
+    stream = _validation_loader(silver[0], prefetch_to=_sharding(),
+                                tracer=tracer).open()
+    def made():
+        return sum(e["name"] == "loader_batch" for e in tracer.drain())
+
+    try:
+        assert _until(lambda: stream._q.full()) and stream.ready()
+        assert stream._thread.is_alive()
+        assert "(producer)" in stream._thread.name
+        # two rest in the queue and one in the producer's hand: no more are
+        # made while nobody asks
+        assert _until(lambda: made() == 3)
+        time.sleep(0.1)
+        assert made() == 3
+        imgs, _ = next(stream)
+        assert isinstance(imgs, jax.Array) and imgs.shape == (8, 16, 16, 3)
+        # one taken, one more made
+        assert _until(lambda: made() == 4) and _until(stream._q.full)
+        time.sleep(0.1)
+        assert made() == 4
+    finally:
+        stream.close()
+    assert not stream._thread.is_alive() and stream._q.empty()
+
+
+@pytest.mark.parametrize("n", [1, 3, 5])
+def test_a_stream_makes_num_batches_and_no_more(silver, n):
+    """``num_batches``: the pass reads, decodes and transfers exactly that
+    many batches (a ``loader_batch`` and a ``loader_h2d`` span each), the
+    leading ones of the unbounded pass, then ends by itself."""
+    from ddw_tpu.obs.trace import Tracer
+
+    tracer = Tracer(capacity=256)
+    stream = _validation_loader(silver[1], num_batches=n, tracer=tracer,
+                                prefetch_to=_sharding()).open()
+    got = [(np.asarray(i), np.asarray(l)) for i, l in stream]
+    assert _until(lambda: not stream._thread.is_alive()) and not stream.ready()
+    names = [e["name"] for e in tracer.drain() if e["tid"] == "loader"]
+    assert names.count("loader_batch") == n == names.count("loader_h2d")
+    # n = 5 wraps around the 12-record validation table, as the unbounded
+    # host pass does
+    want = _take(_validation_loader(silver[1]), n)
+    assert len(got) == n
+    for (gi, gl), (wi, wl) in zip(got, want):
+        np.testing.assert_array_equal(gi, wi)
+        np.testing.assert_array_equal(gl, wl)
+    # the host pipeline alone honours the bound too
+    assert len(list(_validation_loader(silver[1], num_batches=n))) == n
+
+
+@pytest.mark.parametrize("how", ["close", "drop"])
+def test_a_stream_left_early_leaves_nothing_behind(silver, how):
+    """Closed with its queue full (``close`` waits for the producer) or
+    dropped unclosed (the producer notices within one bounded put): no
+    thread, no queued device batch."""
+    import gc
+    import weakref
+
+    stream = _validation_loader(silver[0], prefetch_to=_sharding()).open()
+    assert _until(lambda: stream._q.full())
+    thread, q = stream._thread, stream._q
+    if how == "close":
+        stream.close()
+        assert not thread.is_alive()
+        with pytest.raises(StopIteration):
+            next(stream)
+    else:
+        gone = weakref.ref(stream)
+        del stream
+        gc.collect()
+        assert gone() is None       # the producer does not hold the stream
+        assert _until(lambda: not thread.is_alive())
+    assert q.empty()
+
+
+def test_an_ended_stream_is_left_alone_when_collected(silver, monkeypatch):
+    """What ``close`` released is not released again by ``__del__``: at
+    interpreter exit, where a stream can outlive ``queue``'s names, an ended
+    stream is collected without a word."""
+    from ddw_tpu.data import loader as loader_mod
+
+    stream = _validation_loader(silver[0], num_batches=1).open()
+    assert len(list(stream)) == 1
+    stream.close()
+
+    def gone(q):
+        raise TypeError("queue.Empty is gone")
+
+    monkeypatch.setattr(loader_mod, "_drain", gone)
+    stream.__del__()
+
+
+def test_a_producers_error_reaches_the_consumer(silver, monkeypatch):
+    def broken(self):
+        raise OSError("shard unreadable")
+        yield
+
+    monkeypatch.setattr(ShardedLoader, "_iter_batches", broken)
+    stream = _validation_loader(silver[0], prefetch_to=_sharding()).open()
+    with pytest.raises(OSError, match="shard unreadable"):
+        next(stream)
+    stream.close()
+    assert not stream._thread.is_alive()

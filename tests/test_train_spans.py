@@ -11,7 +11,6 @@ import time
 import numpy as np
 import pytest
 
-from ddw_tpu.data.prep import write_token_table
 from ddw_tpu.obs import trace as trace_mod
 from ddw_tpu.obs.trace import NULL_LANE, Tracer, span_lane
 from ddw_tpu.runtime.mesh import MeshSpec, make_mesh
@@ -30,17 +29,6 @@ IN_EPOCH = {"vision": {"train_chain", "train_fetch", "validation",
                        "epoch_fetch", "epoch_report", "epoch_end"},
             "lm": {"train_chain", "validation", "epoch_fetch",
                    "epoch_report", "epoch_end"}}
-
-
-@pytest.fixture(scope="module")
-def token_tables(tmp_path_factory):
-    from ddw_tpu.data.store import TableStore
-
-    store = TableStore(str(tmp_path_factory.mktemp("tok")))
-    rng = np.random.RandomState(0)
-    toks = rng.randint(0, 32, size=(72, 17)).astype(np.int32)
-    return (write_token_table(store, "train", toks[:56], shard_size=8),
-            write_token_table(store, "val", toks[56:], shard_size=8))
 
 
 def _fit(kind, tracer, small_cfgs, silver, token_tables, steps_per_dispatch=1):
@@ -132,9 +120,13 @@ def test_fit_records_the_span_tree(kind, k, small_cfgs, silver, token_tables):
         assert len(waits) == len(disps) == val["args"]["steps"] >= 1
         assert [w["args"]["first"] for w in sorted(
             waits, key=lambda e: e["ts"])] == [True] + [False] * (len(waits) - 1)
-        # the first wait starts with the validation span: the loader is
-        # built inside it
+        # the first wait starts with the validation span; the stream it
+        # asks was opened after the epoch's first chain, and says for each
+        # batch whether it waited in the queue
         assert min(w["ts"] for w in waits) == pytest.approx(val["ts"], abs=TOL)
+        ready = [w["args"]["ready"] for w in waits]
+        assert all(r in (True, False) for r in ready)
+        assert ep["args"]["val_ready_share"] == sum(ready) / len(ready)
     assert not named("ckpt_save")           # no checkpoint_dir was given
 
     # the loaders' producers: a span a batch on their own thread lane
@@ -142,7 +134,16 @@ def test_fit_records_the_span_tree(kind, k, small_cfgs, silver, token_tables):
     assert {e["name"] for e in loader} >= {"loader_batch", "loader_h2d"}
     assert {e["name"] for e in loader} <= {"loader_batch", "loader_h2d",
                                            "loader_blocked"}
-    assert len(named("loader_batch", loader)) >= EPOCHS * (spe + 1)
+    # on that lane both producers: the training stream's batches, and each
+    # epoch's validation pass, made while that epoch's chains ran — exactly
+    # the one batch validation takes; the training stream may be ahead by its
+    # queue, the batch in its hand, a chain being gathered and one in work
+    made = named("loader_batch", loader)
+    assert len(made) >= EPOCHS * (spe + 1)
+    assert len(made) <= EPOCHS * (spe + 1) + (2 + 1) * k + k + 1
+    assert len(named("loader_h2d", loader)) <= len(made)
+    assert [r["val_ready_share"] for r in history] == [
+        e["args"]["val_ready_share"] for e in epochs]
     assert len(history) == EPOCHS
 
 
@@ -161,8 +162,11 @@ def test_without_a_tracer_no_event_is_built(kind, small_cfgs, silver,
                         lambda self, *a: built.append("lane"))
     plain, _ = _fit(kind, None, small_cfgs, silver, token_tables)
     assert built == []
+    # what a row says of time is the run's own: the seconds, and whether the
+    # validation batch already waited when a host this far ahead asked
     drop = lambda rows: [{k: v for k, v in r.items()
-                          if k not in ("epoch_seconds", "images_per_sec")}
+                          if k not in ("epoch_seconds", "images_per_sec",
+                                       "val_ready_share")}
                          for r in rows]
     assert drop(plain) == drop(traced)
 
