@@ -18,7 +18,9 @@ module runs three ways off one definition:
 Pre-LN blocks, learned positional embeddings, weight-untied vocab head. What a
 layer is made of beyond that — RMSNorm, a gated MLP, heads wider than
 ``hidden / num_heads``, normed queries and keys, multi-axis RoPE, attention
-over keys an indexer chose, routed experts on a chip's share — is one
+over keys an indexer chose, latent attention (low-rank q and kv paths, one
+shared rotary key head, value heads narrower than q/k heads), routed experts
+on a chip's share, a residual path of several hyper-connected streams — is one
 :class:`ddw_tpu.utils.config.LayerSpec` handed from the model to its blocks.
 """
 
@@ -47,6 +49,94 @@ def layer_norm(spec: LayerSpec, name: str | None = None):
                           name=name)
     raise ValueError(f"unknown norm {spec.norm!r}; use 'layernorm' or "
                      f"'rmsnorm'")
+
+
+def yarn_of(spec: LayerSpec) -> tuple:
+    """``apply_rope``'s ``yarn`` argument for the spec's rotary scaling: its
+    four numbers, or nothing."""
+    if spec.rope_scaling not in ("", "yarn"):
+        raise ValueError(f"unknown rope_scaling {spec.rope_scaling!r}; use "
+                         f"'' or 'yarn'")
+    return ((spec.rope_factor, spec.rope_beta_fast, spec.rope_beta_slow,
+             spec.rope_original_len) if spec.rope_scaling else ())
+
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``exp(logits) [..., n, n]`` made doubly stochastic by ``iters`` rounds
+    of (each row divided by its sum + ``eps``, then each column)."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def hyper_read(x, h_pre):
+    """A sublayer's input: the streams ``x [B, S, n, C]`` mixed by ``h_pre
+    [B, S, n]`` -> ``[B, S, C]`` in x's dtype."""
+    with jax.named_scope("hyper_conn"):
+        return jnp.einsum("bsn,bsnc->bsc", h_pre,
+                          x.astype(jnp.float32)).astype(x.dtype)
+
+
+def hyper_write(x, y, h_post, h_res):
+    """The streams after a sublayer: ``h_res x + h_post^T y`` — the streams
+    mixed by ``h_res [B, S, n, n]`` and the sublayer's output ``y [B, S, C]``
+    written to each by ``h_post [B, S, n]``."""
+    with jax.named_scope("hyper_conn"):
+        mixed = jnp.einsum("bsnm,bsmc->bsnc", h_res, x.astype(jnp.float32))
+        return (mixed + h_post[..., None]
+                * y.astype(jnp.float32)[:, :, None, :]).astype(x.dtype)
+
+
+class HyperConnection(nn.Module):
+    """The coefficients of a manifold-constrained hyper-connection
+    (arXiv:2512.24880) around one sublayer, from the stream state ``x [B, S,
+    n, C]`` of each token, in float32:
+
+        xt = RMSNorm(vec(x))                        over all n C numbers, gain
+        [Hpre~ | Hpost~ | Hres~] = alpha * (xt phi) + bias     n | n | n x n
+        Hpre = sigmoid(Hpre~);  Hpost = 2 sigmoid(Hpost~)
+        Hres = sinkhorn(clip(Hres~ + hyper_res_diag I))
+
+    Returns ``(h_pre, h_post, h_res)`` for :func:`hyper_read` and
+    :func:`hyper_write`; sows the counters ``hc_res_offdiag_share`` (the mass
+    of ``Hres`` off its diagonal over ``n``, a mean over tokens: 0 is a plain
+    residual, ``1 - 1/n`` streams mixed evenly) and ``hc_sinkhorn_error``
+    (the largest ``|row sum - 1|`` or ``|column sum - 1|`` after the last
+    round). Nothing of it is kept across a block's rematerialisation."""
+
+    layer: LayerSpec
+
+    @nn.compact
+    def __call__(self, x):
+        spec = self.layer
+        b, s, n, c = x.shape
+        with jax.named_scope("hyper_conn"):
+            xt = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                            name="norm")(x.reshape(b, s, n * c))
+            phi = self.param("phi", nn.initializers.normal(0.02),
+                             (n * c, n * (n + 2)), jnp.float32)
+            alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                               jnp.float32)
+            bias = self.param("bias", nn.initializers.zeros, (n * (n + 2),),
+                              jnp.float32)
+            raw = xt @ phi                                  # [B, S, n(n+2)]
+            gain = jnp.repeat(alpha, jnp.asarray([n, n, n * n]),
+                              total_repeat_length=n * (n + 2))
+            pre, post, res = jnp.split(raw * gain + bias, [n, 2 * n], axis=-1)
+            res = res.reshape(b, s, n, n)
+            if spec.hyper_res_diag:
+                res = res + spec.hyper_res_diag * jnp.eye(n, dtype=res.dtype)
+            h_res = sinkhorn(jnp.clip(res, *spec.hyper_res_clamp),
+                             spec.hyper_sinkhorn_iters, spec.hyper_eps)
+            seen = lax.stop_gradient(h_res)
+            sums = jnp.concatenate([jnp.sum(seen, -1), jnp.sum(seen, -2)], -1)
+            self.sow("intermediates", "counters", {
+                "hc_res_offdiag_share": jnp.mean(
+                    1.0 - jnp.trace(seen, axis1=-2, axis2=-1) / n),
+                "hc_sinkhorn_error": jnp.max(jnp.abs(sums - 1.0))})
+            return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res
 
 
 class CausalSelfAttention(nn.Module):
@@ -93,6 +183,8 @@ class CausalSelfAttention(nn.Module):
         from ddw_tpu.models.lora import maybe_lora_dense, row_lora_delta
 
         spec = self.layer
+        if spec.attention == "latent":
+            return self._latent(x, positions)
         b, s, d = x.shape
         head_dim = spec.head_dim or d // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
@@ -143,12 +235,13 @@ class CausalSelfAttention(nn.Module):
                     rope_at = jnp.broadcast_to(
                         positions, (len(spec.mrope_section), *positions.shape))
                 rope_kw = dict(seq_axis=1, theta=spec.rope_theta,
-                               sections=spec.mrope_section)
+                               sections=spec.mrope_section,
+                               yarn=yarn_of(spec))
                 q = apply_rope(q, rope_at, **rope_kw).astype(self.dtype)
                 k = apply_rope(k, rope_at, **rope_kw).astype(self.dtype)
         if spec.attention not in ("full", "indexed"):
             raise ValueError(f"unknown attention {spec.attention!r}; use "
-                             f"'full' or 'indexed'")
+                             f"'full', 'indexed' or 'latent'")
         if spec.attention == "indexed" and (
                 self.decode or self.seq_axis is not None or positions is None):
             raise NotImplementedError(
@@ -371,6 +464,50 @@ class CausalSelfAttention(nn.Module):
                 out, cn=2)
 
 
+    def _latent(self, x, positions):
+        """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), the
+        training form: ``cq = RMSNorm(x W_dq)``, ``[q_nope | q_rope] = cq
+        W_uq`` a head; ``[ckv | k_rope] = x W_dkv``, ``ckv <- RMSNorm(ckv)``,
+        ``k_nope = ckv W_uk``, ``v = ckv W_uv`` a head; the rotary parts
+        turned (YaRN's frequencies where the spec says), ``k_rope`` ONE head
+        shared by all query heads; softmax scale ``(nope + rope)^-1/2`` times
+        YaRN's factor; q/k heads ``nope + rope`` wide and v heads
+        ``v_head_dim`` go to the flash kernels as they are. No biases."""
+        from ddw_tpu.ops.rope import apply_rope, yarn_softmax_factor
+
+        spec = self.layer
+        if positions is None:       # TransformerLM refuses decode and a ring
+            raise ValueError("latent attention turns a part of every q and k "
+                             "head: pos_encoding must be 'rope'")
+        b, s, d = x.shape
+        h, nope, rope = self.num_heads, spec.qk_nope_dim, spec.qk_rope_dim
+        dense = lambda feats, name: nn.DenseGeneral(          # noqa: E731
+            feats, use_bias=False, dtype=self.dtype, name=name)
+        norm = lambda name: nn.RMSNorm(                       # noqa: E731
+            epsilon=spec.norm_eps, dtype=jnp.float32, name=name)
+        yarn = yarn_of(spec)
+        turn = lambda t: apply_rope(t, positions, seq_axis=1,  # noqa: E731
+                                    theta=spec.rope_theta, yarn=yarn)
+        with jax.named_scope("attn_proj"):
+            cq = norm("q_latent_norm")(dense(spec.q_lora_rank, "q_down")(x))
+            q = dense((h, nope + rope), "q_up")(cq.astype(self.dtype))
+            down = dense(spec.kv_lora_rank + rope, "kv_down")(x)
+            ckv = norm("kv_latent_norm")(
+                down[..., :spec.kv_lora_rank]).astype(self.dtype)
+            k_rope = turn(down[..., None, spec.kv_lora_rank:])  # [B,S,1,rope]
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            k = jnp.concatenate(
+                [dense((h, nope), "k_up")(ckv),
+                 jnp.broadcast_to(k_rope, (b, s, h, rope))], axis=-1)
+            v = dense((h, spec.v_head_dim), "v_up")(ckv)
+        scale = (nope + rope) ** -0.5 * (
+            yarn_softmax_factor(spec.rope_factor) if yarn else 1.0)
+        out = flash_mha_seq_major(q, k, v, causal=True, sm_scale=scale)
+        with jax.named_scope("attn_proj"):
+            return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
+                                   dtype=self.dtype, name="out")(out)
+
+
 def routed_experts(spec: LayerSpec, num_held: int, mlp_dim: int, dtype,
                    name: str):
     """The spec's no-drop routed layer on the ``num_held`` experts this chip
@@ -412,7 +549,13 @@ class DecoderBlock(nn.Module):
     def __call__(self, x, train: bool, positions=None, block_tables=None,
                  start_pos=None, adapters=None):
         spec = self.layer
-        h = layer_norm(spec)(x)
+        # x is then [B, S, n, C]; TransformerLM refuses decode, a ring, LoRA
+        streams = spec.hyper_streams > 1
+        if streams:
+            h_pre, h_post, h_res = HyperConnection(spec, name="hc_attn")(x)
+            h = layer_norm(spec)(hyper_read(x, h_pre))
+        else:
+            h = layer_norm(spec)(x)
         h = CausalSelfAttention(self.num_heads, self.dtype, self.seq_axis,
                                 self.decode, self.max_len,
                                 slot_decode=self.slot_decode,
@@ -429,8 +572,13 @@ class DecoderBlock(nn.Module):
                                              start_pos=start_pos,
                                              adapters=adapters)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
-        x = x + h
-        h = layer_norm(spec)(x)
+        if streams:
+            x = hyper_write(x, h, h_post, h_res)
+            h_pre, h_post, h_res = HyperConnection(spec, name="hc_mlp")(x)
+            h = layer_norm(spec)(hyper_read(x, h_pre))
+        else:
+            x = x + h
+            h = layer_norm(spec)(x)
         if spec.mlp not in ("gelu", "swiglu", "relu2") or (
                 spec.mlp == "relu2" and not (self.num_experts
                                              and spec.experts_per_token)):
@@ -449,7 +597,7 @@ class DecoderBlock(nn.Module):
         else:
             from ddw_tpu.models.lora import maybe_lora_dense, row_lora_delta
 
-            d = x.shape[-1]
+            d = h.shape[-1]
 
             def mlp_dense(feats, name, inp):
                 y = maybe_lora_dense(feats, name, rank=self.lora_rank,
@@ -472,6 +620,8 @@ class DecoderBlock(nn.Module):
                     h = nn.gelu(h)
                     h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
+        if streams:
+            return hyper_write(x, h.astype(x.dtype), h_post, h_res)
         return x + h
 
 
@@ -564,6 +714,9 @@ class TransformerLM(nn.Module):
     layer: LayerSpec = LayerSpec()  # what every block is made of
     pattern: str = ""        # one mixer a layer (MixerBlock), a character
                              # each; "": every layer a DecoderBlock
+    dense_layers: int = 0    # leading DecoderBlocks with the dense MLP at
+    dense_mlp_dim: int = 0   # this width where the rest route
+    mtp_depth: int = 0       # 0 | 1: the multi-token-prediction module
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, block_tables=None,
@@ -595,12 +748,30 @@ class TransformerLM(nn.Module):
         if self.pos_encoding == "rope" and (
                 self.layer.head_dim or self.hidden // self.num_heads) % 2:
             raise ValueError("RoPE needs an even head_dim")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one module (1) "
+                             f"or none (0)")
+        streams = self.layer.hyper_streams if self.layer.hyper_streams > 1 else 0
+        if (streams or self.mtp_depth
+                or self.layer.attention == "latent") and (
+                self.decode or self.seq_axis is not None or self.lora_rank
+                or self.pattern):
+            raise NotImplementedError(
+                "latent attention, a hyper-connected residual path and the "
+                "multi-token-prediction module train on one device's whole "
+                "sequence: the latent cache (ROADMAP M4), drafting from the "
+                "module (M7), a ring and adapters are not written")
         b, s_local = tokens.shape
+        embed = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
+                         name="tok_embed")
+
+        def embedded(ids):
+            e = embed(ids)
+            return e * self.layer.embed_scale \
+                if self.layer.embed_scale != 1.0 else e
+
         with jax.named_scope("embed"):      # the look-up; positions below
-            x = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
-                         name="tok_embed")(tokens)
-            if self.layer.embed_scale != 1.0:
-                x = x * self.layer.embed_scale
+            x = embedded(tokens)
         if self.pos_encoding == "learned":
             pos_table = self.param("pos_embed", nn.initializers.normal(0.02),
                                    (self.max_len, self.hidden), jnp.float32)
@@ -705,6 +876,17 @@ class TransformerLM(nn.Module):
             aidx = jnp.asarray(aidx, jnp.int32)
             row_adapters = jax.tree.map(lambda st: jnp.asarray(st)[aidx],
                                         stacks)
+        def enter(x):       # entry: copied to every stream
+            return x if not streams else jnp.broadcast_to(
+                x[:, :, None, :], (b, s_local, streams, self.hidden))
+
+        def leave(x):       # exit: the streams summed
+            if not streams:
+                return x
+            with jax.named_scope("hyper_conn"):
+                return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+
+        x = enter(x)
         for i, kind in enumerate(self.pattern):
             x = remat(MixerBlock)(
                 kind, self.num_heads, self.mlp_dim, self.dtype,
@@ -716,11 +898,14 @@ class TransformerLM(nn.Module):
             blk_kw = dict(paged_kw)
             if row_adapters is not None:
                 blk_kw["adapters"] = row_adapters.get(f"backbone_block{i}")
-            x = Block(self.num_heads, self.mlp_dim, self.dropout,
+            dense = i < self.dense_layers
+            x = Block(self.num_heads,
+                      self.dense_mlp_dim if dense else self.mlp_dim,
+                      self.dropout,
                       self.dtype, None if self.decode else self.seq_axis,
                       self.decode, self.max_len,
                       slot_decode=self.slot_decode,
-                      num_experts=self.num_experts,
+                      num_experts=0 if dense else self.num_experts,
                       expert_axis=None if self.decode else self.expert_axis,
                       capacity_factor=self.capacity_factor,
                       moe_router=self.moe_router,
@@ -734,11 +919,41 @@ class TransformerLM(nn.Module):
                       layer=self.layer,
                       name=f"backbone_block{i}")(x, train, positions,
                                                  **blk_kw)
+        x = leave(x)
+        final_norm = layer_norm(self.layer)
+        # vocab head in f32: logits feed a softmax CE, keep full precision
+        head = nn.Dense(self.vocab_size, use_bias=self.layer.bias,
+                        dtype=jnp.float32, name="head")
         with jax.named_scope("head"):
-            x = layer_norm(self.layer)(x)
-            # vocab head in f32: logits feed a softmax CE, keep full precision
-            return nn.Dense(self.vocab_size, use_bias=self.layer.bias,
-                            dtype=jnp.float32, name="head")(x)
+            logits = head(final_norm(x))
+        if self.mtp_depth:
+            # DeepSeek-V3's module (arXiv:2412.19437, section 2.2), depth 1:
+            # position i joins the trunk's output h_i with the embedding of
+            # token i + 1 and predicts token i + 2 through one more block and
+            # the SHARED embedding, final norm and head. The row's last
+            # position has no token i + 1 (it reads the row's first: causal
+            # attention lets no other position see it) and no target; the
+            # step's loss leaves it out (train/lm_step.py).
+            with jax.named_scope("mtp"):
+                norm = lambda name: nn.RMSNorm(               # noqa: E731
+                    epsilon=self.layer.norm_eps, dtype=jnp.float32, name=name)
+                with jax.named_scope("embed"):
+                    ahead = embedded(jnp.roll(tokens, -1, axis=1))
+                joined = jnp.concatenate(
+                    [norm("mtp_hidden_norm")(x), norm("mtp_embed_norm")(ahead)],
+                    axis=-1).astype(self.dtype)
+                with jax.named_scope("mtp_proj"):
+                    h = nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
+                                 name="mtp_proj")(joined)
+                h = leave(Block(
+                    self.num_heads, self.mlp_dim, self.dropout, self.dtype,
+                    num_experts=self.num_experts,
+                    num_kv_heads=self.num_kv_heads, layer=self.layer,
+                    name="mtp_block")(enter(h), train, positions))
+                with jax.named_scope("head"):
+                    self.sow("intermediates", "mtp_logits",
+                             head(final_norm(h)))
+        return logits
 
     @staticmethod
     def frozen_prefixes(freeze_base: bool) -> tuple[str, ...]:
@@ -762,7 +977,10 @@ def build_lm(cfg, seq_axis: str | None = None,
         pos_encoding=getattr(cfg, "pos_encoding", "learned"),
         remat=getattr(cfg, "remat", "none"),
         layer=getattr(cfg, "layer", LayerSpec()),
-        pattern=getattr(cfg, "pattern", ""))
+        pattern=getattr(cfg, "pattern", ""),
+        dense_layers=getattr(cfg, "dense_layers", 0),
+        dense_mlp_dim=getattr(cfg, "dense_mlp_dim", 0),
+        mtp_depth=getattr(cfg, "mtp_depth", 0))
 
 
 def init_cache(decode_model: TransformerLM, batch: int):

@@ -720,14 +720,19 @@ class RoutedExperts(nn.Module):
                 self.dtype, rows=chunk_rows(self.k * t, e, width))
         self.sow("intermediates", "counters", {"moe_rows_run_share": share})
         if self.shared_dim:
-            if self.act != "relu2":
+            if self.act not in ("relu2", "swiglu"):
                 raise NotImplementedError("the shared expert is written for "
-                                          "mlp='relu2' (up, down)")
+                                          "mlp='relu2' (up, down) and "
+                                          "'swiglu' (gate, up, down)")
             with jax.named_scope("shared_expert"):
                 dense = lambda width, name: nn.Dense(     # noqa: E731
                     width, use_bias=False, dtype=self.dtype, name=name)
-                hidden = jnp.square(nn.relu(dense(self.shared_dim,
-                                                  "shared_up")(xt)))
+                hidden = dense(self.shared_dim, "shared_up")(xt)
+                if self.act == "swiglu":
+                    hidden = nn.silu(dense(self.shared_dim,
+                                           "shared_gate")(xt)) * hidden
+                else:
+                    hidden = jnp.square(nn.relu(hidden))
                 out = out + dense(d, "shared_down")(hidden)
         self.sow("intermediates", "expert_choice", experts)
         here = (experts >= self.offset) & (experts < self.offset + e)

@@ -27,6 +27,14 @@ Design (Dao et al. flash attention, TPU-first):
   per-row logsumexp; dQ streams K/V blocks, dK/dV streams Q/dO blocks, each
   rematerializing p = exp(s - L) blockwise in VMEM — O(S) HBM for the whole
   train step, the S x S matrices never exist in HBM;
+- q and k heads may be wider than v heads (latent attention: 192 and 128).
+  A lane block then holds the fewest heads whose q/k lanes AND v lanes both
+  fill whole 128-lane rows (two: 384 and 256), nothing is padded in HBM, and
+  a head whose lanes do not start or end on a lane row is read as the aligned
+  window of lane rows around it with the neighbour's lanes zeroed in q (192
+  as one and a half lane rows: a 256-deep contraction, which costs the MXU
+  what a 192-deep one does); v, dO and the accumulator stay 128 wide a head,
+  so no product with v runs at the q/k width (``_head_windows``);
 - sequences under 512 tokens take a one-block form of the same kernels (no
   streaming, one backward kernel, nothing padded in HBM; see "Short
   sequences" below);
@@ -41,6 +49,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.custom_partitioning import custom_partitioning
 from jax.experimental.pallas import tpu as pltpu
@@ -95,7 +104,8 @@ def _def_bh_partition(fn, impl, rule, out_ndims):
     # first-appearance order of the rule string (q before d before s).
     fn.def_partition(partition=partition, infer_sharding_from_operands=infer,
                      sharding_rule=rule,
-                     need_replication_factors=("q", "d", "s"))
+                     need_replication_factors=tuple(
+                         f for f in ("q", "d", "s", "e") if f" {f}" in rule))
     return fn
 
 
@@ -112,7 +122,7 @@ def _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
 
     fn = custom_partitioning(impl)
     return _def_bh_partition(
-        fn, impl, "b q h d, b s h d, b s h d -> b q h d, b h q",
+        fn, impl, "b q h d, b s h d, b s h e -> b q h e, b h q",
         out_ndims=(4, 3))
 
 
@@ -229,9 +239,52 @@ def _only(mask, x):
     return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
 
 
+def _head_windows(heads: int, head_dim: int, width: int):
+    """Where each head of a lane block of ``width`` lanes lies, for a kernel
+    to read it by: ``(lanes, mask)`` a head. ``lanes`` is the aligned window
+    of whole 128-lane rows around the head's ``head_dim`` lanes (a static
+    slice; None where that is the whole block, which is every layout with
+    equal q/k and v widths; :func:`_at` indexes by it) and ``mask`` the head's
+    lanes inside it, ``[1, window]``, None where the head fills its window."""
+    if heads == 1 or width <= _LANES:       # one lane row, or one head: whole
+        return [(None, mask) for mask in _head_masks(heads, head_dim, width)]
+    out = []
+    for t in range(heads):
+        lo, hi = t * head_dim, (t + 1) * head_dim
+        start = lo // _LANES * _LANES
+        stop = min(-(-hi // _LANES) * _LANES, width)
+        lanes = None if (start, stop) == (0, width) else slice(start, stop)
+        mask = None
+        if (start, stop) != (lo, hi):
+            lane = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, stop - start), 1)
+            mask = jnp.logical_and(lane >= lo, lane < hi)
+        out.append((lanes, mask))
+    return out
+
+
+def _at(lanes, rows=None):
+    """The index of a head's window in a block: all rows or ``rows``, the
+    window's ``lanes`` (None: every lane)."""
+    if rows is None:
+        return ... if lanes is None else (slice(None), lanes)
+    return rows, slice(None) if lanes is None else lanes
+
+
+def _qv_windows(heads: int, head_dim: int, q_width: int, v_dim: int,
+                v_width: int):
+    """:func:`_head_windows` of the q/k side and of the v side; one list for
+    both where the two sides are laid out alike."""
+    q_heads = _head_windows(heads, head_dim, q_width)
+    if (head_dim, q_width) == (v_dim, v_width):
+        return q_heads, q_heads
+    return q_heads, _head_windows(heads, v_dim, v_width)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                  heads: int, head_dim: int, block_q: int, block_k: int,
-                  sub_k: int, causal: bool, q_offset: int, k_offset: int,
+                  heads: int, head_dim: int, v_dim: int, block_q: int,
+                  block_k: int, sub_k: int, causal: bool, q_offset: int,
+                  k_offset: int,
                   sm_scale: float, k_valid: int | None):
     """One (batch, head block, q-block, k-block) grid step of online-softmax
     attention, for the ``heads`` heads of the lane block in turn.
@@ -251,7 +304,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     kb = pl.program_id(3)
     num_kb = pl.num_programs(3)
     width = acc_scr.shape[-1]
-    masks = _head_masks(heads, head_dim, width)
+    q_heads, v_heads = _qv_windows(heads, head_dim, q_ref.shape[-1], v_dim,
+                                   width)
+    # the heads' lanes of the whole output block, for the last step
+    masks = ([mask for _, mask in v_heads]
+             if all(lanes is None for lanes, _ in v_heads)
+             else _head_masks(heads, v_dim, width))
 
     @pl.when(kb == 0)
     def _init():
@@ -263,12 +321,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     k0 = k_offset + kb * block_k
     bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    for t, mask in enumerate(masks):
-        q = _only(mask, q_ref[...])                      # [block_q, heads*d]
+    for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
+        q = _only(qmask, q_ref[_at(qw)])                   # [block_q, window]
 
         def _attend(j, masked):
             ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
-            s = _scores(q, k_ref[ks, :], sm_scale)       # [block_q, sub_k]
+            s = _scores(q, k_ref[_at(qw, ks)], sm_scale)      # [block_q, sub_k]
             if masked:
                 s = _mask_scores(s, q0, k0 + j * sub_k, causal, k_valid, 1)
             m_prev = m_scr[t]
@@ -278,11 +336,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             p = jnp.exp(s - _lanes(m_ref, sub_k))
             alpha = jnp.exp(m_prev - m_new)
             l_scr[t] = alpha * l_scr[t] + jnp.sum(p, axis=1, keepdims=True)
-            pv = jnp.dot(p.astype(q.dtype), v_ref[ks, :],
+            pv = jnp.dot(p.astype(q.dtype), v_ref[_at(vw, ks)],
                          preferred_element_type=jnp.float32)
-            acc = acc_scr[...]
-            new = acc * _lanes(alpha, width) + pv        # this head's lanes
-            acc_scr[...] = new if mask is None else jnp.where(mask, new, acc)
+            acc = acc_scr[_at(vw)]
+            new = acc * _lanes(alpha, acc.shape[-1]) + pv  # this head's lanes
+            acc_scr[_at(vw)] = (new if vmask is None
+                              else jnp.where(vmask, new, acc))
             m_scr[t] = m_new
 
         _for_sub_blocks(*bounds, _attend)
@@ -326,6 +385,21 @@ def _head_blocks(h: int, d: int):
     return per, _LANES // per, -(-h // per) * per
 
 
+def _head_layout(h: int, d: int, dv: int):
+    """:func:`_head_blocks` for q/k heads of ``d`` and v heads of ``dv``:
+    ``(heads a block, padded d, padded dv, padded h)``. Equal widths are
+    :func:`_head_blocks`'s layout. Unequal ones take the fewest heads a block
+    whose q/k lanes and v lanes both fill whole lane rows, and pad nothing;
+    None where no such count divides the heads (the XLA tiers serve)."""
+    if d == dv:
+        per, dp, hp = _head_blocks(h, d)
+        return per, dp, dp, hp
+    for per in (1, 2, 4, 8):
+        if h % per == 0 and not (per * d % _LANES or per * dv % _LANES):
+            return per, d, dv, h
+    return None
+
+
 def _to_blocks(x, dp: int, hp: int):
     """``[B, S, H, D]`` -> ``[B, S, hp*dp]`` (zero-padded where d or h grow)."""
     b, s, h, d = x.shape
@@ -348,53 +422,64 @@ def _rows(x, per: int, hp: int):
     return x.reshape(b, hp // per, per, sq)
 
 
-def _specs(per: int, dp: int, bq: int, bk: int, q_inner: bool):
-    """Block specs of the q-side tiles, their f32 rows and the k-side tiles
-    for a grid (batch, head block, outer, inner), the q blocks inner or not."""
+def _specs(per: int, dp: int, dvp: int, bq: int, bk: int, q_inner: bool):
+    """Block specs of the q-side tiles (q, dq at ``dp`` a head; o, dO at
+    ``dvp``), their f32 rows and the k-side tiles (k, dk; v, dv) for a grid
+    (batch, head block, outer, inner), the q blocks inner or not."""
     qi, ki = (3, 2) if q_inner else (2, 3)      # their grid dimensions
-    qspec = pl.BlockSpec((None, bq, per * dp), lambda *g: (g[0], g[qi], g[1]),
-                         memory_space=pltpu.VMEM)
+    tile = lambda rows, wide, at: pl.BlockSpec(                 # noqa: E731
+        (None, rows, per * wide), lambda *g: (g[0], g[at], g[1]),
+        memory_space=pltpu.VMEM)
     qrow = pl.BlockSpec((None, None, per, bq),
                         lambda *g: (g[0], g[1], 0, g[qi]),
                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((None, bk, per * dp), lambda *g: (g[0], g[ki], g[1]),
-                         memory_space=pltpu.VMEM)
-    return qspec, qrow, kspec
+    return (tile(bq, dp, qi), tile(bq, dvp, qi), qrow, tile(bk, dp, ki),
+            tile(bk, dvp, ki))
 
 
 @functools.partial(jax.jit, static_argnums=tuple(range(3, 12)))
 def _flash_forward(q, k, v, causal, q_offset, k_offset, sm_scale, block_q,
                    block_k, interpret, k_valid=None, sub_k=None):
-    """q [B,Sq,H,D], k/v [B,Sk,H,D] -> (out [B,Sq,H,D], lse [B,H,Sq] f32, the
-    backward residual)."""
+    """q [B,Sq,H,D], k [B,Sk,H,D], v [B,Sk,H,Dv] -> (out [B,Sq,H,Dv], lse
+    [B,H,Sq] f32, the backward residual)."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
-    per, dp, hp = _head_blocks(h, d)
+    per, dp, dvp, hp = _require_layout(h, d, dv)
     kernel = functools.partial(
-        _flash_kernel, heads=per, head_dim=dp, block_q=bq, block_k=bk,
-        sub_k=sub_k, causal=causal, q_offset=q_offset, k_offset=k_offset,
-        sm_scale=sm_scale, k_valid=k_valid)
-    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=False)
+        _flash_kernel, heads=per, head_dim=dp, v_dim=dvp, block_q=bq,
+        block_k=bk, sub_k=sub_k, causal=causal, q_offset=q_offset,
+        k_offset=k_offset, sm_scale=sm_scale, k_valid=k_valid)
+    qspec, ospec, qrow, kspec, vspec = _specs(per, dp, dvp, bq, bk,
+                                              q_inner=False)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, hp // per, sq // bq, sk // bk),  # k innermost: scratch carries
-        in_specs=[qspec, kspec, kspec],
-        out_specs=[qspec, qrow],
+        in_specs=[qspec, kspec, vspec],
+        out_specs=[ospec, qrow],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
+            jax.ShapeDtypeStruct((b, sq, hp * dvp), q.dtype),
             jax.ShapeDtypeStruct((b, hp // per, per, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((per, bq, _LANES), jnp.float32),
             pltpu.VMEM((per, bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, per * dp), jnp.float32),
+            pltpu.VMEM((bq, per * dvp), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_fwd",
-    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp))
-    return _from_blocks(out, h, d, dp), lse.reshape(b, hp, sq)[:, :h]
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dvp, hp))
+    return _from_blocks(out, h, dv, dvp), lse.reshape(b, hp, sq)[:, :h]
+
+
+def _require_layout(h: int, d: int, dv: int):
+    layout = _head_layout(h, d, dv)
+    if layout is None:
+        raise NotImplementedError(
+            f"{h} heads of {d} (q, k) and {dv} (v) lanes fill no whole lane "
+            f"rows together; the XLA tiers take them")
+    return layout
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
@@ -412,6 +497,10 @@ def _flash_lse_fwd(q, k, v, causal, q_offset, k_offset, sm_scale, block_q,
                    block_k, interpret, k_valid):
     out, lse = _partitioned_fwd(causal, q_offset, k_offset, sm_scale, block_q,
                                 block_k, interpret, k_valid)(q, k, v)
+    # a block rematerialised whole keeps both (models/lm.py saves the name),
+    # so its backward pass runs no forward kernel a second time
+    out = checkpoint_name(out, "attention_out")
+    lse = checkpoint_name(lse, "attention_out")
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -477,7 +566,8 @@ def flash_attention(q, k, v, causal: bool = False, q_offset: int = 0,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
                dq_scr, lse_scr, dvec_scr, *, heads: int, head_dim: int,
-               block_q: int, block_k: int, sub_k: int, causal: bool,
+               v_dim: int, block_q: int, block_k: int, sub_k: int,
+               causal: bool,
                q_offset: int, k_offset: int, sm_scale: float,
                k_valid: int | None):
     """dQ pass (FA2 backward): grid (B, head blocks, q-blocks, k-blocks), K
@@ -492,7 +582,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     num_kb = pl.num_programs(3)
-    masks = _head_masks(heads, head_dim, dq_scr.shape[-1])
+    q_heads, v_heads = _qv_windows(heads, head_dim, dq_scr.shape[-1], v_dim,
+                                   v_ref.shape[-1])
 
     @pl.when(kb == 0)
     def _init():
@@ -507,22 +598,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
     k0 = k_offset + kb * block_k
     bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    for t, mask in enumerate(masks):
-        q = _only(mask, q_ref[...])
-        do = _only(mask, do_ref[...])
+    for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
+        q = _only(qmask, q_ref[_at(qw)])
+        do = _only(vmask, do_ref[_at(vw)])
 
         def _accum(j, masked):
             ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
-            k_blk = k_ref[ks, :]
+            k_blk = k_ref[_at(qw, ks)]
             s = _scores(q, k_blk, sm_scale)              # [block_q, sub_k]
             if masked:
                 s = _mask_scores(s, q0, k0 + j * sub_k, causal, k_valid, 1)
             p = jnp.exp(s - _lanes(lse_scr[t], sub_k))
-            dp = _scores(do, v_ref[ks, :], 1.0)
+            dp = _scores(do, v_ref[_at(vw, ks)], 1.0)
             ds = p * (dp - _lanes(dvec_scr[t], sub_k))
             dq = jnp.dot(ds.astype(q.dtype), k_blk,
                          preferred_element_type=jnp.float32)
-            dq_scr[...] += dq if mask is None else jnp.where(mask, dq, 0.0)
+            dq_scr[_at(qw)] += (dq if qmask is None
+                              else jnp.where(qmask, dq, 0.0))
 
         _for_sub_blocks(*bounds, _accum)
 
@@ -532,9 +624,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, heads: int, head_dim: int, block_q: int,
-                block_k: int, sub_k: int, causal: bool, q_offset: int,
-                k_offset: int, sm_scale: float, k_valid: int | None):
+                dk_scr, dv_scr, *, heads: int, head_dim: int, v_dim: int,
+                block_q: int, block_k: int, sub_k: int, causal: bool,
+                q_offset: int, k_offset: int, sm_scale: float,
+                k_valid: int | None):
     """dK/dV pass: grid (B, head blocks, k-blocks, q-blocks), Q innermost.
 
     dv_j += p_ij^T dO_i; dk_j += sm_scale * ds_ij^T q_i. Works on TRANSPOSED
@@ -545,7 +638,8 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
     kj = pl.program_id(2)
     qb = pl.program_id(3)
     num_qb = pl.num_programs(3)
-    masks = _head_masks(heads, head_dim, dk_scr.shape[-1])
+    q_heads, v_heads = _qv_windows(heads, head_dim, dk_scr.shape[-1], v_dim,
+                                   dv_scr.shape[-1])
 
     @pl.when(qb == 0)
     def _init():
@@ -556,26 +650,26 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
     k0 = k_offset + kj * block_k
     bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    for t, mask in enumerate(masks):
-        q = _only(mask, q_ref[...])
-        do = _only(mask, do_ref[...])
+    for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
+        q = _only(qmask, q_ref[_at(qw)])
+        do = _only(vmask, do_ref[_at(vw)])
         lse = _finite_ref(lse_ref[t:t + 1, :])           # [1, block_q]
         dvec = dvec_ref[t:t + 1, :]
 
         def _accum(j, masked):
             ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
-            st = _scores(k_ref[ks, :], q, sm_scale)      # [sub_k, block_q]
+            st = _scores(k_ref[_at(qw, ks)], q, sm_scale)     # [sub_k, block_q]
             if masked:
                 st = _mask_scores(st, q0, k0 + j * sub_k, causal, k_valid, 0)
             pt = jnp.exp(st - lse)
             dv = jnp.dot(pt.astype(do.dtype), do,
                          preferred_element_type=jnp.float32)
-            dpt = _scores(v_ref[ks, :], do, 1.0)
+            dpt = _scores(v_ref[_at(vw, ks)], do, 1.0)
             dst = pt * (dpt - dvec)
             dk = jnp.dot(dst.astype(q.dtype), q,
                          preferred_element_type=jnp.float32)
-            dv_scr[ks, :] += dv          # q and do carry this head's lanes
-            dk_scr[ks, :] += dk          # only, so dv and dk do too
+            dv_scr[_at(vw, ks)] += dv        # q and do carry this head's lanes
+            dk_scr[_at(qw, ks)] += dk        # only, so dv and dk do too
 
         _for_sub_blocks(*bounds, _accum)
 
@@ -588,19 +682,21 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
 @functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
 def _flash_dq(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
               block_q, block_k, interpret, k_valid=None, sub_k=None):
-    """q, g [B,Sq,H,D]; k, v [B,Sk,H,D]; lse, dvec [B,H,Sq] f32 -> dq."""
+    """q [B,Sq,H,D], g [B,Sq,H,Dv]; k [B,Sk,H,D], v [B,Sk,H,Dv]; lse, dvec
+    [B,H,Sq] f32 -> dq."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
-    per, dp, hp = _head_blocks(h, d)
-    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=False)
+    per, dp, dvp, hp = _require_layout(h, d, v.shape[-1])
+    qspec, ospec, qrow, kspec, vspec = _specs(per, dp, dvp, bq, bk,
+                                              q_inner=False)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, heads=per, head_dim=dp, block_q=bq,
-                          block_k=bk, sub_k=sub_k, causal=causal,
+        functools.partial(_dq_kernel, heads=per, head_dim=dp, v_dim=dvp,
+                          block_q=bq, block_k=bk, sub_k=sub_k, causal=causal,
                           q_offset=q_offset, k_offset=k_offset,
                           sm_scale=sm_scale, k_valid=k_valid),
         grid=(b, hp // per, sq // bq, sk // bk),
-        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
+        in_specs=[qspec, kspec, vspec, ospec, qrow, qrow],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, per * dp), jnp.float32),
@@ -609,8 +705,8 @@ def _flash_dq(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
         compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_dq",
-    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dp, hp),
-      _to_blocks(g, dp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
+    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dvp, hp),
+      _to_blocks(g, dvp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
     return _from_blocks(dq, h, d, dp)
 
 
@@ -619,28 +715,29 @@ def _flash_dkv(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
                block_q, block_k, interpret, k_valid=None, sub_k=None):
     """Same operands as :func:`_flash_dq` -> (dk, dv)."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv_ = k.shape[1], v.shape[-1]
     bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
-    per, dp, hp = _head_blocks(h, d)
-    qspec, qrow, kspec = _specs(per, dp, bq, bk, q_inner=True)
+    per, dp, dvp, hp = _require_layout(h, d, dv_)
+    qspec, ospec, qrow, kspec, vspec = _specs(per, dp, dvp, bq, bk,
+                                              q_inner=True)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, heads=per, head_dim=dp, block_q=bq,
-                          block_k=bk, sub_k=sub_k, causal=causal,
+        functools.partial(_dkv_kernel, heads=per, head_dim=dp, v_dim=dvp,
+                          block_q=bq, block_k=bk, sub_k=sub_k, causal=causal,
                           q_offset=q_offset, k_offset=k_offset,
                           sm_scale=sm_scale, k_valid=k_valid),
         grid=(b, hp // per, sk // bk, sq // bq),
-        in_specs=[kspec, kspec, qspec, qspec, qrow, qrow],
-        out_specs=[kspec, kspec],
+        in_specs=[kspec, vspec, qspec, ospec, qrow, qrow],
+        out_specs=[kspec, vspec],
         out_shape=[jax.ShapeDtypeStruct((b, sk, hp * dp), k.dtype),
-                   jax.ShapeDtypeStruct((b, sk, hp * dp), v.dtype)],
+                   jax.ShapeDtypeStruct((b, sk, hp * dvp), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, per * dp), jnp.float32),
-                        pltpu.VMEM((bk, per * dp), jnp.float32)],
+                        pltpu.VMEM((bk, per * dvp), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_dkv",
-    )(_to_blocks(k, dp, hp), _to_blocks(v, dp, hp), _to_blocks(q, dp, hp),
-      _to_blocks(g, dp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
-    return _from_blocks(dk, h, d, dp), _from_blocks(dv, h, d, dp)
+    )(_to_blocks(k, dp, hp), _to_blocks(v, dvp, hp), _to_blocks(q, dp, hp),
+      _to_blocks(g, dvp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
+    return _from_blocks(dk, h, d, dp), _from_blocks(dv, h, dv_, dvp)
 
 
 @functools.lru_cache(maxsize=None)
@@ -661,8 +758,8 @@ def _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
     fn = custom_partitioning(impl)
     return _def_bh_partition(
         fn, impl,
-        "b q h d, b s h d, b s h d, b h q, b q h d, b h q -> "
-        "b q h d, b s h d, b s h d",
+        "b q h d, b s h d, b s h e, b h q, b q h e, b h q -> "
+        "b q h d, b s h d, b s h e",
         out_ndims=(4, 4, 4))
 
 
@@ -1085,21 +1182,26 @@ def _xla_attention_lse(q, k, v, causal: bool, q_offset, k_offset,
     return out, lse
 
 
-def _attn_impl(q, k, impl: str) -> str:
-    """The tier for q [B,H,Sq,D] and k [B,H,Sk,D] (shapes are all it reads)."""
+def _attn_impl(q, k, impl: str, v_dim: int | None = None) -> str:
+    """The tier for q [B,H,Sq,D] and k [B,H,Sk,D] (shapes are all it reads),
+    and v heads of ``v_dim`` where they differ from D: the streaming kernels
+    take those where a lane block holds whole heads of both
+    (:func:`_head_layout`), the XLA tiers everything else."""
     if impl != "auto":
         return impl
-    b, h, sq, _ = q.shape
+    b, h, sq, d = q.shape
     sk = k.shape[2]
-    if min(sq, sk) >= _FLASH_MIN_SEQ:
+    equal = v_dim in (None, d)
+    kernels = equal or _head_layout(h, d, v_dim) is not None
+    if min(sq, sk) >= _FLASH_MIN_SEQ and kernels:
         return "pallas"
     if (min(sq, sk) >= _SHORT_MIN_SEQ and max(sq, sk) <= _SHORT_MAX_SEQ
-            and q.shape[3] in _SHORT_HEAD_DIMS):
+            and d in _SHORT_HEAD_DIMS and equal):
         return "pallas_short"
     score_bytes = b * h * sq * sk * 4
     if score_bytes <= _XLA_PLAIN_MAX:
         return "xla"
-    if score_bytes <= _XLA_CKPT_MAX:
+    if score_bytes <= _XLA_CKPT_MAX or not kernels:
         return "xla_ckpt"
     return "pallas"
 
@@ -1131,7 +1233,7 @@ def flash_mha_lse(q, k, v, causal: bool = False, sm_scale: float | None = None,
     per hop so arbitrary local shard lengths work."""
     with jax.named_scope("attention"):      # every tier, for a profile's split
         return _dispatch_lse(q, k, v, causal, sm_scale, block_q, block_k,
-                             interpret, _attn_impl(q, k, impl),
+                             interpret, _attn_impl(q, k, impl, v.shape[-1]),
                              seq_major=False)
 
 
@@ -1141,11 +1243,13 @@ def flash_mha_seq_major(q, k, v, causal: bool = False,
     """:func:`flash_mha` for operands as the projections produce them (the LM
     and ViT): q [B,Sq,H,D], k/v [B,Sk,H,D] -> [B,Sq,H,D]. The kernels take
     that layout as it is; the XLA tiers get the ``[B,H,S,D]`` transposes they
-    always got."""
+    always got. ``v`` may be ``[B,Sk,H,Dv]`` with ``Dv != D``; the output is
+    then ``[B,Sq,H,Dv]``."""
     with jax.named_scope("attention"):
         (b, sq, h, d), sk = q.shape, k.shape[1]
         tier = _attn_impl(jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-                          jax.ShapeDtypeStruct((b, h, sk, d), k.dtype), impl)
+                          jax.ShapeDtypeStruct((b, h, sk, d), k.dtype), impl,
+                          v.shape[-1])
         return _dispatch_lse(q, k, v, causal, sm_scale, None, None, None,
                              tier, seq_major=True)[0]
 

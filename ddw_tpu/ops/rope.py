@@ -22,6 +22,8 @@ distinguish neighboring positions).
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -31,10 +33,59 @@ def rope_angles(positions: jnp.ndarray, head_dim: int,
     (leading axes pass through: ``[B, S]`` -> ``[B, S, hd/2]``)."""
     if head_dim % 2:
         raise ValueError(f"RoPE needs an even head_dim, got {head_dim}")
-    inv_freq = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    return _turns(positions, 1.0 / (theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)))
+
+
+def _turns(positions: jnp.ndarray, inv_freq: jnp.ndarray):
+    """(cos, sin) of each position times each pair's frequency, float32."""
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  beta_fast: float, beta_slow: float,
+                  original_len: int) -> jnp.ndarray:
+    """YaRN's frequencies (Peng et al., arXiv:2309.00071, as DeepSeek-V2/V3
+    apply them): pair ``i`` turns at ``theta^(-2i/hd)`` where it makes
+    ``beta_fast`` turns or more in ``original_len`` positions, at that over
+    ``factor`` where it makes ``beta_slow`` or fewer, and at the linear blend
+    of the two over the pairs between (the ramp's ends are the floor and the
+    ceiling of the two pairs' fractional indices). ``[hd/2]`` float32;
+    ``factor = 1`` is plain RoPE."""
+    pairs = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    plain = 1.0 / theta ** pairs
+
+    def pair_turning(turns: float) -> float:
+        return (head_dim * math.log(original_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_angles(positions: jnp.ndarray, head_dim: int, theta: float,
+                factor: float, beta_fast: float, beta_slow: float,
+                original_len: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`rope_angles` at :func:`yarn_inv_freq`'s frequencies, float32.
+    The cos/sin scale ``mscale / mscale_all_dim`` of the published
+    configurations is 1 and is not applied; the softmax scale's factor is the
+    attention's (``models/lm.py``)."""
+    if head_dim % 2:
+        raise ValueError(f"RoPE needs an even head_dim, got {head_dim}")
+    return _turns(positions, yarn_inv_freq(head_dim, theta, factor, beta_fast,
+                                           beta_slow, original_len))
+
+
+def yarn_softmax_factor(factor: float) -> float:
+    """What YaRN multiplies the softmax scale by at ``mscale_all_dim = 1``:
+    ``(0.1 ln factor + 1)^2``; 1 at a factor of 1 or less."""
+    return (0.1 * math.log(factor) + 1.0) ** 2 if factor > 1 else 1.0
 
 
 def mrope_angles(positions: jnp.ndarray, head_dim: int, theta: float,
@@ -57,14 +108,16 @@ def mrope_angles(positions: jnp.ndarray, head_dim: int, theta: float,
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, seq_axis: int = -2,
                theta: float = 10000.0,
-               sections: tuple[int, ...] = ()) -> jnp.ndarray:
+               sections: tuple[int, ...] = (),
+               yarn: tuple = ()) -> jnp.ndarray:
     """Rotate ``x`` by its positions. The last axis is the head dim;
     ``seq_axis`` is where S lives (``-2`` for ``[B, H, S, hd]``, ``1`` for
     the pre-transpose ``[B, S, H, hd]`` projection layout). ``positions`` is
     ``[S]`` (shared across the batch) or ``[B, S]`` (per-row positions — the
     serving slot pool decodes rows at independent depths); with ``sections``
-    (:func:`mrope_angles`) it carries a leading axis of position components.
-    Returns the same dtype as ``x``."""
+    (:func:`mrope_angles`) it carries a leading axis of position components;
+    ``yarn = (factor, beta_fast, beta_slow, original_len)`` turns by
+    :func:`yarn_angles`. Returns the same dtype as ``x``."""
     hd = x.shape[-1]
     axis = seq_axis % x.ndim
     if axis == x.ndim - 1:
@@ -76,6 +129,8 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, seq_axis: int = -2,
     if sections:
         cos, sin = mrope_angles(positions, hd, theta, tuple(sections))
         positions = positions[0]
+    elif yarn:
+        cos, sin = yarn_angles(positions, hd, theta, *yarn)
     else:
         cos, sin = rope_angles(positions, hd, theta)
     # broadcast cos/sin to x's layout: S at `axis`, hd/2 at the last axis

@@ -103,6 +103,7 @@ def _lm_axes(model, data_axis: str, seq_axis: str | None) -> tuple:
                          f"seq_axis={seq_axis!r} — construct the model with the "
                          f"axis it will run under")
     sows = (getattr(model, "num_experts", 0) > 0
+            or getattr(model, "mtp_depth", 0) > 0
             or getattr(model, "layer", None) is not None and model.layer.sows)
     expert_axis = getattr(model, "expert_axis", None)
     if expert_axis and expert_axis not in axes:
@@ -121,6 +122,7 @@ def make_lm_train_step(
     aux_loss_weight: float = 0.01,
     grad_accum_steps: int = 1,
     hand_out: tuple[str, ...] = (),
+    mtp_weight: float = 0.1,
 ) -> Callable:
     """Build the jitted DP(xSP)(xEP) LM train step.
 
@@ -130,7 +132,11 @@ def make_lm_train_step(
     model's ``expert_axis`` must be one of the step's mesh axes (its all_to_alls
     then ride that axis). Metrics (loss, token accuracy) come back
     world-averaged; for MoE models the Switch load-balance aux loss is added
-    with ``aux_loss_weight`` and reported as ``metrics['aux_loss']``. It
+    with ``aux_loss_weight`` and reported as ``metrics['aux_loss']``; a model
+    with a multi-token-prediction module (``mtp_depth``) descends ``loss +
+    mtp_weight * mtp_loss``, the module's cross-entropy against the token
+    after next over the positions that have one, and reports it among the
+    layers' counters (``loss`` and ``accuracy`` stay the main head's). It
     compiles once for each placement of its arguments:
     ``step.place_state(state)`` before the first call gives the state the
     placement the step returns it in, and one executable serves.
@@ -146,7 +152,7 @@ def make_lm_train_step(
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
     _step = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                               grad_accum_steps, hand_out)
+                               grad_accum_steps, hand_out, mtp_weight)
 
     tok_spec = P(data_axis) if seq_axis is None else P(data_axis, seq_axis)
     smapped = shard_map(
@@ -163,7 +169,8 @@ def make_lm_train_step(
 
 def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
                        aux_loss_weight: float, grad_accum_steps: int,
-                       hand_out: tuple[str, ...] = ()):
+                       hand_out: tuple[str, ...] = (),
+                       mtp_weight: float = 0.1):
     """The per-update shard_map body shared by :func:`make_lm_train_step`
     and :func:`make_lm_train_chain` (which scans it K times)."""
     from flax.traverse_util import flatten_dict
@@ -213,6 +220,13 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
             # ``loss`` stays the cross-entropy; the indexer's KL term reaches
             # its three matrices alone (the layer stops every other path)
             total = ce + aux_loss_weight * aux + terms.get("indexer_kl", 0.0)
+            if sows and (ahead := collect_sown(mods, "mtp_logits")):
+                # the module's logits at position i against the token after
+                # next; the row's last position has none
+                with jax.named_scope("mtp"), jax.named_scope("loss"):
+                    terms["mtp_loss"] = lm_loss(ahead[0][:, :-1],
+                                                tg_mb[:, 1:])
+                total = total + mtp_weight * terms["mtp_loss"]
             return total, (ce, acc, aux, terms, loads, handed)
 
         def grad_fn(*args):
@@ -299,6 +313,7 @@ def make_lm_train_chain(
     donate: bool = True,
     aux_loss_weight: float = 0.01,
     grad_accum_steps: int = 1,
+    mtp_weight: float = 0.1,
 ) -> Callable:
     """Fused K-step LM train program (``TrainCfg.steps_per_dispatch``): the
     :func:`make_lm_train_step` body ``lax.scan``-ned over a stacked token
@@ -310,7 +325,7 @@ def make_lm_train_chain(
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
     body = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                              grad_accum_steps)
+                              grad_accum_steps, mtp_weight=mtp_weight)
 
     def _chain(state: TrainState, inputs, targets, rng):
         def scanned(st, xs):

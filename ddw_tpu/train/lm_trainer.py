@@ -397,11 +397,13 @@ class LMTrainer:
             else:
                 step = make_lm_train_step(
                     self.model, tx, mesh, seq_axis=self.seq_axis,
-                    grad_accum_steps=cfg.grad_accum_steps)
+                    grad_accum_steps=cfg.grad_accum_steps,
+                    mtp_weight=cfg.mtp_weight)
                 if chained:
                     chain = make_lm_train_chain(
                         self.model, tx, mesh, seq_axis=self.seq_axis,
-                        grad_accum_steps=cfg.grad_accum_steps)
+                        grad_accum_steps=cfg.grad_accum_steps,
+                        mtp_weight=cfg.mtp_weight)
             # Under ZeRO/FSDP eval reads the sharded params through the
             # shard_map eval step's replicated in-spec: GSPMD gathers per
             # eval call (same trade the vision Trainer makes).

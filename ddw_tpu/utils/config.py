@@ -159,6 +159,11 @@ class TrainCfg:
                                         # bytes; nu stays f32 (feeds rsqrt).
                                         # adadelta refuses (both its
                                         # accumulators are nu-like)
+    mtp_weight: float = 0.1             # the multi-token-prediction term's
+                                        # weight in the loss that is descended
+                                        # (LMCfg.mtp_depth > 0; DeepSeek-V3's
+                                        # late-training value); ``loss`` stays
+                                        # the main head's cross-entropy
     data_axis: str = "data"             # mesh axis name for DP psum
     num_devices: int = 0                # 0 = all visible devices
     zero: bool = False                  # ZeRO-1: shard optimizer moments over
@@ -234,7 +239,24 @@ class LayerSpec:
     attention: str = "full"             # "full" | "indexed": a lightning
                                         # indexer scores every causal key and
                                         # each query attends to its index_topk
-                                        # best (ops/indexed_attention.py)
+                                        # best (ops/indexed_attention.py) |
+                                        # "latent": low-rank q and kv paths
+                                        # with a norm on each latent, one
+                                        # rotary key head shared by all query
+                                        # heads (MLA, DeepSeek-V2)
+    q_lora_rank: int = 0                # latent: the query latent's width
+    kv_lora_rank: int = 0               # latent: the key/value latent's width
+    qk_nope_dim: int = 0                # latent: a q/k head's part without
+    qk_rope_dim: int = 0                # positions, and its rotary part
+    v_head_dim: int = 0                 # latent: a value head's width
+    rope_scaling: str = ""              # "" | "yarn" (ops/rope.py yarn_angles:
+                                        # blended frequencies, and the latent
+                                        # attention's softmax scale times
+                                        # (0.1 ln rope_factor + 1)^2)
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original_len: int = 0          # the context the factor stretches
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -274,12 +296,25 @@ class LayerSpec:
     ssm_chunk: int = 128                # tokens a chunk of the scan
     ssm_dt_shift: float = 0.0           # added to dt_bias inside the softplus;
                                         # a trained bias can fold it in
+    # manifold-constrained hyper-connections (arXiv:2512.24880) around both
+    # sublayers of a DecoderBlock (models/lm.py HyperConnection)
+    hyper_streams: int = 0              # 0 | 1: the residual h + f(norm(h));
+                                        # n > 1: n streams [B, S, n, C], read
+                                        # by a learnt mix, written back to all
+                                        # and mixed by a doubly stochastic
+                                        # matrix a token and sublayer
+    hyper_sinkhorn_iters: int = 20      # rounds of (rows, then columns)
+    hyper_eps: float = 1e-6             # added to each sum a round divides by
+    hyper_res_clamp: tuple[float, float] = (-30.0, 30.0)  # before the exp
+    hyper_res_diag: float = 0.0         # added to the diagonal of the mixing
+                                        # matrix's logits; a trained b_res can
+                                        # fold it in
 
     @property
     def sows(self) -> bool:
         """Whether a layer of this spec sows a loss term or counters."""
         return (self.attention == "indexed" or self.experts_per_token > 0
-                or self.ssm_heads > 0)
+                or self.ssm_heads > 0 or self.hyper_streams > 1)
 
 
 @dataclass
@@ -326,6 +361,15 @@ class LMCfg:
                                         # attention (h + mixer(norm(h)));
                                         # depth = its length. "": every layer
                                         # an attention and an MLP
+    dense_layers: int = 0               # leading layers whose MLP is the dense
+                                        # one at dense_mlp_dim where the rest
+                                        # route (num_experts > 0)
+    dense_mlp_dim: int = 0              # the leading dense layers' MLP width
+    mtp_depth: int = 0                  # 0 | 1: a multi-token-prediction
+                                        # module after the trunk (DeepSeek-V3)
+                                        # predicts the token after next
+                                        # through the shared embedding and
+                                        # head; TrainCfg.mtp_weight its term
     remat: str = "none"                 # per-block activation remat: "full"
                                         # (keep nothing; recompute block in
                                         # bwd) or "dots" (keep matmul outputs)

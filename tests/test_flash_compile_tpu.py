@@ -72,14 +72,29 @@ def test_one_block_kernels_compile_for_v5e(one_chip, case):
     assert bwd.count("tpu_custom_call") == 1
 
 
-def test_streaming_kernels_compile_for_v5e(one_chip):
-    """The GPT-2 medium cells' attention: [8,1024,16,64] bf16 causal."""
-    b, s, h, d = 8, 1024, 16, 64
+# q/k [B,S,H,D] and v [B,S,H,Dv]
+_STREAMING = {
+    # the GPT-2 medium cells' attention
+    "gpt2m_d64": ((8, 1024, 16, 64), 64),
+    # latent attention's heads: 192 wide q/k as one and a half lane rows, v
+    # at its own 128 (two heads a lane block: 384 and 256 lanes)
+    "latent_192_128": ((1, 4096, 32, 192), 128),
+    "wide_128_64": ((2, 1024, 4, 128), 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STREAMING))
+def test_streaming_kernels_compile_for_v5e(one_chip, case):
+    (b, s, h, d), dv = _STREAMING[case]
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16, sharding=one_chip)
     rows = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one_chip)
-    blocks = (True, 0, 0, 0.125, None, None, False)
+    blocks = (True, 0, 0, d ** -0.5, None, None, False)
     for fn, args in (
-            (lambda q, k, v: fa._flash_forward(q, k, v, *blocks), (q, q, q)),
-            (lambda *a: fa._flash_dq(*a, *blocks), (q, q, q, q, rows, rows)),
-            (lambda *a: fa._flash_dkv(*a, *blocks), (q, q, q, q, rows, rows))):
-        assert _compile(fn, *args).count("tpu_custom_call") == 1
+            (lambda q, k, v: fa._flash_forward(q, k, v, *blocks), (q, q, v)),
+            (lambda *a: fa._flash_dq(*a, *blocks), (q, q, v, v, rows, rows)),
+            (lambda *a: fa._flash_dkv(*a, *blocks), (q, q, v, v, rows, rows))):
+        text = _compile(fn, *args)
+        assert text.count("tpu_custom_call") == 1
+        if d != dv:     # nothing padded or copied on the way in or out
+            assert " pad(" not in text

@@ -260,44 +260,67 @@ def test_the_correction_bias_has_no_gradient_and_moves_by_the_rule(
         2e-3)
 
 
-def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("act", ["relu2", "swiglu"])
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(act):
     """Four chips hold experts 0-3, 4-7, 8-11, 12-15 of one layer and each
     the shared expert whole: their routed parts and the shared expert ONCE
     sum to what the reference's whole layer gives (extends
     ``test_keye_vl2.test_the_shares_of_a_routed_layer_add_up...`` to sigmoid
-    scores, the scaling factor, ``relu2`` and the shared expert). Float32
-    sums in another order: 1e-5."""
-    z = nemotron_h.sizes_of(CONFIG)
+    scores, the scaling factor, ``relu2`` and the shared expert; ``swiglu``:
+    the same with gated routed and shared experts against
+    ``reference/xing4.py``'s layer, top-4 of 8 in shares of 2). Float32 sums
+    in another order: 1e-5."""
+    if act == "relu2":
+        ref, z = nemotron_h, nemotron_h.sizes_of(CONFIG)
+    else:
+        from benchmark.families import lm_latent_hc_moe_train as latent
+        from benchmark.reference import xing4 as ref
+
+        z = ref.sizes_of({**load_json(
+            ROOT + "/benchmark/configs/xing4.0-29b-a4b.json"),
+            **latent.TINY["config"]})
     d, f, fs, width, held = z["d"], z["f"], z["fs"], z["width"], z["held"]
-    keys = jax.random.split(jax.random.PRNGKey(11), 6)
-    whole = {"router": 0.5 * jax.random.normal(keys[0], (d, width)),
-             "w1": 0.1 * jax.random.normal(keys[1], (width, d, f)),
-             "w2": 0.1 * jax.random.normal(keys[2], (width, f, d)),
-             "s1": 0.1 * jax.random.normal(keys[3], (d, fs)),
-             "s2": 0.1 * jax.random.normal(keys[4], (fs, d))}
-    x = jax.random.normal(keys[5], (2, S, d))
+    keys = jax.random.split(jax.random.PRNGKey(11), 9)
+    normal = lambda i, shape, std=0.1: std * jax.random.normal(  # noqa: E731
+        keys[i], shape)
+    # reference leaf -> the program's parameter, routed (a stack a held
+    # expert) and shared
+    routed = ({"w1": "w_up", "w2": "w_down"} if act == "relu2" else
+              {"w1g": "w_gate", "w1u": "w_up", "w2": "w_down"})
+    shared = ({"s1": "shared_up", "s2": "shared_down"} if act == "relu2" else
+              {"sg": "shared_gate", "su": "shared_up", "sd": "shared_down"})
+    down = ("w2", "s2", "sd")
+    whole = {"router": normal(0, (d, width), 0.5)}
+    whole.update({name: normal(i, (width, f, d) if name in down
+                               else (width, d, f))
+                  for i, name in enumerate(routed, start=1)})
+    whole.update({name: normal(i, (fs, d) if name in down else (d, fs))
+                  for i, name in enumerate(shared, start=4)})
+    x = jax.random.normal(keys[8], (2, S, d))
     flat = x.reshape(-1, d)
-    want = nemotron_h.experts(flat, whole, z, make_einsum("f32"),
-                              held=(0, width))[0]
-    shared = jnp.square(jax.nn.relu(flat @ whole["s1"])) @ whole["s2"]
-    total, assigned = shared, 0.0
+    want = ref.experts(flat, whole, z, make_einsum("f32"),
+                       held=(0, width))[0]
+    if act == "relu2":
+        alone = jnp.square(jax.nn.relu(flat @ whole["s1"])) @ whole["s2"]
+    else:
+        alone = (jax.nn.silu(flat @ whole["sg"]) * (flat @ whole["su"])
+                 ) @ whole["sd"]
+    total, assigned = alone, 0.0
     for first in range(0, width, held):
         layer = RoutedExperts(held, f, k=z["k"], router_width=width,
-                              offset=first, act="relu2", dtype=jnp.float32,
+                              offset=first, act=act, dtype=jnp.float32,
                               score="sigmoid", scale=z["scale"],
                               shared_dim=fs)
         mine = slice(first, first + held)
-        part, mods = layer.apply(
-            {"params": {"gate": {"kernel": whole["router"]},
-                        "w_up": whole["w1"][mine],
-                        "w_down": whole["w2"][mine],
-                        "shared_up": {"kernel": whole["s1"]},
-                        "shared_down": {"kernel": whole["s2"]}}},
-            x, mutable=["intermediates"])
+        params = {"gate": {"kernel": whole["router"]}}
+        params.update({p: whole[r][mine] for r, p in routed.items()})
+        params.update({p: {"kernel": whole[r]} for r, p in shared.items()})
+        part, mods = layer.apply({"params": params}, x,
+                                 mutable=["intermediates"])
         counts = mods["intermediates"]["moe_counts"][0]
         assert float(counts["dropped"]) == 0.0
         assigned += float(counts["assignments_per_token"])
-        total = total + (part.reshape(-1, d) - shared)
+        total = total + (part.reshape(-1, d) - alone)
     assert assigned == pytest.approx(z["k"])    # every choice ran somewhere
     np.testing.assert_allclose(total, want, atol=1e-5)
 

@@ -71,6 +71,18 @@ _KERNEL_CASES = {
     "ring_past_hop": dict(s=256, causal=True, q_offset=256),
     "ring_misaligned": dict(s=256, causal=True, q_offset=64),
     "ring_rectangular": dict(sq=128, sk=384, causal=True, q_offset=256),
+    # q/k heads wider than v heads (latent attention's 192 and 128, as one and
+    # a half lane rows beside one: two heads a lane block, the second read as
+    # the aligned window around it with the first's lanes zeroed), and 128
+    # beside 64 (two v heads share a lane row); ``dv`` is the v heads' width
+    "latent_192_128": dict(b=1, h=4, s=256, d=192, dv=128, causal=True),
+    "latent_192_128_bf16": dict(b=1, h=2, s=512, d=192, dv=128, causal=True,
+                                dtype=jnp.bfloat16),
+    "latent_small_blocks": dict(b=1, h=2, s=384, d=192, dv=128, causal=True,
+                                block_q=128, block_k=128),
+    "wide_128_64": dict(b=2, h=4, s=256, d=128, dv=64, causal=True),
+    "wide_128_64_not_causal_padded": dict(b=1, h=2, s=200, d=128, dv=64,
+                                          mha=True),
     # flash_mha pads to a block multiple and masks the padded keys (k_valid)
     "padded_vit": dict(s=196, d=48, mha=True),
     "padded_causal": dict(s=160, d=32, causal=True, mha=True),
@@ -107,8 +119,8 @@ def test_flash_kernels_match_reference(case):
     sq, sk = c.get("sq", c.get("s")), c.get("sk", c.get("s"))
     b, h, d = c.get("b", 2), c.get("h", 2), c.get("d", 64)
     rng = np.random.RandomState(len(case))
-    q, k, v = (jnp.asarray(rng.randn(b, h, n, d).astype(np.float32), dtype)
-               for n in (sq, sk, sk))
+    q, k, v = (jnp.asarray(rng.randn(b, h, n, w).astype(np.float32), dtype)
+               for n, w in ((sq, d), (sk, d), (sk, c.get("dv", d))))
     w_lse = jnp.cos(jnp.arange(sq, dtype=jnp.float32))      # lse cotangent
 
     def attend(q, k, v):
