@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.lax import axis_size
 
+from ddw_tpu.ops import hyper_connection
 from ddw_tpu.ops.flash_attention import flash_mha_seq_major
 from ddw_tpu.parallel.ring_attention import ring_attention
 from ddw_tpu.utils.config import LayerSpec
@@ -63,7 +64,12 @@ def yarn_of(spec: LayerSpec) -> tuple:
 
 def sinkhorn(logits, iters: int, eps: float):
     """``exp(logits) [..., n, n]`` made doubly stochastic by ``iters`` rounds
-    of (each row divided by its sum + ``eps``, then each column)."""
+    of (each row divided by its sum + ``eps``, then each column). Float32
+    logits of a multiple of 128 tokens take the kernel of
+    :mod:`ddw_tpu.ops.hyper_connection` (a token on a lane, the rounds made
+    again for the backward pass); anything else the loop below."""
+    if hyper_connection.sinkhorn_tiles(logits):
+        return hyper_connection.sinkhorn(logits, iters, eps)
     m = jnp.exp(logits)
     for _ in range(iters):
         m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
@@ -73,7 +79,9 @@ def sinkhorn(logits, iters: int, eps: float):
 
 def hyper_read(x, h_pre):
     """A sublayer's input: the streams ``x [B, S, n, C]`` mixed by ``h_pre
-    [B, S, n]`` -> ``[B, S, C]`` in x's dtype."""
+    [B, S, n]`` -> ``[B, S, C]`` in x's dtype. The ``jnp`` form: where the
+    streams tile, :class:`HyperConnection` reads in the pass that makes the
+    coefficients, and this is what the tests hold that pass to."""
     with jax.named_scope("hyper_conn"):
         return jnp.einsum("bsn,bsnc->bsc", h_pre,
                           x.astype(jnp.float32)).astype(x.dtype)
@@ -82,29 +90,54 @@ def hyper_read(x, h_pre):
 def hyper_write(x, y, h_post, h_res):
     """The streams after a sublayer: ``h_res x + h_post^T y`` — the streams
     mixed by ``h_res [B, S, n, n]`` and the sublayer's output ``y [B, S, C]``
-    written to each by ``h_post [B, S, n]``."""
+    written to each by ``h_post [B, S, n]``. Streams that tile
+    (:func:`ddw_tpu.ops.hyper_connection.fuses`) take its one pass, which
+    keeps ``x``, ``y`` and the coefficients for a backward pass of its own;
+    anything else the ``jnp`` form below, the tests' reference."""
     with jax.named_scope("hyper_conn"):
+        if hyper_connection.fuses(x) and y.dtype == x.dtype:
+            return hyper_connection.write(x, y, h_post, h_res)
         mixed = jnp.einsum("bsnm,bsmc->bsnc", h_res, x.astype(jnp.float32))
         return (mixed + h_post[..., None]
                 * y.astype(jnp.float32)[:, :, None, :]).astype(x.dtype)
 
 
+class _Gain(nn.Module):
+    """The gain of an ``nn.RMSNorm`` under its name (``scale``), for a pass
+    that folds it into a product instead of applying the norm."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,),
+                          jnp.float32)
+
+
 class HyperConnection(nn.Module):
-    """The coefficients of a manifold-constrained hyper-connection
-    (arXiv:2512.24880) around one sublayer, from the stream state ``x [B, S,
-    n, C]`` of each token, in float32:
+    """A manifold-constrained hyper-connection (arXiv:2512.24880) around one
+    sublayer: its coefficients from the stream state ``x [B, S, n, C]`` of
+    each token, in float32, and the sublayer's input read with them:
 
         xt = RMSNorm(vec(x))                        over all n C numbers, gain
         [Hpre~ | Hpost~ | Hres~] = alpha * (xt phi) + bias     n | n | n x n
         Hpre = sigmoid(Hpre~);  Hpost = 2 sigmoid(Hpost~)
         Hres = sinkhorn(clip(Hres~ + hyper_res_diag I))
+        h = sum_n Hpre[n] x[n]
 
-    Returns ``(h_pre, h_post, h_res)`` for :func:`hyper_read` and
-    :func:`hyper_write`; sows the counters ``hc_res_offdiag_share`` (the mass
-    of ``Hres`` off its diagonal over ``n``, a mean over tokens: 0 is a plain
-    residual, ``1 - 1/n`` streams mixed evenly) and ``hc_sinkhorn_error``
-    (the largest ``|row sum - 1|`` or ``|column sum - 1|`` after the last
-    round). Nothing of it is kept across a block's rematerialisation."""
+    Returns ``(h, h_post, h_res)``: the input ``[B, S, C]`` in x's dtype, and
+    what :func:`hyper_write` takes. Streams that tile
+    (:func:`ddw_tpu.ops.hyper_connection.fuses`: bfloat16, ``C`` a multiple of
+    128, ``B S`` a multiple of 128) make ``xt phi`` and ``h`` in ONE pass over
+    ``x`` (``RMSNorm(x) @ phi = rsqrt(mean(x^2) + eps) (x @ (g * phi))``: no
+    float32 copy of the state leaves VMEM), whose backward pass keeps ``x``,
+    the folded weight's bfloat16 half, the ``n (n + 2)`` numbers a token
+    before the sigmoids and the norm's ``rsqrt``; anything else takes the
+    ``jnp`` forms (:func:`hyper_read`), which are also what the tests hold
+    the fused pass to. Sows the counters ``hc_res_offdiag_share`` (the mass of
+    ``Hres`` off its diagonal over ``n``, a mean over tokens: 0 is a plain
+    residual, ``1 - 1/n`` streams mixed evenly), ``hc_sinkhorn_error`` (the
+    largest ``|row sum - 1|`` or ``|column sum - 1|`` after the last round)
+    and ``hc_fused_share`` (1 where this sublayer took the fused passes, else
+    0). Nothing of it is kept across a block's rematerialisation."""
 
     layer: LayerSpec
 
@@ -112,19 +145,28 @@ class HyperConnection(nn.Module):
     def __call__(self, x):
         spec = self.layer
         b, s, n, c = x.shape
+        fused = hyper_connection.fuses(x)
         with jax.named_scope("hyper_conn"):
-            xt = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
-                            name="norm")(x.reshape(b, s, n * c))
             phi = self.param("phi", nn.initializers.normal(0.02),
                              (n * c, n * (n + 2)), jnp.float32)
             alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
                                jnp.float32)
             bias = self.param("bias", nn.initializers.zeros, (n * (n + 2),),
                               jnp.float32)
-            raw = xt @ phi                                  # [B, S, n(n+2)]
             gain = jnp.repeat(alpha, jnp.asarray([n, n, n * n]),
                               total_repeat_length=n * (n + 2))
-            pre, post, res = jnp.split(raw * gain + bias, [n, 2 * n], axis=-1)
+            if fused:
+                scale = _Gain(name="norm")(n * c)
+                h, coef = hyper_connection.read(
+                    x, (phi * scale[:, None] * gain).T, bias, spec.norm_eps)
+                pre, post, res = jnp.split(coef, [n, 2 * n], axis=-1)
+            else:
+                xt = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                                name="norm")(x.reshape(b, s, n * c))
+                raw = xt @ phi                              # [B, S, n(n+2)]
+                pre, post, res = jnp.split(raw * gain + bias, [n, 2 * n],
+                                           axis=-1)
+                h = hyper_read(x, jax.nn.sigmoid(pre))
             res = res.reshape(b, s, n, n)
             if spec.hyper_res_diag:
                 res = res + spec.hyper_res_diag * jnp.eye(n, dtype=res.dtype)
@@ -135,8 +177,9 @@ class HyperConnection(nn.Module):
             self.sow("intermediates", "counters", {
                 "hc_res_offdiag_share": jnp.mean(
                     1.0 - jnp.trace(seen, axis1=-2, axis2=-1) / n),
-                "hc_sinkhorn_error": jnp.max(jnp.abs(sums - 1.0))})
-            return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res
+                "hc_sinkhorn_error": jnp.max(jnp.abs(sums - 1.0)),
+                "hc_fused_share": jnp.float32(fused)})
+            return h, 2.0 * jax.nn.sigmoid(post), h_res
 
 
 class CausalSelfAttention(nn.Module):
@@ -549,11 +592,15 @@ class DecoderBlock(nn.Module):
     def __call__(self, x, train: bool, positions=None, block_tables=None,
                  start_pos=None, adapters=None):
         spec = self.layer
-        # x is then [B, S, n, C]; TransformerLM refuses decode, a ring, LoRA
+        # x is then [B, S, n C], a token's streams side by side (the layout
+        # the fused passes read: no copy between them and the blocks' edges);
+        # TransformerLM refuses decode, a ring, LoRA
         streams = spec.hyper_streams > 1
         if streams:
-            h_pre, h_post, h_res = HyperConnection(spec, name="hc_attn")(x)
-            h = layer_norm(spec)(hyper_read(x, h_pre))
+            flat = x.shape
+            x = x.reshape(flat[:2] + (spec.hyper_streams, -1))
+            h, h_post, h_res = HyperConnection(spec, name="hc_attn")(x)
+            h = layer_norm(spec)(h)
         else:
             h = layer_norm(spec)(x)
         h = CausalSelfAttention(self.num_heads, self.dtype, self.seq_axis,
@@ -574,8 +621,8 @@ class DecoderBlock(nn.Module):
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         if streams:
             x = hyper_write(x, h, h_post, h_res)
-            h_pre, h_post, h_res = HyperConnection(spec, name="hc_mlp")(x)
-            h = layer_norm(spec)(hyper_read(x, h_pre))
+            h, h_post, h_res = HyperConnection(spec, name="hc_mlp")(x)
+            h = layer_norm(spec)(h)
         else:
             x = x + h
             h = layer_norm(spec)(x)
@@ -621,7 +668,8 @@ class DecoderBlock(nn.Module):
                     h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         if streams:
-            return hyper_write(x, h.astype(x.dtype), h_post, h_res)
+            return hyper_write(x, h.astype(x.dtype), h_post,
+                               h_res).reshape(flat)
         return x + h
 
 
@@ -876,15 +924,15 @@ class TransformerLM(nn.Module):
             aidx = jnp.asarray(aidx, jnp.int32)
             row_adapters = jax.tree.map(lambda st: jnp.asarray(st)[aidx],
                                         stacks)
-        def enter(x):       # entry: copied to every stream
-            return x if not streams else jnp.broadcast_to(
-                x[:, :, None, :], (b, s_local, streams, self.hidden))
+        def enter(x):       # entry: copied to every stream, side by side
+            return x if not streams else jnp.tile(x, (1, 1, streams))
 
         def leave(x):       # exit: the streams summed
             if not streams:
                 return x
             with jax.named_scope("hyper_conn"):
-                return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+                return sum(jnp.split(x.astype(jnp.float32), streams,
+                                     axis=2)).astype(x.dtype)
 
         x = enter(x)
         for i, kind in enumerate(self.pattern):

@@ -98,3 +98,44 @@ def test_streaming_kernels_compile_for_v5e(one_chip, case):
         assert text.count("tpu_custom_call") == 1
         if d != dv:     # nothing padded or copied on the way in or out
             assert " pad(" not in text
+
+
+# the hyper-connection's passes (ops/hyper_connection.py) at the shape
+# xing4_train_s4096 runs them: [1, 4096, 4, 3584] bfloat16 streams, a token's
+# streams side by side; name -> (function of its arrays, their shapes)
+def _hyper_cases():
+    hc = importlib.import_module("ddw_tpu.ops.hyper_connection")
+    t, n, c = 4096, 4, 3584
+    d, k = n * c, n * (n + 2)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    x, y = ((t, d), bf), ((t, c), bf)
+    coef, z, lanes = ((t, n + n * n), f32), ((t, k), f32), \
+        ((n * n, t // 128, 128), f32)
+    sink = lambda kernel: (lambda *a: hc._sinkhorn_call(     # noqa: E731
+        kernel, n, 20, 1e-6, False, "hc_sinkhorn", *a))
+    return {
+        "read_forward": (lambda *a: hc._read_forward(*a, n, 1e-6, False),
+                         [x, ((2 * k, d), bf), ((1, k), f32)]),
+        "read_backward": (lambda *a: hc._read_backward(*a, n, False),
+                          [x, ((k, d), bf), ((1, k), f32), z, ((t, 1), f32),
+                           y, z]),
+        "write_forward": (lambda *a: hc._write_forward(*a, n, False),
+                          [x, y, coef]),
+        "write_backward": (lambda *a: hc._write_backward(*a, n, False),
+                           [x, y, coef, x]),
+        "sinkhorn_forward": (sink(hc._sinkhorn_fwd_kernel), [lanes]),
+        "sinkhorn_backward": (sink(hc._sinkhorn_bwd_kernel), [lanes, lanes]),
+    }
+
+
+@pytest.mark.parametrize("case", ["read_forward", "read_backward",
+                                  "write_forward", "write_backward",
+                                  "sinkhorn_forward", "sinkhorn_backward"])
+def test_hyper_connection_passes_compile_for_v5e(one_chip, case):
+    """Mosaic takes each pass at the cell's shape inside the VMEM a kernel
+    gets unasked (none of them raises its limit)."""
+    fn, shapes = _hyper_cases()[case]
+    text = _compile(fn, *(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                          for shape, dtype in shapes))
+    assert text.count("tpu_custom_call") == 1
+    assert "vmem_limit_bytes" not in text

@@ -117,6 +117,52 @@ def test_a_scans_body_is_listed_under_its_while(tables):
     assert len(bodies) == len(set(bodies))
 
 
+def test_a_hyper_connected_blocks_kernels_lie_under_hyper_conn():
+    """A model whose four streams tile (hidden 128, 128 tokens, bfloat16)
+    under ``remat="full"``: every operation of the fused passes — the six
+    kernels' (interpreted here: their bodies carry the call's name), forward,
+    made again and backward — has ``hyper_conn`` as its innermost layer scope,
+    so that ``hyper_conn_ms`` reads them where it read the ``jnp`` forms."""
+    import optax
+
+    from benchmark.metrics import hyper_conn_ms, scope_time
+    from ddw_tpu.models.lm import build_lm
+    from ddw_tpu.runtime.mesh import make_data_mesh
+    from ddw_tpu.train.lm_step import init_lm_state, make_lm_train_step
+    from ddw_tpu.utils.config import LayerSpec
+
+    model = build_lm(LMCfg(
+        vocab_size=64, max_len=64, hidden=128, depth=1, num_heads=2,
+        mlp_dim=128, dtype="bfloat16", remat="full",
+        layer=LayerSpec(hyper_streams=4, hyper_res_diag=1.5)))
+    tx = optax.adam(1e-3)
+    step = make_lm_train_step(model, tx, make_data_mesh(
+        devices=jax.devices()[:1]), seq_axis=None)
+    state = jax.eval_shape(lambda: init_lm_state(model, tx,
+                                                 jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 64), np.int32)
+    key = jax.ShapeDtypeStruct((2,), np.uint32)
+    table = parse_hlo(step.lower(state, tokens, tokens, key).compile()
+                      .as_text())
+    layers = (set(scope_time.scope_map()["layers"])
+              | set(hyper_conn_ms.NEW_SCOPES))
+    seen = set()
+    for path in table["scopes"]:
+        for kernel in ("hc_read_fwd", "hc_read_bwd", "hc_write_fwd",
+                       "hc_write_bwd", "hc_sinkhorn_fwd", "hc_sinkhorn_bwd"):
+            if kernel in path.split("/"):
+                layer, which = scope_time.layer_of(path, layers)
+                assert layer == "hyper_conn", path
+                seen.add((kernel, which))
+    assert seen >= {("hc_read_fwd", "fwd"), ("hc_read_fwd", "remat"),
+                    ("hc_write_fwd", "fwd"), ("hc_write_fwd", "remat"),
+                    ("hc_sinkhorn_fwd", "fwd"), ("hc_sinkhorn_fwd", "remat"),
+                    ("hc_read_bwd", "bwd"), ("hc_write_bwd", "bwd"),
+                    ("hc_sinkhorn_bwd", "bwd")}
+    assert not any(which == "fwd" for kernel, which in seen
+                   if kernel.endswith("_bwd"))
+
+
 @pytest.mark.parametrize("op_name,path", [
     ("jit(_step)/shard_map/fwd_bwd/transpose(jvp(TransformerLM))/fwd_bwd/"
      "jvp(TransformerLM)/checkpoint/rematted_computation/backbone_block3/"
