@@ -84,6 +84,10 @@ def layer_terms(mods: dict) -> dict:
     if counts:
         out.update({"moe_" + name: mean([c[name] for c in counts])
                     for name in counts[0]})
+        # the fullest routed block's, beside the mean over the blocks: a
+        # block's grouped products run a further chunk by ITS held load
+        out["moe_block_assignments_max"] = jnp.max(jnp.stack(
+            [c["assignments_per_token"] for c in counts]))
     # counters a layer names itself: a mean over the layers that sowed each
     sown: dict = {}
     for layer in collect_sown(mods, "counters"):
