@@ -228,8 +228,9 @@ def lm_leg(shape: dict, work: str, tracker):
 
     # Which attention tier the step lowered to: the dispatch's own answer at
     # the per-device shape, and the flash kernels in the lowered step: one
-    # Mosaic body for each of forward, dQ and dK/dV, shared by the layers
-    # (none when the CPU interprets them), and a call of each a layer.
+    # Mosaic body for the forward and one for the one-pass backward, shared
+    # by the layers (none when the CPU interprets them), and a call of each
+    # a layer.
     head_dim = shape["hidden"] // shape["heads"]
     qk = jax.ShapeDtypeStruct(
         (shape["batch_per_chip"], shape["heads"], seq, head_dim),
@@ -242,17 +243,17 @@ def lm_leg(shape: dict, work: str, tracker):
     toks = jax.ShapeDtypeStruct((global_batch, seq), jnp.int32)
     lowered = step.lower(state, toks, toks, jax.random.PRNGKey(0)).as_text()
     mosaic_bodies = lowered.count("tpu_custom_call")
-    kernel_calls = len(re.findall(r"call @_flash_(?:forward|dq|dkv)\b",
+    kernel_calls = len(re.findall(r"call @_flash_(?:forward|bwd)\b",
                                   lowered))
     if tier != "pallas":
         raise AssertionError(f"attention tier is {tier!r}, not 'pallas' — "
                              f"the shape no longer exercises the kernels")
-    if kernel_calls != 3 * shape["depth"] or (
-            jax.default_backend() != "cpu" and mosaic_bodies != 3):
+    if kernel_calls != 2 * shape["depth"] or (
+            jax.default_backend() != "cpu" and mosaic_bodies != 2):
         raise AssertionError(
             f"{kernel_calls} flash kernel calls over {mosaic_bodies} Mosaic "
-            f"bodies in the lowered step, expected {3 * shape['depth']} "
-            f"over 3")
+            f"bodies in the lowered step, expected {2 * shape['depth']} "
+            f"over 2")
     report.update(attention_tier=tier, flash_kernel_calls=kernel_calls,
                   mosaic_kernel_bodies=mosaic_bodies)
     return ({"leg": "lm_trainer", "global_batch": global_batch, "seq": seq,

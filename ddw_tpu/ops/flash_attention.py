@@ -23,9 +23,11 @@ Design (Dao et al. flash attention, TPU-first):
   f32 accumulation with inputs in bf16 or f32;
 - causal masking by global position (supports the ring-attention case where this
   rank's K block sits at a rotated global offset);
-- backward pass as two Pallas kernels (FA2 schedule): the forward saves the
-  per-row logsumexp; dQ streams K/V blocks, dK/dV streams Q/dO blocks, each
-  rematerializing p = exp(s - L) blockwise in VMEM — O(S) HBM for the whole
+- backward pass as ONE Pallas kernel: the forward saves the per-row
+  logsumexp; a grid step holds a K/V block, streams the Q/dO blocks past it
+  and rematerializes p = exp(s - L) sub-block-wise in VMEM once, and dQ, dK
+  and dV all come of that tile (``_bwd_kernel``; dQ's sum over the key blocks
+  waits in VMEM or HBM by the shapes, ``_dq_home``) — O(S) HBM for the whole
   train step, the S x S matrices never exist in HBM;
 - q and k heads may be wider than v heads (latent attention: 192 and 128).
   A lane block then holds the fewest heads whose q/k lanes AND v lanes both
@@ -61,6 +63,10 @@ _LANES = 128
 # Scoped VMEM a kernel may use: the largest working set _pick_block allows
 # (f32 score tiles of 1 MiB, a handful live) passes the 16 MiB default.
 _VMEM_LIMIT = 32 * 1024 * 1024
+# ... and what one gets unasked, which the code's own blocks stay inside: a
+# step of kernels that used 20 MiB of a raised limit once never came back
+# (PERF.md section 6, PR 33).
+_VMEM_UNASKED = 16 * 1024 * 1024
 
 
 def _bh_sharding(sharding, ndim):
@@ -187,9 +193,9 @@ def _scores(a, b, sm_scale):
 
 def _mask_scores(sc, q_start, k_start, causal, k_valid, k_axis: int):
     """The causal + key-padding masks at global positions — shared by the
-    forward and both backward kernels so the masking can never desynchronize.
-    Keys run along ``k_axis`` of ``sc`` (1 in the forward and dQ kernels, 0 in
-    the dK/dV kernel, which works on transposed scores). ``k_valid`` (static)
+    forward and the backward kernels so the masking can never desynchronize.
+    Keys run along ``k_axis`` of ``sc`` (1 in the forward kernels, 0 in the
+    backward ones, which work on transposed scores). ``k_valid`` (static)
     masks keys at global position >= it (the padded tail when the sequence was
     padded up to a block multiple). Only sub-blocks the diagonal or the padded
     tail crosses come here."""
@@ -210,8 +216,8 @@ def _finite_ref(ref):
     tile: a row whose every key is masked keeps its running max (forward) or
     logsumexp (backward) at ~_NEG_INF, where ``s - ref`` would cancel in f32
     (exp -> 1). With the reference at 0 there, ``exp(_NEG_INF - 0)`` is 0, so
-    masked rows stay at zero output and zero gradient. Load-bearing in all
-    three kernels."""
+    masked rows stay at zero output and zero gradient. Load-bearing in both
+    streaming kernels."""
     return jnp.where(ref > _NEG_INF / 2, ref, 0.0)
 
 
@@ -564,80 +570,41 @@ def flash_attention(q, k, v, causal: bool = False, q_offset: int = 0,
                                block_q, block_k, interpret, k_valid)[0]
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
-               dq_scr, lse_scr, dvec_scr, *, heads: int, head_dim: int,
-               v_dim: int, block_q: int, block_k: int, sub_k: int,
-               causal: bool,
-               q_offset: int, k_offset: int, sm_scale: float,
-               k_valid: int | None):
-    """dQ pass (FA2 backward): grid (B, head blocks, q-blocks, k-blocks), K
-    innermost.
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, *refs,
+                heads: int, head_dim: int, v_dim: int, block_q: int,
+                block_k: int, sub_k: int, causal: bool, q_offset: int,
+                k_offset: int, sm_scale: float, k_valid: int | None,
+                dq_home: str):
+    """The whole backward pass of one (batch, head block, k-block, q-block)
+    grid step, Q innermost: a score sub-block, its exponential and dS are made
+    ONCE and all three gradients come of them.
 
     p_ij = exp(s_ij - L_i) rematerialized per sub-block from the saved
-    logsumexp; ds_ij = p_ij * (dO_i . v_j - D_i); dq_i += sm_scale * ds_ij k_j.
-    The S x S matrices exist only sub-block-wise in VMEM. L and D arrive as
-    lane-dense rows and are turned once a q block into lane-replicated
-    ``[block_q, 128]`` columns (sublane broadcast + one aligned transpose).
-    """
-    qi = pl.program_id(2)
-    kb = pl.program_id(3)
-    num_kb = pl.num_programs(3)
-    q_heads, v_heads = _qv_windows(heads, head_dim, dq_scr.shape[-1], v_dim,
-                                   v_ref.shape[-1])
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        for t in range(heads):
-            lse_scr[t] = jnp.broadcast_to(_finite_ref(lse_ref[t:t + 1, :]),
-                                          (_LANES, block_q)).T
-            dvec_scr[t] = jnp.broadcast_to(dvec_ref[t:t + 1, :],
-                                           (_LANES, block_q)).T
-
-    q0 = q_offset + qi * block_q
-    k0 = k_offset + kb * block_k
-    bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
-
-    for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
-        q = _only(qmask, q_ref[_at(qw)])
-        do = _only(vmask, do_ref[_at(vw)])
-
-        def _accum(j, masked):
-            ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
-            k_blk = k_ref[_at(qw, ks)]
-            s = _scores(q, k_blk, sm_scale)              # [block_q, sub_k]
-            if masked:
-                s = _mask_scores(s, q0, k0 + j * sub_k, causal, k_valid, 1)
-            p = jnp.exp(s - _lanes(lse_scr[t], sub_k))
-            dp = _scores(do, v_ref[_at(vw, ks)], 1.0)
-            ds = p * (dp - _lanes(dvec_scr[t], sub_k))
-            dq = jnp.dot(ds.astype(q.dtype), k_blk,
-                         preferred_element_type=jnp.float32)
-            dq_scr[_at(qw)] += (dq if qmask is None
-                              else jnp.where(qmask, dq, 0.0))
-
-        _for_sub_blocks(*bounds, _accum)
-
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        dq_ref[...] = (sm_scale * dq_scr[...]).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, heads: int, head_dim: int, v_dim: int,
-                block_q: int, block_k: int, sub_k: int, causal: bool,
-                q_offset: int, k_offset: int, sm_scale: float,
-                k_valid: int | None):
-    """dK/dV pass: grid (B, head blocks, k-blocks, q-blocks), Q innermost.
-
-    dv_j += p_ij^T dO_i; dk_j += sm_scale * ds_ij^T q_i. Works on TRANSPOSED
+    logsumexp; ds_ij = p_ij * (dO_i . v_j - D_i); dv_j += p_ij^T dO_i; dk_j +=
+    sm_scale * ds_ij^T q_i; dq_i += sm_scale * ds_ij k_j. Works on TRANSPOSED
     scores s^T = k q^T ``[sub_k, block_q]``: p^T and ds^T are then the left
-    operands of plain matmuls (no score tile is ever transposed) and L and D
-    broadcast along sublanes from their lane-dense rows.
-    """
-    kj = pl.program_id(2)
-    qb = pl.program_id(3)
-    num_qb = pl.num_programs(3)
+    operands of plain matmuls for dV and dK (no score tile is ever transposed
+    by hand), dQ contracts ds^T over its first dimension, and L and D
+    broadcast along sublanes from their lane-dense rows. The S x S matrices
+    exist only sub-block-wise in VMEM.
+
+    dK and dV sum over the inner grid dimension in VMEM scratch. dQ sums over
+    the OUTER one, so a q block's dQ is visited once a key block, and
+    ``dq_home`` (:func:`_dq_home`) says where the float32 sum waits between
+    visits. ``"vmem"``: a ``[Sq, lanes]`` scratch holding every q block of the
+    (batch, head block) in hand; the last key block's steps scale, round and
+    write it (the output block's index stays at 0 until then, so nothing
+    unfinished is written back). ``"hbm"``: a float32 buffer of that shape in
+    HBM that the kernel reads and writes by its own DMAs
+    (:func:`_dq_through_hbm`), rounding into the output itself on a q block's
+    last visit."""
+    kj, qb = pl.program_id(2), pl.program_id(3)
+    num_kb, num_qb = pl.num_programs(2), pl.num_programs(3)
+    if dq_home == "vmem":
+        dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr = refs
+    else:
+        dk_ref, dv_ref, dq_hbm, spill, dk_scr, dv_scr, dq_scr, dq_in, \
+            dq_out, sems, state = refs
     q_heads, v_heads = _qv_windows(heads, head_dim, dk_scr.shape[-1], v_dim,
                                    dv_scr.shape[-1])
 
@@ -650,28 +617,59 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
     k0 = k_offset + kj * block_k
     bounds = _sub_block_range(q0, k0, block_q, block_k, sub_k, causal, k_valid)
 
-    for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
-        q = _only(qmask, q_ref[_at(qw)])
-        do = _only(vmask, do_ref[_at(vw)])
-        lse = _finite_ref(lse_ref[t:t + 1, :])           # [1, block_q]
-        dvec = dvec_ref[t:t + 1, :]
+    def accumulate(dq_at):
+        """Add this step's dK, dV to their scratch and its dQ to
+        ``dq_scr[dq_at(lanes)]``."""
+        for t, ((qw, qmask), (vw, vmask)) in enumerate(zip(q_heads, v_heads)):
+            q = _only(qmask, q_ref[_at(qw)])
+            do = _only(vmask, do_ref[_at(vw)])
+            lse = _finite_ref(lse_ref[t:t + 1, :])           # [1, block_q]
+            dvec = dvec_ref[t:t + 1, :]
 
-        def _accum(j, masked):
-            ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
-            st = _scores(k_ref[_at(qw, ks)], q, sm_scale)     # [sub_k, block_q]
-            if masked:
-                st = _mask_scores(st, q0, k0 + j * sub_k, causal, k_valid, 0)
-            pt = jnp.exp(st - lse)
-            dv = jnp.dot(pt.astype(do.dtype), do,
-                         preferred_element_type=jnp.float32)
-            dpt = _scores(v_ref[_at(vw, ks)], do, 1.0)
-            dst = pt * (dpt - dvec)
-            dk = jnp.dot(dst.astype(q.dtype), q,
-                         preferred_element_type=jnp.float32)
-            dv_scr[_at(vw, ks)] += dv        # q and do carry this head's lanes
-            dk_scr[_at(qw, ks)] += dk        # only, so dv and dk do too
+            def _accum(j, masked):
+                ks = pl.ds(pl.multiple_of(j * sub_k, sub_k), sub_k)
+                k_blk = k_ref[_at(qw, ks)]
+                st = _scores(k_blk, q, sm_scale)          # [sub_k, block_q]
+                if masked:
+                    st = _mask_scores(st, q0, k0 + j * sub_k, causal, k_valid,
+                                      0)
+                pt = jnp.exp(st - lse)
+                dv = jnp.dot(pt.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+                dpt = _scores(v_ref[_at(vw, ks)], do, 1.0)
+                dst = (pt * (dpt - dvec)).astype(q.dtype)
+                dk = jnp.dot(dst, q, preferred_element_type=jnp.float32)
+                dq = jax.lax.dot_general(dst, k_blk, (((0,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                dv_scr[_at(vw, ks)] += dv    # q and do carry this head's lanes
+                dk_scr[_at(qw, ks)] += dk    # only, so dv and dk do too;
+                dq_scr[dq_at(qw)] += (       # k's window holds a neighbour's
+                    dq if qmask is None else jnp.where(qmask, dq, 0.0))
 
-        _for_sub_blocks(*bounds, _accum)
+            _for_sub_blocks(*bounds, _accum)
+
+    if dq_home == "vmem":
+        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+
+        @pl.when(kj == 0)
+        def _first_visit():
+            dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[-1]),
+                                        jnp.float32)
+
+        accumulate(lambda lanes: _at(lanes, rows))
+
+        @pl.when(kj == num_kb - 1)
+        def _last_visit():
+            dq_ref[...] = (sm_scale * dq_scr[rows, :]).astype(dq_ref.dtype)
+    else:
+        n_vis = bounds[1]
+        last_kj = num_kb - 1        # the last key block this q block sees
+        if causal:
+            last_kj = jnp.clip((q0 + block_q - 1 - k_offset) // block_k, 0,
+                               last_kj)
+        _dq_through_hbm(dq_hbm, spill, dq_scr, dq_in, dq_out, sems, state,
+                        accumulate, isinstance(n_vis, int) or n_vis > 0,
+                        last_kj, block_q, sm_scale)
 
     @pl.when(qb == num_qb - 1)
     def _finalize():
@@ -679,65 +677,170 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
-def _flash_dq(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
-              block_q, block_k, interpret, k_valid=None, sub_k=None):
-    """q [B,Sq,H,D], g [B,Sq,H,Dv]; k [B,Sk,H,D], v [B,Sk,H,Dv]; lse, dvec
-    [B,H,Sq] f32 -> dq."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
-    per, dp, dvp, hp = _require_layout(h, d, v.shape[-1])
-    qspec, ospec, qrow, kspec, vspec = _specs(per, dp, dvp, bq, bk,
-                                              q_inner=False)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, heads=per, head_dim=dp, v_dim=dvp,
-                          block_q=bq, block_k=bk, sub_k=sub_k, causal=causal,
-                          q_offset=q_offset, k_offset=k_offset,
-                          sm_scale=sm_scale, k_valid=k_valid),
-        grid=(b, hp // per, sq // bq, sk // bk),
-        in_specs=[qspec, kspec, vspec, ospec, qrow, qrow],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, per * dp), jnp.float32),
-                        pltpu.VMEM((per, bq, _LANES), jnp.float32),
-                        pltpu.VMEM((per, bq, _LANES), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-        name="flash_dq",
-    )(_to_blocks(q, dp, hp), _to_blocks(k, dp, hp), _to_blocks(v, dvp, hp),
-      _to_blocks(g, dvp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
-    return _from_blocks(dq, h, d, dp)
+def _dq_through_hbm(dq_hbm, spill, dq_scr, dq_in, dq_out, sems, state,
+                    accumulate, visible, last_kj, block_q: int,
+                    sm_scale: float):
+    """The ``"hbm"`` home of :func:`_bwd_kernel`'s dQ. A step that sees any of
+    its keys makes its sum in one of ``dq_scr``'s two slots and adds what the
+    earlier key blocks left in ``spill`` (float32 ``[Sq, lanes]`` in HBM, one
+    (batch, head block) at a time; read into ``dq_in`` while the step
+    computes). At ``last_kj``, the last key block this q block sees, the sum
+    is scaled, rounded into ``dq_out``'s slot and written to ``dq_hbm`` ``[B,
+    Sq, lanes of every head block]``; before it, the slot goes back to
+    ``spill``. Either write is in flight while the next step computes in the
+    other slot. ``state`` (SMEM) holds, across the whole grid (which therefore
+    runs in order): which kind of write is in flight (0 none, 1 to ``spill``,
+    2 to ``dq_hbm``), how many steps have written, and the q block of a write
+    to ``spill``, which a read of that block waits for. A step that sees no
+    key does nothing, but that a q block no key block sees is written as
+    zeros on the first."""
+    pid = [pl.program_id(a) for a in range(4)]
+    steps = [pl.num_programs(a) for a in range(4)]
+    kj, qb = pid[2], pid[3]
+    width = dq_scr.shape[-1]
+    rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+    out_window = dq_hbm.at[
+        pid[0], rows, pl.ds(pl.multiple_of(pid[1] * width, _LANES), width)]
+
+    def to_spill(slot):
+        return pltpu.make_async_copy(dq_scr.at[slot], spill.at[rows],
+                                     sems.at[1])
+
+    def to_output(slot):
+        return pltpu.make_async_copy(dq_out.at[slot], out_window, sems.at[1])
+
+    def wait_for_write():
+        pl.when(state[0] == 1)(to_spill(0).wait)
+        pl.when(state[0] == 2)(to_output(0).wait)
+        state[0] = 0
+
+    @pl.when(functools.reduce(jnp.logical_and, [p == 0 for p in pid]))
+    def _start():
+        state[0] = 0
+        state[1] = 0
+
+    last = kj == last_kj
+
+    def visit():
+        slot = jax.lax.rem(state[1], 2)
+        # whoever sees a key block sees the earlier ones: from the second on
+        # the sum so far is in ``spill``
+        earlier = kj > 0
+        read = pltpu.make_async_copy(spill.at[rows], dq_in, sems.at[0])
+        pl.when(jnp.logical_and(earlier, jnp.logical_and(
+            state[0] == 1, state[2] == qb)))(wait_for_write)
+        pl.when(earlier)(read.start)
+        dq_scr[slot] = jnp.zeros((block_q, width), jnp.float32)
+        accumulate(lambda lanes: (slot, slice(None),
+                                  slice(None) if lanes is None else lanes))
+
+        @pl.when(earlier)
+        def _add_earlier():
+            read.wait()
+            dq_scr[slot] += dq_in[...]
+
+        wait_for_write()            # the step before's, from the other slot
+
+        @pl.when(last)
+        def _round():
+            dq_out[slot] = (sm_scale * dq_scr[slot]).astype(dq_out.dtype)
+            to_output(slot).start()
+            state[0] = 2
+
+        @pl.when(jnp.logical_not(last))
+        def _keep():
+            to_spill(slot).start()
+            state[0] = 1
+            state[2] = qb
+
+        state[1] = state[1] + 1
+
+    if visible is True:
+        visit()
+    else:
+        pl.when(jnp.logical_or(visible, last))(visit)
+
+    pl.when(functools.reduce(
+        jnp.logical_and, [p == n - 1 for p, n in zip(pid, steps)]))(
+            wait_for_write)
+
+
+# A q block's dQ is summed over the key blocks, the kernel's OUTER grid
+# dimension. With one key block there is no sum; else the float32 sum of every
+# q block of a (batch, head block) waits in VMEM while the kernel stays inside
+# _VMEM_UNASKED with it (Mosaic's plan for a described v5e, bfloat16, blocks
+# 512 x 1024 x 512: 5.7 MiB at two heads of 64 and S = 1,024; 9.7 MiB at one
+# of 128 and S = 8,192 with 4 MiB of sum; 17.6 at 192 beside 128 and S =
+# 4,096 with 6 of sum, 23.6 at S = 8,192 with 12), and above that in HBM
+# (13.8 MiB at 192 beside 128, whatever S). What the trip through HBM costs
+# the kernel alone (tools/fa2_sweep.py --preset blocks --dq-home vmem,hbm, one
+# v5e chip, PR 45; ms in VMEM / through HBM): 23.59 / 23.72 at [2,32,8192,128],
+# 4.574 / 4.689 at [1,32,4096,192|128], 32.93 / 33.32 at [2,32,8192,192|128].
+_DQ_VMEM_MAX = 4 * 1024 * 1024
+
+
+def _dq_home(sq: int, sk: int, block_k: int, q_lanes: int) -> str:
+    """Where :func:`_bwd_kernel` keeps dQ's sum between a q block's visits,
+    from the shapes alone: ``"vmem"`` or ``"hbm"``."""
+    if sk == block_k or sq * q_lanes * 4 <= _DQ_VMEM_MAX:
+        return "vmem"
+    return "hbm"
 
 
 @functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
-def _flash_dkv(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
+def _flash_bwd(q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
                block_q, block_k, interpret, k_valid=None, sub_k=None):
-    """Same operands as :func:`_flash_dq` -> (dk, dv)."""
+    """q [B,Sq,H,D], g [B,Sq,H,Dv]; k [B,Sk,H,D], v [B,Sk,H,Dv]; lse, dvec
+    [B,H,Sq] f32 -> (dq, dk, dv), one ``pallas_call``. It keeps the name the
+    dK/dV kernel had, ``flash_dkv``: the benchmark finds the kernels by name."""
     b, sq, h, d = q.shape
     sk, dv_ = k.shape[1], v.shape[-1]
     bq, bk, sub_k = _resolve_blocks(sq, sk, block_q, block_k, sub_k)
     per, dp, dvp, hp = _require_layout(h, d, dv_)
+    nq, nk, lanes = sq // bq, sk // bk, per * dp
+    home = _dq_home(sq, sk, bk, lanes)
     qspec, ospec, qrow, kspec, vspec = _specs(per, dp, dvp, bq, bk,
                                               q_inner=True)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, heads=per, head_dim=dp, v_dim=dvp,
+    scratch = [pltpu.VMEM((bk, lanes), jnp.float32),
+               pltpu.VMEM((bk, per * dvp), jnp.float32)]
+    dq_shapes = [jax.ShapeDtypeStruct((b, sq, hp * dp), q.dtype)]
+    if home == "vmem":
+        # the block index stays put until the last key block's steps write
+        dq_specs = [pl.BlockSpec(
+            (None, bq, lanes),
+            lambda *g: (g[0], jnp.where(g[2] == nk - 1, g[3], 0), g[1]),
+            memory_space=pltpu.VMEM)]
+        scratch.append(pltpu.VMEM((sq, lanes), jnp.float32))
+        semantics = ("parallel", "parallel", "arbitrary", "arbitrary")
+    else:
+        dq_shapes.append(jax.ShapeDtypeStruct((sq, lanes), jnp.float32))
+        dq_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch += [pltpu.VMEM((2, bq, lanes), jnp.float32),
+                    pltpu.VMEM((bq, lanes), jnp.float32),
+                    pltpu.VMEM((2, bq, lanes), q.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SMEM((3,), jnp.int32)]
+        semantics = ("arbitrary",) * 4      # ``state`` runs through them all
+    dk, dv, dq, *_ = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=per, head_dim=dp, v_dim=dvp,
                           block_q=bq, block_k=bk, sub_k=sub_k, causal=causal,
                           q_offset=q_offset, k_offset=k_offset,
-                          sm_scale=sm_scale, k_valid=k_valid),
-        grid=(b, hp // per, sk // bk, sq // bq),
+                          sm_scale=sm_scale, k_valid=k_valid, dq_home=home),
+        grid=(b, hp // per, nk, nq),
         in_specs=[kspec, vspec, qspec, ospec, qrow, qrow],
-        out_specs=[kspec, vspec],
+        out_specs=[kspec, vspec, *dq_specs],
         out_shape=[jax.ShapeDtypeStruct((b, sk, hp * dp), k.dtype),
-                   jax.ShapeDtypeStruct((b, sk, hp * dvp), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, per * dp), jnp.float32),
-                        pltpu.VMEM((bk, per * dvp), jnp.float32)],
-        compiler_params=_compiler_params(),
+                   jax.ShapeDtypeStruct((b, sk, hp * dvp), v.dtype),
+                   *dq_shapes],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_dkv",
     )(_to_blocks(k, dp, hp), _to_blocks(v, dvp, hp), _to_blocks(q, dp, hp),
       _to_blocks(g, dvp, hp), _rows(lse, per, hp), _rows(dvec, per, hp))
-    return _from_blocks(dk, h, d, dp), _from_blocks(dv, h, dv_, dvp)
+    return (_from_blocks(dq, h, d, dp), _from_blocks(dk, h, d, dp),
+            _from_blocks(dv, h, dv_, dvp))
 
 
 @functools.lru_cache(maxsize=None)
@@ -745,15 +848,14 @@ def _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
                      interpret, k_valid):
     """(q, k, v, lse, g, dvec) -> (dq, dk, dv), batch/head-partitioned.
 
-    Pallas FA2 backward: two block kernels (dQ; dK/dV) over the saved
-    logsumexp — O(S) memory, the S x S matrices never leave VMEM. ``lse`` and
-    ``dvec`` arrive as [B,H,Sq] so every operand has the batch and heads dims
-    the partition rule shards."""
+    Pallas FA2 backward in one kernel over the saved logsumexp — O(S) memory,
+    the S x S matrices never leave VMEM. ``lse`` and ``dvec`` arrive as
+    [B,H,Sq] so every operand has the batch and heads dims the partition rule
+    shards."""
 
     def impl(q, k, v, lse, g, dvec):
-        args = (q, k, v, g, lse, dvec, causal, q_offset, k_offset, sm_scale,
-                block_q, block_k, interpret, k_valid)
-        return (_flash_dq(*args),) + _flash_dkv(*args)
+        return _flash_bwd(q, k, v, g, lse, dvec, causal, q_offset, k_offset,
+                          sm_scale, block_q, block_k, interpret, k_valid)
 
     fn = custom_partitioning(impl)
     return _def_bh_partition(
@@ -774,6 +876,18 @@ def _partitioned_bwd(causal, q_offset, k_offset, sm_scale, block_q, block_k,
 # longer MXU runs) until the causal work they cannot skip outweighs it; q
 # blocks of 512 keep two per head at S = 1024, so the upper-right key sub-block
 # of the first is skipped.
+# The same preset with the one-pass backward (PR 45; ms for forward / backward;
+# the dQ and dK/dV kernels it replaced took 0.731 + 0.839 at the chosen
+# blocks): (512,1024,512) 0.572 / 1.015 — again the fastest of 18 for both;
+# (512,512,512) 0.610 / 1.075; (256,1024,512) 0.632 / 1.244; (1024,1024,512)
+# 0.679 / 1.208; (512,1024,256) 0.732 / 1.160; (512,1024,128) 1.088 / 1.772;
+# (256,512,128) 1.437 / 2.434. And at latent attention's widths, q/k heads of
+# 192 beside v heads of 128, [1,32,4096,192|128] (the pair: 3.427 + 3.549):
+# (512,1024,512) 2.433 / 4.689 — the fastest backward of 18 and the fastest
+# sum; (1024,1024,512) 2.393 / 4.985, the fastest forward by 1.7 %;
+# (1024,512,512) 2.439 / 5.518; (512,512,512) 2.545 / 5.229; (256,1024,512)
+# 3.013 / 4.909; (512,1024,256) 2.942 / 4.853; (512,1024,128) 3.877 / 5.340;
+# (256,512,128) 5.711 / 8.029. The caps hold for every layout.
 _BLOCK_Q_MAX = 512
 _BLOCK_K_MAX = 1024
 _SUB_K_MAX = 512
@@ -824,8 +938,8 @@ def _pad_seq(x, mult):
 # [Sq, 128] q tile and [Sk, 128] k and v tiles of a head block for a few batch
 # rows, the softmax is a plain one (no running max, no rescale, no scratch
 # carried between steps), and ONE backward kernel recomputes p once and gives
-# dQ, dK and dV — 5 matmul units where flash_dq + flash_dkv execute 7 — with
-# the D = rowsum(dO . O) reduction inside it. A sequence that is no multiple
+# dQ, dK and dV (as the streaming backward does since PR 45) — with the D =
+# rowsum(dO . O) reduction inside it. A sequence that is no multiple
 # of 128 is not padded in HBM: the block over-runs the array (Pallas reads the
 # boundary block and drops the writes past the edge), the rows past the edge
 # are undefined — NaN in the interpreter — and are replaced by zeros with a
@@ -906,7 +1020,7 @@ def _short_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, glse_ref,
                       dq_ref, dk_ref, dv_ref, *, heads: int, head_dim: int,
                       sq: int, sk: int, causal: bool, sm_scale: float):
     """The whole backward of one (batch rows, head block) grid step, on
-    TRANSPOSED scores s^T = k q^T ``[skp, sqp]`` like the dK/dV kernel: lse,
+    TRANSPOSED scores s^T = k q^T ``[skp, sqp]`` like the streaming one: lse,
     D and the lse cotangent broadcast along sublanes from lane-dense rows, p^T
     and ds^T are the left operands of plain matmuls for dV and dK, and dQ
     contracts ds^T over its first dimension. p is recomputed once."""

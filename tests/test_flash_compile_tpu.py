@@ -72,32 +72,42 @@ def test_one_block_kernels_compile_for_v5e(one_chip, case):
     assert bwd.count("tpu_custom_call") == 1
 
 
-# q/k [B,S,H,D] and v [B,S,H,Dv]
+# q/k [B,S,H,D] and v [B,S,H,Dv]: the attention of the five cells that run the
+# streaming kernels, a chip (where each keeps dQ's sum:
+# test_ops_parallel.py::test_dq_home_of_the_cells)
 _STREAMING = {
-    # the GPT-2 medium cells' attention
+    # both GPT-2 medium cells: one key block, two heads of 64 a lane block
     "gpt2m_d64": ((8, 1024, 16, 64), 64),
+    "nemotron_d128": ((2, 8192, 32, 128), 128),
     # latent attention's heads: 192 wide q/k as one and a half lane rows, v
     # at its own 128 (two heads a lane block: 384 and 256 lanes)
-    "latent_192_128": ((1, 4096, 32, 192), 128),
+    "xing4_192_128": ((1, 4096, 32, 192), 128),
+    "joyai_192_128": ((2, 8192, 32, 192), 128),
     "wide_128_64": ((2, 1024, 4, 128), 64),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_STREAMING))
-def test_streaming_kernels_compile_for_v5e(one_chip, case):
+def test_streaming_kernels_compile_for_v5e(one_chip, case, monkeypatch):
+    """Mosaic takes the forward kernel and the one-pass backward at the cells'
+    shapes, and plans the backward inside the VMEM a kernel gets unasked
+    wherever ``_dq_home`` keeps dQ's sum: compiled with that as its limit,
+    it would be refused for a byte more."""
     (b, s, h, d), dv = _STREAMING[case]
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16, sharding=one_chip)
     rows = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one_chip)
     blocks = (True, 0, 0, d ** -0.5, None, None, False)
-    for fn, args in (
-            (lambda q, k, v: fa._flash_forward(q, k, v, *blocks), (q, q, v)),
-            (lambda *a: fa._flash_dq(*a, *blocks), (q, q, v, v, rows, rows)),
-            (lambda *a: fa._flash_dkv(*a, *blocks), (q, q, v, v, rows, rows))):
-        text = _compile(fn, *args)
-        assert text.count("tpu_custom_call") == 1
-        if d != dv:     # nothing padded or copied on the way in or out
-            assert " pad(" not in text
+    text = _compile(lambda q, k, v: fa._flash_forward(q, k, v, *blocks),
+                    q, q, v)
+    assert text.count("tpu_custom_call") == 1
+    monkeypatch.setattr(fa, "_VMEM_LIMIT", fa._VMEM_UNASKED)
+    text = _compile(lambda *a: fa._flash_bwd.__wrapped__(*a, *blocks),
+                    q, q, v, v, rows, rows)
+    assert text.count("tpu_custom_call") == 1
+    assert f'"size":"{fa._VMEM_UNASKED}"' in text
+    if d != dv:     # nothing padded or copied on the way in or out
+        assert " pad(" not in text
 
 
 # the hyper-connection's passes (ops/hyper_connection.py) at the shape

@@ -2,6 +2,7 @@
 pallas runs in interpret mode off-TPU)."""
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,33 @@ from ddw_tpu.parallel.sharding import (
     shardings_for_params,
 )
 from ddw_tpu.runtime.mesh import make_mesh, MeshSpec
+
+
+fa = importlib.import_module("ddw_tpu.ops.flash_attention")
+
+
+@pytest.fixture
+def dq_home(monkeypatch):
+    """``dq_home(home)`` makes the streaming backward keep dQ's sum in
+    ``home`` whatever the shapes say (None: the code's own choice) for the
+    rest of the test; the jitted entry forgets what it traced before and
+    after, or a choice made for the same shapes elsewhere would stand."""
+    def force(home):
+        if home is not None:
+            monkeypatch.setattr(fa, "_dq_home", lambda *shapes: home)
+        fa._flash_bwd.clear_cache()
+
+    yield force
+    fa._flash_bwd.clear_cache()
+
+
+def _backward_homes(fn, *args):
+    """Where each streaming backward kernel in ``fn``'s jaxpr keeps dQ's sum:
+    the ``"hbm"`` form is the one with a fourth output, that sum's buffer."""
+    return ["hbm" if len(eqn.outvars) == 4 else "vmem"
+            for eqn in _primitives(jax.make_jaxpr(fn)(*args).jaxpr,
+                                   "pallas_call")
+            if eqn.params["name"] == "flash_dkv"]
 
 
 def _qkv(b=2, h=2, s=256, d=64, seed=0, dtype=jnp.float32):
@@ -83,6 +111,32 @@ _KERNEL_CASES = {
     "wide_128_64": dict(b=2, h=4, s=256, d=128, dv=64, causal=True),
     "wide_128_64_not_causal_padded": dict(b=1, h=2, s=200, d=128, dv=64,
                                           mha=True),
+    # the one-pass backward where dQ is summed over several key blocks. In
+    # VMEM (what these shapes' sizes choose): one q block visited once a key
+    # block, and three of each without a mask
+    "key_blocks_one_q_block": dict(sq=128, sk=384, causal=True, q_offset=256,
+                                   block_k=128),
+    "three_blocks_not_causal": dict(s=384, block_q=128, block_k=128),
+    # ... and through HBM, which the cells' long rows choose and ``home``
+    # forces here: each head layout (two heads of 64 a lane block; one of
+    # 128; 192 beside 128), several q blocks a key block and one, a padded
+    # tail, and offsets off the blocks' grid
+    "hbm_d64_two_a_block": dict(s=512, causal=True, block_q=128, block_k=256,
+                                home="hbm"),
+    "hbm_d128_not_causal": dict(b=1, s=384, d=128, block_q=128, block_k=128,
+                                home="hbm"),
+    "hbm_latent_192_128": dict(b=1, h=2, s=384, d=192, dv=128, causal=True,
+                               block_q=128, block_k=128, home="hbm"),
+    "hbm_latent_192_128_bf16": dict(b=1, h=2, s=512, d=192, dv=128,
+                                    causal=True, dtype=jnp.bfloat16,
+                                    block_q=256, block_k=256, home="hbm"),
+    "hbm_one_q_block": dict(sq=128, sk=384, causal=True, q_offset=256,
+                            block_k=128, home="hbm"),
+    "hbm_one_key_block": dict(s=256, causal=True, home="hbm"),
+    "hbm_padded_tail": dict(s=300, causal=True, mha=True, block_q=128,
+                            block_k=256, home="hbm"),
+    "hbm_misaligned_offsets": dict(s=256, causal=True, q_offset=64,
+                                   block_q=128, block_k=128, home="hbm"),
     # flash_mha pads to a block multiple and masks the padded keys (k_valid)
     "padded_vit": dict(s=196, d=48, mha=True),
     "padded_causal": dict(s=160, d=32, causal=True, mha=True),
@@ -109,10 +163,11 @@ _KERNEL_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
-def test_flash_kernels_match_reference(case):
+def test_flash_kernels_match_reference(case, dq_home):
     from ddw_tpu.ops.flash_attention import flash_attention_lse, flash_mha_lse
 
     c = dict(_KERNEL_CASES[case])
+    dq_home(c.get("home"))
     dtype = c.get("dtype", jnp.float32)
     causal, q_off, k_off = c.get("causal", False), c.get("q_offset", 0), \
         c.get("k_offset", 0)
@@ -146,6 +201,10 @@ def test_flash_kernels_match_reference(case):
     (ref_g, (ref_out, ref_lse)) = jax.grad(loss(ref), argnums=(0, 1, 2),
                                            has_aux=True)(q, k, v)
     assert out.dtype == dtype and lse.dtype == jnp.float32
+    if c.get("home"):
+        assert _backward_homes(jax.grad(lambda *a: loss(attend)(*a)[0],
+                                        argnums=(0, 1, 2)),
+                               q, k, v) == [c["home"]]
     tol, gtol = (2e-5, 1e-4) if dtype == jnp.float32 else (3e-2, 0.1)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref_out),
                                rtol=tol, atol=tol)
@@ -195,18 +254,34 @@ def test_flash_causal_ignores_later_keys():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_flash_offsets():
-    """q_offset/k_offset shift the causal mask to global positions (ring case)."""
-    q, k, v = _qkv(s=128)
+@pytest.mark.parametrize("home", [None, "hbm"])
+def test_flash_offsets(home, dq_home):
+    """q_offset/k_offset shift the causal mask to global positions (ring case),
+    forward and backward, dQ summed over two key blocks in either home."""
+    dq_home(home)
+    q, k, v = _qkv(s=256)
+    attend = functools.partial(flash_attention, causal=True, block_q=128,
+                               block_k=128)
     # k block globally BEFORE q block: fully visible
-    out_past = flash_attention(q, k, v, True, 128, 0)
+    out_past = attend(q, k, v, q_offset=256, k_offset=0)
     ref_full = mha_reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out_past), np.asarray(ref_full),
                                rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(attend(*a, q_offset=256) ** 2),
+                   argnums=(0, 1, 2))
+    want = jax.grad(lambda *a: jnp.sum(mha_reference(*a) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+    assert _backward_homes(got, q, k, v) == [home or "vmem"]
+    for a, r in zip(got(q, k, v), want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
     # k block globally AFTER q block: fully masked -> uniform-ish? No: all -inf
-    # rows normalize over zero mass; guard returns zeros
-    out_future = flash_attention(q, k, v, True, 0, 128)
+    # rows normalize over zero mass; guard returns zeros, and no gradient
+    out_future = attend(q, k, v, q_offset=0, k_offset=256)
     assert np.isfinite(np.asarray(out_future)).all()
+    for g in jax.grad(lambda *a: jnp.sum(attend(*a, k_offset=256) ** 2),
+                      argnums=(0, 1, 2))(q, k, v):
+        np.testing.assert_array_equal(np.asarray(g), 0.0)
 
 
 def test_flash_misaligned_offset_masked_rows_zero():
@@ -307,24 +382,39 @@ def test_tp_train_step_vit():
     assert mu_fc1.sharding.spec == P(None, "model")
 
 
-def test_flash_gradients_fully_masked_rows_zero():
+@pytest.mark.parametrize("s,block,home", [(128, None, None),
+                                          (256, 128, None),
+                                          (256, 128, "hbm")])
+def test_flash_gradients_fully_masked_rows_zero(s, block, home, dq_home):
     """Rows with zero visible keys must get zero dQ (and contribute nothing to
-    dK/dV), not NaNs from the masked-softmax residuals."""
-    q, k, v = _qkv(s=128, seed=9)
+    dK/dV), not NaNs from the masked-softmax residuals — in one block a side,
+    and with dQ summed over two key blocks in either home."""
+    dq_home(home)
+    q, k, v = _qkv(s=s, seed=9)
 
     def lf(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, 0, 64) ** 2)
+        return jnp.sum(flash_attention(q, k, v, True, 0, 64, None, block,
+                                       block) ** 2)
 
-    gq, gk, gv = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
+    grad = jax.grad(lf, argnums=(0, 1, 2))
+    assert _backward_homes(grad, q, k, v) == [home or "vmem"]
+    gq, gk, gv = grad(q, k, v)
     assert np.isfinite(np.asarray(gq)).all()
     assert np.isfinite(np.asarray(gk)).all()
     assert np.isfinite(np.asarray(gv)).all()
     np.testing.assert_array_equal(np.asarray(gq)[:, :, :64, :], 0.0)
+    # (row 64 sees one key: its softmax is constant)
+    assert np.abs(np.asarray(gq)[:, :, 65:, :]).max(axis=-1).min() > 0.0
 
 
+@pytest.mark.parametrize("arm", ["auto", "pallas", "pallas_hbm"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_gradients_match_full(causal):
-    """SP ring backward (with per-hop remat) == full-attention backward."""
+def test_ring_attention_gradients_match_full(causal, arm, dq_home):
+    """SP ring backward (with per-hop remat) == full-attention backward, on
+    the tier the shard's length chooses and with the streaming kernels forced
+    a hop (dQ's sum in either home)."""
+    impl, _, home = arm.partition("_")
+    dq_home(home or None)
     n_seq = 4
     mesh = make_mesh(MeshSpec((("seq", n_seq),)), devices=jax.devices()[:n_seq])
     b, h, s, d = 1, 2, 32 * n_seq, 16
@@ -335,7 +425,8 @@ def test_ring_attention_gradients_match_full(causal):
 
     def ring_loss(q, k, v):
         out = shard_map(
-            lambda q, k, v: ring_attention(q, k, v, "seq", causal=causal),
+            lambda q, k, v: ring_attention(q, k, v, "seq", causal=causal,
+                                           impl=impl),
             mesh=mesh, in_specs=(P(None, None, "seq", None),) * 3,
             out_specs=P(None, None, "seq", None), check_vma=False)(q, k, v)
         return jnp.sum(out ** 2)
@@ -344,6 +435,9 @@ def test_ring_attention_gradients_match_full(causal):
         return jnp.sum(mha_reference(jnp.asarray(q), jnp.asarray(k),
                                      jnp.asarray(v), causal=causal) ** 2)
 
+    if impl == "pallas":
+        assert set(_backward_homes(jax.grad(ring_loss, argnums=(0, 1, 2)),
+                                   q, k, v)) == {home or "vmem"}
     gr = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
     gf = jax.grad(full_loss, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(gr, gf):
@@ -351,13 +445,15 @@ def test_ring_attention_gradients_match_full(causal):
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_flash_lse_split_combine_gradients():
+@pytest.mark.parametrize("home", [None, "hbm"])
+def test_flash_lse_split_combine_gradients(home, dq_home):
     """Splitting keys in two flash_attention_lse calls and softmax-combining
     them must match full attention in value AND gradients — the exact contract
     ring attention relies on per hop (exercises the lse cotangent path)."""
     from ddw_tpu.ops.flash_attention import flash_attention_lse
     from ddw_tpu.parallel.ring_attention import _combine
 
+    dq_home(home)
     q, k, v = _qkv(b=1, h=1, s=128, d=32, seed=5)
     k2, v2 = jnp.concatenate([k, k], 2), jnp.concatenate([v, v + 1.0], 2)
 
@@ -378,6 +474,29 @@ def test_flash_lse_split_combine_gradients():
     for a, b in zip(gs, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# the attention of the five cells that run the streaming kernels, a chip:
+# (Sq = Sk, q/k lanes a head block) and where dQ's sum waits (PERF.md, 3)
+_CELL_DQ_HOMES = {
+    "gpt2m_train_s1024": ((8, 1024, 16, 64, 64), "vmem"),       # one key block
+    "gpt2m_train_dp4": ((8, 1024, 16, 64, 64), "vmem"),
+    "nemotron3nano_train_s8192": ((2, 8192, 32, 128, 128), "vmem"),  # 4 MiB
+    "xing4_train_s4096": ((1, 4096, 32, 192, 128), "hbm"),      # 6 MiB
+    "joyai_flash_train_s8192": ((2, 8192, 32, 192, 128), "hbm"),    # 12 MiB
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_DQ_HOMES))
+def test_dq_home_of_the_cells(cell):
+    """The one-pass backward's chooser reads shapes alone and sends each
+    cell's attention where PERF.md says: dQ's float32 sum waits in VMEM while
+    the kernel stays inside the 16 MiB it gets unasked, else in HBM."""
+    (b, s, h, d, dv), home = _CELL_DQ_HOMES[cell]
+    bq, bk, _ = fa._resolve_blocks(s, s, None, None, None)
+    per, dp, _, _ = fa._head_layout(h, d, dv)
+    assert fa._dq_home(s, s, bk, per * dp) == home
+    assert (bq, bk) == (512, 1024)
 
 
 def test_attention_impl_dispatch_equivalence():

@@ -15,8 +15,15 @@ quotes. Presets (all bfloat16, D = 64 unless ``--dim``):
   with a fourth arm, ``pallas_short`` (the one-block kernels), beside the
   streaming kernels; then the one-block forward and backward alone at ViT's
   shape over the batch rows a grid step takes.
-- ``blocks``: at one shape (``--shape``, default the LM cell's), each of the
-  three kernels alone over (block_q, block_k, sub_k), and the chosen blocks.
+- ``blocks``: at one shape (``--shape B,H,S,D`` or ``B,H,S,D|Dv`` for v heads
+  of another width; default the LM cell's), the forward kernel and the
+  one-pass backward alone over (block_q, block_k, sub_k) and at the chosen
+  blocks (``--grid chosen``: those alone). ``--dq-home vmem,hbm`` times the
+  backward with dQ's sum in each home beside the code's own choice.
+  ``--beside FILE`` loads another tree's ``flash_attention.py`` and times its
+  backward at the chosen blocks too (a tree from before PR 45 has ``dq`` and
+  ``dkv`` where this one has ``bwd``); this tree's rows there then carry
+  ``max_abs_diff``, the largest distance of its dq, dk, dv from that tree's.
 
 An arm that fails to compile or to fit prints ``"ms": null`` and the error.
 ``--profile DIR`` also traces one forward + backward of the first shape's
@@ -150,39 +157,88 @@ def run_tiers(preset, shapes, device, tiers=TIERS):
             emit(row, device)
 
 
-def run_blocks(shape, causal, device, grid=BLOCK_GRID):
+def load_beside(path: str):
+    """Another tree's ``flash_attention.py`` as a module of its own (its
+    imports of ``ddw_tpu`` resolve to this tree's, which it shares)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("fa_beside", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def backward_arms(module, blocks, homes=(None,)):
+    """name -> (function of (q, k, v, g, lse, dvec), matmul units, which of
+    dq, dk, dv it returns): the one-pass backward where ``module`` has it (once
+    a home of dQ's sum, None the code's choice), else its dQ and dK/dV kernels."""
+    if not hasattr(module, "_flash_bwd"):
+        return {"dq": (jax.jit(lambda *a: module._flash_dq(*a, *blocks)),
+                       3, ("dq",)),
+                "dkv": (jax.jit(lambda *a: module._flash_dkv(*a, *blocks)),
+                        4, ("dk", "dv"))}
+
+    def forced(home):
+        def fn(*a):
+            chooser = module._dq_home
+            if home is not None:
+                module._dq_home = lambda *_: home
+            try:        # the jitted entry would remember its first choice
+                return module._flash_bwd.__wrapped__(*a, *blocks)
+            finally:
+                module._dq_home = chooser
+        return jax.jit(fn)
+
+    return {"bwd" if home is None else f"bwd_{home}":
+            (forced(home), 5, ("dq", "dk", "dv")) for home in homes}
+
+
+def run_blocks(shape, dv, causal, device, grid=BLOCK_GRID, homes=(),
+               beside=None):
     b, h, s, d = shape
-    q, k, v, g = qkv((b, s, h, d), n=4)          # as the kernels take them
+    q, k = qkv((b, s, h, d), n=2)                # as the kernels take them
+    v, g = qkv((b, s, h, dv), n=2)
     scale, interpret = fa._resolve_defaults(None, None, d)
     out, lse = jax.jit(lambda q, k, v: fa._flash_forward(
         q, k, v, causal, 0, 0, scale, None, None, interpret))(q, k, v)
     dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                    axis=-1).transpose(0, 2, 1)
-
-    def kernels(bq, bk, sub):
-        blocks = (causal, 0, 0, scale, bq, bk, interpret, None, sub)
-        return {
-            "fwd": (jax.jit(lambda q, k, v: fa._flash_forward(
-                q, k, v, *blocks)), (q, k, v), 2),
-            "dq": (jax.jit(lambda *a: fa._flash_dq(*a, *blocks)),
-                   (q, k, v, g, lse, dvec), 3),
-            "dkv": (jax.jit(lambda *a: fa._flash_dkv(*a, *blocks)),
-                    (q, k, v, g, lse, dvec), 4),
-        }
-
+    bwd_args = (q, k, v, g, lse, dvec)
     chosen = fa._resolve_blocks(s, s, None, None, None)
     for bq, bk, sub in dict.fromkeys((chosen,) + tuple(grid)):
         if bq > s or bk > s or bk % sub:
             continue
-        for name, (fn, args, units) in kernels(bq, bk, sub).items():
-            row = {"preset": "blocks", "shape": list(shape),
+        blocks = (causal, 0, 0, scale, bq, bk, interpret, None, sub)
+        is_chosen = (bq, bk, sub) == chosen
+        arms = {"fwd": (jax.jit(lambda q, k, v: fa._flash_forward(
+            q, k, v, *blocks)), 2, ())}
+        if beside is not None and is_chosen:    # before this tree's rows,
+                                                # which compare with them
+            arms.update({f"beside_{name}": arm for name, arm in
+                         backward_arms(beside, blocks).items()})
+        arms.update(backward_arms(
+            fa, blocks, (None,) + (tuple(homes) if is_chosen else ())))
+        theirs = {}         # the other tree's dq, dk, dv at these blocks
+        for name, (fn, units, gives) in arms.items():
+            row = {"preset": "blocks", "shape": list(shape), "v_dim": dv,
                    "dtype": "bfloat16", "causal": causal, "arm": name,
-                   "blocks": [bq, bk, sub],
-                   "chosen": (bq, bk, sub) == chosen}
+                   "blocks": [bq, bk, sub], "chosen": is_chosen}
+            args = bwd_args if gives else (q, k, v)
             try:
                 ms = time_ms(fn, *args, min_s=0.1)
-                row.update(ms=round(ms, 4), tflops=round(
-                    matmul_flops(shape, causal, units) / ms / 1e9, 2))
+                row.update(ms=round(ms, 4), tflops=round(matmul_flops(
+                    (b, h, s, (d + dv) / 2), causal, units) / ms / 1e9, 2))
+                if gives and beside is not None and is_chosen:
+                    got = fn(*args)
+                    got = dict(zip(gives, got if isinstance(got, tuple)
+                                   else (got,)))
+                    if name.startswith("beside_"):
+                        theirs.update(got)
+                    else:
+                        row["max_abs_diff"] = {
+                            what: float(jnp.max(jnp.abs(
+                                val.astype(jnp.float32)
+                                - theirs[what].astype(jnp.float32))))
+                            for what, val in got.items() if what in theirs}
             except Exception as e:
                 row.update(ms=None, error=f"{type(e).__name__}: {e}"[:300])
             emit(row, device)
@@ -244,7 +300,13 @@ def main():
     ap.add_argument("--preset", default="cells",
                     help="comma list of cells, ladder, short, blocks")
     ap.add_argument("--shape", default="8,16,1024,64",
-                    help="B,H,S,D of the blocks preset")
+                    help="B,H,S,D (or B,H,S,D|Dv) of the blocks preset")
+    ap.add_argument("--grid", default="all", choices=("all", "chosen"),
+                    help="blocks preset: every block triple, or the chosen")
+    ap.add_argument("--dq-home", default="",
+                    help="blocks preset: comma list of vmem, hbm to force")
+    ap.add_argument("--beside", default=None, metavar="FILE",
+                    help="blocks preset: another tree's flash_attention.py")
     ap.add_argument("--dim", type=int, default=64,
                     help="head dimension of the ladder and short presets")
     ap.add_argument("--not-causal", action="store_true",
@@ -256,7 +318,8 @@ def main():
     from ddw_tpu.utils.config import require_tpu_or_exit
     device = require_tpu_or_exit("sweep")
     tiers = tuple(args.tiers.split(",")) if args.tiers else None
-    shape = tuple(int(x) for x in args.shape.split(","))
+    dims, _, v_dim = args.shape.partition("|")
+    shape = tuple(int(x) for x in dims.split(","))
     for preset in args.preset.split(","):
         if preset == "cells":
             run_tiers(preset, CELL_SHAPES, device, tiers or TIERS)
@@ -273,7 +336,10 @@ def main():
                       device, tiers or SHORT_TIERS)
             run_short_images(vit, False, device)
         elif preset == "blocks":
-            run_blocks(shape, not args.not_causal, device)
+            run_blocks(shape, int(v_dim or shape[3]), not args.not_causal,
+                       device, BLOCK_GRID if args.grid == "all" else (),
+                       tuple(filter(None, args.dq_home.split(","))),
+                       args.beside and load_beside(args.beside))
         else:
             ap.error(f"unknown preset {preset!r}")
     if args.profile:
