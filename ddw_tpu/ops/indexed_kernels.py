@@ -2,8 +2,9 @@
 
 The kernels of :mod:`ddw_tpu.ops.indexed_attention`'s ``pallas`` tier, in the
 manner of :mod:`ddw_tpu.ops.flash_attention`'s streaming tier (online softmax,
-a saved log-sum-exp, separate dQ and dK/dV passes, the interpreter on the CPU
-backend, wrappers under ``jit``) with what that tier lacks:
+a saved log-sum-exp, ONE backward kernel that makes a score tile, its
+exponential and dS once and takes dQ, dK and dV from them, the interpreter on
+the CPU backend, wrappers under ``jit``) with what that tier lacks:
 
 - **the choice is a mask tile.** ``mask [B, S, S]``, one byte a (query, key)
   pair and the same for every head of a query, is read a ``[block_q,
@@ -44,33 +45,47 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ddw_tpu.ops.backend import interpret_by_default
-from ddw_tpu.ops.flash_attention import (_LANES, _NEG_INF, _finite_ref,
-                                         _lanes, _scores)
+from ddw_tpu.ops.flash_attention import (_LANES, _NEG_INF, _dq_through_hbm,
+                                         _finite_ref, _lanes, _scores)
 
 # Blocks: the largest that divide S, up to 256 queries by 512 keys. With
 # eight query heads a grid step, that keeps the kernels' working set (the
 # backward's q and dO tiles of eight heads, double-buffered, their float32
 # accumulator and a few score tiles) under the 16 MiB of VMEM a kernel may
-# use without asking. 512 x 512 needs 20 MiB and is 5 % faster alone (v5e,
-# the cell's shape, ms for forward / dQ / dK,dV / target: 8.71 / 11.78 /
-# 14.03 / 5.54 against 9.28 / 12.34 / 14.83 / 5.91; tools/indexed_sweep.py,
-# PR 33), but a train step of two layers or more whose kernels raised their
-# limit to 32 MiB never came back from the chip (PERF.md section 6, PR 33):
-# these kernels ask for nothing.
+# use without asking. 512 x 512 needs 20 MiB and was 5 % faster alone (v5e,
+# the cell's shape, ms for forward / dQ / dK,dV / target, the backward then
+# two kernels: 8.71 / 11.78 / 14.03 / 5.54 against 9.28 / 12.34 / 14.83 /
+# 5.91; tools/indexed_sweep.py, PR 33), but a train step of two layers or
+# more whose kernels raised their limit to 32 MiB never came back from the
+# chip (PERF.md section 6, PR 33): these kernels ask for nothing.
 BLOCKS_Q = (256, 128)
 BLOCKS_K = (512, 256, 128)
+# The one-pass backward kernel may take key blocks of 1024 besides: its MXU
+# runs are twice as long (the scores' and dP's products stream 1,024 rows
+# past a weight tile) at 11.4 MiB of VMEM, and the pairs above the diagonal
+# that the wider block visits for nothing are 6 % of the visit at S = 8,192.
+# ms for forward / backward / target at the cell's shape (v5e, tools/
+# indexed_sweep.py, PR 46; the dQ and dK/dV kernels this backward replaced
+# took 12.32 + 14.81 at 256 x 512): 256 x 512 9.26 / 21.67 / 5.92; 256 x 1024
+# 9.16 / 19.76 / 5.06 (the forward and the target keep 256 x 512: not this
+# PR's); 128 x 1024 10.93 / 21.13 / 5.52; 128 x 512 13.30 / 24.81 / 6.79;
+# 512 x 256 9.15 / 25.07 / 7.55; 256 x 256 10.83 / 28.23 / 8.29; 512 x 512
+# does not fit (the backward plans 16.006 MiB). Sequences no workload runs
+# lose 2 % of this kernel to the diagonal at the wider block (S = 2,048:
+# 1.678 for 1.642; 4,096: not measured).
+BLOCKS_K_BWD = (1024,) + BLOCKS_K
 MIN_SEQ = 512               # below it "auto" keeps the XLA tiles
 HEAD_DIMS = (64, 128)
 
 
 def pick_blocks(s: int):
-    """``(block_q, block_k)`` for a sequence of ``s``: the largest of
-    ``BLOCKS_Q`` and of ``BLOCKS_K`` that divide it (None: the kernels do not
-    take ``s``)."""
+    """``(block_q, block_k, the backward kernel's block_k)`` for a sequence
+    of ``s``: the largest of ``BLOCKS_Q``, of ``BLOCKS_K`` and of
+    ``BLOCKS_K_BWD`` that divide it (None: the kernels do not take ``s``)."""
     if s % _LANES:
         return None
-    return (next(b for b in BLOCKS_Q if s % b == 0),
-            next(b for b in BLOCKS_K if s % b == 0))
+    return tuple(next(b for b in blocks if s % b == 0)
+                 for blocks in (BLOCKS_Q, BLOCKS_K, BLOCKS_K_BWD))
 
 
 def _visited(qb, kb, block_q: int, block_k: int):
@@ -135,46 +150,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr,
                                ).astype(o_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, dvec_ref,
-               dq_ref, dq_scr, lse_scr, dvec_scr, *, group: int,
-               head_dim: int, block_q: int, block_k: int, sm_scale: float):
-    """dQ: the forward's grid. ``p = exp(s - L)`` again from the saved
-    log-sum-exp, ``ds = p * (dO . v - D)``, ``dq += sm_scale * ds k``."""
-    qb, kb, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        for t in range(group):
-            lse_scr[t] = _column(lse_ref[t:t + 1, :], block_q)
-            dvec_scr[t] = _column(dvec_ref[t:t + 1, :], block_q)
-
-    @pl.when(_visited(qb, kb, block_q, block_k))
-    def _accum():
-        bias = _bias(mask_ref)
-        k, v = k_ref[...], v_ref[...]
-        for t in range(group):
-            lanes = slice(t * head_dim, (t + 1) * head_dim)
-            s = _scores(q_ref[:, lanes], k, sm_scale) + bias
-            p = jnp.exp(s - _lanes(lse_scr[t], block_k))
-            dp = _scores(do_ref[:, lanes], v, 1.0)
-            ds = p * (dp - _lanes(dvec_scr[t], block_k))
-            dq_scr[:, lanes] += jnp.dot(ds.astype(k.dtype), k,
-                                        preferred_element_type=jnp.float32)
-
-    @pl.when(kb == nk - 1)
-    def _finalize():
-        dq_ref[...] = (sm_scale * dq_scr[...]).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, mask_t_ref, lse_ref, dvec_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, group: int, head_dim: int,
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, mask_ref, lse_ref, dvec_ref,
+                dk_ref, dv_ref, dq_hbm, spill, dk_scr, dv_scr, dq_scr, dq_in,
+                dq_out, sems, state, *, group: int, head_dim: int,
                 block_q: int, block_k: int, sm_scale: float):
-    """dK/dV: grid (batch, key head, k block, q block), q innermost, on
-    TRANSPOSED scores ``k q^T [block_k, block_q]`` under the transposed mask
-    tile: p^T and ds^T are the left operands of plain matmuls, the
-    log-sum-exp and D broadcast along sublanes from their lane-dense rows,
-    and the group's heads add up in one accumulator."""
+    """The whole backward pass of one (batch, key head, k block, q block) grid
+    step, q innermost, on TRANSPOSED scores ``k q^T [block_k, block_q]`` under
+    the mask tile's bias, transposed in VMEM (no transposed mask in HBM): for
+    each of the group's heads ``p^T = exp(s^T - L)`` and ``ds^T = p^T (v dO^T
+    - D)`` are made ONCE and all three gradients come of them. p^T and ds^T
+    are the left operands of plain matmuls for dV and dK, dQ contracts ds^T
+    over its first dimension, the log-sum-exp and D broadcast along sublanes
+    from their lane-dense rows, and the group's heads add up in one dK and
+    one dV accumulator.
+
+    dK and dV sum over the inner grid dimension in VMEM scratch. dQ sums over
+    the OUTER one: a q block is visited once a key block on or below its
+    diagonal, and between visits the group's float32 sum waits in ``spill``,
+    a ``[S, group * head_dim]`` buffer in HBM read and written by the
+    kernel's own DMAs (``flash_attention._dq_through_hbm``, the streaming
+    kernels' ``"hbm"`` home), and is rounded into the output on the last key
+    block the q block sees."""
     kb, qb, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
     @pl.when(qb == 0)
@@ -182,9 +178,10 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, mask_t_ref, lse_ref, dvec_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_visited(qb, kb, block_q, block_k))
-    def _accum():
-        bias = _bias(mask_t_ref)
+    def accumulate(dq_at):
+        """Add this step's dK, dV to their scratch and its dQ to
+        ``dq_scr[dq_at(a head's lanes)]``."""
+        bias = _bias(mask_ref).T        # once a step, for all the heads
         k, v = k_ref[...], v_ref[...]
         for t in range(group):
             lanes = slice(t * head_dim, (t + 1) * head_dim)
@@ -193,9 +190,16 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, mask_t_ref, lse_ref, dvec_ref,
             pt = jnp.exp(st - lse_ref[t:t + 1, :])
             dv_scr[...] += jnp.dot(pt.astype(do.dtype), do,
                                    preferred_element_type=jnp.float32)
-            dst = pt * (_scores(v, do, 1.0) - dvec_ref[t:t + 1, :])
-            dk_scr[...] += jnp.dot(dst.astype(q.dtype), q,
-                                   preferred_element_type=jnp.float32)
+            dst = (pt * (_scores(v, do, 1.0) - dvec_ref[t:t + 1, :])
+                   ).astype(q.dtype)
+            dk_scr[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            dq_scr[dq_at(lanes)] += jax.lax.dot_general(
+                dst, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    _dq_through_hbm(dq_hbm, spill, dq_scr, dq_in, dq_out, sems, state,
+                    accumulate, _visited(qb, kb, block_q, block_k),
+                    _last_k(qb, block_q, block_k), block_q, sm_scale)
 
     @pl.when(qb == nq - 1)
     def _finalize():
@@ -241,9 +245,9 @@ def _last_k(qb, block_q: int, block_k: int):
 def _specs(group: int, dp: int, bq: int, bk: int, q_inner: bool):
     """Block specs for a grid (batch, key head, outer, inner) with the k
     blocks inner (or the q blocks): the q-side tile of a group, its float32
-    rows, the k-side tile, the mask tile and the transposed mask tile. A step
-    the diagonal cuts off maps to the block of the nearest step that is
-    visited, so nothing is fetched for it."""
+    rows, the k-side tile and the mask tile. A step the diagonal cuts off
+    maps to the block of the nearest step that is visited, so nothing is
+    fetched for it."""
     def qk(g):
         if q_inner:     # (b, h, kb, qb): the first q block that sees kb
             return jnp.maximum(g[3], g[2] * bk // bq), g[2]
@@ -258,9 +262,7 @@ def _specs(group: int, dp: int, bq: int, bk: int, q_inner: bool):
                          lambda *g: (g[0], qk(g)[1], g[1]), **vmem)
     mask = pl.BlockSpec((None, bq, bk),
                         lambda *g: (g[0], *qk(g)), **vmem)
-    mask_t = pl.BlockSpec((None, bk, bq),
-                          lambda *g: (g[0], *qk(g)[::-1]), **vmem)
-    return qspec, qrow, kspec, mask, mask_t
+    return qspec, qrow, kspec, mask
 
 
 def _views(q, k, *more):
@@ -302,7 +304,7 @@ def _forward(q, k, v, mask, sm_scale, bq, bk, interpret):
     b, s, h, d = q.shape
     kv = k.shape[2]
     (qv, kview, vv), group, dp = _views(q, k, v)
-    qspec, qrow, kspec, mspec, _ = _specs(group, dp, bq, bk, q_inner=False)
+    qspec, qrow, kspec, mspec = _specs(group, dp, bq, bk, q_inner=False)
     out, lse = _call(
         functools.partial(_fwd_kernel, group=group, head_dim=dp, block_q=bq,
                           block_k=bk, sm_scale=sm_scale),
@@ -318,44 +320,37 @@ def _forward(q, k, v, mask, sm_scale, bq, bk, interpret):
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
-def _dq(q, k, v, mask, g, lse, dvec, sm_scale, bq, bk, interpret):
+def _backward(q, k, v, mask, g, lse, dvec, sm_scale, bq, bk, interpret):
     """The operands, the output's cotangent ``g``, the saved ``lse`` and
-    ``dvec = rowsum(g * out)`` (both ``[B,KV,H//KV,S]``) -> dq."""
+    ``dvec = rowsum(g * out)`` (both ``[B,KV,H//KV,S]``) -> (dq, dk, dv), one
+    ``pallas_call``. It keeps the name the dK/dV kernel had, ``indexed_dkv``:
+    the benchmark finds the kernels by name. Nothing is transposed in HBM."""
     b, s, h, d = q.shape
     kv = k.shape[2]
     (qv, kview, vv, gv), group, dp = _views(q, k, v, g)
-    qspec, qrow, kspec, mspec, _ = _specs(group, dp, bq, bk, q_inner=False)
-    dq = _call(
-        functools.partial(_dq_kernel, group=group, head_dim=dp, block_q=bq,
-                          block_k=bk, sm_scale=sm_scale),
-        "indexed_dq", (b, kv, s // bq, s // bk),
-        [qspec, kspec, kspec, mspec, qspec, qrow, qrow], qspec,
-        jax.ShapeDtypeStruct(qv.shape, q.dtype),
-        [pltpu.VMEM((bq, group * dp), jnp.float32),
-         pltpu.VMEM((group, bq, _LANES), jnp.float32),
-         pltpu.VMEM((group, bq, _LANES), jnp.float32)],
-        interpret, (qv, kview, vv, mask, gv, lse, dvec))
-    return _unview(dq, d, dp)
-
-
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
-def _dkv(q, k, v, mask, g, lse, dvec, sm_scale, bq, bk, interpret):
-    """:func:`_dq`'s operands -> (dk, dv). The mask is transposed in HBM
-    first, a pass over a byte a pair."""
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    (qv, kview, vv, gv), group, dp = _views(q, k, v, g)
-    qspec, qrow, kspec, _, mspec_t = _specs(group, dp, bq, bk, q_inner=True)
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, group=group, head_dim=dp, block_q=bq,
+    lanes = group * dp
+    qspec, qrow, kspec, mspec = _specs(group, dp, bq, bk, q_inner=True)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dk, dv, dq, _ = _call(
+        functools.partial(_bwd_kernel, group=group, head_dim=dp, block_q=bq,
                           block_k=bk, sm_scale=sm_scale),
         "indexed_dkv", (b, kv, s // bk, s // bq),
-        [kspec, kspec, qspec, qspec, mspec_t, qrow, qrow], [kspec, kspec],
+        [kspec, kspec, qspec, qspec, mspec, qrow, qrow],
+        [kspec, kspec, in_hbm, in_hbm],
         [jax.ShapeDtypeStruct(kview.shape, k.dtype),
-         jax.ShapeDtypeStruct(vv.shape, v.dtype)],
-        [pltpu.VMEM((bk, dp), jnp.float32), pltpu.VMEM((bk, dp), jnp.float32)],
-        interpret, (kview, vv, qv, gv, jnp.swapaxes(mask, 1, 2), lse, dvec))
-    return _unview(dk, d, dp), _unview(dv, d, dp)
+         jax.ShapeDtypeStruct(vv.shape, v.dtype),
+         jax.ShapeDtypeStruct(qv.shape, q.dtype),
+         jax.ShapeDtypeStruct((s, lanes), jnp.float32)],    # dQ's sum
+        [pltpu.VMEM((bk, dp), jnp.float32),
+         pltpu.VMEM((bk, dp), jnp.float32),
+         pltpu.VMEM((2, bq, lanes), jnp.float32),
+         pltpu.VMEM((bq, lanes), jnp.float32),
+         pltpu.VMEM((2, bq, lanes), q.dtype),
+         pltpu.SemaphoreType.DMA((2,)),
+         pltpu.SMEM((3,), jnp.int32)],
+        interpret, (kview, vv, qv, gv, mask, lse, dvec),
+        carried=4)      # the DMAs' ``state`` runs through the whole grid
+    return _unview(dq, d, dp), _unview(dk, d, dp), _unview(dv, d, dp)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
@@ -389,12 +384,12 @@ def _scale(q) -> float:
     return float(q.shape[-1]) ** -0.5
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _attend(q, k, v, mask, bq, bk, interpret):
-    return _attend_fwd(q, k, v, mask, bq, bk, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _attend(q, k, v, mask, bq, bk, bk_bwd, interpret):
+    return _attend_fwd(q, k, v, mask, bq, bk, bk_bwd, interpret)[0]
 
 
-def _attend_fwd(q, k, v, mask, bq, bk, interpret):
+def _attend_fwd(q, k, v, mask, bq, bk, bk_bwd, interpret):
     out, lse = _forward(q, k, v, mask, _scale(q), bq, bk, interpret)
     # a block rematerialised whole keeps both (models/lm.py saves the name),
     # so its backward pass runs no forward kernel a second time
@@ -403,13 +398,19 @@ def _attend_fwd(q, k, v, mask, bq, bk, interpret):
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _attend_bwd(bq, bk, interpret, residuals, cotangents):
+def _attend_bwd(bq, bk, bk_bwd, interpret, residuals, cotangents):
     q, k, v, mask, out, lse = residuals
     g, _ = cotangents       # the log-sum-exp is a constant to its one reader
+    # Under ``remat`` the mask is made again for this pass, and nothing but
+    # the barrier ties it to the cotangent: without it the compiler's schedule
+    # makes a layer's mask (S x S bytes a row) before the layer's expert
+    # blocks have gone backward, and holds it through their peak (128 MiB at
+    # the cell's shape, PERF.md section 6, PR 46).
+    mask, g = jax.lax.optimization_barrier((mask, g))
     dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     dvec = dvec.transpose(0, 2, 1).reshape(lse.shape)
-    args = (q, k, v, mask, g, lse, dvec, _scale(q), bq, bk, interpret)
-    return _dq(*args), *_dkv(*args), None
+    return *_backward(q, k, v, mask, g, lse, dvec, _scale(q), bq, bk_bwd,
+                      interpret), None
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
@@ -422,10 +423,10 @@ def attend_chosen(q, k, v, mask, *, block_q: int | None = None,
     attention output ``[B,S,H,D]`` (differentiable in q, k, v) and the
     indexer's target ``sum_h p_h / H [B,S,S]`` float32, a constant to the
     gradient and defined only where the mask is set. Blocks default to
-    ``pick_blocks(S)``."""
+    ``pick_blocks(S)``; a ``block_k`` given is the backward kernel's too."""
     s = q.shape[1]
-    bq, bk = (block_q, block_k) if block_q and block_k else (
-        pick_blocks(s) or (_LANES, _LANES))
+    bq, bk, bk_bwd = (block_q, block_k, block_k) if block_q and block_k else (
+        pick_blocks(s) or (_LANES,) * 3)
     if s % bq or s % bk:
         raise ValueError(f"sequence {s} is no multiple of the kernels' "
                          f"blocks ({bq}, {bk})")
@@ -433,7 +434,7 @@ def attend_chosen(q, k, v, mask, *, block_q: int | None = None,
         interpret = interpret_by_default()
     stop = jax.lax.stop_gradient
     mask = stop(mask)
-    out, lse = _attend(q, k, v, mask, bq, bk, interpret)
+    out, lse = _attend(q, k, v, mask, bq, bk, bk_bwd, interpret)
     target = _target(stop(q), stop(k), mask, stop(lse), _scale(q), bq, bk,
                      interpret)
     return out, target

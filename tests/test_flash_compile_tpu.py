@@ -15,6 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 fa = importlib.import_module("ddw_tpu.ops.flash_attention")
+ik = importlib.import_module("ddw_tpu.ops.indexed_kernels")
 
 
 @pytest.fixture(scope="module")
@@ -149,3 +150,35 @@ def test_hyper_connection_passes_compile_for_v5e(one_chip, case):
                           for shape, dtype in shapes))
     assert text.count("tpu_custom_call") == 1
     assert "vmem_limit_bytes" not in text
+
+
+# the chosen-key attention kernels (ops/indexed_kernels.py): S and the
+# backward's key block. [2, S, 32 / 4, 128] bfloat16 is keyevl2_train_s8192's
+# attention at S = 8,192; 1,536 is a length that 1,024 does not divide
+_INDEXED = {"keye_s8192": (8192, 1024), "s1536": (1536, 512)}
+
+
+@pytest.mark.parametrize("case", sorted(_INDEXED))
+def test_indexed_kernels_compile_for_v5e(one_chip, case):
+    """Mosaic takes the forward kernel, the one-pass backward and the target
+    pass at the code's own blocks inside the VMEM a kernel gets unasked: none
+    of them raises its limit (a step whose kernels did never came back from
+    the chip, PERF.md section 6, PR 33)."""
+    s, bk_bwd = _INDEXED[case]
+    b, h, kv, d = 2, 32, 4, 128
+    bq, bk, _ = blocks = ik.pick_blocks(s)
+    assert blocks == (256, 512, bk_bwd)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k, mask = sds((b, s, h, d)), sds((b, s, kv, d)), sds((b, s, s), jnp.int8)
+    rows = sds((b, kv, h // kv, s), jnp.float32)
+    for fn, args, block_k in (
+            (ik._forward, (q, k, k, mask), bk),
+            (ik._backward, (q, k, k, mask, q, rows, rows), bk_bwd),
+            (ik._target, (q, k, mask, rows), bk)):
+        text = _compile(lambda *a: fn.__wrapped__(
+            *a, d ** -0.5, bq, block_k, False), *args)
+        assert text.count("tpu_custom_call") == 1
+        assert "vmem_limit_bytes" not in text

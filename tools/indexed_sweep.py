@@ -4,18 +4,21 @@ At the shape of the ``keyevl2_train_s8192`` cell (``[2, 8192, 32 / 4, 128]``
 bfloat16, 16 index heads of 64, top 2,048; ``--seq`` for another length), on
 a choice the real choose pass made from random index inputs:
 
-- ``kernels``: each of the four kernels of ``ops/indexed_kernels.py`` alone
-  (``indexed_fwd``, ``indexed_dq``, ``indexed_dkv``, ``indexed_target``) over
-  (block_q, block_k), the code's own blocks first, with the share of the
-  causal half's block pairs in which some query chose a key (the kernels
-  visit them all: what skipping the others could earn);
+- ``kernels``: each of the three kernels of ``ops/indexed_kernels.py`` alone
+  (``indexed_fwd``, ``indexed_dkv`` -- the whole backward pass -- and
+  ``indexed_target``) over (block_q, block_k), the code's own blocks first
+  (``chosen`` for the forward and the target, ``chosen_bwd`` the backward's),
+  with the share of the causal half's block pairs in which some query chose
+  a key (the kernels visit them all: what skipping the others could earn);
 - ``tiers``: ``indexed_attention`` forward + backward to all six inputs on
   the kernels and on the XLA tiles, the KL terms alone (from a given target),
   and how far the two tiers' outputs and gradients lie apart.
 
-An arm that fails to compile or to fit prints ``"ms": null`` and the error.
+An arm that fails to compile or to fit (blocks over the 16 MiB of VMEM a
+kernel gets unasked) prints ``"ms": null`` and the error.
 
 Run on the TPU:  python tools/indexed_sweep.py [--only kernels|tiers]
+    [--grid chosen|all] [--seq S] [--batch B]
 """
 
 import sys, os
@@ -32,8 +35,8 @@ from ddw_tpu.ops import indexed_attention as ia
 from ddw_tpu.ops import indexed_kernels as ik
 from tools.fa2_sweep import time_ms
 
-BLOCK_GRID = ((512, 512), (256, 512), (512, 256), (256, 256), (1024, 512),
-              (512, 1024))
+BLOCK_GRID = ((512, 512), (256, 512), (512, 256), (256, 256), (128, 512),
+              (256, 1024), (128, 1024))
 
 
 def inputs(b, s, h, kv, d, j, di, seed=0):
@@ -62,8 +65,14 @@ def timed(row, device, fn, *args):
 def run_kernels(q, k, v, g, mask, device, grid):
     s, d = q.shape[1], q.shape[-1]
     scale = float(d) ** -0.5
-    chosen = ik.pick_blocks(s)
-    for bq, bk in dict.fromkeys((chosen,) + tuple(grid)):
+    *chosen, bk_bwd = ik.pick_blocks(s)
+    chosen, chosen_bwd = tuple(chosen), (chosen[0], bk_bwd)
+    # the backward's and the target's inputs, which no block size changes
+    out, lse = ik._forward(q, k, v, mask, scale, *chosen,
+                           ik.interpret_by_default())
+    dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1).transpose(0, 2, 1).reshape(lse.shape)
+    for bq, bk in dict.fromkeys((chosen, chosen_bwd) + tuple(grid)):
         if s % bq or s % bk:
             continue
         nq, nk = s // bq, s // bk
@@ -74,21 +83,12 @@ def run_kernels(q, k, v, g, mask, device, grid):
         static = (scale, bq, bk, ik.interpret_by_default())
         row = {"preset": "kernels", "blocks": [bq, bk], "shape": list(q.shape),
                "chosen": (bq, bk) == chosen,
+               "chosen_bwd": (bq, bk) == chosen_bwd,
                "occupied_share": round(occupied / causal, 4)}
-        try:
-            out, lse = ik._forward(q, k, v, mask, *static)
-        except Exception as e:      # the blocks do not fit the kernel's VMEM
-            emit(dict(row, arm="indexed_fwd", ms=None,
-                      error=f"{type(e).__name__}: {e}"[:300]), device)
-            continue
-        dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                       axis=-1).transpose(0, 2, 1).reshape(lse.shape)
+        bwd_args = (q, k, v, mask, g, lse, dvec)
         arms = {
             "indexed_fwd": (lambda: ik._forward(q, k, v, mask, *static)),
-            "indexed_dq": (lambda: ik._dq(q, k, v, mask, g, lse, dvec,
-                                          *static)),
-            "indexed_dkv": (lambda: ik._dkv(q, k, v, mask, g, lse, dvec,
-                                            *static)),
+            "indexed_dkv": (lambda: ik._backward(*bwd_args, *static)),
             "indexed_target": (lambda: ik._target(q, k, mask, lse, *static)),
         }
         for name, fn in arms.items():
