@@ -85,7 +85,7 @@ def raw_u8_view(content: bytes, height: int, width: int) -> np.ndarray:
 def dequantize_raw_u8(batch: np.ndarray) -> None:
     """In-place inverse of materialize_decoded's quantization: a float batch
     holding uint8 pixel values becomes [-1, 1]. THE single definition of the
-    raw_u8 scheme — loader, batch scorer, and bench all call this, so a
+    raw_u8 scheme — loader, batch scorer and feature cache all call this, so a
     change to the quantization can never reintroduce train/serve skew (the
     bug class ``preprocess_image`` exists to prevent on the JPEG path).
     :func:`dequantize_raw_u8_device` is the jit-side twin — change BOTH or

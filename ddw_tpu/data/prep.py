@@ -255,9 +255,9 @@ def materialize_decoded(
     table into a decoded parquet cache before training,
     ``03_model_training_distributed.py:137-144``): decode + resize every JPEG
     ONCE at prep time and store raw uint8 [H, W, 3] pixels, so the training
-    loader's per-batch work drops from JPEG decode (~1.7 ms/img on a 1-core
-    host — measured in ``bench.py``, where live decode starves the chip ~65x)
-    to a memcpy + scale. Pixels are produced by the SAME shared
+    loader's per-batch work drops from JPEG decode to a memcpy + scale
+    (``tools/loader_bench.py`` prints both paths' records/s on the host at
+    hand). Pixels are produced by the SAME shared
     ``preprocess_image`` path training/serving use, then quantized to uint8
     (max quantization error 1/255 of the [-1, 1] range — the JPEG already
     quantized harder). The loader detects ``meta.encoding == 'raw_u8'`` and

@@ -1,9 +1,9 @@
 """Where XLA's persistent compilation cache lives.
 
-Every entry point that compiles (``chip_smoke.py``, ``bench.py``, the serving
-and gang worker mains, the examples) calls :func:`enable_compile_cache` before
-its first compile, so a second run — or a child process — finds the programs
-the first one paid for. The directory is part of the cache key, so it is either
+Every entry point that compiles (``chip_smoke.py``, ``benchmark/run.py``, the
+serving and gang worker mains, the examples) calls
+:func:`enable_compile_cache` before its first compile, so a second run — or a
+child process — finds the programs the first one paid for. The directory is part of the cache key, so it is either
 the one the environment names or ONE fixed path inside the checkout; never a
 temp dir, a pid or a timestamp.
 """

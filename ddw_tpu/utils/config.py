@@ -417,9 +417,8 @@ _TYPES = {"data": DataCfg, "model": ModelCfg, "train": TrainCfg, "tune": TuneCfg
 def require_tpu_or_exit(verb: str = "measure") -> str:
     """The opt-in refusal of the offline measurement tools (``tools/``): with
     ``DDW_REQUIRE_TPU`` set and a backend that is not a TPU, print the refusal
-    to stderr and exit 4. Returns the device kind. ``chip_smoke.py`` and
-    full-shape ``bench.py`` do not use it — they refuse a non-TPU platform
-    unconditionally."""
+    to stderr and exit 4. Returns the device kind. ``chip_smoke.py`` does not
+    use it — it refuses a non-TPU platform unconditionally."""
     import sys
 
     import jax
@@ -434,7 +433,7 @@ def require_tpu_or_exit(verb: str = "measure") -> str:
 
 
 def env_flag(name: str) -> bool:
-    """Boolean environment flag shared by bench.py and the perf tools.
+    """Boolean environment flag of the perf tools (``tools/``).
 
     Accepts the common spellings both ways; anything else raises — a typo
     must not silently flip a flag in either direction (enabling
@@ -449,33 +448,6 @@ def env_flag(name: str) -> bool:
         return True
     raise ValueError(f"{name} must be a boolean flag "
                      f"(1/true/yes/on or 0/false/no/off), got {val!r}")
-
-
-def vit_geometry_env() -> dict:
-    """``DDW_BENCH_VIT_HIDDEN`` / ``DDW_BENCH_VIT_HEADS`` → ModelCfg kwargs.
-
-    The ONE parser for the tile-geometry A/B knobs, shared by ``bench.py``
-    (the chip arm) and ``tools/attn_dispatch_evidence.py`` (the offline
-    lowering ``tools/mxu_roofline.py`` analyzes) — the two must describe the
-    same program by construction, not by hand-synced duplication. Empty or
-    unset vars mean "model default"."""
-    import os
-
-    geo = {}
-    if os.environ.get("DDW_BENCH_VIT_HIDDEN", "").strip():
-        geo["hidden"] = int(os.environ["DDW_BENCH_VIT_HIDDEN"])
-    if os.environ.get("DDW_BENCH_VIT_HEADS", "").strip():
-        geo["num_heads"] = int(os.environ["DDW_BENCH_VIT_HEADS"])
-    return geo
-
-
-def lm_heads_env(default: int) -> int:
-    """``DDW_BENCH_LM_HEADS`` override (tile-geometry A/B arm), shared like
-    :func:`vit_geometry_env`. Empty or unset means ``default``."""
-    import os
-
-    val = os.environ.get("DDW_BENCH_LM_HEADS", "").strip()
-    return int(val) if val else default
 
 
 def apply_overrides(cfgs: dict[str, Any], overrides: list[str]) -> dict[str, Any]:

@@ -24,7 +24,7 @@ or a run on another machine, proves byte-for-byte the same pipeline):
   prep            contract 1: scan -> bronze -> seeded split -> silver
   train-single    contract 2: frozen-base transfer on one device; asserts
                   val top-1 >= --bar (reference publishes no number —
-                  BASELINE.md "Published numbers" — so the bar is this
+                  BASELINE.json: "published": {} — so the bar is this
                   framework's own stake in the ground, default 0.85)
   train-dist      contract 3: the same fit over every local device
   hpo             contract 4: TPE over the reference's space (optimizer
@@ -40,7 +40,7 @@ training or HPO; hpo-dist records its tuned params in the report so
 package-score can resume past it).
 
 On the bar: the reference never publishes a top-1 number for its headline
-run (BASELINE.md "Published numbers" documents the absence), so 0.85 is this
+run (BASELINE.json's ``"published": {}`` records the absence), so 0.85 is this
 framework's own stake — chosen below the 0.88-0.92 that frozen
 ImageNet-MobileNetV2 transfer on tf_flowers typically reaches, so it fails
 on real regressions (wrong preprocessing, broken weight import) without

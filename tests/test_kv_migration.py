@@ -39,6 +39,8 @@ tools/load_gen.py --disagg / tier-2 with the other process-fleet boots.
 """
 
 import json
+import threading
+import time
 
 import jax
 import numpy as np
@@ -313,16 +315,39 @@ def test_disagg_seeded_identity_crosses_the_handoff(disagg, pm):
     assert np.array_equal(a, b) and np.array_equal(a, direct)
 
 
-def test_disagg_identity_through_mid_decode_preemption(disagg, pm):
+def test_disagg_identity_through_mid_decode_preemption(disagg, pm,
+                                                       monkeypatch):
     """The decode pool runs OUT of blocks mid-flight (overcommit admits
     more growth than it holds): the youngest migrated stream preempts,
     recomputes, and every answer still matches the sequential path."""
     rs, _, D = disagg
     prompts = _prompts([18, 19, 21], seed=17)
     steps = 24
+    # the overcommit is the pool's arithmetic, not the threads' timing: each
+    # stream writes 6 blocks before it ends, so any two that start decoding
+    # together outgrow the pool's 10 while neither can finish first
+    per_stream = [D.pool.blocks_for(D.pool.total_positions(len(p), steps))
+                  for p in prompts]
+    assert per_stream == [6, 6, 6] and 2 * 6 > D.pool.n_blocks
     refs = [np.asarray(pm.generate(p[None, :], steps))[0] for p in prompts]
     base = D.snapshot()["serve.preemptions"]
-    futs = [rs.submit_generate(p, steps, timeout_s=300.0) for p in prompts]
+    # ordered admission: a handoff runs on the submitting thread (prefill on
+    # P, migrate, queue on D), so without the gate the first stream may be
+    # done before the second arrives. D keeps admitting and landing imports
+    # but decodes nothing until two streams are resident.
+    gate = threading.Event()
+    tick = D._decode_tick
+    monkeypatch.setattr(D, "_decode_tick", lambda: gate.is_set() and tick())
+    try:
+        futs = [rs.submit_generate(p, steps, timeout_s=300.0)
+                for p in prompts]
+        deadline = time.monotonic() + 120.0
+        while (D.snapshot()["serve.resident_streams"] < 2
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        assert D.snapshot()["serve.resident_streams"] >= 2
+    finally:
+        gate.set()
     out = [f.result(timeout=300) for f in futs]
     assert D.snapshot()["serve.preemptions"] > base, \
         "overcommit never ran out — the drill lost its teeth"

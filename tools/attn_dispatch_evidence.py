@@ -29,7 +29,7 @@ import json
 import re
 import subprocess
 
-# headline bench shapes (bench.py: vit b256/224², lm_flash b8/S2048/h512);
+# the two transformer shapes (vit b256/224², lm_flash b8/S2048/h512);
 # DDW_BENCH_SMOKE shrinks them for CI (mechanism only — tiny scores all land
 # in the plain tier, so smoke exercises the ckpt_force delta, not the real
 # dispatch decisions)
@@ -80,13 +80,8 @@ def lower_bench_step(config: str):
 
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")
-            # same geometry knobs bench.py's vit row honors (one shared
-            # parser), so the offline ceiling (mxu_roofline) and the chip
-            # arm (ab_vit_tile) always describe the same program
-            from ddw_tpu.utils.config import vit_geometry_env
-
             mcfg = ModelCfg(name="vit", num_classes=5, dropout=0.5,
-                            dtype="bfloat16", **vit_geometry_env())
+                            dtype="bfloat16")
             model = build_model(mcfg)
         tcfg = TrainCfg(batch_size=cfg["batch"], optimizer="adam")
         img = (cfg["img"], cfg["img"], 3)
@@ -111,12 +106,9 @@ def lower_bench_step(config: str):
         from ddw_tpu.models.lm import TransformerLM
         from ddw_tpu.train.lm_step import init_lm_state, make_lm_train_step
 
-        from ddw_tpu.utils.config import lm_heads_env
-
-        heads = lm_heads_env(cfg["heads"])
         model = TransformerLM(vocab_size=cfg["vocab"], max_len=cfg["seq"],
                               hidden=cfg["hidden"], depth=cfg["depth"],
-                              num_heads=heads,
+                              num_heads=cfg["heads"],
                               mlp_dim=cfg["hidden"] * 4, dropout=0.0,
                               dtype=jnp.bfloat16, seq_axis=None, remat="none")
         tx = optax.adam(3e-4)

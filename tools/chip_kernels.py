@@ -8,7 +8,7 @@ what puts them on the device:
 1. **depthwise numerics** — fwd + both grads, Pallas (Mosaic-compiled) vs the
    XLA grouped conv at MobileNetV2's stride-1 depthwise shapes; max |err|.
 2. **depthwise timing** — fwd and fwd+bwd A/B vs XLA at those shapes
-   (bench.py's forced-fetch differential).
+   (the forced-fetch differential, ``_time_steps`` below).
 3. **ring** — with >= 2 devices the ring runs over the first two and over all
    of them, is checked against ``lax.psum``, and is timed against it at a
    gradient-sized buffer. With one device there is nothing to run: ``n == 1``
@@ -25,6 +25,7 @@ import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import json
+import statistics
 import time
 
 import jax
@@ -36,14 +37,43 @@ from jax import shard_map
 from ddw_tpu.utils.config import env_flag
 
 SMOKE = env_flag("DDW_BENCH_SMOKE")
+REPEATS = 1 if SMOKE else 3
+# Adaptive sizing: grow N until one differential run holds >= this much device
+# work, so fixed dispatch/fetch latency stays inside the noise floor.
+MIN_MEASURE_S = 0.05 if SMOKE else 1.0
+MAX_STEPS = 8 if SMOKE else 1024
+
+
+def _time_steps(run_n) -> tuple[float, int]:
+    """True seconds-per-``N``-steps of device work, via differential timing.
+
+    ``run_n(n)`` must run ``n`` chained steps and FORCE completion with a
+    device-to-host fetch (``np.asarray`` of a scalar output). The differential
+    ``T(2N) - T(N)`` cancels the fixed dispatch+fetch latency; N doubles until
+    the differential holds >= MIN_MEASURE_S of device work. Returns (median
+    differential seconds, N) — i.e. the time N steps take.
+    """
+    n = 2 if SMOKE else 8
+    chunk = getattr(run_n, "chunk", 1)
+    if chunk > 1:
+        # A runner that executes whole megasteps needs n to be a multiple of
+        # its chunk. Round up here (doubling preserves it).
+        n = -(-n // chunk) * chunk
+    while True:
+        dt = run_n(2 * n) - run_n(n)
+        if dt >= MIN_MEASURE_S or n >= MAX_STEPS:
+            break
+        n *= 2
+    times = [dt]
+    for _ in range(REPEATS - 1):
+        times.append(run_n(2 * n) - run_n(n))
+    good = [t for t in times if t > 0]
+    return (statistics.median(good) if good else run_n(n)), n
 
 
 def _t(fn, *args):
-    """Seconds per call via bench.py's adaptive differential ``_time_steps``
-    — the one timing methodology across bench.py and every perf tool (a
+    """Seconds per call via the adaptive differential ``_time_steps`` (a
     fixed small N would be dispatch-jitter-dominated for sub-ms kernels)."""
-    from bench import _time_steps
-
     def run_n(n):
         t0 = time.perf_counter()
         out = None

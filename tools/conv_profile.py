@@ -1,11 +1,10 @@
 """Per-conv attribution + roofline analysis for the conv train steps.
 
-VERDICT r2 item 1: the bench's MFU numbers (ResNet50 24.6%, MobileNetV2
-unfrozen 4.8%) say the chip idles but not WHERE. This tool answers that
-without TensorBoard: it enumerates every conv layer of a model (shape, stride,
-groups), microbenchmarks each unique conv fwd+bwd in isolation with the same
-differential forced-fetch timing bench.py uses, and compares the measured time
-against BOTH hardware ceilings:
+A conv train step's MFU says the chip idles but not WHERE. This tool answers
+that without TensorBoard: it enumerates every conv layer of a model (shape,
+stride, groups), microbenchmarks each unique conv fwd+bwd in isolation with
+the differential forced-fetch timing ``tools/chip_kernels.py`` holds, and
+compares the measured time against BOTH hardware ceilings:
 
 - compute bound: ``flops / peak_bf16_flops``
 - memory bound:  ``bytes_moved / hbm_bandwidth``
@@ -126,12 +125,12 @@ def resnet50_convs(img: int) -> list[ConvSpec]:
     return specs
 
 
-from bench import _time_steps  # bench.py's differential forced-fetch timing
+from chip_kernels import _time_steps  # the differential forced-fetch timing
 
 
 def _time_fn(fn, *args) -> float:
-    """Median seconds per call via bench.py's ``_time_steps`` (one timing
-    methodology across bench.py and both perf tools)."""
+    """Median seconds per call via ``chip_kernels._time_steps`` (one timing
+    methodology across both perf tools)."""
     out = fn(*args)  # warmup/compile
     np.asarray(jax.tree.leaves(out)[0]).ravel()[:1]
 

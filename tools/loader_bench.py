@@ -1,10 +1,11 @@
 """Host-side loader throughput: records/s for each ShardedLoader fast path.
 
 The loader's contract ("the TPU never waits on host IO", ``data/loader.py``)
-has two sides: the chip's consumption rate (measured by ``bench.py``'s
-``e2e_*`` rows on the chip) and the host's production rate — this
-tool, which needs NO device at all: it iterates the loader's host pipeline
-(read -> decode/reinterpret -> assemble) and reports records/s per path.
+has two sides: the chip's consumption rate (the benchmark's JPEG-fed cell,
+``vitb16_train_224``, reads ``data_wait_ms`` on the chip) and the host's
+production rate — this tool, which needs NO device at all: it iterates the
+loader's host pipeline (read -> decode/reinterpret -> assemble) and reports
+records/s per path.
 Completes the Petastorm reader-pool role with a number on the host side
 (reference ``Part 1 - Distributed Training/03_model_training_distributed
 .py:200,332-337`` sizes ``workers_count`` against exactly this rate).

@@ -9,7 +9,9 @@ static capacity ``C = ceil(cf * k * T / E)``, k = choices per token, out of
 ``k*T`` slots) and normalized assignment entropy (1.0 = balanced, 0.0 =
 collapsed). Routing semantics and the capacity formula come from
 ``ddw_tpu.models.moe.router_fn`` / ``expert_capacity`` — the exact code the
-model runs. The numbers land in BASELINE.md's MoE tables.
+model runs. The tables it prints are CPU runs at toy width: the chip has not
+measured them, and no document quotes them (``docs/DISTRIBUTED.md`` says how
+to regenerate them).
 
 Run:
     JAX_PLATFORMS=cpu \

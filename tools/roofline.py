@@ -24,10 +24,10 @@ The model is deliberately optimistic for the hardware (a true ceiling):
 - the optimizer update streams params + Adam moments once:
   read (p, m, v, g) + write (p, m, v) = 7 f32 accesses per param.
 
-If the *measured* step time (bench.py) sits near the floor, the remaining
-MFU gap is physics — arithmetic intensity, not implementation. If it sits
-far above, the gap is fixable and conv_profile's per-layer `vs_bound`
-column says where.
+If the *measured* step time (``tools/conv_profile.py``, on the chip) sits near
+the floor, the remaining MFU gap is physics — arithmetic intensity, not
+implementation. If it sits far above, the gap is fixable and conv_profile's
+per-layer `vs_bound` column says where.
 
 Run anywhere:  PYTHONPATH=. python tools/roofline.py
 Reference role: the cuDNN-backed conv path the reference inherits from
@@ -169,11 +169,11 @@ def main():
                 print(f"    {k:<12}{ms:>8.2f} ms  {fl/1e9:>7.0f} GF "
                       f"{bt/1e9:>6.2f} GB  AI {fl/max(bt,1):>5.0f}")
 
-    # The transformer rows at bench.py's fixed shapes: the in-tree ViT mean-
+    # The transformer rows at two fixed toy shapes: the in-tree ViT mean-
     # pools 196 patch tokens (no CLS — models/vit.py), the LM runs seq 2048.
     # Matmul-dominated, so the ceilings sit near peak — the honest contrast
     # with the conv models' memory-bound ~10%.
-    print(f"\n{'transformer rows (bench shapes)':<42}{'floor ms':>9}"
+    print(f"\n{'transformer rows (fixed shapes)':<42}{'floor ms':>9}"
           f"{'GFLOP':>8}{'GB':>7}{'MFU ceil':>9}{'bound':>9}{'AI':>7}")
     for r in (
         transformer_floor("vit (224², p16, S=196, b256)", batch=256,

@@ -66,6 +66,7 @@ import tempfile
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ddw_tpu.utils.config import env_flag
@@ -88,9 +89,32 @@ def _timed(call, *args, **kw):
     return float(np.median(times)), float(np.percentile(times, 90))
 
 
-def image_curve(batches, img):
-    from bench import throwaway_image_package
+def throwaway_image_package(tmp: str, img: tuple):
+    """Frozen-random bf16 MobileNetV2 packaged into ``tmp`` and loaded back —
+    the serving fixture ``image_curve`` measures. Returns the loaded
+    :class:`PackagedModel`."""
+    import warnings
 
+    from ddw_tpu.models.registry import build_model
+    from ddw_tpu.serving.package import PackagedModel, save_packaged_model
+    from ddw_tpu.utils.config import ModelCfg
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # frozen-random warning: speed only
+        mcfg = ModelCfg(name="mobilenet_v2", num_classes=5, dropout=0.0,
+                        freeze_base=True, allow_frozen_random=True,
+                        dtype="bfloat16")
+        model = build_model(mcfg)
+        variables = model.init({"params": jax.random.PRNGKey(0)},
+                               jnp.zeros((1, *img)), train=False)
+        save_packaged_model(tmp, mcfg, [f"c{i}" for i in range(5)],
+                            variables["params"],
+                            variables.get("batch_stats"),
+                            img_height=img[0], img_width=img[1])
+        return PackagedModel(tmp)
+
+
+def image_curve(batches, img):
     rng = np.random.RandomState(0)
     rows = []
     with tempfile.TemporaryDirectory() as tmp:
