@@ -596,6 +596,12 @@ class DecoderBlock(nn.Module):
         # the fused passes read: no copy between them and the blocks' edges);
         # TransformerLM refuses decode, a ring, LoRA
         streams = spec.hyper_streams > 1
+
+        def joined(x, h):
+            # with post_norm (the sandwich) h is a norm's float32 output:
+            # the sum is made in float32 and rounded to the stream's dtype
+            return (x + h).astype(x.dtype) if spec.post_norm else x + h
+
         if streams:
             flat = x.shape
             x = x.reshape(flat[:2] + (spec.hyper_streams, -1))
@@ -619,12 +625,14 @@ class DecoderBlock(nn.Module):
                                              start_pos=start_pos,
                                              adapters=adapters)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
+        if spec.post_norm:
+            h = layer_norm(spec, "attn_post_norm")(h)
         if streams:
             x = hyper_write(x, h, h_post, h_res)
             h, h_post, h_res = HyperConnection(spec, name="hc_mlp")(x)
             h = layer_norm(spec)(h)
         else:
-            x = x + h
+            x = joined(x, h)
             h = layer_norm(spec)(x)
         if spec.mlp not in ("gelu", "swiglu", "relu2") or (
                 spec.mlp == "relu2" and not (self.num_experts
@@ -667,10 +675,12 @@ class DecoderBlock(nn.Module):
                     h = nn.gelu(h)
                     h = mlp_dense(d, "fc2", h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
+        if spec.post_norm:
+            h = layer_norm(spec, "mlp_post_norm")(h)
         if streams:
             return hyper_write(x, h.astype(x.dtype), h_post,
                                h_res).reshape(flat)
-        return x + h
+        return joined(x, h)
 
 
 MIXERS = {"M": "a Mamba-2 mixer", "E": "routed experts", "*": "attention"}
@@ -709,6 +719,103 @@ class MixerBlock(nn.Module):
                                     layer=spec, name="mixer")(
                                         h, positions=positions)
         return x + h.astype(x.dtype)
+
+
+_EXIT_CHUNK = 2048      # tokens whose logits an exit holds at a time
+
+
+def exit_distribution(gate_logits):
+    """A token's distribution over the exits from its gate's logits
+    ``[passes, ...]``, in float32: with ``lam_t = sigmoid(logit_t)``,
+    ``p_1 = lam_1``, ``p_t = lam_t prod_{j<t} (1 - lam_j)``, and the LAST exit
+    takes what is left, ``prod_{j<passes} (1 - lam_j)``, so that the
+    ``passes`` numbers sum to 1 (the last pass's own gate is not read)."""
+    lam = jax.nn.sigmoid(gate_logits.astype(jnp.float32))[:-1]
+    left = jnp.cumprod(1.0 - lam, axis=0)       # after exits 1..t
+    before = jnp.concatenate([jnp.ones_like(left[:1]), left[:-1]], axis=0)
+    return jnp.concatenate([lam * before, left[-1:]], axis=0)
+
+
+def run_passes(model, x, positions, targets, token_reading, block,
+               make_head):
+    """The looped stack: ``h_t = norm(blocks(h_{t-1}))`` for ``passes``
+    passes over the SAME blocks and final norm, as one ``nn.scan`` whose
+    parameters are broadcast, so that the compiled program holds a
+    block's forward and backward once and the weights' gradient is the
+    scan's sum over the passes. The carried state is the final norm's
+    float32 output; a pass reads it in the model's dtype.
+
+    Returns the last pass's logits; with an ``exit_gate`` and
+    ``targets [B, S]`` the exits' readings in their place: a dict of
+    ``[passes, B, S]`` float32, ``gate`` the gate's logit and whatever
+    ``token_reading(logits, targets)`` (the step's: a dict of arrays a
+    token) reads of an exit's logits, with every exit's logits made
+    inside the loop and made again in the backward pass, never kept: what
+    survives an exit's forward pass is its state and these ``[B, S]``."""
+    read = targets is not None
+
+    def chunk_reading(mdl, _, h_tg):
+        h, tg = h_tg
+        with jax.named_scope("head"):
+            logits = make_head()(h)
+        with jax.named_scope("loss"):
+            return None, token_reading(logits, tg)
+
+    def exit_reading(mdl, h, tg):
+        # at most _EXIT_CHUNK tokens' logits at a time, each chunk's made
+        # again in the backward pass: a whole row's over a large vocabulary,
+        # with their gradient, are most of a chip. Equal chunks; a count
+        # they do not divide is filled up to it (fewer tokens than there
+        # are chunks, read and dropped)
+        n = -(-tg.size // _EXIT_CHUNK)
+        chunk = -(-tg.size // n)
+        fill = n * chunk - tg.size
+        h, flat = h.reshape(-1, h.shape[-1]), tg.reshape(-1)
+        if fill:
+            h = jnp.pad(h, ((0, fill), (0, 0)))
+            flat = jnp.pad(flat, (0, fill))
+        out = nn.scan(
+            nn.remat(chunk_reading, prevent_cse=False),
+            variable_broadcast="params", split_rngs={"params": False})(
+                mdl, None, (h.reshape(n, chunk, -1),
+                            flat.reshape(n, chunk)))[1]
+        return jax.tree.map(
+            lambda r: r.reshape(-1)[:tg.size].reshape(tg.shape), out)
+
+    def pass_exit(mdl, x, tg):
+        h = layer_norm(model.layer)(x)
+        out = {}
+        if model.exit_gate:
+            with jax.named_scope("loss"), jax.named_scope("exit_gate"):
+                out["gate"] = nn.Dense(1, dtype=jnp.float32,
+                                       precision=lax.Precision.HIGHEST,
+                                       name="exit_gate")(h)[..., 0]
+        if read:
+            out.update(exit_reading(mdl, h, tg))
+        return h, out
+
+    if model.remat != "none":
+        # as a block: what a pass keeps of its exit is the stack's output
+        # (in the model's dtype), not the norm's float32 working
+        pass_exit = nn.remat(pass_exit, prevent_cse=False)
+
+    def one_pass(mdl, h, positions, tg):
+        x = h.astype(model.dtype)
+        for i in range(model.depth):
+            x = block(i, x, positions)
+        return pass_exit(mdl, x, tg)
+
+    h, out = nn.scan(
+        one_pass, variable_broadcast="params",
+        split_rngs={"params": False, "dropout": True},
+        in_axes=nn.broadcast, length=model.passes)(
+            model, x.astype(jnp.float32), positions, targets)
+    if read:
+        return out
+    if model.exit_gate:
+        model.sow("intermediates", "exit_gate_logits", out["gate"])
+    with jax.named_scope("head"):
+        return make_head()(h)
 
 
 class TransformerLM(nn.Module):
@@ -765,10 +872,15 @@ class TransformerLM(nn.Module):
     dense_layers: int = 0    # leading DecoderBlocks with the dense MLP at
     dense_mlp_dim: int = 0   # this width where the rest route
     mtp_depth: int = 0       # 0 | 1: the multi-token-prediction module
+    passes: int = 1          # > 1: the blocks and the final norm run this
+                             # many times over ONE set of weights
+    exit_gate: bool = False  # an exit a pass through the one head, and a
+                             # learned gate a token and pass (run_passes)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, block_tables=None,
-                 start_pos=None, adapters=None):
+                 start_pos=None, adapters=None, targets=None,
+                 token_reading=None):
         # adapters: optional (stacks, idx) pair for heterogeneous-adapter
         # batched serving (ddw_tpu.serve.adapters.AdapterPool). ``stacks`` is
         # {f"backbone_block{i}": {target: (a_stack [S+1,*in,r],
@@ -809,6 +921,30 @@ class TransformerLM(nn.Module):
                 "multi-token-prediction module train on one device's whole "
                 "sequence: the latent cache (ROADMAP M4), drafting from the "
                 "module (M7), a ring and adapters are not written")
+        looped = self.passes > 1 or self.exit_gate
+        if self.passes < 1 or (self.exit_gate and self.passes < 2):
+            raise ValueError(f"passes {self.passes}: at least 1, and at "
+                             f"least 2 for the exits an exit_gate weighs")
+        if looped and (
+                self.decode or self.seq_axis is not None or self.lora_rank
+                or self.pattern or streams or self.mtp_depth
+                or self.num_experts or self.layer.sows):
+            raise NotImplementedError(
+                "a stack run several times over one set of weights, and its "
+                "exit gate, train on one device's whole sequence through "
+                "plain blocks: a cache of keys and values a layer AND pass, "
+                "a ring, adapters, a pattern, streams, the "
+                "multi-token-prediction module and layers that sow are not "
+                "written for it (ROADMAP M11)")
+        if self.layer.post_norm and (self.decode or self.pattern):
+            raise NotImplementedError(
+                "a norm on each sublayer's output is written for the "
+                "training path of a DecoderBlock: decode has not run it and "
+                "a pattern's MixerBlock has no such norm (ROADMAP M2)")
+        if targets is not None and not (self.exit_gate and token_reading):
+            raise ValueError("targets are for a model with an exit_gate, "
+                             "which reads its exits' logits itself with "
+                             "the step's token_reading")
         b, s_local = tokens.shape
         embed = nn.Embed(self.vocab_size, self.hidden, dtype=self.dtype,
                          name="tok_embed")
@@ -911,8 +1047,12 @@ class TransformerLM(nn.Module):
                           "expert_sort", "expert_hidden")
                       if self.remat == "full"
                       else jax.checkpoint_policies.checkpoint_dots)
+            # inside the passes' loop the barriers that keep a block's
+            # second making apart from its first are not needed (the two lie
+            # in different loops) and would only pin the loop's schedule
             remat = lambda block: nn.remat(                # noqa: E731
-                block, static_argnums=(2,), policy=policy)
+                block, static_argnums=(2,), policy=policy,
+                prevent_cse=not looped)
         else:
             remat = lambda block: block                     # noqa: E731
         Block = remat(DecoderBlock)
@@ -941,37 +1081,46 @@ class TransformerLM(nn.Module):
                 num_experts=self.num_experts, num_kv_heads=self.num_kv_heads,
                 layer=self.layer, name=f"backbone_block{i}")(x, train,
                                                              positions)
-        # without a pattern: depth blocks of an attention and an MLP each
-        for i in range(0 if self.pattern else self.depth):
+
+        def block(i, x, positions):
             blk_kw = dict(paged_kw)
             if row_adapters is not None:
                 blk_kw["adapters"] = row_adapters.get(f"backbone_block{i}")
             dense = i < self.dense_layers
-            x = Block(self.num_heads,
-                      self.dense_mlp_dim if dense else self.mlp_dim,
-                      self.dropout,
-                      self.dtype, None if self.decode else self.seq_axis,
-                      self.decode, self.max_len,
-                      slot_decode=self.slot_decode,
-                      num_experts=0 if dense else self.num_experts,
-                      expert_axis=None if self.decode else self.expert_axis,
-                      capacity_factor=self.capacity_factor,
-                      moe_router=self.moe_router,
-                      num_kv_heads=self.num_kv_heads,
-                      lora_rank=self.lora_rank,
-                      lora_alpha=self.lora_alpha,
-                      lora_targets=self.lora_targets,
-                      paged_decode=self.paged_decode,
-                      kv_cache_blocks=self.kv_cache_blocks,
-                      kv_block_size=self.kv_block_size,
-                      layer=self.layer,
-                      name=f"backbone_block{i}")(x, train, positions,
-                                                 **blk_kw)
+            return Block(self.num_heads,
+                         self.dense_mlp_dim if dense else self.mlp_dim,
+                         self.dropout,
+                         self.dtype, None if self.decode else self.seq_axis,
+                         self.decode, self.max_len,
+                         slot_decode=self.slot_decode,
+                         num_experts=0 if dense else self.num_experts,
+                         expert_axis=None if self.decode else self.expert_axis,
+                         capacity_factor=self.capacity_factor,
+                         moe_router=self.moe_router,
+                         num_kv_heads=self.num_kv_heads,
+                         lora_rank=self.lora_rank,
+                         lora_alpha=self.lora_alpha,
+                         lora_targets=self.lora_targets,
+                         paged_decode=self.paged_decode,
+                         kv_cache_blocks=self.kv_cache_blocks,
+                         kv_block_size=self.kv_block_size,
+                         layer=self.layer,
+                         name=f"backbone_block{i}")(x, train, positions,
+                                                    **blk_kw)
+
+        # vocab head in f32: logits feed a softmax CE, keep full precision
+        make_head = lambda: nn.Dense(                       # noqa: E731
+            self.vocab_size, use_bias=self.layer.bias, dtype=jnp.float32,
+            name="head")
+        if looped:
+            return run_passes(self, x, positions, targets, token_reading,
+                              block, make_head)
+        # without a pattern: depth blocks of an attention and an MLP each
+        for i in range(0 if self.pattern else self.depth):
+            x = block(i, x, positions)
         x = leave(x)
         final_norm = layer_norm(self.layer)
-        # vocab head in f32: logits feed a softmax CE, keep full precision
-        head = nn.Dense(self.vocab_size, use_bias=self.layer.bias,
-                        dtype=jnp.float32, name="head")
+        head = make_head()
         with jax.named_scope("head"):
             logits = head(final_norm(x))
         if self.mtp_depth:
@@ -1028,7 +1177,9 @@ def build_lm(cfg, seq_axis: str | None = None,
         pattern=getattr(cfg, "pattern", ""),
         dense_layers=getattr(cfg, "dense_layers", 0),
         dense_mlp_dim=getattr(cfg, "dense_mlp_dim", 0),
-        mtp_depth=getattr(cfg, "mtp_depth", 0))
+        mtp_depth=getattr(cfg, "mtp_depth", 0),
+        passes=getattr(cfg, "passes", 1),
+        exit_gate=getattr(cfg, "exit_gate", False))
 
 
 def init_cache(decode_model: TransformerLM, batch: int):

@@ -25,7 +25,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddw_tpu.train.step import (TrainState, cross_entropy_loss,
-                                replicated_placer)
+                                replicated_placer, token_cross_entropy)
 
 # next-token CE is the same sparse CE (it broadcasts over [B, S, V] vs [B, S])
 lm_loss = cross_entropy_loss
@@ -97,6 +97,49 @@ def layer_terms(mods: dict) -> dict:
     return out
 
 
+def exit_token_reading(logits, targets) -> dict:
+    """What the step reads of an exit's logits, a token (the model's
+    ``token_reading``, called on a chunk of tokens inside its passes' loop):
+    the cross-entropy and whether the first choice is the target."""
+    return {"ce": token_cross_entropy(logits, targets),
+            "correct": (jnp.argmax(logits, -1) == targets).astype(
+                jnp.float32)}
+
+
+def exit_loss(readings: dict, entropy_weight: float) -> tuple:
+    """What a model with an exit gate descends, from its exits' readings
+    (``models/lm.py::run_passes``: ``gate`` and :func:`exit_token_reading`'s
+    ``ce`` and ``correct``, each ``[passes, B, S]``): the mean over tokens of ``sum_t p_t CE_t -
+    entropy_weight H(p)``, ``p`` the token's distribution over the exits
+    (:func:`ddw_tpu.models.lm.exit_distribution`) and ``H`` its entropy, all
+    in float32. Returns ``(total, last exit's mean CE, last exit's accuracy,
+    counters)``: ``exit_loss_t`` and ``exit_share_t`` (the means of ``CE_t``
+    and ``p_t``), ``exit_expected_passes`` (``mean sum_t t p_t``: what
+    leaving at the gate's word would run), ``exit_entropy`` (``mean H``, at
+    most ``ln passes``) and ``exit_expected_loss`` (``mean sum_t p_t CE_t``:
+    the total is this less ``entropy_weight`` times the entropy)."""
+    from ddw_tpu.models.lm import exit_distribution
+
+    ce = readings["ce"].astype(jnp.float32)
+    with jax.named_scope("exit_gate"):
+        p = exit_distribution(readings["gate"])
+        # a saturated gate makes a p of exactly 0: 0 ln 0 = 0, and a finite
+        # slope there
+        entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-30)), axis=0)
+        expected = jnp.sum(p * ce, axis=0)
+        total = jnp.mean(expected - entropy_weight * entropy)
+        ranks = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+        terms = {"exit_expected_passes": jnp.mean(
+                     jnp.tensordot(ranks, p, axes=1)),
+                 "exit_entropy": jnp.mean(entropy),
+                 "exit_expected_loss": jnp.mean(expected)}
+        for t in range(p.shape[0]):
+            terms[f"exit_loss_{t + 1}"] = jnp.mean(ce[t])
+            terms[f"exit_share_{t + 1}"] = jnp.mean(p[t])
+    return (total, jnp.mean(ce[-1]), jnp.mean(readings["correct"][-1]),
+            terms)
+
+
 def _lm_axes(model, data_axis: str, seq_axis: str | None) -> tuple:
     """Validate the model/step axis contract shared by the per-step and
     chained factories; returns ``(axes, sows)``: whether the model's layers
@@ -127,6 +170,7 @@ def make_lm_train_step(
     grad_accum_steps: int = 1,
     hand_out: tuple[str, ...] = (),
     mtp_weight: float = 0.1,
+    exit_entropy_weight: float = 0.05,
 ) -> Callable:
     """Build the jitted DP(xSP)(xEP) LM train step.
 
@@ -140,7 +184,10 @@ def make_lm_train_step(
     with a multi-token-prediction module (``mtp_depth``) descends ``loss +
     mtp_weight * mtp_loss``, the module's cross-entropy against the token
     after next over the positions that have one, and reports it among the
-    layers' counters (``loss`` and ``accuracy`` stay the main head's). It
+    layers' counters (``loss`` and ``accuracy`` stay the main head's); a
+    model with an ``exit_gate`` descends :func:`exit_loss` with
+    ``exit_entropy_weight`` and reports its counters there too (``loss`` and
+    ``accuracy`` are the LAST exit's). It
     compiles once for each placement of its arguments:
     ``step.place_state(state)`` before the first call gives the state the
     placement the step returns it in, and one executable serves.
@@ -156,7 +203,8 @@ def make_lm_train_step(
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
     _step = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                               grad_accum_steps, hand_out, mtp_weight)
+                               grad_accum_steps, hand_out, mtp_weight,
+                               exit_entropy_weight)
 
     tok_spec = P(data_axis) if seq_axis is None else P(data_axis, seq_axis)
     smapped = shard_map(
@@ -174,13 +222,15 @@ def make_lm_train_step(
 def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
                        aux_loss_weight: float, grad_accum_steps: int,
                        hand_out: tuple[str, ...] = (),
-                       mtp_weight: float = 0.1):
+                       mtp_weight: float = 0.1,
+                       exit_entropy_weight: float = 0.05):
     """The per-update shard_map body shared by :func:`make_lm_train_step`
     and :func:`make_lm_train_chain` (which scans it K times)."""
     from flax.traverse_util import flatten_dict
 
     from ddw_tpu.models.moe import collect_sown
 
+    exits = getattr(model, "exit_gate", False)
     if hand_out and (not sows or grad_accum_steps > 1):
         raise ValueError("hand_out returns what the layers sow on a step: "
                          "the model's layers sow nothing, or "
@@ -196,6 +246,18 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
 
         def loss_fn(params, in_mb, tg_mb, rng_mb):
             terms, loads, handed = {}, {}, {}
+            if exits:
+                # the model reads its exits against the targets itself: no
+                # exit's logits outlive the pass that makes them
+                readings = model.apply({"params": params}, in_mb, train=True,
+                                       rngs={"dropout": rng_mb},
+                                       targets=tg_mb,
+                                       token_reading=exit_token_reading)
+                with jax.named_scope("loss"):
+                    total, ce, acc, terms = exit_loss(readings,
+                                                      exit_entropy_weight)
+                return total, (ce, acc, jnp.zeros((), jnp.float32), terms,
+                               loads, handed)
             if sows:
                 variables = {"params": params}
                 if buffers:
@@ -318,6 +380,7 @@ def make_lm_train_chain(
     aux_loss_weight: float = 0.01,
     grad_accum_steps: int = 1,
     mtp_weight: float = 0.1,
+    exit_entropy_weight: float = 0.05,
 ) -> Callable:
     """Fused K-step LM train program (``TrainCfg.steps_per_dispatch``): the
     :func:`make_lm_train_step` body ``lax.scan``-ned over a stacked token
@@ -329,7 +392,8 @@ def make_lm_train_chain(
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
     body = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                              grad_accum_steps, mtp_weight=mtp_weight)
+                              grad_accum_steps, mtp_weight=mtp_weight,
+                              exit_entropy_weight=exit_entropy_weight)
 
     def _chain(state: TrainState, inputs, targets, rng):
         def scanned(st, xs):

@@ -87,6 +87,14 @@ class LMTrainer:
                     f"indexer's KL term), which would silently train an "
                     f"unbalanced router or an untrained indexer — use the "
                     f"plain DP/EP step (no zero/fsdp)")
+        if ((self.pp and getattr(lm_cfg, "passes", 1) > 1)
+                or ((self.pp or self.sharded)
+                    and getattr(lm_cfg, "exit_gate", False))):
+            raise NotImplementedError(
+                "lm.passes > 1 and lm.exit_gate train through the plain "
+                "DP step: the pipeline step builds each stage's blocks once "
+                "and runs them once, and neither it nor the zero/fsdp step "
+                "descends the exits' expected loss (ROADMAP M11)")
         if train_cfg.steps_per_dispatch < 1:
             raise ValueError(f"train.steps_per_dispatch must be >= 1, got "
                              f"{train_cfg.steps_per_dispatch}")
@@ -398,12 +406,14 @@ class LMTrainer:
                 step = make_lm_train_step(
                     self.model, tx, mesh, seq_axis=self.seq_axis,
                     grad_accum_steps=cfg.grad_accum_steps,
-                    mtp_weight=cfg.mtp_weight)
+                    mtp_weight=cfg.mtp_weight,
+                    exit_entropy_weight=cfg.exit_entropy_weight)
                 if chained:
                     chain = make_lm_train_chain(
                         self.model, tx, mesh, seq_axis=self.seq_axis,
                         grad_accum_steps=cfg.grad_accum_steps,
-                        mtp_weight=cfg.mtp_weight)
+                        mtp_weight=cfg.mtp_weight,
+                        exit_entropy_weight=cfg.exit_entropy_weight)
             # Under ZeRO/FSDP eval reads the sharded params through the
             # shard_map eval step's replicated in-spec: GSPMD gathers per
             # eval call (same trade the vision Trainer makes).

@@ -50,10 +50,15 @@ class TrainState:
     step: jnp.ndarray         # i32 scalar
 
 
+def token_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """Each item's sparse categorical cross-entropy from its logits."""
+    return optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+
+
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Sparse categorical cross-entropy from logits (reference
     ``02_model_training_single_node.py:202`` — ``from_logits=True``)."""
-    return optax.softmax_cross_entropy_with_integer_labels(logits, labels).mean()
+    return token_cross_entropy(logits, labels).mean()
 
 
 def _base_optimizer(name: str, learning_rate,

@@ -164,6 +164,11 @@ class TrainCfg:
                                         # (LMCfg.mtp_depth > 0; DeepSeek-V3's
                                         # late-training value); ``loss`` stays
                                         # the main head's cross-entropy
+    exit_entropy_weight: float = 0.05   # beta of the loss a model with
+                                        # LMCfg.exit_gate descends: the mean
+                                        # over tokens of sum_t p_t CE_t -
+                                        # beta H(p), p a token's distribution
+                                        # over the exits (arXiv:2510.25741)
     data_axis: str = "data"             # mesh axis name for DP psum
     num_devices: int = 0                # 0 = all visible devices
     zero: bool = False                  # ZeRO-1: shard optimizer moments over
@@ -230,6 +235,9 @@ class LayerSpec:
     norm: str = "layernorm"             # "layernorm" | "rmsnorm", in float32
     norm_eps: float = 1e-6
     bias: bool = True                   # on every projection, MLP and the head
+    post_norm: bool = False             # a second norm on each sublayer's
+                                        # OUTPUT, before the residual add
+                                        # (sandwich norm): x + norm(f(norm(x)))
     head_dim: int = 0                   # 0: hidden // num_heads
     qk_norm: bool = False               # RMSNorm over each head of q and of k
     rope_theta: float = 10000.0
@@ -370,6 +378,20 @@ class LMCfg:
                                         # predicts the token after next
                                         # through the shared embedding and
                                         # head; TrainCfg.mtp_weight its term
+    passes: int = 1                     # > 1: the stack of depth blocks and
+                                        # the final norm run this many times
+                                        # over ONE set of weights, the normed
+                                        # state of a pass the next one's input
+                                        # (a looped decoder); the head reads
+                                        # the last pass
+    exit_gate: bool = False             # an exit after every pass through the
+                                        # one head, and a learned gate (hidden
+                                        # -> 1, sigmoid) a token and pass that
+                                        # spreads the token over the exits;
+                                        # training descends the expected loss
+                                        # over them (TrainCfg.
+                                        # exit_entropy_weight); logits, loss
+                                        # and accuracy stay the last exit's
     remat: str = "none"                 # per-block activation remat: "full"
                                         # (keep nothing; recompute block in
                                         # bwd) or "dots" (keep matmul outputs)
