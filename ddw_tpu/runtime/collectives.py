@@ -18,7 +18,9 @@ collectives itself (Horovod's Tensor Fusion falls out of XLA fusion). The in-tre
 "native collective" exists at two levels: :func:`ring_all_reduce` (``ppermute``
 ring — XLA emits the transfers) and :func:`ring_all_reduce_pallas`
 (:mod:`ddw_tpu.ops.ring_reduce` — hand-written RDMA hops, the Horovod-core
-analog all the way down to the semaphores).
+analog all the way down to the semaphores). The train steps' own gradient mean
+is :func:`ddw_tpu.parallel.collectives.grad_mean`, which also carries the
+compiler options under which the reduce rides beneath the backward pass.
 
 All functions take an ``axis_name`` and must be called under ``shard_map``/``pmap``
 binding that name.

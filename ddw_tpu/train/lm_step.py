@@ -11,7 +11,7 @@ Loss plumbing: callers pre-shift on the host (``inputs = tokens[:, :-1]``,
 ``targets = tokens[:, 1:]``) so no cross-shard halo exchange is needed at shard
 boundaries; per-device mean CE is exact globally because every shard holds the
 same token count (identical-shape guarantee, SURVEY.md §7 hard-part 2). Gradients
-``pmean`` over data x seq in one collective.
+are averaged over data x seq by :func:`ddw_tpu.parallel.collectives.grad_mean`.
 """
 
 from __future__ import annotations
@@ -24,6 +24,8 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ddw_tpu.parallel.collectives import (data_parallel_compile_options,
+                                          grad_mean)
 from ddw_tpu.train.step import (TrainState, cross_entropy_loss,
                                 replicated_placer, token_cross_entropy)
 
@@ -202,9 +204,10 @@ def make_lm_train_step(
     """
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
-    _step = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                               grad_accum_steps, hand_out, mtp_weight,
-                               exit_entropy_weight)
+    options = data_parallel_compile_options(mesh, axes, model)
+    _step = _make_lm_step_body(model, tx, axes, sows, options is not None,
+                               aux_loss_weight, grad_accum_steps, hand_out,
+                               mtp_weight, exit_entropy_weight)
 
     tok_spec = P(data_axis) if seq_axis is None else P(data_axis, seq_axis)
     smapped = shard_map(
@@ -213,19 +216,24 @@ def make_lm_train_step(
         out_specs=(P(), P()),
         check_vma=False,
     )
-    step = jax.jit(smapped, donate_argnums=(0,) if donate else ())
+    step = jax.jit(smapped, donate_argnums=(0,) if donate else (),
+                   compiler_options=options)
     step.batch_sharding = NamedSharding(mesh, tok_spec)  # type: ignore[attr-defined]
     step.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
     return step
 
 
 def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
-                       aux_loss_weight: float, grad_accum_steps: int,
+                       fused: bool, aux_loss_weight: float,
+                       grad_accum_steps: int,
                        hand_out: tuple[str, ...] = (),
                        mtp_weight: float = 0.1,
                        exit_entropy_weight: float = 0.05):
     """The per-update shard_map body shared by :func:`make_lm_train_step`
-    and :func:`make_lm_train_chain` (which scans it K times)."""
+    and :func:`make_lm_train_chain` (which scans it K times). ``fused``: the
+    builder's ``jit`` got :func:`data_parallel_compile_options`, so the
+    means take :func:`grad_mean`'s form; else they are the ``lax.pmean`` calls
+    they were, in their order."""
     from flax.traverse_util import flatten_dict
 
     from ddw_tpu.models.moe import collect_sown
@@ -339,7 +347,16 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
             (_, (loss, acc, aux, terms, loads, handed)), grads = grad_fn(
                 state.params, inputs, targets, dropout_rng)
         with jax.named_scope("grad_sync"):
-            grads = lax.pmean(grads, axes)
+            if fused:
+                # the scalar means ride in the flat buffer of the small
+                # gradients: the step holds no synchronous reduce, which
+                # would run inside an asynchronous one's window
+                grads, (loss, acc, aux, terms) = grad_mean(
+                    (grads, (loss, acc, aux, terms)), axes)
+            else:
+                grads = lax.pmean(grads, axes)
+        scalar_mean = (lambda x: x) if fused else (
+            lambda x: lax.pmean(x, axes))
         with jax.named_scope("optimizer"):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -349,15 +366,13 @@ def _make_lm_step_body(model, tx: optax.GradientTransformation, axes, sows,
                 buffers = step_router_bias(
                     buffers, loads, model.layer.router_bias_rate,
                     lambda load: lax.pmean(load, axes))
-        metrics = {"loss": lax.pmean(loss, axes),
-                   "accuracy": lax.pmean(acc, axes)}
+        metrics = {"loss": scalar_mean(loss), "accuracy": scalar_mean(acc)}
         if getattr(model, "num_experts", 0) > 0:
-            metrics["aux_loss"] = lax.pmean(aux, axes)
+            metrics["aux_loss"] = scalar_mean(aux)
         if terms:
             # what the layers sowed, under one key: the loop fetches whatever
             # is there once an epoch (train/loop.py)
-            metrics["layers"] = {k: lax.pmean(v, axes)
-                                 for k, v in terms.items()}
+            metrics["layers"] = {k: scalar_mean(v) for k, v in terms.items()}
         if handed:
             # [layers, this shard's rows of the sown value, ...]: every
             # shard's, in the mesh's order
@@ -391,8 +406,10 @@ def make_lm_train_chain(
     shape — one callable serves the full and the trailing partial chain."""
     tx = _maybe_lora_tx(model, tx)
     axes, sows = _lm_axes(model, data_axis, seq_axis)
-    body = _make_lm_step_body(model, tx, axes, sows, aux_loss_weight,
-                              grad_accum_steps, mtp_weight=mtp_weight,
+    options = data_parallel_compile_options(mesh, axes, model)
+    body = _make_lm_step_body(model, tx, axes, sows, options is not None,
+                              aux_loss_weight, grad_accum_steps,
+                              mtp_weight=mtp_weight,
                               exit_entropy_weight=exit_entropy_weight)
 
     def _chain(state: TrainState, inputs, targets, rng):
@@ -410,7 +427,8 @@ def make_lm_train_chain(
         out_specs=(P(), P()),
         check_vma=False,
     )
-    chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else ())
+    chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else (),
+                    compiler_options=options)
     chain.batch_sharding = NamedSharding(mesh, tok_spec)  # type: ignore[attr-defined]
     chain.super_batch_sharding = NamedSharding(mesh, sup_spec)  # type: ignore[attr-defined]
     chain.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]

@@ -6,8 +6,10 @@ background C++ thread fuses gradient tensors and ring-allreduces them
 (``Part 1 - Distributed Training/03_model_training_distributed.py:302``; stack in
 SURVEY.md §3.3). Here the entire step — forward, backward, gradient ``pmean`` over
 the ``data`` mesh axis, optimizer update — is a single ``shard_map``-ped, jitted XLA
-program: the collective is compiled into the step (no daemon, no fusion buffer; XLA
-overlaps the allreduce with remaining backward compute on its own).
+program: the collective is compiled into the step (no daemon, no fusion buffer). XLA
+does NOT overlap the allreduce with the backward pass on its own: on more than one
+TPU :mod:`ddw_tpu.parallel.collectives` hands it a reduce a leaf and the compiler
+options under which each runs as an asynchronous fusion beneath the backward pass.
 
 Design choices, TPU-first:
 - per-device batch is the loader's per-worker batch; loss/metrics are computed
@@ -360,13 +362,16 @@ def scan_microbatches(model, state: TrainState, im, lb, base_rng):
 
 
 def _dp_step_body(model, tx: optax.GradientTransformation, axis_name: str,
-                  grad_accum_steps: int, state: TrainState, images, labels,
-                  rng):
+                  grad_accum_steps: int, fused: bool, state: TrainState,
+                  images, labels, rng):
     """One optimizer update on a per-device batch slice — the shard_map body
     shared by :func:`make_train_step` (one dispatch per step) and
     :func:`make_train_chain` (``lax.scan``-ned K times inside one program).
     The dropout rng folds the device counter ``state.step``, so a scanned
-    step draws exactly the mask the equivalent host-dispatched step would."""
+    step draws exactly the mask the equivalent host-dispatched step would.
+    ``fused``: the builder's ``jit`` got
+    :func:`~ddw_tpu.parallel.collectives.data_parallel_compile_options`, so
+    the means take ``grad_mean``'s form."""
     me = lax.axis_index(axis_name)
     dropout_rng = jax.random.fold_in(jax.random.fold_in(rng, me), state.step)
     if grad_accum_steps > 1:
@@ -378,14 +383,24 @@ def _dp_step_body(model, tx: optax.GradientTransformation, axis_name: str,
     # THE collective: gradient averaging across the data axis
     # (hvd.DistributedOptimizer role, reference :302).
     with jax.named_scope("grad_sync"):
-        grads = lax.pmean(grads, axis_name)
-        if state.batch_stats:
-            # world-consistent BN statistics
-            new_bs = lax.pmean(new_bs, axis_name)
-        metrics = {
-            "loss": lax.pmean(loss, axis_name),
-            "accuracy": lax.pmean(acc, axis_name),
-        }
+        if fused:
+            # imported here: ddw_tpu.parallel's ZeRO steps import this module
+            from ddw_tpu.parallel.collectives import grad_mean
+
+            # the statistics and the scalar means ride in the flat buffer of
+            # the small gradients: the step holds no synchronous reduce,
+            # which would run inside an asynchronous one's window
+            grads, new_bs, metrics = grad_mean(
+                (grads, new_bs, {"loss": loss, "accuracy": acc}), axis_name)
+        else:
+            grads = lax.pmean(grads, axis_name)
+            if state.batch_stats:
+                # world-consistent BN statistics
+                new_bs = lax.pmean(new_bs, axis_name)
+            metrics = {
+                "loss": lax.pmean(loss, axis_name),
+                "accuracy": lax.pmean(acc, axis_name),
+            }
     return apply_gradients(state, tx, grads, new_bs), metrics
 
 
@@ -426,8 +441,12 @@ def make_train_step(
     arguments: ``step.place_state(state)`` before the first call gives the
     state the placement the step returns it in, and one executable serves.
     """
+    # imported here: ddw_tpu.parallel's ZeRO steps import this module
+    from ddw_tpu.parallel.collectives import data_parallel_compile_options
+
+    options = data_parallel_compile_options(mesh, axis_name, model)
     _step = functools.partial(_dp_step_body, model, tx, axis_name,
-                              grad_accum_steps)
+                              grad_accum_steps, options is not None)
 
     repl = P()
     data_spec = P(axis_name)
@@ -438,7 +457,8 @@ def make_train_step(
         out_specs=(repl, repl),
         check_vma=False,
     )
-    step = jax.jit(smapped, donate_argnums=(0,) if donate else ())
+    step = jax.jit(smapped, donate_argnums=(0,) if donate else (),
+                   compiler_options=options)
     step.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
     return step
 
@@ -469,8 +489,11 @@ def make_train_chain(
     calls (the scanned body folds ``state.step`` into the dropout rng exactly
     as the per-step program does) — pinned by ``tests/test_chain.py``.
     """
+    from ddw_tpu.parallel.collectives import data_parallel_compile_options
+
+    options = data_parallel_compile_options(mesh, axis_name, model)
     body = functools.partial(_dp_step_body, model, tx, axis_name,
-                             grad_accum_steps)
+                             grad_accum_steps, options is not None)
 
     def _chain(state: TrainState, images, labels, rng):
         def scanned(st, xs):
@@ -488,7 +511,8 @@ def make_train_chain(
         out_specs=(repl, repl),
         check_vma=False,
     )
-    chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else ())
+    chain = jax.jit(smapped, donate_argnums=(0, 1, 2) if donate else (),
+                    compiler_options=options)
     chain.place_state = replicated_placer(mesh, donate)  # type: ignore[attr-defined]
     return chain
 
